@@ -10,6 +10,7 @@ import argparse
 import numpy as np
 
 import paddle_tpu as paddle
+from paddle_tpu.core.compile_cache import enable_compile_cache
 from paddle_tpu.distributed import fleet
 from paddle_tpu.models import LlamaConfig, LlamaForCausalLM
 
@@ -23,6 +24,7 @@ def main():
     ap.add_argument("--batch", type=int, default=4)
     ap.add_argument("--seq", type=int, default=128)
     args = ap.parse_args()
+    enable_compile_cache()      # before the first jit
 
     strategy = fleet.DistributedStrategy()
     strategy.hybrid_configs = {"dp_degree": args.dp, "mp_degree": args.mp,

@@ -65,6 +65,7 @@ import argparse
 import numpy as np
 
 import paddle_tpu as paddle
+from paddle_tpu.core.compile_cache import enable_compile_cache
 from paddle_tpu.models import LlamaConfig, LlamaForCausalLM
 from paddle_tpu.serving import Engine, ServingConfig
 
@@ -470,6 +471,7 @@ def stream_demo(model):
 
 
 def main():
+    enable_compile_cache()      # before the first jit
     ap = argparse.ArgumentParser()
     ap.add_argument("--prefix-cache", action="store_true",
                     help="shared-system-prompt workload exercising the "

@@ -68,6 +68,7 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import jax
 import numpy as np
+from jax.extend.core import Literal
 
 from .verifier import ERROR, INFO, WARNING, Diagnostic
 from .hazards import _where_key, sort_diagnostics
@@ -90,6 +91,7 @@ _BARRIERS = {
     "scatter", "scatter_add", "scatter_mul", "scatter_min", "scatter_max",
     "dynamic_update_slice", "sort", "top_k", "copy", "device_put",
     "pure_callback", "io_callback", "outside_call", "debug_callback",
+    "debug_print",
     "rng_bit_generator", "random_seed", "random_wrap", "random_bits",
     "infeed", "outfeed", "custom_call",
 }
@@ -108,12 +110,10 @@ def _source_where(eqn) -> str:
     """``file:line`` of the innermost NON-PLUMBING user frame that
     emitted ``eqn`` (the same location the lint-tpu suppression
     comments key on)."""
-    try:
-        from jax._src import source_info_util
+    # private: jax.extend.source_info_util exposes no frame walker
+    from jax._src import source_info_util
 
-        frames = list(source_info_util.user_frames(eqn.source_info))
-    except Exception:  # pragma: no cover - jax internals moved
-        frames = []
+    frames = list(source_info_util.user_frames(eqn.source_info.traceback))
     frame = None
     for fr in frames:
         if not any(part in fr.file_name for part in _INTERNAL_FRAMES):
@@ -159,7 +159,7 @@ def _inner_interior_bytes(jaxpr) -> float:
     """Bytes of a transparent call body's own intermediates (everything
     its equations define short of the body outputs)."""
     outs = set(v for v in jaxpr.outvars
-               if not isinstance(v, jax.core.Literal))
+               if not isinstance(v, Literal))
     total = 0.0
     for eqn in jaxpr.eqns:
         for inner, _ in _sub_jaxprs(eqn):
@@ -373,7 +373,7 @@ def _mine_level(jaxpr, mul: float, path: str, regions: List[_Region],
 
     free = set(v for v in tuple(jaxpr.invars) + tuple(jaxpr.constvars))
     escaping = set(v for v in jaxpr.outvars
-                   if not isinstance(v, jax.core.Literal))
+                   if not isinstance(v, Literal))
     producer: Dict[Any, int] = {}
     consumers: Dict[Any, List[int]] = {}
     for i, eqn in enumerate(eqns):
@@ -381,7 +381,7 @@ def _mine_level(jaxpr, mul: float, path: str, regions: List[_Region],
             if not isinstance(v, jax.core.DropVar):
                 producer[v] = i
         for v in eqn.invars:
-            if not isinstance(v, jax.core.Literal):
+            if not isinstance(v, Literal):
                 consumers.setdefault(v, []).append(i)
 
     # chain growth to a fixpoint: a fusible producer joins its
@@ -413,7 +413,7 @@ def _mine_level(jaxpr, mul: float, path: str, regions: List[_Region],
         if kind == "anchor":
             weight_anchor[i] = any(
                 v in free for v in eqns[i].invars
-                if not isinstance(v, jax.core.Literal))
+                if not isinstance(v, Literal))
     for root, members in comp_eqns.items():
         mset = set(members)
         interior = 0.0
@@ -439,7 +439,7 @@ def _mine_level(jaxpr, mul: float, path: str, regions: List[_Region],
         seen_in: set = set()
         for i in members:
             for v in eqns[i].invars:
-                if isinstance(v, jax.core.Literal) or v in seen_in:
+                if isinstance(v, Literal) or v in seen_in:
                     continue
                 seen_in.add(v)
                 prod = producer.get(v)

@@ -54,6 +54,7 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import jax
 import numpy as np
+from jax.extend.core import Literal
 
 from .topology import Topology, format_recommendations, rank_layouts
 from .verifier import ERROR, WARNING, Diagnostic
@@ -425,12 +426,12 @@ class _Planner:
     # -- env ---------------------------------------------------------------
 
     def spec_of(self, v) -> ShardSpec:
-        if isinstance(v, jax.core.Literal):
+        if isinstance(v, Literal):
             return _rep(_rank(v))
         return self.env.get(v, _rep(_rank(v)))
 
     def set_spec(self, v, spec: ShardSpec):
-        if isinstance(v, jax.core.Literal):
+        if isinstance(v, Literal):
             return
         self.env[v] = self._drop_indivisible(v, spec)
 
@@ -507,7 +508,7 @@ class _Planner:
             # to the gather path under a live mesh), so outputs
             # replicate and no wire traffic is emitted.
             self._default_specs_only(eqn)
-        elif name in ("cond", "while", "scan", "pjit") or \
+        elif name in ("cond", "while", "scan", "jit") or \
                 "jaxpr" in eqn.params or "call_jaxpr" in eqn.params \
                 or "fun_jaxpr" in eqn.params:
             self._call_like(eqn, mul)
@@ -538,7 +539,7 @@ class _Planner:
             merged: List[Tuple[str, ...]] = [()] * rank
             conflict_axes: set = set()
             for v in eqn.invars:
-                if isinstance(v, jax.core.Literal):
+                if isinstance(v, Literal):
                     continue
                 v_shape = tuple(getattr(v.aval, "shape", None) or ())
                 off = rank - len(v_shape)
@@ -581,7 +582,7 @@ class _Planner:
         dim); anything else replicates."""
         for ov, iv in zip(outer_vars, inner_vars):
             src, dst = (ov, iv) if outer_to_inner else (iv, ov)
-            if isinstance(dst, jax.core.Literal):
+            if isinstance(dst, Literal):
                 continue
             s_shape = tuple(getattr(src.aval, "shape", ()) or ())
             d_shape = tuple(getattr(dst.aval, "shape", ()) or ())
@@ -913,7 +914,7 @@ def _rule_concatenate(pl: _Planner, eqn, mul: float):
     rank = len(out.aval.shape)
     merged: List[Tuple[str, ...]] = [()] * rank
     for v in eqn.invars:
-        if isinstance(v, jax.core.Literal):
+        if isinstance(v, Literal):
             continue
         spec = pl.spec_of(v)
         for d in range(min(rank, len(spec))):
@@ -1160,7 +1161,7 @@ def plan_jaxpr(closed, invar_specs: Sequence[Any], *,
 
     def sharded_bytes(v) -> int:
         b = _var_bytes(v)
-        if isinstance(v, jax.core.Literal) or b == 0:
+        if isinstance(v, Literal) or b == 0:
             return b
         n = _shard_count(pl.spec_of(v), pl.mesh)
         return -(-b // n)  # ceil: padding never under-counts

@@ -57,6 +57,7 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import jax
 import numpy as np
+from jax.extend.core import Literal
 
 from .verifier import ERROR, WARNING, Diagnostic
 
@@ -175,7 +176,7 @@ def _aval_bytes(aval) -> int:
 
 
 def _var_bytes(v) -> int:
-    if isinstance(v, jax.core.Literal):
+    if isinstance(v, Literal):
         return 0  # inlined scalar constants
     return _aval_bytes(v.aval)
 
@@ -188,7 +189,7 @@ def _elems(aval) -> int:
 
 
 # call-like primitives and where their sub-jaxprs live; validated
-# against jax 0.4.x primitive params (pjit carries a ClosedJaxpr,
+# against jax 0.9.0 primitive params (jit carries a ClosedJaxpr,
 # custom_* carry call_jaxpr, scan multiplies by its trip count)
 _TRANSCENDENTAL = {
     "exp", "log", "log1p", "expm1", "tanh", "sin", "cos", "tan",
@@ -208,6 +209,7 @@ _CALLBACKS = {
     "io_callback": ERROR,
     "outside_call": ERROR,
     "debug_callback": WARNING,
+    "debug_print": WARNING,        # what jax.debug.print traces to
 }
 
 
@@ -289,7 +291,7 @@ def _pallas_cost(eqn):
 
     in_avals = [(tuple(v.aval.shape), str(v.aval.dtype))
                 for v in eqn.invars
-                if not isinstance(v, jax.core.Literal)]
+                if not isinstance(v, Literal)]
     out_avals = [(tuple(v.aval.shape), str(v.aval.dtype))
                  for v in eqn.outvars]
     cost = price_eqn_avals(_pallas_kernel_name(eqn), in_avals, out_avals)
@@ -314,7 +316,7 @@ def _eqn_flops(eqn) -> float:
     if name in _MOVEMENT:
         return 0.0
     in_elems = max((_elems(v.aval) for v in eqn.invars
-                    if not isinstance(v, jax.core.Literal)), default=0)
+                    if not isinstance(v, Literal)), default=0)
     out_elems = max((_elems(v.aval) for v in eqn.outvars), default=0)
     if name in ("sort", "top_k"):
         n = max(in_elems, 1)
@@ -416,10 +418,10 @@ def _peak_live_by_dtype(jaxpr, var_bytes=_var_bytes
     last_use: Dict[Any, int] = {}
     for i, eqn in enumerate(jaxpr.eqns):
         for v in eqn.invars:
-            if not isinstance(v, jax.core.Literal):
+            if not isinstance(v, Literal):
                 last_use[v] = i
     for v in jaxpr.outvars:
-        if not isinstance(v, jax.core.Literal):
+        if not isinstance(v, Literal):
             last_use[v] = n  # live through the end
     live: Dict[Any, int] = {}
     by_dtype: Dict[str, int] = {}
@@ -488,7 +490,7 @@ def _peak_live_by_dtype(jaxpr, var_bytes=_var_bytes
             for dt, b in extra_bd.items():
                 snap[dt] = snap.get(dt, 0) + b
         for v in tuple(eqn.invars) + tuple(eqn.outvars):
-            if isinstance(v, jax.core.Literal):
+            if isinstance(v, Literal):
                 continue
             if last_use.get(v, -1) <= i and v in live:
                 current -= _drop(v)
@@ -543,7 +545,7 @@ def _scan_donation(jaxpr, donated: Sequence[bool], min_bytes: int,
     is not the input itself — XLA must keep both alive (double-buffered
     HBM for its full size)."""
     out_pool: List[Any] = [v for v in jaxpr.outvars
-                           if not isinstance(v, jax.core.Literal)]
+                           if not isinstance(v, Literal)]
     for i, v in enumerate(jaxpr.invars):
         if i < len(donated) and donated[i]:
             continue
@@ -703,14 +705,14 @@ def _donated_mask(closed, abstract_args, donate_argnums) -> Tuple[bool, ...]:
                 for j in range(pos, min(pos + leaves, n_in)):
                     mask[j] = True
             pos += leaves
-    # a jitted step traces to ONE pjit eqn that carries the real
+    # a jitted step traces to ONE jit eqn that carries the real
     # donated_invars — trust it over the caller's donate_argnums
     eqns = closed.jaxpr.eqns
-    if len(eqns) == 1 and eqns[0].primitive.name == "pjit":
+    if len(eqns) == 1 and eqns[0].primitive.name == "jit":
         flags = eqns[0].params.get("donated_invars")
         if flags is not None:
             by_var = {v: f for v, f in zip(eqns[0].invars, flags)
-                      if not isinstance(v, jax.core.Literal)}
+                      if not isinstance(v, Literal)}
             mask = [by_var.get(v, False) for v in closed.jaxpr.invars]
     return tuple(mask)
 

@@ -41,7 +41,7 @@ def get_available_custom_device():
 
 def synchronize(device=None):
     """Block until all queued device work finishes."""
-    for d in jax.live_arrays() if hasattr(jax, "live_arrays") else []:
+    for d in jax.live_arrays():
         try:
             d.block_until_ready()
         except Exception:
@@ -79,7 +79,7 @@ def memory_allocated(device=None) -> int:
     if stats:
         return int(stats.get("bytes_in_use", 0))
     total = 0
-    for a in (jax.live_arrays() if hasattr(jax, "live_arrays") else []):
+    for a in jax.live_arrays():
         try:
             total += a.nbytes
         except Exception:

@@ -341,7 +341,7 @@ class _DoneTask:
 def barrier(group=None):
     """Host-level barrier: single controller → trivially passed; multi-host
     uses the TCPStore barrier in distributed.launch."""
-    jax.effects_barrier() if hasattr(jax, "effects_barrier") else None
+    jax.effects_barrier()
     return _DoneTask()
 
 

@@ -9,8 +9,9 @@ GSPMD program per step over a real ``jax.sharding.Mesh``:
 - ``MeshExecutor({"data": 2, "fsdp": 2, "tp": 2})`` builds the mesh —
   from real TPU devices, or on CPU from forced host devices
   (``XLA_FLAGS=--xla_force_host_platform_device_count=8``) so tier-1
-  covers every code path.  When the host has fewer devices than the
-  axes need, it degrades to an all-ones mesh instead of failing.
+  covers every code path.  A host with fewer devices than the axes
+  need is an error: a sharded run that quietly became a one-device run
+  would measure, and test, something else.
 - ``install(model)`` lays out params, optimizer slots (inheriting each
   param's spec, same id-matching as shardplan), batch, and RNG with
   ``NamedSharding``s and arranges for the hapi train step to be jitted
@@ -29,7 +30,6 @@ GSPMD program per step over a real ``jax.sharding.Mesh``:
 from __future__ import annotations
 
 import re
-import warnings
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import jax
@@ -137,19 +137,14 @@ class MeshExecutor:
             raise ValueError(f"invalid mesh axes {axes!r}")
         devs = list(devices) if devices is not None else list(jax.devices())
         need = int(np.prod(sizes))
-        self.degraded = False
         if need > len(devs):
             hint = ""
             if devs and devs[0].platform == "cpu":
                 hint = (" (set XLA_FLAGS=--xla_force_host_platform_"
                         "device_count=N to emulate an N-device host)")
-            warnings.warn(
+            raise ValueError(
                 f"mesh {dict(zip(names, sizes))} needs {need} devices but "
-                f"only {len(devs)} are visible{hint}; degrading to a "
-                f"single-device {dict.fromkeys(names, 1)} mesh")
-            sizes = [1] * len(names)
-            need = 1
-            self.degraded = True
+                f"only {len(devs)} are visible{hint}")
         self.mesh = Mesh(
             np.asarray(devs[:need]).reshape(sizes), tuple(names))
         self.axes: Dict[str, int] = dict(zip(names, sizes))
@@ -577,13 +572,12 @@ class MeshExecutor:
         self._check_plan_topology(plan)
         fn = model._train_step_fn
         sfn = getattr(fn, "_fn", fn)
-        entries = [e for e in sfn._cache.values()
-                   if getattr(e, "_compiled", None) is not None]
-        if not entries:
+        programs = sfn.compiled_programs()
+        if not programs:
             raise RuntimeError(
                 "reconcile_train needs a compiled train step — run at "
                 "least one train batch first")
-        entry = entries[-1]
+        compiled = programs[-1]
         state = sfn._state
         names: Dict[int, str] = {}
         for layer in (sfn._layers or ()):
@@ -612,10 +606,10 @@ class MeshExecutor:
             expect.append((f"slot[{names.get(key, 'global')}]", shape,
                            spec))
         diags = self._reconcile_compiled(
-            plan, entry._compiled, name="hapi::train_step",
+            plan, compiled, name="hapi::train_step",
             trailing_out_expect=expect)
         diags = self._aggregate_process_diags(
-            "hapi::train_step", entry._compiled, diags)
+            "hapi::train_step", compiled, diags)
         self.reports["hapi::train_step"] = (plan, diags)
         return plan, diags
 
@@ -733,5 +727,4 @@ class MeshExecutor:
         self.close()
 
     def __repr__(self) -> str:
-        return (f"MeshExecutor({self.axes}, devices={self.mesh.size}, "
-                f"degraded={self.degraded})")
+        return f"MeshExecutor({self.axes}, devices={self.mesh.size})"

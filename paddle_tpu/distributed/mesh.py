@@ -23,15 +23,10 @@ _GLOBAL_HCG: Optional["HybridCommunicateGroup"] = None
 
 
 def shard_map_compat(fn, mesh, in_specs, out_specs):
-    """jax.shard_map across JAX versions (top-level since 0.4.31+ with
-    check_vma; jax.experimental.shard_map with check_rep before)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map
-
-    return shard_map(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                     check_rep=False)
+    """jax.shard_map with the varying-manual-axes check off (the
+    callers' bodies mix replicated and per-shard values freely)."""
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def init_mesh(axes: Dict[str, int], devices=None) -> Mesh:

@@ -194,11 +194,10 @@ def _load_exported(config: Config):
     from ..jit import load as jit_load
 
     if config._compile_cache_dir:
-        try:
-            jax.config.update("jax_compilation_cache_dir",
-                              config._compile_cache_dir)
-        except Exception:
-            pass
+        from ..core.compile_cache import enable_compile_cache
+
+        # JAX_COMPILATION_CACHE_DIR, where set, wins over the config's
+        enable_compile_cache(config._compile_cache_dir)
     return jit_load(config.prog_file or config._model_dir)
 
 

@@ -169,6 +169,7 @@ class _State:
     def __init__(self, layers, optimizers):
         self.params: List[Tensor] = []
         self.buffers: List[Tensor] = []
+        self._param_order: Dict[int, int] = {}
         seen = set()
         for layer in layers:
             for _, p in layer.named_parameters():
@@ -198,11 +199,20 @@ class _State:
                         self.params.append(q)
 
     def opt_slots(self):
+        # slots are keyed by id(param); walk them in PARAMETER order, not
+        # id order: the order is the compiled program's argument order,
+        # and a program that follows object addresses is a different
+        # program every run — the persistent compile cache never hits
+        if len(self._param_order) != len(self.params):
+            self._param_order = {id(p): i
+                                 for i, p in enumerate(self.params)}
+        order, last = self._param_order, len(self.params)
         slots = []
         for opt in self.optimizers:
             for name in sorted(opt._accumulators):
                 store = opt._accumulators[name]
-                for pid in sorted(store):
+                for pid in sorted(store,
+                                  key=lambda k: (order.get(k, last), k)):
                     slots.append((store, pid))
             for key in sorted(opt._global_state):
                 slots.append((opt._global_state, key))
@@ -443,6 +453,13 @@ class StaticFunction:
         n_in = len(closed.jaxpr.invars)
         donated = tuple(i < min(n_state, n_in) for i in range(n_in))
         return closed, donated
+
+    def compiled_programs(self):
+        """The ``jax.stages.Compiled`` of every cache entry that has
+        run, oldest first — what actually executes, for audits that
+        read its HLO text or ``memory_analysis()``."""
+        return [e._compiled for e in self._cache.values()
+                if e._compiled is not None]
 
     # ----- parity helpers
     @property

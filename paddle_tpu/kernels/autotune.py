@@ -30,18 +30,17 @@ _retune = False
 
 
 def _chip() -> str:
-    """Accelerator kind for the cache key (e.g. ``TPU_v5e`` or
-    ``cpu``) — resolved once; device enumeration is not free."""
+    """Accelerator kind for the cache key (e.g. ``TPU_v5_lite`` or
+    ``cpu``) — resolved once; device enumeration is not free.  A
+    backend that cannot say what it is raises: a winner filed under a
+    made-up chip name would be served to every chip."""
     global _chip_name
     if _chip_name is None:
-        try:
-            import jax
+        import jax
 
-            kind = jax.devices()[0].device_kind
-            _chip_name = str(kind).strip().replace(" ", "_") or \
-                jax.default_backend()
-        except Exception:
-            _chip_name = "unknown"
+        kind = jax.devices()[0].device_kind
+        _chip_name = str(kind).strip().replace(" ", "_") or \
+            jax.default_backend()
     return _chip_name
 
 
@@ -57,8 +56,11 @@ def retune_enabled() -> bool:
 
 
 def _cache_path() -> str:
-    base = os.environ.get("PADDLE_TPU_CACHE_DIR") or os.path.join(
-        os.path.expanduser("~"), ".cache", "paddle_tpu")
+    # next to the compile cache: a home directory does not outlive a
+    # chip call, and tiles without their compiled programs save nothing
+    from ..core.compile_cache import cache_dir
+
+    base = os.environ.get("PADDLE_TPU_CACHE_DIR") or cache_dir()
     return os.path.join(base, "autotune.json")
 
 

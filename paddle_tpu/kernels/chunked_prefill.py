@@ -32,20 +32,22 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
-
-try:
-    from jax.experimental.pallas import tpu as pltpu
-
-    _HAS_PLTPU = True
-except Exception:  # pragma: no cover
-    pltpu = None
-    _HAS_PLTPU = False
+from jax.experimental.pallas import tpu as pltpu
 
 from .costs import KernelCost, register_kernel_cost
 from .kv_quant import decode_codes
 
 KERNEL_NAME = "fused_chunked_prefill"
 NEG_INF = -1e30
+
+
+def _pick_head(page, h):
+    """Head ``h`` of one [bs, KVH, D] f32 page -> [bs, D].  ``h`` is a
+    grid index, and Mosaic takes no dynamic index on a tiled (sublane)
+    dim, so the pick is a mask and a sum over KVH — exact, since every
+    other term is zero."""
+    heads = jax.lax.broadcasted_iota(jnp.int32, page.shape, 1)
+    return jnp.sum(jnp.where(heads == h, page, 0.0), axis=1)
 
 
 def _chunk_kernel(bt_ref, pos_ref, q_ref, k_ref, v_ref, *rest,
@@ -56,6 +58,7 @@ def _chunk_kernel(bt_ref, pos_ref, q_ref, k_ref, v_ref, *rest,
         o_ref, acc_ref, m_ref, l_ref = rest
         ks_ref = vs_ref = None
     b = pl.program_id(0)
+    h = pl.program_id(1)
     p = pl.program_id(2)
 
     @pl.when(p == 0)
@@ -71,13 +74,13 @@ def _chunk_kernel(bt_ref, pos_ref, q_ref, k_ref, v_ref, *rest,
     # the per-row scale multiply rides the same f32 upcast.
     qv = q_ref[0, 0].astype(jnp.float32)                # [RT, D]
     if kv_dtype is not None:
-        kb = decode_codes(k_ref[0, :, 0, :], kv_dtype) * \
-            ks_ref[0][:, None]                          # [bs, D]
-        vb = decode_codes(v_ref[0, :, 0, :], kv_dtype) * \
-            vs_ref[0][:, None]
+        kb = _pick_head(decode_codes(k_ref[0], kv_dtype), h) * \
+            ks_ref[0].T                                 # [bs, D]
+        vb = _pick_head(decode_codes(v_ref[0], kv_dtype), h) * \
+            vs_ref[0].T
     else:
-        kb = k_ref[0, :, 0, :].astype(jnp.float32)      # [bs, D]
-        vb = v_ref[0, :, 0, :].astype(jnp.float32)
+        kb = _pick_head(k_ref[0].astype(jnp.float32), h)    # [bs, D]
+        vb = _pick_head(v_ref[0].astype(jnp.float32), h)
 
     scores = jax.lax.dot_general(
         qv, kb, (((1,), (1,)), ((), ())),
@@ -118,20 +121,25 @@ def _pallas_chunked(q_g, k_pool, v_pool, block_table, positions, chunk,
     in_specs = [
         pl.BlockSpec((1, 1, RT, D),
                      lambda b, h, p, bt, pos: (b, h, 0, 0)),
-        pl.BlockSpec((1, bs, 1, D),
-                     lambda b, h, p, bt, pos: (bt[b, p], 0, h, 0)),
-        pl.BlockSpec((1, bs, 1, D),
-                     lambda b, h, p, bt, pos: (bt[b, p], 0, h, 0)),
+        # the page rides in with ALL its KV heads and the kernel picks
+        # head h: a one-head (1, bs, 1, D) block of the [nb, bs, KVH, D]
+        # pool is not (8, 128)-tileable on its (KVH, D) minor dims
+        pl.BlockSpec((1, bs, KVH, D),
+                     lambda b, h, p, bt, pos: (bt[b, p], 0, 0, 0)),
+        pl.BlockSpec((1, bs, KVH, D),
+                     lambda b, h, p, bt, pos: (bt[b, p], 0, 0, 0)),
     ]
     operands = [q_g, k_pool, v_pool]
     if kv_dtype is not None:
         # per-row scale sidecars ride the same block-table indexing as
         # the pools they describe ([nb, bs] -> one (1, bs) row strip)
         in_specs += [
-            pl.BlockSpec((1, bs), lambda b, h, p, bt, pos: (bt[b, p], 0)),
-            pl.BlockSpec((1, bs), lambda b, h, p, bt, pos: (bt[b, p], 0)),
+            pl.BlockSpec((1, 1, bs),
+                         lambda b, h, p, bt, pos: (bt[b, p], 0, 0)),
+            pl.BlockSpec((1, 1, bs),
+                         lambda b, h, p, bt, pos: (bt[b, p], 0, 0)),
         ]
-        operands += [k_scale, v_scale]
+        operands += [k_scale[:, None, :], v_scale[:, None, :]]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
@@ -147,7 +155,9 @@ def _pallas_chunked(q_g, k_pool, v_pool, block_table, positions, chunk,
     )
     L = nbs * bs
     esize = jnp.dtype(k_pool.dtype).itemsize
-    scale_bytes = 2.0 * B * KVH * L * 4 if kv_dtype is not None else 0.0
+    scale_bytes = 2 * B * KVH * L * 4 if kv_dtype is not None else 0
+    # every one of the KVH head steps DMAs the whole page (see in_specs)
+    kv_bytes = 2 * B * L * KVH * D * esize * KVH
     return pl.pallas_call(
         functools.partial(_chunk_kernel, bs=bs, chunk=chunk,
                           n_pages=nbs, kv_dtype=kv_dtype),
@@ -155,12 +165,11 @@ def _pallas_chunked(q_g, k_pool, v_pool, block_table, positions, chunk,
         out_shape=jax.ShapeDtypeStruct((B, KVH, RT, D), jnp.float32),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"))
-        if (_HAS_PLTPU and not interpret) else None,
+        if not interpret else None,
         cost_estimate=pl.CostEstimate(
-            flops=4.0 * B * KVH * RT * D * L,
-            bytes_accessed=float(2 * B * L * KVH * D * esize)
-            + scale_bytes,
-            transcendentals=float(B * KVH * RT * L)),
+            flops=4 * B * KVH * RT * D * L,
+            bytes_accessed=kv_bytes + scale_bytes,
+            transcendentals=B * KVH * RT * L),
         interpret=interpret,
         name=KERNEL_NAME,
     )(block_table, positions, *operands)
@@ -236,11 +245,11 @@ def fused_chunked_attention(q, k_pool, v_pool, block_table, positions,
     scale = 1.0 / math.sqrt(D)
 
     if use_pallas is None:
-        if pallas_interpret_forced() and _HAS_PLTPU:
+        if pallas_interpret_forced():
             use_pallas, interpret = True, True
         else:
             use_pallas = bool(flag("use_pallas_kernels")) and \
-                jax.default_backend() == "tpu" and _HAS_PLTPU
+                jax.default_backend() == "tpu"
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
 
@@ -283,9 +292,10 @@ def _chunked_prefill_cost(in_avals, out_avals):
         for shape, dt in in_avals[:3])                  # table/pos/q
     # the pools are read THROUGH the block table: B*L rows each, not
     # the whole pool allocation (esize already reflects int8 when the
-    # pool is quantized); per-row f32 scale sidecars ride along per
-    # kv-head grid step when present
-    kv_bytes = 2.0 * B * L * KVH * D * esize
+    # pool is quantized) — but once per kv-head grid step, since a page
+    # rides in with all its heads; per-row f32 scale sidecars ride
+    # along per kv-head grid step when present
+    kv_bytes = 2.0 * B * L * KVH * D * esize * KVH
     if len(in_avals) > 5:
         kv_bytes += 2.0 * B * KVH * L * \
             np.dtype(in_avals[5][1]).itemsize

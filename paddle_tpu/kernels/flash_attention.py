@@ -22,15 +22,11 @@ import math
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-try:  # TPU-only module; import lazily-safe for CPU test runs
-    from jax.experimental.pallas import tpu as pltpu
-
-    _HAS_PLTPU = True
-except Exception:  # pragma: no cover
-    pltpu = None
-    _HAS_PLTPU = False
-
+# pallas_call names: what a compiled program's HLO and a profiler trace
+# show for the forward, dQ and dK/dV kernels
+KERNEL_NAME = "flash_attention"
 DEFAULT_BLOCK_Q = 256
 DEFAULT_BLOCK_K = 512
 NEG_INF = -1e30
@@ -208,8 +204,7 @@ def _flash_fwd_bhtd(q, k, v, causal, scale, block_q, block_k, interpret):
         _fwd_kernel, scale=scale, causal=causal, block_q=bq, block_k=bk,
         offset=Tk - Tq)
     scratch = [
-        pltpu.VMEM((bq, D), jnp.float32) if _HAS_PLTPU and not interpret
-        else pltpu.VMEM((bq, D), jnp.float32),
+        pltpu.VMEM((bq, D), jnp.float32),
         pltpu.VMEM((bq, 1), jnp.float32),
         pltpu.VMEM((bq, 1), jnp.float32),
     ]
@@ -227,7 +222,8 @@ def _flash_fwd_bhtd(q, k, v, causal, scale, block_q, block_k, interpret):
         interpret=interpret,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"))
-        if (_HAS_PLTPU and not interpret) else None,
+        if not interpret else None,
+        name=KERNEL_NAME + "_fwd",
     )(qr, kr, vr)
     return out.reshape(B, H, Tq, D)
 
@@ -275,7 +271,8 @@ def _flash_fwd_lse_bhtd(q, k, v, causal, scale, block_q, block_k, interpret):
         interpret=interpret,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"))
-        if (_HAS_PLTPU and not interpret) else None,
+        if not interpret else None,
+        name=KERNEL_NAME + "_fwd",
     )(qr, kr, vr)
     return out.reshape(B, H, Tq, D), lse[:, :, 0]
 
@@ -314,7 +311,8 @@ def _flash_bwd_bhtd(q, k, v, out, lse, g, causal, scale, block_q, block_k,
         interpret=interpret,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"))
-        if (_HAS_PLTPU and not interpret) else None,
+        if not interpret else None,
+        name=KERNEL_NAME + "_bwd_dq",
     )(qr, kr, vr, gr, lse_l, delta_l)
 
     # dkv: grid over kv blocks, q innermost
@@ -334,7 +332,8 @@ def _flash_bwd_bhtd(q, k, v, out, lse, g, causal, scale, block_q, block_k,
         interpret=interpret,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"))
-        if (_HAS_PLTPU and not interpret) else None,
+        if not interpret else None,
+        name=KERNEL_NAME + "_bwd_dkv",
     )(qr, kr, vr, gr, lse_l, delta_l)
     return (dq.reshape(B, H, Tq, D), dk.reshape(B, H, Tk, D),
             dv.reshape(B, H, Tk, D))
@@ -418,8 +417,6 @@ def flash_attention_bhtd(q, k, v, causal=False, scale=None,
         scale = 1.0 / math.sqrt(q.shape[-1])
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
-    if not _HAS_PLTPU:
-        return _attn_reference(q, k, v, causal, scale)
     if block_q is None or block_k is None:
         # explicit flag override (perf experiments: FLAGS_flash_block_q=…
         # env or set_flags) beats autotune/defaults

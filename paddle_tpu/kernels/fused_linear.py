@@ -17,14 +17,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:
-    from jax.experimental.pallas import tpu as pltpu
-
-    _HAS_PLTPU = True
-except Exception:  # pragma: no cover
-    pltpu = None
-    _HAS_PLTPU = False
+from jax.experimental.pallas import tpu as pltpu
 
 DEFAULT_BM, DEFAULT_BN, DEFAULT_BK = 256, 256, 512
 
@@ -85,7 +78,7 @@ def _fused_linear_fwd(x, w, b, act, bm, bn, bk, interpret):
         interpret=interpret,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"))
-        if (_HAS_PLTPU and not interpret) else None,
+        if not interpret else None,
     )(x, w, b_in)
     return out
 

@@ -26,14 +26,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
-
-try:
-    from jax.experimental.pallas import tpu as pltpu
-
-    _HAS_PLTPU = True
-except Exception:  # pragma: no cover
-    pltpu = None
-    _HAS_PLTPU = False
+from jax.experimental.pallas import tpu as pltpu
 
 from .costs import KernelCost, register_kernel_cost
 from .fused_linear import _ACTS, DEFAULT_BK, DEFAULT_BM, DEFAULT_BN
@@ -104,12 +97,12 @@ def _norm_linear_pallas(x2d, rs, nw, w, act, bm, bn, bk, interpret):
         scratch_shapes=[pltpu.VMEM((bm_, bn_), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"))
-        if (_HAS_PLTPU and not interpret) else None,
+        if not interpret else None,
         cost_estimate=pl.CostEstimate(
-            flops=2.0 * M * N * K,
-            bytes_accessed=float((M * K + K * N + M * N)
-                                 * jnp.dtype(x2d.dtype).itemsize),
-            transcendentals=0.0),
+            flops=2 * M * N * K,
+            bytes_accessed=(M * K + K * N + M * N)
+            * jnp.dtype(x2d.dtype).itemsize,
+            transcendentals=0),
         interpret=interpret,
         name=KERNEL_NAME,
     )(x2d, rs_b, nw_row, w)
@@ -167,13 +160,13 @@ def fused_norm_linear(x, row_scale, norm_weight, w, activation="none",
 
     if activation not in _ACTS:
         raise ValueError(f"unsupported activation {activation!r}")
-    if use_pallas is None and pallas_interpret_forced() and _HAS_PLTPU:
+    if use_pallas is None and pallas_interpret_forced():
         use_pallas, interpret = True, True
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     if use_pallas is None:
         use_pallas = bool(flag("use_pallas_kernels")) and \
-            jax.default_backend() == "tpu" and _HAS_PLTPU
+            jax.default_backend() == "tpu"
     lead = x.shape[:-1]
     K = x.shape[-1]
     x2d = x.reshape(-1, K)

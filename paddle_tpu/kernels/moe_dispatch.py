@@ -19,14 +19,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:
-    from jax.experimental.pallas import tpu as pltpu
-
-    _HAS_PLTPU = True
-except Exception:  # pragma: no cover
-    pltpu = None
-    _HAS_PLTPU = False
+from jax.experimental.pallas import tpu as pltpu
 
 DEFAULT_BT = 256
 DEFAULT_BC = 128
@@ -132,7 +125,7 @@ def _dispatch_raw(tokens, eidx, sidx, weights, E, C, bt, bc, interpret):
         interpret=interpret,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"))
-        if (_HAS_PLTPU and not interpret) else None,
+        if not interpret else None,
     )(tokens, eidx, sidx, weights)
     return out
 
@@ -162,7 +155,7 @@ def _combine_raw(expert_out, eidx, sidx, weights, bt, bj, interpret):
         interpret=interpret,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"))
-        if (_HAS_PLTPU and not interpret) else None,
+        if not interpret else None,
     )(eo, eidx, sidx, weights)
     return out
 

@@ -38,14 +38,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
-
-try:
-    from jax.experimental.pallas import tpu as pltpu
-
-    _HAS_PLTPU = True
-except Exception:  # pragma: no cover
-    pltpu = None
-    _HAS_PLTPU = False
+from jax.experimental.pallas import tpu as pltpu
 
 from .costs import KernelCost, register_kernel_cost
 from .kv_quant import decode_codes, quantize_kv
@@ -134,10 +127,16 @@ def _decode_kernel(bt_ref, pos_ref, q_ref, cos_ref, sin_ref, k_ref, v_ref,
     # in f32, so the wide KV copy never exists in HBM (ISSUE 20)
     kq, vq = k_ref[0], v_ref[0]
     if kv_dtype is not None:
-        kq = decode_codes(kq, kv_dtype) * ks_ref[0][:, None, None]
-        vq = decode_codes(vq, kv_dtype) * vs_ref[0][:, None, None]
-    kb = jnp.swapaxes(kq.astype(jnp.float32), 0, 1)
-    vb = jnp.swapaxes(vq.astype(jnp.float32), 0, 1)
+        # the [1, bs] scale row turns into a [bs, 1] column (Mosaic has
+        # no layout for [bs] -> [bs, 1, 1]) and meets the codes AFTER
+        # the swap: the same products, elementwise
+        kb = jnp.swapaxes(decode_codes(kq, kv_dtype), 0, 1) \
+            * ks_ref[0].T[None]
+        vb = jnp.swapaxes(decode_codes(vq, kv_dtype), 0, 1) \
+            * vs_ref[0].T[None]
+    else:
+        kb = jnp.swapaxes(kq.astype(jnp.float32), 0, 1)
+        vb = jnp.swapaxes(vq.astype(jnp.float32), 0, 1)
 
     scores = jax.lax.dot_general(
         qrot_ref[:], kb, (((2,), (2,)), ((0,), (0,))),
@@ -181,8 +180,10 @@ def _pallas_partials(q_rot_unused, q, cos_b, sin_b, k_pool, v_pool,
     in_specs = [
         pl.BlockSpec((1, KVH, rep, D),
                      lambda b, s, p, bt, pos: (b, 0, 0, 0)),
-        pl.BlockSpec((1, half), lambda b, s, p, bt, pos: (b, 0)),
-        pl.BlockSpec((1, half), lambda b, s, p, bt, pos: (b, 0)),
+        # one row per sequence, as a full-extent (1, half) tile: a bare
+        # (1, half) block of a [B, half] array is not (8, 128)-tileable
+        pl.BlockSpec((1, 1, half), lambda b, s, p, bt, pos: (b, 0, 0)),
+        pl.BlockSpec((1, 1, half), lambda b, s, p, bt, pos: (b, 0, 0)),
         pl.BlockSpec((1, bs, KVH, D),
                      lambda b, s, p, bt, pos, _P=P:
                      (bt[b, s * _P + p], 0, 0, 0)),
@@ -190,17 +191,17 @@ def _pallas_partials(q_rot_unused, q, cos_b, sin_b, k_pool, v_pool,
                      lambda b, s, p, bt, pos, _P=P:
                      (bt[b, s * _P + p], 0, 0, 0)),
     ]
-    operands = [q, cos_b, sin_b, k_pool, v_pool]
+    operands = [q, cos_b[:, None, :], sin_b[:, None, :], k_pool, v_pool]
     if kv_dtype is not None:
         # per-block scale rows ride the SAME block-table index map as
         # their blocks — one [bs] f32 row per DMA'd block
         in_specs += [
-            pl.BlockSpec((1, bs), lambda b, s, p, bt, pos, _P=P:
-                         (bt[b, s * _P + p], 0)),
-            pl.BlockSpec((1, bs), lambda b, s, p, bt, pos, _P=P:
-                         (bt[b, s * _P + p], 0)),
+            pl.BlockSpec((1, 1, bs), lambda b, s, p, bt, pos, _P=P:
+                         (bt[b, s * _P + p], 0, 0)),
+            pl.BlockSpec((1, 1, bs), lambda b, s, p, bt, pos, _P=P:
+                         (bt[b, s * _P + p], 0, 0)),
         ]
-        operands += [k_scale, v_scale]
+        operands += [k_scale[:, None, :], v_scale[:, None, :]]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
@@ -225,7 +226,7 @@ def _pallas_partials(q_rot_unused, q, cos_b, sin_b, k_pool, v_pool,
     H = KVH * rep
     esize = jnp.dtype(k_pool.dtype).itemsize
     # quantized pools also stream one f32 scale per (pool, token) row
-    scale_bytes = 2.0 * B * L * 4 if kv_dtype is not None else 0.0
+    scale_bytes = 2 * B * L * 4 if kv_dtype is not None else 0
     acc, m_b, l_b = pl.pallas_call(
         functools.partial(_decode_kernel, bs=bs, pages_per_split=P,
                           scale=scale, kv_dtype=kv_dtype),
@@ -240,12 +241,11 @@ def _pallas_partials(q_rot_unused, q, cos_b, sin_b, k_pool, v_pool,
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"))
-        if (_HAS_PLTPU and not interpret) else None,
+        if not interpret else None,
         cost_estimate=pl.CostEstimate(
-            flops=4.0 * B * H * D * L,
-            bytes_accessed=float(2 * B * L * KVH * D * esize
-                                 + scale_bytes),
-            transcendentals=float(B * H * L)),
+            flops=4 * B * H * D * L,
+            bytes_accessed=2 * B * L * KVH * D * esize + scale_bytes,
+            transcendentals=B * H * L),
         interpret=interpret,
         name=KERNEL_NAME,
     )(block_table, positions, *operands)
@@ -423,11 +423,11 @@ def fused_paged_decode(q, k_new, v_new, k_pool, v_pool, block_table,
     from .fusion import pallas_interpret_forced
 
     if use_pallas is None:
-        if pallas_interpret_forced() and _HAS_PLTPU:
+        if pallas_interpret_forced():
             use_pallas, interpret = True, True
         else:
             use_pallas = bool(flag("use_pallas_kernels")) and \
-                jax.default_backend() == "tpu" and _HAS_PLTPU
+                jax.default_backend() == "tpu"
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     if num_splits is None:

@@ -17,16 +17,15 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-try:
-    from jax.experimental.pallas import tpu as pltpu
-
-    _HAS_PLTPU = True
-except Exception:  # pragma: no cover
-    pltpu = None
-    _HAS_PLTPU = False
-
+KERNEL_NAME = "fused_rope"
 DEFAULT_BLOCK_T = 256
+# elements per [block_t, H*D] tile: the in and out tiles are
+# double-buffered and the body holds several f32 temporaries of the same
+# extent, all inside 16 MiB of scoped VMEM — 256 rows of 8 KV heads, 128
+# rows of 32 heads at head 128
+_MAX_BLOCK_ELEMS = 1 << 19
 
 
 def _rope_kernel(x_ref, cos_ref, sin_ref, o_ref, *, H, D):
@@ -42,7 +41,7 @@ def _rope_kernel(x_ref, cos_ref, sin_ref, o_ref, *, H, D):
 
 def _rope_fwd(x, cos, sin, block_t, interpret):
     B, T, H, D = x.shape
-    bt = min(block_t, T)
+    bt = min(block_t, T, max(8, _MAX_BLOCK_ELEMS // (H * D) // 8 * 8))
     if T % bt or (H * D) % 128 or D % 2:
         # untileable: plain XLA formula
         c = cos[None, :, None, :]
@@ -64,7 +63,8 @@ def _rope_fwd(x, cos, sin, block_t, interpret):
         interpret=interpret,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel"))
-        if (_HAS_PLTPU and not interpret) else None,
+        if not interpret else None,
+        name=KERNEL_NAME,
     )(xr, cos, sin)
     return out.reshape(B, T, H, D)
 

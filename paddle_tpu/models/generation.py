@@ -205,6 +205,54 @@ def _fingerprint_matches(model, fp):
         r() is p._value for r, p in zip(fp, params))
 
 
+class jit_with_weights:
+    """``jax.jit`` of a step whose model weights ride in as the leading
+    ARGUMENT instead of being closed over.  A closed-over array is baked
+    into the lowered program as a literal: harmless at toy size, but an
+    8B-width layer is 436 MB of literals per program — compile time,
+    the program text, the persistent-cache entry and a second HBM copy
+    per compiled step all scale with it.
+
+    Call signature, ``lower`` and ``_cache_size`` are the jitted raw
+    step's own (minus the weights), so engines, ``warn_on_retrace`` and
+    the analyzers use it as they would the ``jax.jit`` product; traced
+    from outside (``jax.make_jaxpr(step)``) the live weights surface as
+    the trace's top-level consts.  The step builders stack it, as
+    ``functools.partial(jit_with_weights, model)``, over
+    ``register_decode_step`` where ``jax.jit`` used to go."""
+
+    def __init__(self, model, fn):
+        # rebinding a tensor's ``_value`` (optimizer step, load, mesh
+        # placement) is seen by the next call; a NEW parameter is not
+        self._tensors = [t for _, t in model.named_parameters()] + \
+            [t for _, t in model.named_buffers()]
+        functools.update_wrapper(self, fn)
+
+        def with_weights(weights, *args):
+            live = [t._value for t in self._tensors]
+            for t, w in zip(self._tensors, weights):
+                t._value = w
+            try:
+                return fn(*args)
+            finally:
+                for t, v in zip(self._tensors, live):
+                    t._value = v
+
+        self._jitted = jax.jit(with_weights)
+
+    def _weights(self):
+        return [t._value for t in self._tensors]
+
+    def __call__(self, *args):
+        return self._jitted(self._weights(), *args)
+
+    def lower(self, *args):
+        return self._jitted.lower(self._weights(), *args)
+
+    def _cache_size(self):
+        return self._jitted._cache_size()
+
+
 def make_decode_step(model):
     """One jit-compiled single-token decode step over static caches.
 
@@ -231,7 +279,7 @@ def make_decode_step(model):
 
     from ..core.dispatch import no_grad_ctx
 
-    @jax.jit
+    @functools.partial(jit_with_weights, model)
     @functools.partial(register_decode_step, kind="decode")
     def step(tok, caches, offset):
         with no_grad_ctx():
@@ -263,7 +311,7 @@ def make_beam_decode_step(model):
 
     from ..core.dispatch import no_grad_ctx
 
-    @jax.jit
+    @functools.partial(jit_with_weights, model)
     @functools.partial(register_decode_step, kind="beam_decode")
     def step(tok, caches, offset, parents):
         with no_grad_ctx():
@@ -297,7 +345,7 @@ def make_prefill_step(model):
 
     from ..core.dispatch import no_grad_ctx
 
-    @jax.jit
+    @functools.partial(jit_with_weights, model)
     @functools.partial(register_decode_step, kind="prefill")
     def step(ids, caches, last_index):
         with no_grad_ctx():
@@ -386,7 +434,7 @@ def make_paged_decode_step(model, fused=None, kv_cache_dtype=None):
     kind = ("paged_decode_fused" if fused else "paged_decode") \
         + _kv_dtype_suffix(kv_dtype)
 
-    @jax.jit
+    @functools.partial(jit_with_weights, model)
     @functools.partial(register_decode_step, kind=kind)
     def step(tok, pools, block_tables, lengths):
         with no_grad_ctx(), serving_fusion(fused):
@@ -453,7 +501,7 @@ def make_chunked_prefill_step(model, fused=None, kv_cache_dtype=None):
     kind = ("chunked_prefill_fused" if fused else "chunked_prefill") \
         + _kv_dtype_suffix(kv_dtype)
 
-    @jax.jit
+    @functools.partial(jit_with_weights, model)
     @functools.partial(register_decode_step, kind=kind)
     def step(ids, pools, block_table, start, last_index):
         with no_grad_ctx(), serving_fusion(fused):

@@ -90,6 +90,30 @@ class LlamaConfig:
         return cfg
 
 
+def _mesh_live() -> bool:
+    """A device mesh is in force at this trace: the global fleet mesh, a
+    registered ``MeshExecutor``'s, or a manual-mp ``shard_map`` stage.
+    The Pallas kernels have no partitioning rule (Mosaic: "kernels
+    cannot be automatically partitioned"), so under a mesh every kernel
+    site below takes its XLA form until the kernels are wrapped in
+    ``shard_map`` (ROADMAP Speed 5)."""
+    from ..distributed.executor import active_mesh
+    from ..distributed.mesh import get_mesh
+    from ..distributed.parallel_layers import manual_axis
+
+    return get_mesh() is not None or active_mesh() is not None \
+        or manual_axis("mp")[0] is not None
+
+
+def _pallas_kernels_on() -> bool:
+    """The training-path kernels (rms_norm, fused_rope, flash
+    attention): on a TPU, by flag, outside a mesh."""
+    from ..core.flags import flag
+
+    return bool(flag("use_pallas_kernels")) and \
+        jax.default_backend() == "tpu" and not _mesh_live()
+
+
 def precompute_rope(head_dim, max_pos, theta):
     inv_freq = 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32)
                                 / head_dim))
@@ -215,10 +239,8 @@ class LlamaRMSNorm(nn.Layer):
             [hidden_size], default_initializer=I.Constant(1.0))
 
     def forward(self, x):
-        from ..core.flags import flag
-
         def _rms(v, w):
-            if flag("use_pallas_kernels") and jax.default_backend() == "tpu":
+            if _pallas_kernels_on():
                 from ..kernels.rms_norm import rms_norm as pallas_rms
 
                 return pallas_rms(v, w, self._epsilon)
@@ -284,16 +306,13 @@ class LlamaAttention(nn.Layer):
 
         if isinstance(cache, PagedKVCache) and T == 1 \
                 and jnp.ndim(position_offset) == 1 and attn_mask is None:
-            from ..distributed.mesh import get_mesh
-            from ..distributed.parallel_layers import manual_axis
             from ..kernels.fusion import fusion_enabled
 
             # the kernel consumes the whole pool through the block
             # table; under a live mesh (GSPMD sharded pools / manual-mp
             # shard_map) it has no partitioning rule, so serve those
             # from the unfused gather path below
-            if fusion_enabled() and get_mesh() is None \
-                    and manual_axis("mp")[0] is None:
+            if fusion_enabled() and not _mesh_live():
                 # fused decode hot path: RoPE + pool scatter + block
                 # gather + split-K attention in one kernel (XLA
                 # fallback off-TPU) — models/generation.py's paged
@@ -330,12 +349,9 @@ class LlamaAttention(nn.Layer):
                 return self.o_proj(out), new_cache
 
         def _rope_fn(xv):
-            from ..core.flags import flag
-
             # the fused kernel takes a scalar offset; per-sequence vector
             # offsets (continuous-batching decode) use the gather path
-            if flag("use_pallas_kernels") and jax.default_backend() == "tpu" \
-                    and not jnp.ndim(position_offset):
+            if _pallas_kernels_on() and not jnp.ndim(position_offset):
                 from ..kernels.rope import fused_rope
 
                 return fused_rope(xv, cos, sin, position_offset)
@@ -423,14 +439,11 @@ class LlamaAttention(nn.Layer):
                 new_cache = PagedKVCache(k_pool._value, v_pool._value, bt)
 
             if T > 1:
-                from ..distributed.mesh import get_mesh
-                from ..distributed.parallel_layers import manual_axis
                 from ..kernels.fusion import fusion_enabled
 
                 # same mesh caveat as the fused decode intercept: the
                 # kernel reads the whole pool through the block table
-                if fusion_enabled() and get_mesh() is None \
-                        and manual_axis("mp")[0] is None:
+                if fusion_enabled() and not _mesh_live():
                     # fused chunked-prefill hot path: block gather +
                     # causal mask + online softmax + context in one
                     # kernel (XLA fallback off-TPU) — the #1 candidate
@@ -573,12 +586,10 @@ class LlamaAttention(nn.Layer):
             return self.o_proj(out)
 
         def _attn(qv, kv, vv):
-            from ..core.flags import flag
             from ..kernels.flash_attention import (_attn_reference,
                                                    flash_attention_bthd)
 
-            if self.config.use_flash_attention and flag("use_pallas_kernels") \
-                    and jax.default_backend() == "tpu":
+            if self.config.use_flash_attention and _pallas_kernels_on():
                 return flash_attention_bthd(qv, kv, vv, causal=causal)
             # reference path with GQA repeat
             rep = qv.shape[2] // kv.shape[2]
@@ -728,12 +739,7 @@ class LlamaDecoderLayer(nn.Layer):
 
         if not fusion_enabled():
             return False
-        from ..distributed.mesh import get_mesh
-        from ..distributed.parallel_layers import manual_axis
-
-        if get_mesh() is not None or manual_axis("mp")[0] is not None:
-            return False
-        return isinstance(self.mlp, LlamaMLP)
+        return not _mesh_live() and isinstance(self.mlp, LlamaMLP)
 
     def forward(self, hidden, cos, sin, attn_mask=None, cache=None,
                 position_offset=0):
@@ -772,10 +778,19 @@ class LlamaModel(nn.Layer):
     def __init__(self, config: LlamaConfig):
         super().__init__()
         self.config = config
-        self.embed_tokens = VocabParallelEmbedding(config.vocab_size,
-                                                   config.hidden_size)
+        bf16 = config.dtype == "bfloat16"
+
+        def built(layer):
+            # parameters are created in float32; narrowing each part as
+            # it is built (same values as one cast at the end) keeps the
+            # float32 transient to one part — a whole float32 model is
+            # twice what the chip will hold of it
+            return layer.bfloat16() if bf16 else layer
+
+        self.embed_tokens = built(VocabParallelEmbedding(
+            config.vocab_size, config.hidden_size))
         self.layers = nn.LayerList(
-            [LlamaDecoderLayer(config)
+            [built(LlamaDecoderLayer(config))
              for _ in range(config.num_hidden_layers)])
         self.norm = LlamaRMSNorm(config.hidden_size, config.rms_norm_eps)
         head_dim = config.hidden_size // config.num_attention_heads
@@ -783,8 +798,8 @@ class LlamaModel(nn.Layer):
                                    config.rope_theta)
         self.register_buffer("rope_cos", Tensor(cos), persistable=False)
         self.register_buffer("rope_sin", Tensor(sin), persistable=False)
-        if config.dtype == "bfloat16":
-            self.bfloat16()
+        if bf16:
+            self.bfloat16()     # the norm, the rope tables, every _dtype
 
     def forward(self, input_ids, attn_mask=None, caches=None,
                 position_offset=0):

@@ -13,6 +13,7 @@ traced jaxpr plays the Program's role and the converter is in-tree.
 from __future__ import annotations
 
 import numpy as np
+from jax.extend.core import Literal
 
 from . import _pb
 
@@ -368,8 +369,6 @@ class Converter:
         return vi
 
     def _read(self, g, env, var):
-        from jax._src.core import Literal
-
         if isinstance(var, Literal):
             return g.init(np.asarray(var.val), "lit")
         return env[var]
@@ -401,7 +400,7 @@ class Converter:
         pb = self.pb
 
         # --- structural / call primitives ---
-        if prim in ("jit", "pjit", "closed_call", "core_call",
+        if prim in ("jit", "closed_call", "core_call",
                     "custom_vjp_call", "custom_jvp_call", "remat",
                     "checkpoint", "custom_vjp_call_jaxpr", "remat2"):
             closed = p.get("jaxpr") or p.get("call_jaxpr") or \

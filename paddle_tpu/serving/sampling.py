@@ -41,7 +41,7 @@ import numpy as np
 
 from ..core.tensor import Tensor
 from ..models.generation import (_fingerprint_matches, _weights_fingerprint,
-                                 register_decode_step)
+                                 jit_with_weights, register_decode_step)
 
 # key-derivation tags: the draft proposal, acceptance uniform and bonus/
 # residual resample for token index i must be independent of the target
@@ -224,7 +224,7 @@ def make_sampled_decode_step(model, fused=None, kv_cache_dtype=None):
 
     kind = "sampled_decode" + _kv_dtype_suffix(kv_dtype)
 
-    @jax.jit
+    @functools.partial(jit_with_weights, model)
     @functools.partial(register_decode_step, kind=kind)
     def step(tok, pools, block_tables, lengths, temps, top_ks, top_ps,
              keys, counters):

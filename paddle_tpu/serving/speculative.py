@@ -51,7 +51,8 @@ import jax.numpy as jnp
 
 from ..core.tensor import Tensor
 from ..models.generation import (_cache_dims, _fingerprint_matches,
-                                 _weights_fingerprint, register_decode_step)
+                                 _weights_fingerprint, jit_with_weights,
+                                 register_decode_step)
 from .sampling import (ACCEPT_TAG, BONUS_TAG, DRAFT_TAG, filtered_probs,
                        fold_keys, sample_tokens)
 
@@ -120,7 +121,7 @@ def make_draft_propose_step(draft_model, num_draft, fused=None):
         return step
     fp = _weights_fingerprint(draft_model)
 
-    @jax.jit
+    @functools.partial(jit_with_weights, draft_model)
     @functools.partial(register_decode_step, kind="draft_propose")
     def step(tok, pools, block_tables, lengths, temps, top_ks, top_ps,
              keys, counters):
@@ -229,7 +230,7 @@ def make_spec_verify_step(model, num_draft, fused=None):
         return step
     fp = _weights_fingerprint(model)
 
-    @jax.jit
+    @functools.partial(jit_with_weights, model)
     @functools.partial(register_decode_step, kind="spec_verify")
     def step(pending, proposals, draft_probs, pools, block_tables,
              lengths, temps, top_ks, top_ps, keys, counters):

@@ -3,15 +3,13 @@
 zero-copy tensor exchange with other frameworks via the DLPack protocol."""
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
 
 from ..core.tensor import Tensor
 
 
 def to_dlpack(x: Tensor):
-    return jax.dlpack.to_dlpack(x._value) if hasattr(jax.dlpack, "to_dlpack") \
-        else x._value.__dlpack__()
+    return x._value.__dlpack__()
 
 
 class _CapsuleHolder:

@@ -1234,3 +1234,26 @@ class TestBareTensorState:
         x = paddle.to_tensor(np.ones(4, np.float32))
         losses = [float(np.asarray(step(x).numpy())) for _ in range(10)]
         assert losses[-1] < losses[0], losses
+
+
+class TestSlotOrder:
+    def test_opt_slots_follow_parameter_order_not_object_ids(self):
+        """The slot walk is the compiled step's argument order.  By
+        id(param) it followed object addresses, so the same script
+        lowered to a different program each run and the persistent
+        compile cache never hit (seen on the chip, PR 22)."""
+        from paddle_tpu.jit import _State
+
+        first, second = nn.Linear(4, 4), nn.Linear(4, 4)
+        layers = [second, first]        # against creation (and id) order
+        opt = Adam(1e-3, parameters=[p for l in layers
+                                     for p in l.parameters()])
+        x = paddle.to_tensor(np.ones((2, 4), np.float32))
+        (first(x).sum() + second(x).sum()).backward()
+        opt.step()
+        state = _State(layers, [opt])
+        want = [id(p) for p in state.params]
+        for name in ("moment1", "moment2"):
+            store = opt._accumulators[name]
+            got = [k for s, k in state.opt_slots() if s is store]
+            assert got == want

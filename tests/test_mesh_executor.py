@@ -75,16 +75,12 @@ class TestMeshBuild:
         ex = MeshExecutor(AXES)
         assert dict(ex.mesh.shape) == AXES
         assert ex.mesh.size == 8
-        assert not ex.degraded
         ex.close()
 
-    def test_degrades_when_devices_scarce(self):
-        with pytest.warns(UserWarning, match="degrading"):
-            ex = MeshExecutor({"data": 16, "fsdp": 1, "tp": 1})
-        assert ex.degraded
-        assert ex.mesh.size == 1
-        assert ex.axes == {"data": 1, "fsdp": 1, "tp": 1}
-        ex.close()
+    def test_raises_when_devices_scarce(self):
+        with pytest.raises(ValueError, match="needs 16 devices but only 8"):
+            MeshExecutor({"data": 16, "fsdp": 1, "tp": 1})
+        assert ex_mod.current_executor() is None
 
     def test_as_executor_coercions(self):
         ex = MeshExecutor(AXES)

@@ -1,0 +1,185 @@
+"""The main-path Pallas kernels, compiled by the installed TPU compiler for
+a DESCRIBED v5e (no chip attached) at Llama-3-8B widths: 32 heads, 8 KV
+heads, head 128, hidden 4096, MLP 14336, bf16, the serving defaults of
+``block_size`` 16 and ``chunk_tokens`` 256.
+
+Interpret mode cannot see what these catch: a block shape the TPU cannot
+tile, a reshape Mosaic has no layout for, more scoped VMEM than a kernel
+may use.  Each of those stopped a kernel here before it ever reached a
+chip (PR 22).  Nothing runs, so results are the parity tests' business
+(test_kernels, test_fused_serving, test_quantized_serving).
+
+The topology is described inside a module-scoped fixture, never at import:
+only one process may load the TPU library, and every xdist worker imports
+this file.  All such tests stay in this one file for the same reason.
+"""
+import importlib
+import re
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+H, KVH, D, HIDDEN, MLP = 32, 8, 128, 4096, 14336
+SEQ, CHUNK, BATCH = 2048, 256, 8
+NUM_BLOCKS, BLOCK, PAGES = 1024, 16, 128
+bf16, f32, i32, i8 = jnp.bfloat16, jnp.float32, jnp.int32, jnp.int8
+
+
+def _kernel(name):
+    # the package re-exports functions under some of the modules' names
+    return importlib.import_module(f"paddle_tpu.kernels.{name}")
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — whatever keeps libtpu away
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def compile_for_chip(topo):
+    """``compile_for_chip(fn, (shape, dtype), ...)`` -> compiled HLO text
+    for one described chip.  The persistent compile cache is off around
+    these compiles: an entry written for a described chip cannot be read
+    back without one, and only warns."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    was_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+
+    def compile_(fn, *avals):
+        args = [jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+                for shape, dtype in avals]
+        return jax.jit(fn).lower(*args).compile().as_text()
+
+    yield compile_
+    jax.config.update("jax_enable_compilation_cache", was_on)
+    compilation_cache.reset_cache()
+
+
+def _sum_grads(fn, n):
+    """Gradients of ``sum(fn(*args))`` in all ``n`` arguments."""
+    return jax.grad(lambda *a: fn(*a).astype(f32).sum(),
+                    argnums=tuple(range(n)))
+
+
+def test_flash_attention_forward(compile_for_chip):
+    fa = _kernel("flash_attention")
+    q = ((1, H, SEQ, D), bf16)
+    text = compile_for_chip(
+        lambda q, k, v: fa.flash_attention_bhtd(q, k, v, causal=True,
+                                                interpret=False), q, q, q)
+    assert text.count("tpu_custom_call") >= 1
+    assert "flash_attention_fwd" in text
+
+
+def test_flash_attention_backward_with_gqa(compile_for_chip):
+    fa = _kernel("flash_attention")
+    kv = ((1, SEQ, KVH, D), bf16)
+    text = compile_for_chip(
+        _sum_grads(lambda q, k, v: fa.flash_attention_bthd(
+            q, k, v, causal=True, interpret=False), 3),
+        ((1, SEQ, H, D), bf16), kv, kv)
+    for name in ("flash_attention_fwd", "flash_attention_bwd_dq",
+                 "flash_attention_bwd_dkv"):
+        assert name in text
+    assert "tpu_custom_call" in text
+
+
+def test_rms_norm_at_hidden_4096(compile_for_chip):
+    # 512-row blocks at this width were 16.01M of 16.00M scoped VMEM
+    rn = _kernel("rms_norm")
+    text = compile_for_chip(
+        lambda x, w: rn.rms_norm(x, w, 1e-5, interpret=False),
+        ((SEQ, HIDDEN), bf16), ((HIDDEN,), bf16))
+    assert "tpu_custom_call" in text and "rms_norm" in text
+
+
+@pytest.mark.parametrize("heads", [H, KVH])
+def test_fused_rope_forward_and_backward(compile_for_chip, heads):
+    # the backward is the same kernel on the cotangent
+    rope = _kernel("rope")
+    text = compile_for_chip(
+        lambda x, c, s: _sum_grads(
+            lambda x_: rope.fused_rope(x_, c, s, interpret=False), 1)(x),
+        ((1, SEQ, heads, D), bf16), ((SEQ, D // 2), bf16),
+        ((SEQ, D // 2), bf16))
+    assert "tpu_custom_call" in text and "fused_rope" in text
+    # Alone the kernel compiled at 14.3M of the 16M scoped VMEM with
+    # 256-row tiles of 32 heads; inside the whole train step XLA fuses
+    # producers into its operands and the same call needed 21.9M.  Half
+    # the budget is the kernel's to use.
+    used = [int(n) for line in text.splitlines()
+            if "tpu_custom_call" in line and "fused_rope" in line
+            for n in re.findall(r'"used_scoped_memory_configs":\[\{'
+                                r'"memory_space":"1","offset":"0",'
+                                r'"size":"(\d+)"', line)]
+    assert used and max(used) <= 8 << 20
+
+
+@pytest.mark.parametrize("rows", [BATCH, CHUNK])
+def test_fused_norm_linear(compile_for_chip, rows):
+    fnl = _kernel("fused_norm_linear")
+    text = compile_for_chip(
+        lambda x, rs, nw, w: fnl.fused_norm_linear(
+            x, rs, nw, w, activation="silu", use_pallas=True,
+            interpret=False),
+        ((rows, HIDDEN), bf16), ((rows, 1), f32), ((HIDDEN,), bf16),
+        ((HIDDEN, MLP), bf16))
+    assert "tpu_custom_call" in text and "fused_norm_linear" in text
+
+
+_POOLS = [(None, bf16), ("int8", i8), ("fp8", i8)]
+_POOL_IDS = ["bf16", "int8", "fp8"]
+
+
+def _pool_avals(pool_dtype, kv_dtype):
+    pool = ((NUM_BLOCKS, BLOCK, KVH, D), pool_dtype)
+    scales = [((NUM_BLOCKS, BLOCK), f32)] * 2 if kv_dtype else []
+    return [pool, pool], scales
+
+
+@pytest.mark.parametrize("kv_dtype,pool_dtype", _POOLS, ids=_POOL_IDS)
+def test_fused_paged_decode(compile_for_chip, kv_dtype, pool_dtype):
+    pa = _kernel("paged_attention")
+    pools, scales = _pool_avals(pool_dtype, kv_dtype)
+    rope_table = ((SEQ, D // 2), bf16)
+
+    def decode(q, k_new, v_new, kp, vp, table, pos, cos, sin, *sc):
+        ks, vs = sc if sc else (None, None)
+        return pa.fused_paged_decode(
+            q, k_new, v_new, kp, vp, table, pos, cos, sin,
+            use_pallas=True, interpret=False, k_scale=ks, v_scale=vs,
+            kv_cache_dtype=kv_dtype)
+
+    text = compile_for_chip(
+        decode, ((BATCH, 1, H, D), bf16), ((BATCH, 1, KVH, D), bf16),
+        ((BATCH, 1, KVH, D), bf16), *pools, ((BATCH, PAGES), i32),
+        ((BATCH,), i32), rope_table, rope_table, *scales)
+    assert "tpu_custom_call" in text and "fused_paged_decode" in text
+
+
+@pytest.mark.parametrize("kv_dtype,pool_dtype", _POOLS, ids=_POOL_IDS)
+def test_fused_chunked_attention(compile_for_chip, kv_dtype, pool_dtype):
+    cp = _kernel("chunked_prefill")
+    pools, scales = _pool_avals(pool_dtype, kv_dtype)
+
+    def chunk(q, kp, vp, table, pos, *sc):
+        ks, vs = sc if sc else (None, None)
+        return cp.fused_chunked_attention(
+            q, kp, vp, table, pos, use_pallas=True, interpret=False,
+            k_scale=ks, v_scale=vs, kv_cache_dtype=kv_dtype)
+
+    text = compile_for_chip(
+        chunk, ((1, CHUNK, H, D), bf16), *pools, ((1, PAGES), i32),
+        ((1,), i32), *scales)
+    assert "tpu_custom_call" in text and "fused_chunked_prefill" in text
+

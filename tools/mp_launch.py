@@ -8,6 +8,9 @@ PADDLE_TPU_* coordinator triple; the script joins the cluster by calling
 paddle_tpu.distributed.bootstrap.initialize_cluster() (no arguments).
 The first child to die takes the job with it (fleet-controller
 semantics); the launcher's exit code is 0 only if every process exits 0.
+
+CPU-only by design: a chip belongs to one process, and one process drives
+all the chips of a host, so nothing here ever starts a child on a chip.
 """
 import os
 import sys
