@@ -17,6 +17,19 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+try:    # without it the backward only loses its labels in a trace
+    from jax.extend.source_info_util import (current_name_stack,
+                                             set_name_stack)
+except ImportError:
+    current_name_stack = set_name_stack = None
+
+
+def _open_scope():
+    if current_name_stack is None:
+        return None
+    scope = current_name_stack()
+    return scope if scope.stack else None
+
 
 def _zero_cotangent(shape, dtype):
     if jnp.issubdtype(dtype, jnp.inexact):
@@ -39,12 +52,17 @@ class GradNode:
         "input_versions",
         "grad_raw_fn",
         "record_vjp",
+        "scope",
         "__weakref__",
     )
 
     def __init__(self, name: str, vjp_fn):
         self.name = name
         self.vjp_fn = vjp_fn
+        # the forward's ``jax.named_scope`` stack (None outside any):
+        # the backward sweep re-enters it, so that a part's backward
+        # operations carry the part's name in a trace as its forward's do
+        self.scope = _open_scope()
         self.out_avals: List[Tuple[tuple, Any]] = []
         self.single_output = True
         self.pending: Optional[List[Any]] = None
@@ -303,8 +321,12 @@ def run_backward(tensors, grad_tensors=None, retain_graph=False,
                         f"op '{node.name}' does not support create_graph "
                         "(no recordable vjp)")
             else:
-                in_cots = node.vjp_fn(
-                    cots[0] if node.single_output else tuple(cots))
+                cot = cots[0] if node.single_output else tuple(cots)
+                if node.scope is None:
+                    in_cots = node.vjp_fn(cot)
+                else:
+                    with set_name_stack(node.scope):
+                        in_cots = node.vjp_fn(cot)
 
             for (kind, *rest), cot in zip(node.edges, in_cots):
                 if cot is None or _cot_dtype(cot) == jax.dtypes.float0:

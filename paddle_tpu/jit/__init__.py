@@ -561,6 +561,12 @@ class _CompiledEntry:
                 state.write(orig_vals, slots=pre_slots)
             return out_raw, new_state
 
+        # the program takes the wrapped function's name: a profiler trace
+        # and the HLO then read ``jit_train_step``, not ``jit_jax_fn``
+        # for every to_static program
+        name = getattr(fn, "__name__", None)
+        if name:
+            jax_fn.__name__ = jax_fn.__qualname__ = name
         self._jax_fn = jax_fn
         if in_shardings is None:
             self._jitted = jax.jit(jax_fn, donate_argnums=(0,))

@@ -443,18 +443,20 @@ def fused_paged_decode(q, k_new, v_new, k_pool, v_pool, block_table,
     s = sin[positions]
     k_rot = _rotate_half(k_new[:, 0].astype(jnp.float32),
                          c[:, None, :], s[:, None, :]).astype(k_new.dtype)
-    if kv_cache_dtype is not None:
-        new_k_pool, new_k_scale = _scatter_token_quant(
-            k_pool, k_scale, k_rot, block_table, positions,
-            kv_cache_dtype)
-        new_v_pool, new_v_scale = _scatter_token_quant(
-            v_pool, v_scale, v_new[:, 0], block_table, positions,
-            kv_cache_dtype)
-    else:
-        new_k_pool = _scatter_token(k_pool, k_rot, block_table, positions)
-        new_v_pool = _scatter_token(v_pool, v_new[:, 0], block_table,
-                                    positions)
-        new_k_scale = new_v_scale = None
+    with jax.named_scope("kv_write"):
+        if kv_cache_dtype is not None:
+            new_k_pool, new_k_scale = _scatter_token_quant(
+                k_pool, k_scale, k_rot, block_table, positions,
+                kv_cache_dtype)
+            new_v_pool, new_v_scale = _scatter_token_quant(
+                v_pool, v_scale, v_new[:, 0], block_table, positions,
+                kv_cache_dtype)
+        else:
+            new_k_pool = _scatter_token(k_pool, k_rot, block_table,
+                                        positions)
+            new_v_pool = _scatter_token(v_pool, v_new[:, 0], block_table,
+                                        positions)
+            new_k_scale = new_v_scale = None
 
     q_g = q[:, 0].reshape(B, KVH, rep, D)               # GQA grouping
     if use_pallas:

@@ -238,6 +238,10 @@ class jit_with_weights:
                 for t, v in zip(self._tensors, live):
                     t._value = v
 
+        # the program takes the step's name: a profiler trace and the
+        # HLO then read ``jit_paged_decode_step``, not ``jit_with_weights``
+        # for every step of every model
+        with_weights.__name__ = with_weights.__qualname__ = fn.__name__
         self._jitted = jax.jit(with_weights)
 
     def _weights(self):
@@ -281,7 +285,7 @@ def make_decode_step(model):
 
     @functools.partial(jit_with_weights, model)
     @functools.partial(register_decode_step, kind="decode")
-    def step(tok, caches, offset):
+    def decode_step(tok, caches, offset):
         with no_grad_ctx():
             wrapped = [StaticKVCache(k, v) for k, v in caches]
             logits, new_caches = model(Tensor(tok), caches=wrapped,
@@ -289,9 +293,9 @@ def make_decode_step(model):
             return (logits._value[:, -1].astype(jnp.float32),
                     [(c.k, c.v) for c in new_caches])
 
-    model._decode_step = step
+    model._decode_step = decode_step
     model._decode_step_fp = fp
-    return step
+    return decode_step
 
 
 def make_beam_decode_step(model):
@@ -313,7 +317,7 @@ def make_beam_decode_step(model):
 
     @functools.partial(jit_with_weights, model)
     @functools.partial(register_decode_step, kind="beam_decode")
-    def step(tok, caches, offset, parents):
+    def beam_decode_step(tok, caches, offset, parents):
         with no_grad_ctx():
             wrapped = [StaticKVCache(k[parents], v[parents])
                        for k, v in caches]
@@ -322,9 +326,9 @@ def make_beam_decode_step(model):
             return (logits._value[:, -1].astype(jnp.float32),
                     [(c.k, c.v) for c in new_caches])
 
-    model._beam_decode_step = step
+    model._beam_decode_step = beam_decode_step
     model._beam_decode_step_fp = fp
-    return step
+    return beam_decode_step
 
 
 def make_prefill_step(model):
@@ -347,7 +351,7 @@ def make_prefill_step(model):
 
     @functools.partial(jit_with_weights, model)
     @functools.partial(register_decode_step, kind="prefill")
-    def step(ids, caches, last_index):
+    def prefill_step(ids, caches, last_index):
         with no_grad_ctx():
             wrapped = [StaticKVCache(k, v) for k, v in caches]
             logits, new_caches = model(Tensor(ids), caches=wrapped,
@@ -357,9 +361,9 @@ def make_prefill_step(model):
             return (last.astype(jnp.float32),
                     [(c.k, c.v) for c in new_caches])
 
-    model._prefill_step = step
+    model._prefill_step = prefill_step
     model._prefill_step_fp = fp
-    return step
+    return prefill_step
 
 
 def _wrap_paged(pools, block_tables, kv_dtype):
@@ -436,7 +440,7 @@ def make_paged_decode_step(model, fused=None, kv_cache_dtype=None):
 
     @functools.partial(jit_with_weights, model)
     @functools.partial(register_decode_step, kind=kind)
-    def step(tok, pools, block_tables, lengths):
+    def paged_decode_step(tok, pools, block_tables, lengths):
         with no_grad_ctx(), serving_fusion(fused):
             wrapped = _wrap_paged(pools, block_tables, kv_dtype)
             logits, new_caches = model(Tensor(tok), caches=wrapped,
@@ -444,9 +448,9 @@ def make_paged_decode_step(model, fused=None, kv_cache_dtype=None):
             return (logits._value[:, -1].astype(jnp.float32),
                     _unwrap_paged(new_caches, kv_dtype))
 
-    setattr(model, attr, step)
+    setattr(model, attr, paged_decode_step)
     setattr(model, attr + "_fp", fp)
-    return step
+    return paged_decode_step
 
 
 def make_chunked_prefill_step(model, fused=None, kv_cache_dtype=None):
@@ -503,7 +507,7 @@ def make_chunked_prefill_step(model, fused=None, kv_cache_dtype=None):
 
     @functools.partial(jit_with_weights, model)
     @functools.partial(register_decode_step, kind=kind)
-    def step(ids, pools, block_table, start, last_index):
+    def chunked_prefill_step(ids, pools, block_table, start, last_index):
         with no_grad_ctx(), serving_fusion(fused):
             wrapped = _wrap_paged(pools, block_table, kv_dtype)
             valid = (jnp.arange(ids.shape[1]) <= last_index)[None, :]
@@ -516,9 +520,9 @@ def make_chunked_prefill_step(model, fused=None, kv_cache_dtype=None):
             return (last.astype(jnp.float32),
                     _unwrap_paged(new_caches, kv_dtype))
 
-    setattr(model, attr, step)
+    setattr(model, attr, chunked_prefill_step)
     setattr(model, attr + "_fp", fp)
-    return step
+    return chunked_prefill_step
 
 
 def make_moe_block_step(model):

@@ -272,6 +272,10 @@ class LlamaAttention(nn.Layer):
             self.num_heads * self.head_dim, h, has_bias=False,
             input_is_parallel=True)
 
+    def _out(self, out):
+        with jax.named_scope("attn_out"):
+            return self.o_proj(out)
+
     def forward(self, hidden, cos, sin, attn_mask=None, cache=None,
                 position_offset=0, norm_weight=None, norm_eps=None):
         B, T = hidden.shape[0], hidden.shape[1]
@@ -293,16 +297,18 @@ class LlamaAttention(nn.Layer):
                         fused_norm_linear(hv, rs, nw, wk),
                         fused_norm_linear(hv, rs, nw, wv))
 
-            q, k, v = apply("fused_rmsnorm_qkv", _fused_qkv, hidden,
-                            norm_weight, self.q_proj.weight,
-                            self.k_proj.weight, self.v_proj.weight)
+            with jax.named_scope("attn_qkv"):
+                q, k, v = apply("fused_rmsnorm_qkv", _fused_qkv, hidden,
+                                norm_weight, self.q_proj.weight,
+                                self.k_proj.weight, self.v_proj.weight)
             q = q.reshape([B, T, -1, self.head_dim])
             k = k.reshape([B, T, -1, self.head_dim])
             v = v.reshape([B, T, -1, self.head_dim])
         else:
-            q = self.q_proj(hidden).reshape([B, T, -1, self.head_dim])
-            k = self.k_proj(hidden).reshape([B, T, -1, self.head_dim])
-            v = self.v_proj(hidden).reshape([B, T, -1, self.head_dim])
+            with jax.named_scope("attn_qkv"):
+                q = self.q_proj(hidden).reshape([B, T, -1, self.head_dim])
+                k = self.k_proj(hidden).reshape([B, T, -1, self.head_dim])
+                v = self.v_proj(hidden).reshape([B, T, -1, self.head_dim])
 
         if isinstance(cache, PagedKVCache) and T == 1 \
                 and jnp.ndim(position_offset) == 1 and attn_mask is None:
@@ -329,24 +335,28 @@ class LlamaAttention(nn.Layer):
                                               k_scale=ks, v_scale=vs,
                                               kv_cache_dtype=cache.kv_dtype)
 
+                # (rope and the new token's pool write are inside the
+                # fused kernel's wrapper, which scopes the write itself)
                 if cache.kv_dtype is not None:
                     # quantized pools: the kernel scatter-quantizes the
                     # new token's row and returns updated scale sidecars
-                    out, k_pool, v_pool, k_sc, v_sc = apply(
-                        "fused_paged_attention", _fused_decode, q, k, v,
-                        Tensor(cache.k), Tensor(cache.v),
-                        Tensor(cache.k_scale), Tensor(cache.v_scale))
+                    with jax.named_scope("attn"):
+                        out, k_pool, v_pool, k_sc, v_sc = apply(
+                            "fused_paged_attention", _fused_decode, q, k,
+                            v, Tensor(cache.k), Tensor(cache.v),
+                            Tensor(cache.k_scale), Tensor(cache.v_scale))
                     new_cache = PagedKVCache(
                         k_pool._value, v_pool._value, bt,
                         k_sc._value, v_sc._value, kv_dtype=cache.kv_dtype)
                 else:
-                    out, k_pool, v_pool = apply(
-                        "fused_paged_attention", _fused_decode, q, k, v,
-                        Tensor(cache.k), Tensor(cache.v))
+                    with jax.named_scope("attn"):
+                        out, k_pool, v_pool = apply(
+                            "fused_paged_attention", _fused_decode, q, k,
+                            v, Tensor(cache.k), Tensor(cache.v))
                     new_cache = PagedKVCache(k_pool._value, v_pool._value,
                                              bt)
                 out = out.reshape([B, T, -1])
-                return self.o_proj(out), new_cache
+                return self._out(out), new_cache
 
         def _rope_fn(xv):
             # the fused kernel takes a scalar offset; per-sequence vector
@@ -356,8 +366,9 @@ class LlamaAttention(nn.Layer):
 
                 return fused_rope(xv, cos, sin, position_offset)
             return apply_rope(xv, cos, sin, position_offset)
-        q = apply("rope", _rope_fn, q)
-        k = apply("rope", _rope_fn, k)
+        with jax.named_scope("attn_qkv"):
+            q = apply("rope", _rope_fn, q)
+            k = apply("rope", _rope_fn, k)
 
         if isinstance(cache, PagedKVCache):
             # serving decode (T == 1) or a chunked-prefill chunk (T ==
@@ -422,20 +433,22 @@ class LlamaAttention(nn.Layer):
                     return flat.reshape(pool.shape), \
                         sflat.reshape(scales.shape)
 
-                k_pool, k_sc = apply("paged_kv_update_quant", _scatter_q,
-                                     Tensor(cache.k),
-                                     Tensor(cache.k_scale), k)
-                v_pool, v_sc = apply("paged_kv_update_quant", _scatter_q,
-                                     Tensor(cache.v),
-                                     Tensor(cache.v_scale), v)
+                with jax.named_scope("kv_write"):
+                    k_pool, k_sc = apply(
+                        "paged_kv_update_quant", _scatter_q,
+                        Tensor(cache.k), Tensor(cache.k_scale), k)
+                    v_pool, v_sc = apply(
+                        "paged_kv_update_quant", _scatter_q,
+                        Tensor(cache.v), Tensor(cache.v_scale), v)
                 new_cache = PagedKVCache(k_pool._value, v_pool._value,
                                          bt, k_sc._value, v_sc._value,
                                          kv_dtype=cache.kv_dtype)
             else:
-                k_pool = apply("paged_kv_update", _scatter,
-                               Tensor(cache.k), k)
-                v_pool = apply("paged_kv_update", _scatter,
-                               Tensor(cache.v), v)
+                with jax.named_scope("kv_write"):
+                    k_pool = apply("paged_kv_update", _scatter,
+                                   Tensor(cache.k), k)
+                    v_pool = apply("paged_kv_update", _scatter,
+                                   Tensor(cache.v), v)
                 new_cache = PagedKVCache(k_pool._value, v_pool._value, bt)
 
             if T > 1:
@@ -461,10 +474,11 @@ class LlamaAttention(nn.Layer):
                     chunk_args = (q, k_pool, v_pool)
                     if cache.kv_dtype is not None:
                         chunk_args += (k_sc, v_sc)
-                    out = apply("fused_chunked_attention", _fused_chunk,
-                                *chunk_args)
+                    with jax.named_scope("attn"):
+                        out = apply("fused_chunked_attention",
+                                    _fused_chunk, *chunk_args)
                     out = out.reshape([B, T, -1])
-                    return self.o_proj(out), new_cache
+                    return self._out(out), new_cache
 
             def _paged_attn(qv, kp, vp, *scales):
                 # contiguous per-sequence views of the block pool: the
@@ -503,9 +517,10 @@ class LlamaAttention(nn.Layer):
             attn_args = (q, k_pool, v_pool)
             if cache.kv_dtype is not None:
                 attn_args += (k_sc, v_sc)
-            out = apply("paged_attention", _paged_attn, *attn_args)
+            with jax.named_scope("attn"):
+                out = apply("paged_attention", _paged_attn, *attn_args)
             out = out.reshape([B, T, -1])
-            return self.o_proj(out), new_cache
+            return self._out(out), new_cache
 
         if isinstance(cache, StaticKVCache):
             # fixed-size buffer write; one compiled program per decode
@@ -513,8 +528,9 @@ class LlamaAttention(nn.Layer):
                 return jax.lax.dynamic_update_slice(
                     buf, new.astype(buf.dtype), (0, position_offset, 0, 0))
 
-            k_buf = apply("kv_cache_update", _upd, Tensor(cache.k), k)
-            v_buf = apply("kv_cache_update", _upd, Tensor(cache.v), v)
+            with jax.named_scope("kv_write"):
+                k_buf = apply("kv_cache_update", _upd, Tensor(cache.k), k)
+                v_buf = apply("kv_cache_update", _upd, Tensor(cache.v), v)
             new_cache = StaticKVCache(k_buf._value, v_buf._value)
             max_len = cache.k.shape[1]
 
@@ -537,10 +553,11 @@ class LlamaAttention(nn.Layer):
                 probs = jax.nn.softmax(scores, axis=-1).astype(qv.dtype)
                 return jnp.einsum("bhts,bshd->bthd", probs, vb)
 
-            out = apply("static_cache_attention", _static_attn, q, k_buf,
-                        v_buf)
+            with jax.named_scope("attn"):
+                out = apply("static_cache_attention", _static_attn, q,
+                            k_buf, v_buf)
             out = out.reshape([B, T, -1])
-            return self.o_proj(out), new_cache
+            return self._out(out), new_cache
 
         if cache is not None:
             from ..ops.manipulation import concat
@@ -581,9 +598,11 @@ class LlamaAttention(nn.Layer):
                 return ring_attention(qv, kv, vv, causal=causal,
                                       batch_axis=baxis)
 
-            out = apply("context_parallel_attention", _cp_attn, q, k, v)
+            with jax.named_scope("attn"):
+                out = apply("context_parallel_attention", _cp_attn, q, k,
+                            v)
             out = out.reshape([B, T, -1])
-            return self.o_proj(out)
+            return self._out(out)
 
         def _attn(qv, kv, vv):
             from ..kernels.flash_attention import (_attn_reference,
@@ -603,9 +622,10 @@ class LlamaAttention(nn.Layer):
                                   1.0 / math.sqrt(self.head_dim))
             return jnp.swapaxes(out, 1, 2)
 
-        out = apply("attention", _attn, q, k, v)
+        with jax.named_scope("attn"):
+            out = apply("attention", _attn, q, k, v)
         out = out.reshape([B, T, -1])
-        out = self.o_proj(out)
+        out = self._out(out)
         if cache is not None:
             return out, new_cache
         return out
@@ -752,22 +772,25 @@ class LlamaDecoderLayer(nn.Layer):
                     norm_weight=self.input_layernorm.weight,
                     norm_eps=self.input_layernorm._epsilon)
             else:
-                h, new_cache = self.self_attn(
-                    self.input_layernorm(hidden), cos, sin, attn_mask,
-                    cache, position_offset)
+                with jax.named_scope("attn_qkv"):
+                    normed = self.input_layernorm(hidden)
+                h, new_cache = self.self_attn(normed, cos, sin, attn_mask,
+                                              cache, position_offset)
         else:
-            h = self.self_attn(self.input_layernorm(hidden), cos, sin,
-                               attn_mask)
+            with jax.named_scope("attn_qkv"):
+                normed = self.input_layernorm(hidden)
+            h = self.self_attn(normed, cos, sin, attn_mask)
             new_cache = None
         hidden = residual + h
         residual = hidden
-        if fuse_epi:
-            h = self.mlp(
-                hidden,
-                norm_weight=self.post_attention_layernorm.weight,
-                norm_eps=self.post_attention_layernorm._epsilon)
-        else:
-            h = self.mlp(self.post_attention_layernorm(hidden))
+        with jax.named_scope("mlp"):
+            if fuse_epi:
+                h = self.mlp(
+                    hidden,
+                    norm_weight=self.post_attention_layernorm.weight,
+                    norm_eps=self.post_attention_layernorm._epsilon)
+            else:
+                h = self.mlp(self.post_attention_layernorm(hidden))
         hidden = residual + h
         if cache is not None:
             return hidden, new_cache
@@ -803,7 +826,8 @@ class LlamaModel(nn.Layer):
 
     def forward(self, input_ids, attn_mask=None, caches=None,
                 position_offset=0):
-        hidden = self.embed_tokens(input_ids)
+        with jax.named_scope("embed"):
+            hidden = self.embed_tokens(input_ids)
         if self.config.sequence_parallel:
             from ..distributed.sharding import shard_tensor
 
@@ -821,7 +845,8 @@ class LlamaModel(nn.Layer):
                 hidden = recompute(layer, hidden, cos, sin, attn_mask)
             else:
                 hidden = layer(hidden, cos, sin, attn_mask)
-        hidden = self.norm(hidden)
+        with jax.named_scope("final_norm"):
+            hidden = self.norm(hidden)
         if caches is not None:
             return hidden, new_caches
         return hidden
@@ -892,18 +917,21 @@ class LlamaForCausalLM(nn.Layer):
         if labels is not None and self.config.fused_lm_loss:
             w = (self.model.embed_tokens.weight
                  if self.config.tie_word_embeddings else self.lm_head.weight)
-            loss = apply(
-                "fused_causal_lm_loss", _fused_causal_lm_loss, hidden, w,
-                labels, w_is_vocab_major=self.config.tie_word_embeddings,
-                chunk=self.config.lm_loss_chunk)
+            with jax.named_scope("lm_loss"):
+                loss = apply(
+                    "fused_causal_lm_loss", _fused_causal_lm_loss, hidden,
+                    w, labels,
+                    w_is_vocab_major=self.config.tie_word_embeddings,
+                    chunk=self.config.lm_loss_chunk)
             return loss, None
-        if self.config.tie_word_embeddings:
-            def _tied(h, w):
-                return h @ w.T.astype(h.dtype)
-            logits = apply("lm_head_tied", _tied, hidden,
-                           self.model.embed_tokens.weight)
-        else:
-            logits = self.lm_head(hidden)
+        with jax.named_scope("lm_head"):
+            if self.config.tie_word_embeddings:
+                def _tied(h, w):
+                    return h @ w.T.astype(h.dtype)
+                logits = apply("lm_head_tied", _tied, hidden,
+                               self.model.embed_tokens.weight)
+            else:
+                logits = self.lm_head(hidden)
         if labels is not None:
             def _loss(lg, lab):
                 lg = lg[:, :-1].astype(jnp.float32)
@@ -912,7 +940,8 @@ class LlamaForCausalLM(nn.Layer):
                 picked = jnp.take_along_axis(
                     logp, lab[..., None].astype(jnp.int32), axis=-1)[..., 0]
                 return -jnp.mean(picked)
-            loss = apply("causal_lm_loss", _loss, logits, labels)
+            with jax.named_scope("lm_loss"):
+                loss = apply("causal_lm_loss", _loss, logits, labels)
             return loss, logits
         if caches is not None:
             return logits, new_caches

@@ -5,7 +5,8 @@ platform/profiler/ host tracer + CUPTI).
 TPU-native: host ranges recorded with perf_counter_ns (the HostTraceLevel
 analog); device activity comes from jax.profiler (XLA/Xprof) traces.  Export
 keeps the chrome://tracing JSON format the reference emits
-(chrometracing_logger.cc).
+(chrometracing_logger.cc).  A ``RecordEvent`` is also a
+``jax.profiler.TraceAnnotation``: it shows in any XLA trace, session or not.
 """
 from __future__ import annotations
 
@@ -17,9 +18,11 @@ import time
 from enum import Enum
 from typing import Callable, List, Optional
 
+import jax
+
 __all__ = ["Profiler", "ProfilerState", "ProfilerTarget", "RecordEvent",
            "make_scheduler", "export_chrome_tracing", "load_profiler_result",
-           "current_profiler", "record_host_range"]
+           "current_profiler"]
 
 
 class ProfilerState(Enum):
@@ -101,35 +104,46 @@ _active_profiler: Optional["Profiler"] = None
 
 
 def current_profiler() -> Optional["Profiler"]:
-    """The active Profiler session, or None.  External event sources
-    (e.g. serving metrics) use this to emit host ranges only while a
-    session is actually recording."""
+    """The active Profiler session, or None."""
     return _active_profiler
 
 
-def record_host_range(name: str, start_ns: int, end_ns: int,
-                      category: str = "host"):
-    """Record an explicit host range with caller-measured timestamps
-    (perf_counter_ns).  Lands in the active session's chrome trace next
-    to RecordEvent ranges; categories other than "host" stay on the
-    Python buffer so they keep their category at export."""
-    _recorder.record(name, start_ns, end_ns, category=category)
-
-
 class RecordEvent:
-    """Annotated host range (reference: event_tracing.h RecordEvent)."""
+    """Annotated host range (reference: event_tracing.h RecordEvent): the
+    program's one span.
 
-    def __init__(self, name: str, event_type=None):
+    ``begin`` opens a ``jax.profiler.TraceAnnotation``, so the range
+    lands in ANY ``jax.profiler`` trace that is recording, on line
+    ``python3`` of plane ``/host:CPU`` and on the clock of the device
+    planes, with ``metadata`` as the event's stats; while no trace
+    records it costs a few hundred nanoseconds.  The range is also
+    appended to the host recorder, for the chrome export, but only while
+    a :class:`Profiler` session is active: used on a hot path outside a
+    session it buffers nothing.  Keep ``name`` one of a fixed set; what
+    varies (a request id, a size) belongs in ``metadata``."""
+
+    __slots__ = ("name", "_metadata", "_annotation", "_start")
+
+    def __init__(self, name: str, event_type=None, **metadata):
         self.name = name
+        self._metadata = metadata
+        self._annotation = None
         self._start = None
 
     def begin(self):
-        self._start = time.perf_counter_ns()
+        self._annotation = jax.profiler.TraceAnnotation(self.name,
+                                                        **self._metadata)
+        self._annotation.__enter__()
+        if _active_profiler is not None:
+            self._start = time.perf_counter_ns()
 
     def end(self):
         if self._start is not None:
             _recorder.record(self.name, self._start, time.perf_counter_ns())
             self._start = None
+        if self._annotation is not None:
+            self._annotation.__exit__(None, None, None)
+            self._annotation = None
 
     def __enter__(self):
         self.begin()
@@ -247,8 +261,6 @@ class Profiler:
                                ProfilerState.RECORD_AND_RETURN):
             import tempfile
 
-            import jax
-
             self._jax_trace_dir = tempfile.mkdtemp(prefix="paddle_tpu_trace_")
             try:
                 jax.profiler.start_trace(self._jax_trace_dir)
@@ -258,8 +270,6 @@ class Profiler:
 
     def _maybe_stop_device_trace(self):
         if self._jax_trace_dir is not None:
-            import jax
-
             try:
                 jax.profiler.stop_trace()
             except Exception:

@@ -34,15 +34,12 @@ import time
 from dataclasses import dataclass
 from typing import Any, Dict, List, Optional
 
-import contextlib
-
 import numpy as np
 
 from ..models.generation import (_cache_dims, make_chunked_prefill_step,
                                  make_paged_decode_step,
                                  normalize_stop_sequences)
 from ..observability import warn_on_retrace
-from .. import profiler
 from .cache import BlockKVPool, PoolExhausted
 from .metrics import ServingMetrics
 from .overload import EngineQuarantined, OverloadController
@@ -51,15 +48,6 @@ from .scheduler import (FINISHED, PREFILLING, RUNNING, AdmissionError,
                         QueueFull, Request, Scheduler)
 from .speculative import (SpeculativeConfig, make_draft_propose_step,
                           make_spec_verify_step)
-
-
-def _trace(name: str):
-    """Profiler range for the serving hot path — a no-op unless a
-    profiler session is recording (RecordEvent buffers until drained, so
-    unconditional use would grow host memory for the engine's lifetime)."""
-    if profiler.current_profiler() is not None:
-        return profiler.RecordEvent(name)
-    return contextlib.nullcontext()
 
 
 @dataclass
@@ -586,14 +574,26 @@ class Engine:
             raise EngineQuarantined(
                 f"engine quarantined FAILED "
                 f"({self.overload.health.last_error}); revive() first")
-        # one hysteresis step of the memory-pressure ladder BEFORE
-        # admission, so pause_admissions takes effect this iteration
-        self.overload.ladder.tick(self)
-        self._admit()
+        # The phases below (``metrics.phase``: serving::admit,
+        # prefill_dispatch, first_token, decode_prepare, decode_dispatch,
+        # decode_fetch, sample_emit, pool_sync) are flat, never nested,
+        # and together cover the step, so that in a profiler trace every
+        # idle gap of the device has one owner.
+        metrics = self.metrics
+        with metrics.phase("admit"):
+            # one hysteresis step of the memory-pressure ladder BEFORE
+            # admission, so pause_admissions takes effect this iteration
+            self.overload.ladder.tick(self)
+            self._admit()
+        chunks_before = metrics.prefill_chunks_run
         self._prefill_tick()
         if any(r is not None and r.state == RUNNING for r in self._slots):
             self._decode_iteration()
-        self._sync_pool_metrics()
+        with metrics.phase("pool_sync"):
+            self._sync_pool_metrics()
+        metrics.engine_steps += 1
+        if metrics.prefill_chunks_run > chunks_before:
+            metrics.prefill_steps += 1
         return self.has_work()
 
     def has_work(self) -> bool:
@@ -698,8 +698,7 @@ class Engine:
                     from ..resilience import chaos
 
                     chaos.maybe_fail_request(req.request_id)
-                    with _trace(f"serving::prefill:{req.request_id}"):
-                        self._prefill_chunk(req)
+                    self._prefill_chunk(req)
                 except EngineQuarantined:
                     # an ENGINE-level failure (step watchdog out of
                     # retries) is not the request's fault — propagate
@@ -717,11 +716,27 @@ class Engine:
     def _prefill_chunk(self, req: Request):
         """Run ONE [1, chunk_tokens] compiled prefill chunk for ``req``
         at its current prompt position, copy-on-write-protecting every
-        block the chunk writes into."""
+        block the chunk writes into; the prompt's last chunk yields the
+        first token."""
+        start = req.prefill_pos
+        n_tok = min(self.chunk_tokens, req.prompt_len - start)
+        with self.metrics.phase("prefill_dispatch",
+                                request_id=req.request_id, start=start,
+                                tokens=n_tok):
+            last = self._dispatch_chunk(req, start, n_tok)
+        if req.prefill_pos < req.prompt_len:
+            return
+        with self.metrics.phase("first_token", request_id=req.request_id):
+            self._first_token(req, last)
+
+    def _dispatch_chunk(self, req: Request, start: int, n_tok: int):
+        """Phase ``prefill_dispatch``: copy-on-write checks, the chunk's
+        ids, and the call into the chunk program until it returns
+        handles.  Returns the (device) logits row of the chunk's last
+        real token."""
         bs = self.config.block_size
         C = self.chunk_tokens
-        start = req.prefill_pos
-        n_tok = min(C, req.prompt_len - start)
+        self.metrics.on_prefill_dispatch(req.request_id)
         # blocks this chunk writes: CoW any that are shared/registered
         # (a cache hit whose last block the final recompute token lands
         # in, or blocks registered by a previous admission)
@@ -753,12 +768,14 @@ class Engine:
             self._rebind_draft(new_draft)
         req.prefill_pos = start + n_tok
         req.prefill_chunks += 1
-        if req.prefill_pos < req.prompt_len:
-            return
-        # prompt complete: the last chunk's logits row IS the first token
-        # (token index 0 — sampled lanes fold the base key with 0, the
-        # same program generate() runs, so the streams agree from the
-        # very first token)
+        return last
+
+    def _first_token(self, req: Request, last):
+        """Phase ``first_token``: the prompt is complete, and the last
+        chunk's logits row IS the first token (token index 0 — sampled
+        lanes fold the base key with 0, the same program generate()
+        runs, so the streams agree from the very first token).  Reading
+        ``last`` waits for the device."""
         params = req.sampling
         if params is not None:
             first_tok = int(np.asarray(sample_at(
@@ -772,6 +789,7 @@ class Engine:
             first_tok = int(np.argmax(np.asarray(last)[0]))
         req.state = RUNNING
         req.generated = [first_tok]
+        self.metrics.tokens_generated += 1
         slot = req.slot
         self._lengths[slot] = req.prompt_len
         self._pending[slot] = first_tok
@@ -865,7 +883,7 @@ class Engine:
         self.scheduler.running.remove(victim)
         self.pool.free_request(victim.request_id)
         victim.preemptions += 1
-        self.metrics.on_preempt(victim.request_id)
+        self.metrics.on_preempt(victim.request_id, victim.num_generated)
         self._slots[slot] = None
         self._block_tables[slot] = 0
         self._lengths[slot] = 0
@@ -914,44 +932,65 @@ class Engine:
         if self.spec is not None:
             self._spec_iteration()
             return
-        self._ensure_blocks()
-        active = [r for r in self._slots
-                  if r is not None and r.state == RUNNING]
+        active, bt = self._decode_prepare()
         if not active:
             return
-        bt = self._decode_block_view()
         if any(r.sampling is not None for r in active):
             self._sampled_iteration(active, bt)
             return
-        with _trace("serving::decode_step"):
-            # the np.asarray device→host sync happens INSIDE the timed
-            # closure so the watchdog budget covers device execution,
-            # not just dispatch; retries recompute the same pure step
-            # on the unchanged pool (the rebind below is post-success)
-            def _timed_decode(tokens, layers, tables, lengths):
+        phase = self.metrics.phase
+
+        # the np.asarray device→host sync happens INSIDE the timed
+        # closure so the watchdog budget covers device execution, not
+        # just dispatch; retries recompute the same pure step on the
+        # unchanged pool (the rebind below is post-success) and show as
+        # a second dispatch/fetch pair of spans
+        def _timed_decode(tokens, layers, tables, lengths):
+            with phase("decode_dispatch", slots=len(active)):
                 out, pools = self._decode_step(tokens, layers, tables,
                                                lengths)
+            with phase("decode_fetch"):
                 return np.asarray(out), pools
 
-            logits, new_pools = self.overload.decode_watchdog.call(
-                _timed_decode, self._pending[:, None],
-                self._target_pools(), bt, self._lengths)
+        logits, new_pools = self.overload.decode_watchdog.call(
+            _timed_decode, self._pending[:, None],
+            self._target_pools(), bt, self._lengths)
+        with phase("sample_emit"):
             self._rebind_target(new_pools)
+            self._on_decode_iteration(active)
+            for req in active:
+                slot = req.slot
+                # the pending token was written at position lengths[slot]
+                self._lengths[slot] += 1
+                next_tok = int(np.argmax(logits[slot]))
+                self._append_token(req, next_tok)
+                self._pending[slot] = next_tok
+                self._counters[slot] = len(req.generated)
+                if not self._emit_token(req, next_tok):
+                    self._retire(req, "error")
+                    continue
+                self._maybe_retire(req)
+
+    def _decode_prepare(self, horizon: int = 1):
+        """Phase ``decode_prepare``: writable blocks for every running
+        slot's next ``horizon`` positions, then the active requests and
+        the decode view of the block tables."""
+        with self.metrics.phase("decode_prepare"):
+            self._ensure_blocks(horizon)
+            active = [r for r in self._slots
+                      if r is not None and r.state == RUNNING]
+            return active, self._decode_block_view() if active else None
+
+    def _on_decode_iteration(self, active):
+        # every slot that is not running has length 0
+        self.metrics.decode_context_tokens += int(self._lengths.sum())
         self.metrics.on_decode_iteration(
             len(active), self.config.max_batch_size,
             self.pool.utilization())
-        for req in active:
-            slot = req.slot
-            # the pending token was written at position lengths[slot]
-            self._lengths[slot] += 1
-            next_tok = int(np.argmax(logits[slot]))
-            req.generated.append(next_tok)
-            self._pending[slot] = next_tok
-            self._counters[slot] = len(req.generated)
-            if not self._emit_token(req, next_tok):
-                self._retire(req, "error")
-                continue
-            self._maybe_retire(req)
+
+    def _append_token(self, req: Request, tok: int):
+        req.generated.append(tok)
+        self.metrics.tokens_generated += 1
 
     def _sampled_iteration(self, active, bt):
         """One bucket-wide sampled decode step: identical forward pass
@@ -959,33 +998,35 @@ class Engine:
         categorical — runs whenever ANY active slot samples (greedy
         slots ride along on the temperature-0 argmax lane, so the
         bucket stays ONE compiled program with zero retraces)."""
-        with _trace("serving::sampled_decode_step"):
-            def _timed_decode(tokens, layers, tables, lengths, temps,
-                              tks, tps, keys, counters):
+        phase = self.metrics.phase
+
+        def _timed_decode(tokens, layers, tables, lengths, temps,
+                          tks, tps, keys, counters):
+            with phase("decode_dispatch", slots=len(active)):
                 out, pools = self._sampled_decode_step(
                     tokens, layers, tables, lengths, temps, tks, tps,
                     keys, counters)
+            with phase("decode_fetch"):
                 return np.asarray(out), pools
 
-            toks, new_pools = self._sampled_wd.call(
-                _timed_decode, self._pending[:, None],
-                self._target_pools(), bt, self._lengths, self._temps,
-                self._top_ks, self._top_ps, self._keys, self._counters)
+        toks, new_pools = self._sampled_wd.call(
+            _timed_decode, self._pending[:, None],
+            self._target_pools(), bt, self._lengths, self._temps,
+            self._top_ks, self._top_ps, self._keys, self._counters)
+        with phase("sample_emit"):
             self._rebind_target(new_pools)
-        self.metrics.on_decode_iteration(
-            len(active), self.config.max_batch_size,
-            self.pool.utilization())
-        for req in active:
-            slot = req.slot
-            self._lengths[slot] += 1
-            next_tok = int(toks[slot])
-            req.generated.append(next_tok)
-            self._pending[slot] = next_tok
-            self._counters[slot] = len(req.generated)
-            if not self._emit_token(req, next_tok):
-                self._retire(req, "error")
-                continue
-            self._maybe_retire(req)
+            self._on_decode_iteration(active)
+            for req in active:
+                slot = req.slot
+                self._lengths[slot] += 1
+                next_tok = int(toks[slot])
+                self._append_token(req, next_tok)
+                self._pending[slot] = next_tok
+                self._counters[slot] = len(req.generated)
+                if not self._emit_token(req, next_tok):
+                    self._retire(req, "error")
+                    continue
+                self._maybe_retire(req)
 
     def _spec_iteration(self):
         """One speculative iteration: draft-propose (K tokens, one
@@ -996,77 +1037,76 @@ class Engine:
         and accepted lengths sync to host — less per-iteration traffic
         than the greedy step's [S, V] logits."""
         k_draft = self.spec.num_draft_tokens
-        self._ensure_blocks(horizon=k_draft + 1)
-        active = [r for r in self._slots
-                  if r is not None and r.state == RUNNING]
+        active, bt = self._decode_prepare(horizon=k_draft + 1)
         if not active:
             return
-        bt = self._decode_block_view()
-        with _trace("serving::spec_step"):
-            # draft proposals + distributions stay ON DEVICE between the
-            # two steps; the verify closure's np.asarray is the only
-            # host sync of the iteration
-            def _timed_draft(tokens, layers, tables, lengths, temps,
-                             tks, tps, keys, counters):
+        phase = self.metrics.phase
+
+        # draft proposals + distributions stay ON DEVICE between the
+        # two steps; the verify closure's np.asarray is the only host
+        # sync of the iteration
+        def _timed_draft(tokens, layers, tables, lengths, temps,
+                         tks, tps, keys, counters):
+            with phase("decode_dispatch", slots=len(active)):
                 return self._draft_propose_step(
                     tokens, layers, tables, lengths, temps, tks, tps,
                     keys, counters)
 
-            props, dprobs, new_draft = self._draft_propose_wd.call(
-                _timed_draft, self._pending[:, None], self._draft_pools(),
-                bt, self._lengths, self._temps, self._top_ks,
-                self._top_ps, self._keys, self._counters)
-            self._rebind_draft(new_draft)
+        props, dprobs, new_draft = self._draft_propose_wd.call(
+            _timed_draft, self._pending[:, None], self._draft_pools(),
+            bt, self._lengths, self._temps, self._top_ks,
+            self._top_ps, self._keys, self._counters)
+        self._rebind_draft(new_draft)
 
-            def _timed_verify(pending, proposals, probs, layers, tables,
-                              lengths, temps, tks, tps, keys, counters):
+        def _timed_verify(pending, proposals, probs, layers, tables,
+                          lengths, temps, tks, tps, keys, counters):
+            with phase("decode_dispatch", slots=len(active)):
                 committed, accepted, pools = self._spec_verify_step(
                     pending, proposals, probs, layers, tables, lengths,
                     temps, tks, tps, keys, counters)
+            with phase("decode_fetch"):
                 return np.asarray(committed), np.asarray(accepted), pools
 
-            committed, accepted, new_target = \
-                self._spec_verify_wd.call(
-                    _timed_verify, self._pending, props, dprobs,
-                    self._target_pools(), bt, self._lengths, self._temps,
-                    self._top_ks, self._top_ps, self._keys,
-                    self._counters)
+        committed, accepted, new_target = self._spec_verify_wd.call(
+            _timed_verify, self._pending, props, dprobs,
+            self._target_pools(), bt, self._lengths, self._temps,
+            self._top_ks, self._top_ps, self._keys, self._counters)
+        with phase("sample_emit"):
             self._rebind_target(new_target)
-        self.metrics.on_decode_iteration(
-            len(active), self.config.max_batch_size,
-            self.pool.utilization())
-        accepted_drafts = 0
-        for req in active:
-            slot = req.slot
-            n_new = int(accepted[slot])          # 1..K+1 committed tokens
-            accepted_drafts += n_new - 1
-            self.metrics.on_spec_commit(n_new)
-            taken = 0
-            finished = False
-            for tok in committed[slot, :n_new]:
-                tok = int(tok)
-                req.generated.append(tok)
-                taken += 1
-                if not self._emit_token(req, tok):
-                    self._retire(req, "error")
-                    finished = True
-                    break
-                reason = self.scheduler.finish_reason(req)
-                if reason is not None:
-                    # eos / stop / length may land mid-commit: trailing
-                    # committed tokens are DROPPED, matching where
-                    # sequential generate() stops — zero lost, zero
-                    # duplicated (_retire frees every block)
-                    self._retire(req, reason)
-                    finished = True
-                    break
-            if finished:
-                continue
-            self._lengths[slot] += taken
-            self._pending[slot] = int(committed[slot, taken - 1])
-            self._counters[slot] = len(req.generated)
-            self._rollback_blocks(req)
-        self.metrics.on_spec_step(k_draft * len(active), accepted_drafts)
+            self._on_decode_iteration(active)
+            accepted_drafts = 0
+            for req in active:
+                slot = req.slot
+                n_new = int(accepted[slot])      # 1..K+1 committed tokens
+                accepted_drafts += n_new - 1
+                self.metrics.on_spec_commit(n_new)
+                taken = 0
+                finished = False
+                for tok in committed[slot, :n_new]:
+                    tok = int(tok)
+                    self._append_token(req, tok)
+                    taken += 1
+                    if not self._emit_token(req, tok):
+                        self._retire(req, "error")
+                        finished = True
+                        break
+                    reason = self.scheduler.finish_reason(req)
+                    if reason is not None:
+                        # eos / stop / length may land mid-commit:
+                        # trailing committed tokens are DROPPED, matching
+                        # where sequential generate() stops — zero lost,
+                        # zero duplicated (_retire frees every block)
+                        self._retire(req, reason)
+                        finished = True
+                        break
+                if finished:
+                    continue
+                self._lengths[slot] += taken
+                self._pending[slot] = int(committed[slot, taken - 1])
+                self._counters[slot] = len(req.generated)
+                self._rollback_blocks(req)
+            self.metrics.on_spec_step(k_draft * len(active),
+                                      accepted_drafts)
 
     def _rollback_blocks(self, req: Request):
         """Truncate ``req``'s KV back to its accepted frontier: blocks

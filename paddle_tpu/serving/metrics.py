@@ -6,11 +6,13 @@ preemptions), exportable three ways:
 
 - ``as_dict()`` — everything, JSON-ready (the metrics schema in
   README "Serving");
-- live host ranges into an ACTIVE ``paddle_tpu.profiler`` session
-  (request lifecycle spans land in the same chrome trace as the
-  framework's host ranges and the XLA device lanes);
-- ``export_chrome(path)`` — standalone chrome://tracing JSON of the
-  recorded request spans when no profiler session was running;
+- ``phase(name)`` — the spans inside ``Engine.step()``
+  (``serving::admit`` … ``serving::pool_sync``, flat and never nested):
+  each is a ``profiler.RecordEvent``, so it lands in any
+  ``jax.profiler`` trace on the device trace's clock (and in an active
+  ``paddle_tpu.profiler`` session's chrome export), and its host time
+  accumulates into the ``step_ns.<phase>`` counters whether or not
+  anything records;
 - the shared ``paddle_tpu.observability`` registry — every lifecycle
   event is mirrored (``serving_*`` counters/gauges, TTFT/TPOT/queue/e2e
   latency histograms) whenever telemetry is enabled, so serving shows
@@ -21,16 +23,42 @@ unchanged by the registry mirror.
 """
 from __future__ import annotations
 
-import json
 import time
-from dataclasses import dataclass, field
-from typing import Dict, List, Optional
+from dataclasses import dataclass
+from typing import Dict, Optional
 
 from ..observability import registry as _obsreg
+from ..profiler import RecordEvent
+
+_now_ns = time.perf_counter_ns
+
+# the phases of one ``Engine.step()``, in the order they run; a span is
+# named ``serving::<phase>``
+PHASES = ("admit", "prefill_dispatch", "first_token", "decode_prepare",
+          "decode_dispatch", "decode_fetch", "sample_emit", "pool_sync")
+_SPAN_NAMES = {phase: "serving::" + phase for phase in PHASES}
 
 
-def _now_ns() -> int:
-    return time.perf_counter_ns()
+class _Phase:
+    """One open span of ``ServingMetrics.phase``."""
+
+    __slots__ = ("_metrics", "_phase", "_event", "_t0")
+
+    def __init__(self, metrics, phase, metadata):
+        self._metrics = metrics
+        self._phase = phase
+        self._event = RecordEvent(_SPAN_NAMES[phase], **metadata)
+
+    def __enter__(self):
+        self._event.begin()
+        self._t0 = _now_ns()
+        return self
+
+    def __exit__(self, *exc):
+        spent = _now_ns() - self._t0
+        self._event.end()
+        self._metrics.step_ns[self._phase] += spent
+        return False
 
 
 @dataclass
@@ -39,6 +67,8 @@ class RequestTimeline:
 
     submitted_ns: int = 0
     admitted_ns: int = 0          # last admission (re-set on re-admit)
+    first_admitted_ns: int = 0
+    first_chunk_ns: int = 0       # first prefill chunk dispatched
     first_token_ns: int = 0
     finished_ns: int = 0
     tokens_generated: int = 0
@@ -77,9 +107,21 @@ class ServingMetrics:
         self.timed_out = 0          # retired past their deadline_s
         self.failed = 0             # retired with finish_reason "error"
         self.preempted = 0          # preemption EVENTS (re-admits recount)
+        # tokens held by finished and live requests: raised as each
+        # token is emitted, lowered by what a preemption drops
         self.tokens_generated = 0
         self.decode_iterations = 0
         self.prefills = 0
+        # inside Engine.step(): host nanoseconds per phase (``phase()``)
+        # and what the steps did
+        self.step_ns = dict.fromkeys(PHASES, 0)
+        self.engine_steps = 0
+        self.prefill_steps = 0          # steps that ran >= 1 chunk
+        self.prefill_chunks_run = 0     # raised per chunk dispatched
+        self.decode_context_tokens = 0  # sum of active lengths per step
+        self.admissions = 0             # requests admitted a first time
+        self.queue_wait_ns = 0          # first admission - submit
+        self.lane_wait_ns = 0           # first chunk - first admission
         # prefix cache / chunked prefill
         self.prefix_cache_hits = 0      # admissions reusing >= 1 block
         self.prefix_cache_misses = 0    # admissions reusing none
@@ -114,14 +156,28 @@ class ServingMetrics:
         self.last_cache_utilization = 0.0
         # per-request
         self.requests: Dict[str, RequestTimeline] = {}
-        # chrome spans: (name, start_ns, end_ns, category)
-        self._spans: List[tuple] = []
 
     # handles are looked up per event (not cached) so a test calling
     # ``registry.clear()`` never leaves a mirror pointing at dead metrics
     @staticmethod
     def _obs():
         return _obsreg.get_registry() if _obsreg.enabled() else None
+
+    # ------------------------------------------------------ step phases
+    def phase(self, name: str, **metadata) -> _Phase:
+        """Context manager around one phase of ``Engine.step()``
+        (``name`` one of ``PHASES``): the span ``serving::<name>`` with
+        ``metadata`` as its stats (a request id goes there, never into
+        the name), and its host time into ``step_ns[name]``."""
+        return _Phase(self, name, metadata)
+
+    def on_prefill_dispatch(self, request_id: str):
+        """One chunk is about to be dispatched for ``request_id``."""
+        self.prefill_chunks_run += 1
+        t = self.requests[request_id]
+        if t.first_chunk_ns == 0:
+            t.first_chunk_ns = _now_ns()
+            self.lane_wait_ns += t.first_chunk_ns - t.first_admitted_ns
 
     # ------------------------------------------------------- lifecycle
     def on_submit(self, request_id: str):
@@ -145,8 +201,9 @@ class ServingMetrics:
         t.admitted_ns = _now_ns()
         self.prefills += 1
         if was == 0:
-            self._span(f"queued:{request_id}", t.submitted_ns,
-                       t.admitted_ns)
+            t.first_admitted_ns = t.admitted_ns
+            self.admissions += 1
+            self.queue_wait_ns += t.admitted_ns - t.submitted_ns
         reg = self._obs()
         if reg is not None:
             reg.counter("serving_prefills_total", "prefill passes").inc()
@@ -209,8 +266,11 @@ class ServingMetrics:
             reg.counter("serving_prefix_cache_evictions_total",
                         "prefix-cache blocks evicted (LRU)").inc(n)
 
-    def on_preempt(self, request_id: str):
+    def on_preempt(self, request_id: str, dropped_tokens: int = 0):
+        """``dropped_tokens``: what the victim had generated, which the
+        re-admission computes (and emits) again."""
         self.preempted += 1
+        self.tokens_generated -= dropped_tokens
         self.requests[request_id].preemptions += 1
         reg = self._obs()
         if reg is not None:
@@ -225,7 +285,7 @@ class ServingMetrics:
             self.failed += 1
         elif reason == "shed":
             self.shed += 1
-        self.tokens_generated += tokens
+        # (tokens_generated was raised as each of them was emitted)
         # goodput: tokens that were WORTH producing — the request
         # finished inside its SLO (timeouts/sheds/errors contribute 0)
         if reason in ("eos", "stop", "length"):
@@ -234,7 +294,6 @@ class ServingMetrics:
         t.finished_ns = _now_ns()
         t.tokens_generated = tokens
         t.finish_reason = reason
-        self._span(f"decode:{request_id}", t.first_token_ns, t.finished_ns)
         reg = self._obs()
         if reg is not None:
             reg.counter("serving_requests_completed_total",
@@ -392,20 +451,6 @@ class ServingMetrics:
                           cache_utilization)
 
     # --------------------------------------------------------- export
-    def _span(self, name: str, start_ns: int, end_ns: int,
-              category: str = "serving"):
-        if not start_ns or end_ns < start_ns:
-            return
-        self._spans.append((name, start_ns, end_ns, category))
-        # mirror into a live profiler session, if one is recording —
-        # request spans then interleave with the framework's host
-        # ranges and XLA device lanes in ONE chrome trace
-        from .. import profiler
-
-        if profiler.current_profiler() is not None:
-            profiler.record_host_range(name, start_ns, end_ns,
-                                       category=category)
-
     def as_dict(self) -> dict:
         n = max(self._gauge_samples, 1)
         return {
@@ -429,6 +474,16 @@ class ServingMetrics:
                 "step_retries": self.step_retries,
                 "spec_tokens_drafted": self.spec_tokens_drafted,
                 "spec_tokens_accepted": self.spec_tokens_accepted,
+                "engine_steps": self.engine_steps,
+                "prefill_steps": self.prefill_steps,
+                "prefill_chunks_run": self.prefill_chunks_run,
+                "decode_context_tokens": self.decode_context_tokens,
+                "prompt_tokens": self._prompt_tokens_sum,
+                "cached_prompt_tokens": self._cached_tokens_sum,
+                "admissions": self.admissions,
+                "queue_wait_ns": self.queue_wait_ns,
+                "lane_wait_ns": self.lane_wait_ns,
+                **{f"step_ns.{p}": ns for p, ns in self.step_ns.items()},
             },
             "gauges": {
                 "degradation_level": self.degradation_level,
@@ -449,15 +504,3 @@ class ServingMetrics:
             "requests": {rid: t.to_dict()
                          for rid, t in self.requests.items()},
         }
-
-    def export_chrome(self, path: str) -> str:
-        """Standalone chrome://tracing JSON of the request spans (use a
-        live ``paddle_tpu.profiler.Profiler`` session instead to merge
-        them with host/device lanes)."""
-        events = [{"name": name, "cat": cat, "ph": "X",
-                   "ts": start / 1000.0, "dur": (end - start) / 1000.0,
-                   "pid": 0, "tid": 0}
-                  for name, start, end, cat in self._spans]
-        with open(path, "w") as f:
-            json.dump({"traceEvents": events}, f)
-        return path

@@ -226,8 +226,8 @@ def make_sampled_decode_step(model, fused=None, kv_cache_dtype=None):
 
     @functools.partial(jit_with_weights, model)
     @functools.partial(register_decode_step, kind=kind)
-    def step(tok, pools, block_tables, lengths, temps, top_ks, top_ps,
-             keys, counters):
+    def sampled_decode_step(tok, pools, block_tables, lengths, temps,
+                            top_ks, top_ps, keys, counters):
         with no_grad_ctx(), serving_fusion(fused):
             wrapped = _wrap_paged(pools, block_tables, kv_dtype)
             logits, new_caches = model(Tensor(tok), caches=wrapped,
@@ -237,6 +237,6 @@ def make_sampled_decode_step(model, fused=None, kv_cache_dtype=None):
                                  fold_keys(keys, counters))
             return toks, _unwrap_paged(new_caches, kv_dtype)
 
-    setattr(model, attr, step)
+    setattr(model, attr, sampled_decode_step)
     setattr(model, attr + "_fp", fp)
-    return step
+    return sampled_decode_step

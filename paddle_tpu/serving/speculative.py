@@ -123,8 +123,8 @@ def make_draft_propose_step(draft_model, num_draft, fused=None):
 
     @functools.partial(jit_with_weights, draft_model)
     @functools.partial(register_decode_step, kind="draft_propose")
-    def step(tok, pools, block_tables, lengths, temps, top_ks, top_ps,
-             keys, counters):
+    def draft_propose_step(tok, pools, block_tables, lengths, temps,
+                           top_ks, top_ps, keys, counters):
         with no_grad_ctx(), serving_fusion(fused):
             def propose(carry, i):
                 cur, layers = carry
@@ -147,9 +147,9 @@ def make_draft_propose_step(draft_model, num_draft, fused=None):
             return (jnp.transpose(props)[:, :num_draft],
                     jnp.transpose(probs, (1, 0, 2))[:, :num_draft], layers)
 
-    setattr(draft_model, attr, step)
+    setattr(draft_model, attr, draft_propose_step)
     setattr(draft_model, attr + "_fp", fp)
-    return step
+    return draft_propose_step
 
 
 def _spec_acceptance(lg, proposals, draft_probs, temps, top_ks, top_ps,
@@ -232,8 +232,9 @@ def make_spec_verify_step(model, num_draft, fused=None):
 
     @functools.partial(jit_with_weights, model)
     @functools.partial(register_decode_step, kind="spec_verify")
-    def step(pending, proposals, draft_probs, pools, block_tables,
-             lengths, temps, top_ks, top_ps, keys, counters):
+    def spec_verify_step(pending, proposals, draft_probs, pools,
+                         block_tables, lengths, temps, top_ks, top_ps,
+                         keys, counters):
         with no_grad_ctx(), serving_fusion(fused):
             ids = jnp.concatenate(
                 [pending[:, None], proposals.astype(pending.dtype)],
@@ -247,6 +248,6 @@ def make_spec_verify_step(model, num_draft, fused=None):
                 keys, counters)
             return committed, accepted, [(c.k, c.v) for c in new_caches]
 
-    setattr(model, attr, step)
+    setattr(model, attr, spec_verify_step)
     setattr(model, attr + "_fp", fp)
-    return step
+    return spec_verify_step

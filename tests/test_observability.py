@@ -395,7 +395,14 @@ class TestServingMirror:
         "prefix_cache_hits", "prefix_cache_misses",
         "prefix_cache_evictions", "prefill_chunks",
         "watchdog_stalls", "step_retries",
-        "spec_tokens_drafted", "spec_tokens_accepted"}
+        "spec_tokens_drafted", "spec_tokens_accepted",
+        # inside Engine.step() (ISSUE 26)
+        "engine_steps", "prefill_steps", "prefill_chunks_run",
+        "decode_context_tokens", "prompt_tokens", "cached_prompt_tokens",
+        "admissions", "queue_wait_ns", "lane_wait_ns",
+    } | {f"step_ns.{phase}" for phase in (
+        "admit", "prefill_dispatch", "first_token", "decode_prepare",
+        "decode_dispatch", "decode_fetch", "sample_emit", "pool_sync")}
     _CONTRACT_GAUGES = {
         "batch_occupancy", "batch_occupancy_avg",
         "cache_utilization", "cache_utilization_avg",

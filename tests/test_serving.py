@@ -305,17 +305,6 @@ class TestMetrics:
             assert len(h) == 32                         # blake2b-128
         assert set(idx["roots"]) <= set(hashes)
 
-    def test_chrome_export(self, model, tmp_path):
-        import json
-
-        eng = Engine(model, _config())
-        eng.generate(_prompts([3]), max_new_tokens=3)
-        path = eng.metrics.export_chrome(str(tmp_path / "trace.json"))
-        events = json.load(open(path))["traceEvents"]
-        names = {e["name"] for e in events}
-        assert any(n.startswith("decode:") for n in names)
-        assert all(e["ph"] == "X" and e["dur"] >= 0 for e in events)
-
 
 class TestEndpoint:
     def test_predictor_parity_handles(self, model):
