@@ -4,18 +4,29 @@ The serving decode hot path was four separate HBM round trips per
 layer: rotate q/k (RoPE), scatter the new k/v into the block pool,
 gather every sequence's blocks back out, then run masked softmax
 attention over the gathered copy.  This module fuses the gather + q
-RoPE + attention into ONE Pallas kernel: the block table rides in as a
-scalar-prefetch operand, so each grid step DMAs exactly one KV block
-straight from the pool — the gathered [B, L, H, D] context copy never
-exists in HBM.
+RoPE + attention into ONE Pallas kernel that reads the pages straight
+from the pool — the gathered [B, L, H, D] context copy never exists in
+HBM.
 
-Flash-decoding split-K: the context pages are divided into
-``num_splits`` independent chunks.  Each (batch, split) cell produces
-an UNNORMALIZED partial — running max ``m``, exp-sum ``l`` and
-accumulator ``acc`` — and the chunks are combined afterwards with the
-standard log-sum-exp merge.  Splits are parallel grid cells, so one
-128k-context straggler occupies ``num_splits`` cells instead of
-serializing its whole context behind everyone else's decode step.
+The walk follows the live context, not the table's width.  The pools
+stay in HBM (``memory_space=pl.ANY``); the block table and the lengths
+ride in as scalar-prefetch operands.  Sequence ``b`` has
+``positions[b] // bs + 1`` live pages (keys at ``k_pos <=
+positions[b]``, the new token included; an idle slot has one), and
+nothing past them is read: not the table entry, not the page.  Pages
+are fetched with ``pltpu.make_async_copy``, a COMPUTE BLOCK of ``G =
+max(1, 128 // bs)`` pages at a time, into a double-buffered VMEM
+scratch: one online-softmax update a compute block, the next block's
+copies in flight under it, ``ceil(live_pages / G)`` turns of a
+``fori_loop`` whose bound is read from ``positions``.
+
+Flash-decoding split-K: the table's pages are divided into
+``num_splits`` independent chunks.  Each (batch, split) grid cell
+walks the live pages of its chunk and produces an UNNORMALIZED partial
+— running max ``m``, exp-sum ``l`` and accumulator ``acc`` — and the
+chunks are combined afterwards with the standard log-sum-exp merge.  A
+chunk with no live page emits ``(NEG_INF, 0, 0)``, which the merge
+weighs to zero, so every ``num_splits`` gives the same answer.
 
 Numerics contract: ``_xla_partials`` + ``_combine_splits`` is the
 SAME split-K math in plain XLA ops (identical masking semantics, f32
@@ -92,136 +103,193 @@ def _scatter_token_quant(pool, scales, new, block_table, positions,
 # split-K partials: Pallas kernel
 # ---------------------------------------------------------------------------
 
-def _decode_kernel(bt_ref, pos_ref, q_ref, cos_ref, sin_ref, k_ref, v_ref,
+def _decode_kernel(bt_ref, pos_ref, q_ref, cos_ref, sin_ref, k_hbm, v_hbm,
                    *rest, bs, pages_per_split, scale, kv_dtype=None):
-    # quantized pools carry two extra per-block scale operands between
-    # the KV refs and the outputs (same scalar-prefetch index map, so
-    # each grid step DMAs its block's [bs] scale row alongside the
-    # block itself)
+    # the pools (and a quantized pool's scale rows) stay in HBM; each
+    # stream has a [2, G, page] VMEM buffer and a DMA semaphore a slot
     if kv_dtype is not None:
-        (ks_ref, vs_ref, o_ref, m_out_ref, l_out_ref,
-         qrot_ref, acc_ref, m_ref, l_ref) = rest
+        (ks_hbm, vs_hbm, o_ref, m_out_ref, l_out_ref,
+         k_buf, v_buf, ks_buf, vs_buf, sems) = rest
+        streams = ((k_hbm, k_buf), (v_hbm, v_buf),
+                   (ks_hbm, ks_buf), (vs_hbm, vs_buf))
     else:
-        (o_ref, m_out_ref, l_out_ref,
-         qrot_ref, acc_ref, m_ref, l_ref) = rest
-        ks_ref = vs_ref = None
+        o_ref, m_out_ref, l_out_ref, k_buf, v_buf, sems = rest
+        ks_buf = vs_buf = None
+        streams = ((k_hbm, k_buf), (v_hbm, v_buf))
+    G = k_buf.shape[1]
     b = pl.program_id(0)
     s = pl.program_id(1)
-    p = pl.program_id(2)
 
-    @pl.when(p == 0)
-    def _init():
-        # rotate + pre-scale q once per (batch, split) cell: RoPE lives
-        # inside the kernel, and folding 1/sqrt(D) into q here keeps the
-        # score math a bare dot
-        qv = q_ref[0].astype(jnp.float32)               # [KVH, rep, D]
-        c = cos_ref[0].astype(jnp.float32)              # [half]
-        sn = sin_ref[0].astype(jnp.float32)
-        qrot_ref[:] = _rotate_half(qv, c, sn) * scale
-        acc_ref[:] = jnp.zeros_like(acc_ref)
-        m_ref[:] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[:] = jnp.zeros_like(l_ref)
+    # this cell's pages: its split of the table, cut at the sequence's
+    # live pages (keys at k_pos <= pos, the new token included).  The
+    # walk never reads a table entry, or fetches a page, past them.
+    pos = pos_ref[b]
+    live = jnp.minimum(pos // bs + 1, bt_ref.shape[1])
+    lo = s * pages_per_split
+    hi = jnp.minimum(lo + pages_per_split, live)
+    num_blocks = jnp.maximum(hi - lo + G - 1, 0) // G
+    key_limit = jnp.minimum(pos + 1, (lo + pages_per_split) * bs)
 
-    # one gathered KV block: [bs, KVH, D] -> [KVH, bs, D].  Quantized
-    # pools dequant HERE, at the DMA boundary — codes * per-row scale
-    # in f32, so the wide KV copy never exists in HBM (ISSUE 20)
-    kq, vq = k_ref[0], v_ref[0]
-    if kv_dtype is not None:
-        # the [1, bs] scale row turns into a [bs, 1] column (Mosaic has
-        # no layout for [bs] -> [bs, 1, 1]) and meets the codes AFTER
-        # the swap: the same products, elementwise
-        kb = jnp.swapaxes(decode_codes(kq, kv_dtype), 0, 1) \
-            * ks_ref[0].T[None]
-        vb = jnp.swapaxes(decode_codes(vq, kv_dtype), 0, 1) \
-            * vs_ref[0].T[None]
-    else:
-        kb = jnp.swapaxes(kq.astype(jnp.float32), 0, 1)
-        vb = jnp.swapaxes(vq.astype(jnp.float32), 0, 1)
+    def page_copies(slot, g, block):
+        return [pltpu.make_async_copy(hbm.at[block], buf.at[slot, g],
+                                      sems.at[i, slot])
+                for i, (hbm, buf) in enumerate(streams)]
 
-    scores = jax.lax.dot_general(
-        qrot_ref[:], kb, (((2,), (2,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32)             # [KVH, rep, bs]
+    def live_in_block(j):
+        return jnp.minimum(hi - (lo + j * G), G)
 
-    page = s * pages_per_split + p                      # logical page
-    k_pos = page * bs + jax.lax.broadcasted_iota(
-        jnp.int32, scores.shape, 2)
-    scores = jnp.where(k_pos <= pos_ref[b], scores, NEG_INF)
+    def fetch(j, slot):
+        def start(g, _):
+            for copy in page_copies(slot, g, bt_ref[b, lo + j * G + g]):
+                copy.start()
 
-    m_cur = jnp.max(scores, axis=-1, keepdims=True)     # [KVH, rep, 1]
-    m_new = jnp.maximum(m_ref[:], m_cur)
-    alpha = jnp.exp(m_ref[:] - m_new)
-    pexp = jnp.exp(scores - m_new)
-    acc_ref[:] = acc_ref[:] * alpha + jax.lax.dot_general(
-        pexp, vb, (((2,), (1,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32)             # [KVH, rep, D]
-    l_ref[:] = l_ref[:] * alpha + jnp.sum(pexp, axis=-1, keepdims=True)
-    m_ref[:] = m_new
+        # a dead page of the last compute block: its keys are masked
+        # below, and zeroed values keep 0 * stale from being a NaN
+        def zero(g, _):
+            v_buf[slot, g] = jnp.zeros(v_buf.shape[2:], v_buf.dtype)
+            if vs_buf is not None:
+                vs_buf[slot, g] = jnp.zeros(vs_buf.shape[2:], vs_buf.dtype)
 
-    @pl.when(p == pages_per_split - 1)
-    def _emit():
-        o_ref[0, 0] = acc_ref[:]
-        # per-row scalars broadcast over the lane dim (flash kernel lse
-        # idiom: a 1-wide trailing dim is not a legal TPU output tile)
-        m_out_ref[0, 0] = jnp.broadcast_to(m_ref[:], m_out_ref.shape[2:])
-        l_out_ref[0, 0] = jnp.broadcast_to(l_ref[:], l_out_ref.shape[2:])
+        n = live_in_block(j)
+        jax.lax.fori_loop(0, n, start, None)
+        jax.lax.fori_loop(n, G, zero, None)
+
+    def wait(j, slot):
+        def wait_page(g, _):
+            # a wait needs the copy's shape only, not its source
+            for copy in page_copies(slot, g, 0):
+                copy.wait()
+
+        jax.lax.fori_loop(0, live_in_block(j), wait_page, None)
+
+    def keys_major(buf, scale_buf, slot):
+        """One compute block as f32 [KVH, G * bs, D].  A quantized pool
+        dequantizes HERE, at the DMA boundary: codes * per-row scale, so
+        the wide KV copy never exists in HBM (ISSUE 20)."""
+        if scale_buf is None:
+            x = buf[slot].reshape(G * bs, *buf.shape[3:])
+            return jnp.swapaxes(x.astype(jnp.float32), 0, 1)
+        # a page's [1, bs] scale row turns into a [bs, 1] column (Mosaic
+        # has no layout for [bs] -> [bs, 1, 1]) and meets the codes
+        # AFTER the swap: the same products, elementwise
+        return jnp.concatenate(
+            [jnp.swapaxes(decode_codes(buf[slot, g], kv_dtype), 0, 1)
+             * scale_buf[slot, g][:, :bs].T[None] for g in range(G)],
+            axis=1)
+
+    # rotate + pre-scale q once per (batch, split) cell: RoPE lives
+    # inside the kernel, and folding 1/sqrt(D) into q here keeps the
+    # score math a bare dot
+    q_rot = _rotate_half(q_ref[0].astype(jnp.float32),      # [KVH,rep,D]
+                         cos_ref[0].astype(jnp.float32),    # [1, half]
+                         sin_ref[0].astype(jnp.float32)) * scale
+
+    @pl.when(num_blocks > 0)
+    def _prologue():
+        fetch(0, 0)
+
+    def compute_block(j, carry):
+        m, l, acc = carry
+        slot = jax.lax.rem(j, 2)
+
+        # the next block's copies fly under this block's arithmetic
+        @pl.when(j + 1 < num_blocks)
+        def _prefetch():
+            fetch(j + 1, 1 - slot)
+
+        wait(j, slot)
+        scores = jax.lax.dot_general(
+            q_rot, keys_major(k_buf, ks_buf, slot),
+            (((2,), (2,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32)         # [KVH,rep,G*bs]
+        k_pos = (lo + j * G) * bs + jax.lax.broadcasted_iota(
+            jnp.int32, scores.shape, 2)
+        scores = jnp.where(k_pos < key_limit, scores, NEG_INF)
+
+        m_new = jnp.maximum(m, jnp.max(scores, axis=-1, keepdims=True))
+        alpha = jnp.exp(m - m_new)
+        pexp = jnp.exp(scores - m_new)
+        acc = acc * alpha + jax.lax.dot_general(
+            pexp, keys_major(v_buf, vs_buf, slot),
+            (((2,), (1,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32)         # [KVH, rep, D]
+        l = l * alpha + jnp.sum(pexp, axis=-1, keepdims=True)
+        return m_new, l, acc
+
+    KVH, rep, D = q_rot.shape
+    # a split with no live page emits (NEG_INF, 0, 0): the combine
+    # weighs it to zero
+    m, l, acc = jax.lax.fori_loop(
+        0, num_blocks, compute_block,
+        (jnp.full((KVH, rep, 1), NEG_INF, jnp.float32),
+         jnp.zeros((KVH, rep, 1), jnp.float32),
+         jnp.zeros((KVH, rep, D), jnp.float32)))
+    o_ref[0, 0] = acc
+    # per-row scalars broadcast over the lane dim (flash kernel lse
+    # idiom: a 1-wide trailing dim is not a legal TPU output tile)
+    m_out_ref[0, 0] = jnp.broadcast_to(m, m_out_ref.shape[2:])
+    l_out_ref[0, 0] = jnp.broadcast_to(l, l_out_ref.shape[2:])
 
 
-def _pallas_partials(q_rot_unused, q, cos_b, sin_b, k_pool, v_pool,
-                     block_table, positions, num_splits, scale, interpret,
+@functools.partial(jax.jit, static_argnames=("num_splits", "scale",
+                                             "interpret", "kv_dtype"))
+def _pallas_partials(q, cos_b, sin_b, k_pool, v_pool, block_table,
+                     positions, num_splits, scale, interpret,
                      k_scale=None, v_scale=None, kv_dtype=None):
     """q: UNROTATED [B, KVH, rep, D]; returns (acc [B,S,KVH,rep,D] f32,
-    m [B,S,KVH,rep] f32, l [B,S,KVH,rep] f32)."""
+    m [B,S,KVH,rep] f32, l [B,S,KVH,rep] f32).
+
+    Jitted so that a model's layers share ONE trace and ONE lowering of
+    the kernel: ``pallas_call`` traces its kernel at every call, and a
+    step program's first call is part of a server's start."""
     B, KVH, rep, D = q.shape
     bs = k_pool.shape[1]
     nbs = block_table.shape[1]
     P = nbs // num_splits
+    # a compute block is about 128 keys, whatever the pool's page size:
+    # 8 pages at the served ``block_size`` 16
+    G = max(1, 128 // bs)
     half = D // 2
 
     in_specs = [
-        pl.BlockSpec((1, KVH, rep, D),
-                     lambda b, s, p, bt, pos: (b, 0, 0, 0)),
+        pl.BlockSpec((1, KVH, rep, D), lambda b, s, bt, pos: (b, 0, 0, 0)),
         # one row per sequence, as a full-extent (1, half) tile: a bare
         # (1, half) block of a [B, half] array is not (8, 128)-tileable
-        pl.BlockSpec((1, 1, half), lambda b, s, p, bt, pos: (b, 0, 0)),
-        pl.BlockSpec((1, 1, half), lambda b, s, p, bt, pos: (b, 0, 0)),
-        pl.BlockSpec((1, bs, KVH, D),
-                     lambda b, s, p, bt, pos, _P=P:
-                     (bt[b, s * _P + p], 0, 0, 0)),
-        pl.BlockSpec((1, bs, KVH, D),
-                     lambda b, s, p, bt, pos, _P=P:
-                     (bt[b, s * _P + p], 0, 0, 0)),
+        pl.BlockSpec((1, 1, half), lambda b, s, bt, pos: (b, 0, 0)),
+        pl.BlockSpec((1, 1, half), lambda b, s, bt, pos: (b, 0, 0)),
+        pl.BlockSpec(memory_space=pl.ANY),
+        pl.BlockSpec(memory_space=pl.ANY),
     ]
     operands = [q, cos_b[:, None, :], sin_b[:, None, :], k_pool, v_pool]
+    scratch = [pltpu.VMEM((2, G, bs, KVH, D), k_pool.dtype),
+               pltpu.VMEM((2, G, bs, KVH, D), v_pool.dtype)]
     if kv_dtype is not None:
-        # per-block scale rows ride the SAME block-table index map as
-        # their blocks — one [bs] f32 row per DMA'd block
-        in_specs += [
-            pl.BlockSpec((1, 1, bs), lambda b, s, p, bt, pos, _P=P:
-                         (bt[b, s * _P + p], 0, 0)),
-            pl.BlockSpec((1, 1, bs), lambda b, s, p, bt, pos, _P=P:
-                         (bt[b, s * _P + p], 0, 0)),
-        ]
-        operands += [k_scale[:, None, :], v_scale[:, None, :]]
+        # a page's [bs] f32 scale row rides with the page, one more copy;
+        # Mosaic slices an HBM array only in whole 128-lane rows
+        lanes = -(-bs // _LANES) * _LANES
+        in_specs += [pl.BlockSpec(memory_space=pl.ANY)] * 2
+        operands += [jnp.pad(sc, ((0, 0), (0, lanes - bs)))[:, None, :]
+                     for sc in (k_scale, v_scale)]
+        scratch += [pltpu.VMEM((2, G, 1, lanes), jnp.float32)] * 2
+    # one DMA semaphore a stream and slot
+    scratch.append(pltpu.SemaphoreType.DMA((len(scratch), 2)))
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(B, num_splits, P),
+        grid=(B, num_splits),
         in_specs=in_specs,
         out_specs=[
             pl.BlockSpec((1, 1, KVH, rep, D),
-                         lambda b, s, p, bt, pos: (b, s, 0, 0, 0)),
+                         lambda b, s, bt, pos: (b, s, 0, 0, 0)),
             pl.BlockSpec((1, 1, KVH, rep, _LANES),
-                         lambda b, s, p, bt, pos: (b, s, 0, 0, 0)),
+                         lambda b, s, bt, pos: (b, s, 0, 0, 0)),
             pl.BlockSpec((1, 1, KVH, rep, _LANES),
-                         lambda b, s, p, bt, pos: (b, s, 0, 0, 0)),
+                         lambda b, s, bt, pos: (b, s, 0, 0, 0)),
         ],
-        scratch_shapes=[
-            pltpu.VMEM((KVH, rep, D), jnp.float32),
-            pltpu.VMEM((KVH, rep, D), jnp.float32),
-            pltpu.VMEM((KVH, rep, 1), jnp.float32),
-            pltpu.VMEM((KVH, rep, 1), jnp.float32),
-        ],
+        scratch_shapes=scratch,
     )
+    # priced for the whole table, the worst case: shapes cannot see the
+    # lengths that bound the walk
     L = nbs * bs
     H = KVH * rep
     esize = jnp.dtype(k_pool.dtype).itemsize
@@ -240,7 +308,7 @@ def _pallas_partials(q_rot_unused, q, cos_b, sin_b, k_pool, v_pool,
                                  jnp.float32),
         ],
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"))
+            dimension_semantics=("parallel", "parallel"))
         if not interpret else None,
         cost_estimate=pl.CostEstimate(
             flops=4 * B * H * D * L,
@@ -260,7 +328,7 @@ def _xla_partials(q_rot, k_pool, v_pool, block_table, positions,
                   num_splits, k_scale=None, v_scale=None, kv_dtype=None):
     """Same split-K partials in plain XLA: q_rot is the ROTATED and
     pre-scaled [B, KVH, rep, D] f32 query (scale folded in, exactly as
-    the kernel does at p == 0).  Quantized pools dequant at the gather
+    the kernel does once a grid cell).  Quantized pools dequant at the gather
     with the IDENTICAL codes * per-row-scale f32 multiply the kernel
     fuses into its block DMA, so CPU covers the exact served math."""
     B = q_rot.shape[0]
@@ -461,7 +529,7 @@ def fused_paged_decode(q, k_new, v_new, k_pool, v_pool, block_table,
     q_g = q[:, 0].reshape(B, KVH, rep, D)               # GQA grouping
     if use_pallas:
         acc, m, l = _pallas_partials(
-            None, q_g, c, s, new_k_pool, new_v_pool, block_table,
+            q_g, c, s, new_k_pool, new_v_pool, block_table,
             positions, num_splits, scale, interpret,
             k_scale=new_k_scale, v_scale=new_v_scale,
             kv_dtype=kv_cache_dtype)
@@ -549,8 +617,10 @@ def _paged_decode_cost(in_avals, out_avals):
     in_bytes = sum(
         float(np.prod(shape, dtype=np.int64)) * np.dtype(dt).itemsize
         for shape, dt in in_avals[:5])                  # q/rope/tables
-    # the pools are read THROUGH the block table: B*L rows each, not
-    # the whole pool allocation
+    # the pools are read THROUGH the block table: at most B*L rows each,
+    # not the whole pool allocation.  The kernel walks only the live
+    # pages, but shapes cannot see lengths: this is the worst case, every
+    # sequence at the table's end
     kv_bytes = 2.0 * B * L * KVH * D * esize
     if len(in_avals) > 7:                               # quantized pool
         # one f32 absmax per (pool, token) row streams with its block

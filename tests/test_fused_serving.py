@@ -25,6 +25,7 @@ from paddle_tpu.kernels.costs import (KernelCost, register_kernel_cost,
 from paddle_tpu.kernels.fused_norm_linear import (fused_norm_linear,
                                                   fused_rmsnorm_linear,
                                                   rms_scale)
+from paddle_tpu.kernels.kv_quant import quantize_kv
 from paddle_tpu.kernels.paged_attention import (fused_paged_decode,
                                                 paged_decode_reference)
 from paddle_tpu.models import LlamaConfig, LlamaForCausalLM
@@ -33,6 +34,12 @@ from paddle_tpu.models import LlamaConfig, LlamaForCausalLM
 # ---------------------------------------------------------------------------
 # decode-kernel operands: GQA heads, garbage block 0, varied frontiers
 # ---------------------------------------------------------------------------
+
+def _rope_tables(max_pos, D):
+    inv = 1.0 / (10000.0 ** (np.arange(0, D, 2) / D))
+    t = np.arange(max_pos)[:, None] * inv[None, :]
+    return np.cos(t).astype(np.float32), np.sin(t).astype(np.float32)
+
 
 def _decode_operands(B=2, KVH=2, rep=2, D=8, bs=4, nbs=4, seed=0,
                      dtype=np.float32):
@@ -55,10 +62,7 @@ def _decode_operands(B=2, KVH=2, rep=2, D=8, bs=4, nbs=4, seed=0,
     block_table = (1 + np.arange(B * nbs)).reshape(B, nbs).astype(np.int32)
     positions = np.array([bs + 1, (nbs - 1) * bs + 2][:B],
                          dtype=np.int32)
-    inv = 1.0 / (10000.0 ** (np.arange(0, D, 2) / D))
-    t = np.arange(max_pos)[:, None] * inv[None, :]
-    cos = np.cos(t).astype(np.float32)
-    sin = np.sin(t).astype(np.float32)
+    cos, sin = _rope_tables(max_pos, D)
     return (jnp.asarray(q), jnp.asarray(k_new), jnp.asarray(v_new),
             jnp.asarray(k_pool), jnp.asarray(v_pool),
             jnp.asarray(block_table), jnp.asarray(positions),
@@ -129,6 +133,97 @@ class TestFusedPagedDecodeParity:
         args[0] = jnp.zeros((2, 2, 4, 8), jnp.float32)  # T == 2
         with pytest.raises(ValueError, match="single-token"):
             fused_paged_decode(*args)
+
+
+# ---------------------------------------------------------------------------
+# ragged batches: the Pallas walk is bounded by each sequence's length
+# ---------------------------------------------------------------------------
+
+_POOL_KINDS = [None, "int8", "fp8"]     # None: bf16 values in f32 pools
+_POOL_IDS = ["bf16", "int8", "fp8"]
+
+
+def _ragged_operands(bs, kv_dtype=None, dead="own", seed=0, KVH=2, rep=2,
+                     D=8):
+    """One batch with every edge of the walk in it: an idle slot (length
+    0, a table row of zeros), a length that ends in the middle of a page,
+    in the middle of a compute block and on a compute block's last
+    token, a sequence at the table's end, and one of two pages.
+
+    ``dead`` says what the table holds PAST each sequence's live pages:
+    ``"own"`` blocks of benign data, ``"poison"`` (one block of 1e3 keys
+    and NaN values) or ``"outside"`` (an id beyond the pool).  Block 0
+    is the garbage block, as in ``_decode_operands``."""
+    G = max(1, 128 // bs)               # pages of one compute block
+    nbs = 2 * G + 8
+    positions = np.array([0, bs + bs // 2, (G + G // 2) * bs + 1,
+                          2 * G * bs - 1, nbs * bs - 1, 2 * bs - 1],
+                         np.int32)
+    B, H = len(positions), KVH * rep
+    nb = 2 + B * nbs                    # garbage block, B rows, poison
+    rng = np.random.RandomState(seed)
+
+    def bf16_values(*shape):
+        x = jnp.asarray(rng.randn(*shape), jnp.bfloat16)
+        return np.array(x.astype(jnp.float32))
+
+    q, k_new, v_new = (bf16_values(B, 1, n, D) for n in (H, KVH, KVH))
+    k_pool, v_pool = bf16_values(nb, bs, KVH, D), bf16_values(nb, bs, KVH, D)
+    k_pool[0], v_pool[0] = 1e3, -1e3
+    k_pool[-1], v_pool[-1] = 1e3, np.nan
+    table = (1 + np.arange(B * nbs)).reshape(B, nbs).astype(np.int32)
+    if dead != "own":
+        past = np.arange(nbs)[None, :] > positions[:, None] // bs
+        table[past] = {"poison": nb - 1, "outside": nb + 3}[dead]
+    table[0] = 0                        # the idle slot
+    kw = {}
+    if kv_dtype is not None:
+        pools = []
+        for pool in (k_pool, v_pool):
+            pool[-1] = 1e3              # the poison rides in the scale
+            codes, scale = quantize_kv(
+                jnp.asarray(pool).reshape(nb * bs, KVH, D), kv_dtype)
+            scale = scale.reshape(nb, bs).at[-1].set(jnp.nan)
+            pools.append((codes.reshape(pool.shape), scale))
+        (k_pool, kw["k_scale"]), (v_pool, kw["v_scale"]) = pools
+        kw["kv_cache_dtype"] = kv_dtype
+    args = (q, k_new, v_new, k_pool, v_pool, table, positions,
+            *_rope_tables(nbs * bs + 1, D))
+    return tuple(jnp.asarray(a) for a in args), kw
+
+
+class TestPagedDecodeRaggedWalk:
+    @pytest.mark.parametrize("kv_dtype", _POOL_KINDS, ids=_POOL_IDS)
+    @pytest.mark.parametrize("num_splits", [1, 2, 4, 8])
+    @pytest.mark.parametrize("bs", [4, 16])
+    def test_three_way_parity(self, bs, num_splits, kv_dtype):
+        args, kw = _ragged_operands(bs, kv_dtype)
+        ref = paged_decode_reference(*args, **kw)
+        for use_pallas in (True, False):
+            got = fused_paged_decode(*args, num_splits=num_splits,
+                                     use_pallas=use_pallas, interpret=True,
+                                     **kw)
+            np.testing.assert_allclose(np.asarray(got[0]),
+                                       np.asarray(ref[0]),
+                                       rtol=2e-5, atol=2e-5)
+            for pool, ref_pool in zip(got[1:3], ref[1:3]):
+                np.testing.assert_array_equal(np.asarray(pool),
+                                              np.asarray(ref_pool))
+
+    @pytest.mark.parametrize("kv_dtype", _POOL_KINDS, ids=_POOL_IDS)
+    @pytest.mark.parametrize("dead", ["poison", "outside"])
+    def test_walk_ends_at_the_length(self, dead, kv_dtype):
+        # table entries past a sequence's live pages are never read: a
+        # fetched NaN value would survive its zero weight (0 * NaN), and
+        # the interpreter clamps an id beyond the pool onto the NaN block
+        outs = []
+        for past in ("own", dead):
+            args, kw = _ragged_operands(16, kv_dtype, dead=past)
+            outs.append(np.asarray(fused_paged_decode(
+                *args, num_splits=2, use_pallas=True, interpret=True,
+                **kw)[0]))
+        assert np.isfinite(outs[0]).all()
+        np.testing.assert_array_equal(outs[1], outs[0])
 
 
 # ---------------------------------------------------------------------------

@@ -141,17 +141,23 @@ _POOLS = [(None, bf16), ("int8", i8), ("fp8", i8)]
 _POOL_IDS = ["bf16", "int8", "fp8"]
 
 
-def _pool_avals(pool_dtype, kv_dtype):
-    pool = ((NUM_BLOCKS, BLOCK, KVH, D), pool_dtype)
-    scales = [((NUM_BLOCKS, BLOCK), f32)] * 2 if kv_dtype else []
+def _pool_avals(pool_dtype, kv_dtype, num_blocks=NUM_BLOCKS):
+    pool = ((num_blocks, BLOCK, KVH, D), pool_dtype)
+    scales = [((num_blocks, BLOCK), f32)] * 2 if kv_dtype else []
     return [pool, pool], scales
 
 
+# the second case is the benchmark's serving cells: 32 slots, a table of
+# 256 pages, 2,048 blocks
+@pytest.mark.parametrize("batch,pages,num_blocks",
+                         [(BATCH, PAGES, NUM_BLOCKS), (32, 256, 2048)],
+                         ids=["batch8", "served32"])
 @pytest.mark.parametrize("kv_dtype,pool_dtype", _POOLS, ids=_POOL_IDS)
-def test_fused_paged_decode(compile_for_chip, kv_dtype, pool_dtype):
+def test_fused_paged_decode(compile_for_chip, kv_dtype, pool_dtype, batch,
+                            pages, num_blocks):
     pa = _kernel("paged_attention")
-    pools, scales = _pool_avals(pool_dtype, kv_dtype)
-    rope_table = ((SEQ, D // 2), bf16)
+    pools, scales = _pool_avals(pool_dtype, kv_dtype, num_blocks)
+    rope_table = ((pages * BLOCK, D // 2), bf16)
 
     def decode(q, k_new, v_new, kp, vp, table, pos, cos, sin, *sc):
         ks, vs = sc if sc else (None, None)
@@ -161,9 +167,9 @@ def test_fused_paged_decode(compile_for_chip, kv_dtype, pool_dtype):
             kv_cache_dtype=kv_dtype)
 
     text = compile_for_chip(
-        decode, ((BATCH, 1, H, D), bf16), ((BATCH, 1, KVH, D), bf16),
-        ((BATCH, 1, KVH, D), bf16), *pools, ((BATCH, PAGES), i32),
-        ((BATCH,), i32), rope_table, rope_table, *scales)
+        decode, ((batch, 1, H, D), bf16), ((batch, 1, KVH, D), bf16),
+        ((batch, 1, KVH, D), bf16), *pools, ((batch, pages), i32),
+        ((batch,), i32), rope_table, rope_table, *scales)
     assert "tpu_custom_call" in text and "fused_paged_decode" in text
 
 
