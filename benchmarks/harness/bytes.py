@@ -6,7 +6,7 @@ comes on top, so a share of the roofline built on these cannot pass
 nothing of the program is imported for them."""
 from __future__ import annotations
 
-from . import flops
+from . import cells, flops
 
 _DTYPE_BYTES = {"bfloat16": 2, "float16": 2, "float32": 4}
 
@@ -30,11 +30,17 @@ def kv_bytes_per_token(cfg) -> int:
         * head_dim(cfg) * weight_bytes(cfg)
 
 
-def decode_step_bytes(cfg, context_tokens: float) -> float:
+def decode_step_bytes(cfg, context_tokens: float, counters=None) -> float:
     """One decode step over sequences that hold ``context_tokens`` tokens
     of context together: every matrix of the model is read once
     (whatever the batch) and every cached key and value of every live
     sequence once.  The embedding rows, the logits, the new token's
-    write and the block tables are left out."""
+    write and the block tables are left out.  A configuration that names
+    its own ``"costs"`` (``flops.py``) is asked instead, and given the
+    window's ``counters`` too: a routed model reads only the experts a
+    step chose, which only the program can count."""
+    costs = cells.config_module(cfg, "costs")
+    if costs is not None:
+        return costs.decode_step_bytes(cfg, context_tokens, counters)
     return flops.matmul_params(cfg) * weight_bytes(cfg) \
         + context_tokens * kv_bytes_per_token(cfg)

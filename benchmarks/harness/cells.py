@@ -11,6 +11,11 @@ A cell names a ``config`` and a ``traffic``.  The loader resolves
 by ``json`` and ``importlib`` alone.  There is no registry: a later PR
 adds a file and an entry in ``BENCHMARK.json`` and edits nothing here.
 ``<bench>`` is the first of ``BENCHMARK.json``'s ``paths``.
+
+A configuration names further files of its own by their paths from the
+checkout's root (``"reference"``, ``"costs"``): ``config_module`` loads
+them from the checkout the cell was loaded from, which ``load_cell``
+leaves in the configuration under ``"root"``.
 """
 from __future__ import annotations
 
@@ -53,6 +58,16 @@ def load_json(path: str) -> dict:
         return json.load(f)
 
 
+def config_module(config: dict, key: str):
+    """The module whose path ``config[key]`` gives, ``None`` where the
+    configuration has no such key."""
+    path = config.get(key)
+    if path is None:
+        return None
+    return load_module(os.path.join(config.get("root", REPO_ROOT), path),
+                       os.path.basename(path))
+
+
 def load_benchmark(root: str = REPO_ROOT) -> dict:
     return load_json(os.path.join(root, "BENCHMARK.json"))
 
@@ -71,7 +86,8 @@ def load_cell(name: str, root: str = REPO_ROOT) -> Cell:
     bench_dir = os.path.join(root, bench["paths"][0])
     config_entry = [c for c in bench["configs"]
                     if c["name"] == entry["config"]][0]
-    config = load_json(os.path.join(root, config_entry["file"]))
+    config = dict(load_json(os.path.join(root, config_entry["file"])),
+                  root=root)
     traffic = load_json(os.path.join(
         bench_dir, "traffic", entry["traffic"] + ".json"))
     kind = load_module(os.path.join(

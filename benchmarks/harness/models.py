@@ -1,6 +1,17 @@
 """Builds the system under test from a configuration file and compares
 its logits with the configuration's plain reference.
 
+A model whose forward pass makes discrete choices (which experts a
+token goes to) is not continuous in its roundings: where two scores lie
+closer than the bf16 error of the residual stream, the timed path and a
+float32 reference choose differently and the row moves by a whole
+choice's worth.  Such a configuration names a ``"witness"``: a function
+of the program that says what the timed path chose.  The harness
+fetches it after that path has run and hands it, opaque, to the
+reference, which replays the choices and holds each to its own
+arithmetic (``witness`` and ``referee`` below; README, "A configuration
+whose model chooses").
+
 Adapted from ``chip_smoke.py`` (``build_engine``, ``step_logits``,
 ``check_logits``), which ran on the chip in PR 22.  The program is
 reached only through what PERF.md lists under "symbols the benchmark
@@ -10,7 +21,6 @@ from __future__ import annotations
 
 import dataclasses
 import importlib
-import os
 
 import numpy as np
 
@@ -62,18 +72,48 @@ def build_model(config: dict, seed: int):
     return resolve(config["model"])(model_config(config))
 
 
-def load_reference(config: dict, root: str = cells.REPO_ROOT):
-    path = os.path.join(root, config["reference"])
-    return cells.load_module(path, os.path.basename(path))
+def load_reference(config: dict):
+    return cells.config_module(config, "reference")
+
+
+def witness(config: dict, **where):
+    """What the path that has just run chose, from the configuration's
+    ``"witness": "package.module:function"``, called as ``function(
+    model=, engine=, tokens=, block_table=, prompt_tokens=)``: a pytree
+    of arrays that only the configuration's reference reads.  ``None``
+    for a configuration that names none."""
+    if "witness" not in config:
+        return None
+    return resolve(config["witness"])(**where)
+
+
+def referee(function, *args, witness=None, **kwargs):
+    """``(want, report)`` of one of the reference's functions.  Without
+    a witness it is called as ever and ``report`` is ``None``.  With one
+    the reference gets it as ``witness=``, takes the witness's choice at
+    every decision of its own float32 trajectory and returns the
+    ``report`` beside what it computed: ``ok`` (every choice within the
+    reference's stated ``margin`` of its own k-th best; one conjunct of
+    ``correct``), ``decisions``, ``not_first_choice``,
+    ``largest_shortfall``, ``margin``."""
+    if witness is None:
+        return function(*args, **kwargs), None
+    return function(*args, witness=witness, **kwargs)
+
+
+def chose_admissibly(report) -> bool:
+    return report is None or bool(report["ok"])
 
 
 def engine_logits(eng, prompt, feed):
-    """Logits ``[1 + len(feed), V]`` of the first token and of one decode
-    step per fed token for one sequence, through the step programs the
-    engine itself compiled, at the engine's own shapes (so nothing
-    compiles), on blocks 1.. of its pool.  The steps are pure; the new
-    pools are bound back so that no third copy of the pool is held: call
-    this only when the engine is idle and will serve nothing more."""
+    """``(logits, table)``: logits ``[1 + len(feed), V]`` of the first
+    token and of one decode step per fed token for one sequence, through
+    the step programs the engine itself compiled, at the engine's own
+    shapes (so nothing compiles), on blocks 1.. of its pool, and the row
+    of the block table those steps ran under.  The steps are pure; the new
+    pools are bound back so that no third copy of the pool is held (and a
+    witness can read what the steps wrote): call this only when the
+    engine is idle and will serve nothing more."""
     from paddle_tpu.models.generation import (make_chunked_prefill_step,
                                               make_paged_decode_step)
 
@@ -104,7 +144,7 @@ def engine_logits(eng, prompt, feed):
         eng.pool.layers = pools = [tuple(entry) for entry in pools]
         out.append(np.asarray(logits)[0])
         lengths[0] += 1
-    return np.stack(out)
+    return np.stack(out), table[0]
 
 
 def forward_logits(model, tokens, last):

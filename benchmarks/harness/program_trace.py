@@ -3,13 +3,10 @@ its own host spans (``<part>::<phase>`` ``TraceAnnotation``s), the idle
 gaps of the device charged to those spans, its step programs by name
 (the ``XLA Modules`` line) and device time by ``jax.named_scope``.
 
-``trace_reduce`` reduces the same file to busy time and the benchmark's
-own ``bench.*`` spans; a run carries that reduction, not the file's
-path.  So ``load(run)`` takes the newest ``.xplane.pb`` under
-``<repo>/.bench_trace/`` and proves that it is this run's by reducing
-it again and comparing ``busy_s`` and ``window_s`` with what the run
-carries; a trace that differs is refused (``None``), never read.  All
-times are clipped to the slice ``trace_reduce`` uses.
+``trace_reduce`` reduces the same file to busy time, kernel time and
+the breakdown; a run carries that reduction and the file's path
+(``run["trace_path"]``), which ``load(run)`` reads.  All times are
+clipped to the slice ``trace_reduce`` uses.
 
 Where things are in a v5e trace is written down in PERF.md section 3
 ("Reading a trace").  In short: a program's run is an event
@@ -27,7 +24,6 @@ library alone.
 from __future__ import annotations
 
 import bisect
-import glob
 import os
 import re
 
@@ -35,7 +31,6 @@ from . import cells, trace_reduce
 from .stats import percentile, union_seconds
 
 _NS = 1e-9
-PROGRAM_SPAN = re.compile(r"^[a-z_0-9]+::[a-z_0-9]+$")
 OUTSIDE = "outside"
 _MODULE = re.compile(r"^jit_(.+?)(\(\d+\))?$")
 _TRANSFORM = re.compile(r"^(?:transpose|jvp|vmap|pmap|remat|checkpoint)"
@@ -226,7 +221,7 @@ def read_events(xplane_path: str):
             for ev in line.events:
                 name, stats = ev.name, None
                 if not device:
-                    if PROGRAM_SPAN.match(name):
+                    if trace_reduce.PROGRAM_SPAN.match(name):
                         stats = {k: v for k, v in ev.stats}
                     elif not name.startswith(
                             trace_reduce.HOST_SPAN_PREFIX):
@@ -272,9 +267,11 @@ class ProgramTrace:
             (s, s + d, n, stats) for p, ln, n, s, d, stats in events
             if stats is not None and s + d > lo and s < hi)
         first = sorted(ops)[0]
-        self.idle_by_span = _charge(
-            trace_reduce._gaps([(s, e) for s, e, _ in ops[first]], lo, hi),
-            _innermost(self.spans))
+        self.idle_by_span = {
+            name: ns * _NS for name, ns in trace_reduce.charge(
+                trace_reduce._gaps([(s, e) for s, e, _ in ops[first]],
+                                   lo, hi),
+                trace_reduce.innermost(self.spans), OUTSIDE).items()}
         self.program_runs = {}      # program -> [seconds], first device
         self.ops_by_scope = {}      # (program, scopes, short name) -> s
         self._by_scope = {}         # (plane, scope) -> [(start, end)]
@@ -313,74 +310,23 @@ class ProgramTrace:
                    if name == scope) * _NS / self.devices
 
 
-def _innermost(spans):
-    """Disjoint ``(start, end, name)`` pieces of possibly nested
-    ``(start, end, name, ...)`` spans: where several cover a moment, the
-    one that began last owns it."""
-    spans = sorted(spans)
-    edges = sorted({t for s, e, *_ in spans for t in (s, e)})
-    pieces, active, i = [], [], 0
-    for a, b in zip(edges, edges[1:]):
-        while i < len(spans) and spans[i][0] <= a:
-            active.append(spans[i])
-            i += 1
-        active = [sp for sp in active if sp[1] >= b]
-        if active:
-            pieces.append((a, b, max(active)[2]))
-    return pieces
-
-
-def _charge(gaps, pieces):
-    """``{name: seconds}``: each gap's time to the piece it falls in,
-    ``OUTSIDE`` where it falls in none.  Both lists are in order."""
-    out, i = {}, 0
-    for gs, ge in gaps:
-        while i < len(pieces) and pieces[i][1] <= gs:
-            i += 1
-        j, covered = i, 0
-        while j < len(pieces) and pieces[j][0] < ge:
-            part = min(ge, pieces[j][1]) - max(gs, pieces[j][0])
-            if part > 0:
-                out[pieces[j][2]] = out.get(pieces[j][2], 0.0) + part * _NS
-                covered += part
-            j += 1
-        if ge - gs > covered:
-            out[OUTSIDE] = out.get(OUTSIDE, 0.0) + (ge - gs - covered) * _NS
-    return out
-
-
 # ------------------------------------------------------------------- load
-_loaded = {}        # path -> (mtime, ProgramTrace)
+_loaded = {}        # path -> ProgramTrace or None
 
 
-def newest_xplane(root: str = cells.REPO_ROOT):
-    found = glob.glob(os.path.join(root, ".bench_trace", "**",
-                                   "*.xplane.pb"), recursive=True)
-    return max(found, key=os.path.getmtime) if found else None
-
-
-def load(run, root: str = cells.REPO_ROOT):
-    """The ``ProgramTrace`` of the trace THIS run wrote, parsed once a
-    process; ``None`` when the run was not traced, no trace is found, or
-    the newest one is not this run's."""
-    carried = run.get("trace")
-    if not carried:
+def load(run):
+    """The ``ProgramTrace`` of the trace this run wrote
+    (``run["trace_path"]``), parsed once a process; ``None`` when the
+    run was not traced or its trace holds no device operation."""
+    path = run.get("trace_path")
+    if not path or not os.path.isfile(path):
         return None
-    path = newest_xplane(root)
-    if path is None:
-        return None
-    mtime = os.path.getmtime(path)
-    if _loaded.get(path, (None,))[0] != mtime:
+    if path not in _loaded:
         try:
-            trace = ProgramTrace(read_events(path), hlo_scopes(path))
+            _loaded[path] = ProgramTrace(read_events(path), hlo_scopes(path))
         except ValueError:
-            trace = None
-        _loaded[path] = (mtime, trace)
-    trace = _loaded[path][1]
-    if trace is None or trace.busy_s != carried["busy_s"] \
-            or trace.window_s != carried["window_s"]:
-        return None
-    return trace
+            _loaded[path] = None
+    return _loaded[path]
 
 
 # ---------------------------------------------------------------- readers
@@ -416,7 +362,8 @@ def idle_pct_inside(run, spans):
 def main(argv):
     """``python -m benchmarks.harness.program_trace [xplane.pb]``: the
     tables PERF.md section 5 is written from."""
-    path = argv[1] if len(argv) > 1 else newest_xplane()
+    path = argv[1] if len(argv) > 1 else trace_reduce.newest_xplane(
+        os.path.join(cells.REPO_ROOT, ".bench_trace"))
     t = ProgramTrace(read_events(path), hlo_scopes(path))
     idle = t.window_s - t.busy_s
     print(f"{path}\nslice {t.window_s:.6f} s, busy {t.busy_s:.6f} s, "

@@ -5,6 +5,7 @@ import dataclasses
 import json
 import math
 import os
+import sys
 
 import jax
 
@@ -66,7 +67,7 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
     run = dict(result["window"], end_to_end=result["end_to_end"],
                config=cell.config, traffic=cell.traffic, device=dev,
                chips=cell.chips,
-               trace=ctx.tracer.reduced)
+               trace=ctx.tracer.reduced, trace_path=ctx.tracer.path)
     ctx.say(phase="done", setup_s=setup_s, end_to_end=result["end_to_end"],
             compile_requests=ctx.compiles.count,
             compile_seconds=ctx.compiles.seconds,
@@ -102,4 +103,12 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
         line["breakdown"] = {
             "device_ops": ctx.tracer.reduced["device_ops"],
             "idle_gaps": ctx.tracer.reduced["idle_gaps"]}
+    # every number ``correct`` compared beside its limit, last in the
+    # line and last on standard error: what is kept of a run that fails
+    line["compared"] = {name: {"value": value, "limit": limit}
+                        for name, (value, limit)
+                        in result.get("compared", {}).items()}
+    for name, pair in line["compared"].items():
+        print(f"compared {name}: {pair['value']!r} (limit {pair['limit']!r})",
+              file=sys.stderr, flush=True)
     return line

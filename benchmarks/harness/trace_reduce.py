@@ -2,6 +2,10 @@
 operations that took most time and what the host was doing in the idle
 gaps.
 
+A gap's owner is the innermost host span around it: the program's own
+``<part>::<phase>`` span where the host was inside one, else the
+benchmark's ``bench.<what>`` span.
+
 ``reduce_trace`` is a pure function over ``(plane, line, name, start_ns,
 dur_ns)`` tuples, so it is tested on a hand-built list; ``read_xplane``
 turns the profiler's ``.xplane.pb`` into such tuples with
@@ -19,6 +23,7 @@ import re
 from .stats import union_seconds
 
 HOST_SPAN_PREFIX = "bench."
+PROGRAM_SPAN = re.compile(r"^[a-z_0-9]+::[a-z_0-9]+$")
 _NS = 1e-9
 _SUFFIX = re.compile(r"[.\-_]?\d+$")
 
@@ -61,10 +66,17 @@ def newest_xplane(trace_dir: str) -> str:
     return max(found, key=os.path.getmtime)
 
 
+def is_host_span(name: str) -> bool:
+    """A span the host opened on purpose: the benchmark's own
+    (``bench.<what>``) or the program's (``<part>::<phase>``)."""
+    return name.startswith(HOST_SPAN_PREFIX) \
+        or PROGRAM_SPAN.match(name) is not None
+
+
 def read_xplane(path: str):
     """``(plane, line, name, start_ns, dur_ns)`` for every device
-    operation (named by ``short_name``) and every ``bench.*`` host span
-    of the trace at ``path``."""
+    operation (named by ``short_name``) and every host span
+    (``is_host_span``) of the trace at ``path``."""
     from jax.profiler import ProfileData
 
     out = []
@@ -75,7 +87,7 @@ def read_xplane(path: str):
                 name = ev.name
                 if device:
                     name = short_name(name)
-                elif not name.startswith(HOST_SPAN_PREFIX):
+                elif not is_host_span(name):
                     continue
                 out.append((plane.name, line.name, name,
                             int(ev.start_ns), int(ev.duration_ns)))
@@ -96,6 +108,42 @@ def _gaps(intervals, lo, hi):
     return [(s, e) for s, e in gaps if e > s]
 
 
+def innermost(spans):
+    """Disjoint ``(start, end, name)`` pieces of possibly nested
+    ``(start, end, name, ...)`` spans: where several cover a moment, the
+    one that began last owns it."""
+    spans = sorted(spans)
+    edges = sorted({t for s, e, *_ in spans for t in (s, e)})
+    pieces, active, i = [], [], 0
+    for a, b in zip(edges, edges[1:]):
+        while i < len(spans) and spans[i][0] <= a:
+            active.append(spans[i])
+            i += 1
+        active = [sp for sp in active if sp[1] >= b]
+        if active:
+            pieces.append((a, b, max(active)[2]))
+    return pieces
+
+
+def charge(gaps, pieces, outside):
+    """``{name: ns}``: each gap's time to the piece it falls in,
+    ``outside`` where it falls in none.  Both lists are in order."""
+    out, i = {}, 0
+    for gs, ge in gaps:
+        while i < len(pieces) and pieces[i][1] <= gs:
+            i += 1
+        j, covered = i, 0
+        while j < len(pieces) and pieces[j][0] < ge:
+            part = min(ge, pieces[j][1]) - max(gs, pieces[j][0])
+            if part > 0:
+                out[pieces[j][2]] = out.get(pieces[j][2], 0) + part
+                covered += part
+            j += 1
+        if ge - gs > covered:
+            out[outside] = out.get(outside, 0) + (ge - gs) - covered
+    return out
+
+
 def reduce_trace(events):
     """``None`` when no device operation ran inside the traced window,
     else a dict: ``window_s`` (from the first ``bench.*`` span's start, or
@@ -103,11 +151,16 @@ def reduce_trace(events):
     span's end; the device events' own range where there is no span),
     ``busy_s`` (union of device-operation intervals, averaged over the
     device planes), ``pallas_s`` (summed Pallas kernel time, same average), ``device_ops`` and ``idle_gaps`` (the ten largest
-    ``[name, seconds]`` each; a gap is charged to the ``bench.*`` span
-    the host was inside, ``bench.outside`` where it was in none)."""
+    ``[name, seconds]`` each; a gap is charged to the host span it falls
+    in, the innermost where they nest: the program's ``<part>::<phase>``
+    where the host was inside one, else the ``bench.*`` span around it,
+    ``bench.outside`` where it was in none)."""
     spans = sorted((s, s + d, name) for plane, line, name, s, d in events
                    if name.startswith(HOST_SPAN_PREFIX)
                    and not is_device_op(plane, line))
+    program_spans = [(s, s + d, name) for plane, line, name, s, d in events
+                     if PROGRAM_SPAN.match(name)
+                     and not is_device_op(plane, line)]
     ops = {}
     for plane, line, name, s, d in events:
         if is_device_op(plane, line):
@@ -136,21 +189,9 @@ def reduce_trace(events):
         return None
     # idle gaps of the first device, by what the host was doing
     first = ops[sorted(ops)[0]]
-    by_span = {}
-    i = 0
-    for gs, ge in _gaps([(s, e) for s, e, _ in first], lo, hi):
-        while i < len(spans) and spans[i][1] <= gs:
-            i += 1
-        j, covered = i, 0
-        while j < len(spans) and spans[j][0] < ge:
-            part = min(ge, spans[j][1]) - max(gs, spans[j][0])
-            if part > 0:
-                by_span[spans[j][2]] = by_span.get(spans[j][2], 0) + part
-                covered += part
-            j += 1
-        if ge - gs > covered:
-            by_span["bench.outside"] = \
-                by_span.get("bench.outside", 0) + (ge - gs) - covered
+    by_span = charge(_gaps([(s, e) for s, e, _ in first], lo, hi),
+                     innermost(spans + program_spans),
+                     HOST_SPAN_PREFIX + "outside")
 
     def top(d, scale):
         rows = sorted(d.items(), key=lambda kv: -kv[1])[:10]

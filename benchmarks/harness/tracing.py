@@ -32,6 +32,7 @@ class SliceTracer:
         self.start_at = None
         self.running = False
         self.reduced = None
+        self.path = None        # the .xplane.pb this run wrote
 
     def arm(self, window_start: float, seconds: float):
         self.start_at = window_start + max(
@@ -54,5 +55,6 @@ class SliceTracer:
             return
         jax.profiler.stop_trace()
         self.running = False
-        self.reduced = trace_reduce.reduce_trace(trace_reduce.read_xplane(
-            trace_reduce.newest_xplane(self.trace_dir)))
+        self.path = trace_reduce.newest_xplane(self.trace_dir)
+        self.reduced = trace_reduce.reduce_trace(
+            trace_reduce.read_xplane(self.path))

@@ -43,6 +43,12 @@ request is judged whole (ended ``stop``/``length`` with all its tokens)
 and ``pool.check_leaks()`` can show that every block came back.  It is
 capped at the iterations the requests in flight can need at most; a
 request that has not ended by then counts as failed.
+
+Then one seeded prompt and a few fed tokens go through the engine's own
+step programs and their logits are compared with the configuration's
+reference; where the configuration names a witness
+(``harness/models.py``) it is fetched after those steps, from the pools
+they left, and the reference replays and verifies what they chose.
 """
 from __future__ import annotations
 
@@ -263,26 +269,45 @@ def run(ctx) -> dict:
                           dtype=np.int32)
     feed = rng.integers(1, model.config.vocab_size, size=LOGIT_DECODE_STEPS,
                         dtype=np.int32)
-    got = models.engine_logits(eng, prompt, feed)
+    got, table = models.engine_logits(eng, prompt, feed)
+    checked = np.concatenate([prompt, feed])
+    # what those steps chose, read where they wrote it: the pools are
+    # bound to the engine as the last step left them
+    chose = models.witness(config, model=model, engine=eng, tokens=checked,
+                           block_table=table, prompt_tokens=len(prompt))
     reference = models.load_reference(config)
-    want = np.asarray(reference.logits(
-        reference.weights_of(model), config,
-        np.concatenate([prompt, feed]), last=1 + len(feed)))[:len(got)]
-    logits = models.compare_logits(got, want)
+    want, choices = models.referee(
+        reference.logits, reference.weights_of(model), config, checked,
+        last=1 + len(feed), witness=chose)
+    logits = models.compare_logits(got, np.asarray(want)[:len(got)])
     still_one = (eng.decode_cache_size() == 1
                  and eng.prefill_cache_size() == 1)
     ctx.say(phase="check", failed_requests=bad[:20],
             requests_checked=len(sent), requests_ended=len(loop.done),
             one_program_each=one_program_each and still_one, logits=logits,
+            **({} if choices is None else {"choices": choices}),
             checked_s=clock() - ctx.t_start)
 
+    compared = {
+        "requests_not_whole": [len(bad), 0],
+        "leaked_blocks": [int(leaks is not None), 0],
+        "programs_a_step": [max(eng.decode_cache_size(),
+                                eng.prefill_cache_size()), 1],
+        "compiles_in_window": [compiles_in_window, 0],
+        "logit_gap": [max(logits["max_abs_diff"]), logits["tolerance"]],
+    }
+    if choices is not None:
+        compared["choice_shortfall"] = [choices["largest_shortfall"],
+                                        choices["margin"]]
     return {
         "window_start": w0,
         "attempted": len(measured),
         "failed": sum(1 for s in measured if not s.ok()),
         "correct": bool(not bad and leaks is None and one_program_each
                         and still_one and compiles_in_window == 0
-                        and logits["ok"]),
+                        and logits["ok"]
+                        and models.chose_admissibly(choices)),
+        "compared": compared,
         "end_to_end": {
             "serve_tok_s": tokens / seconds,
             "itl_p95_ms": percentile(gaps_ms, 95),

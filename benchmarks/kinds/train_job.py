@@ -22,6 +22,12 @@ against the reference's, position by position, on the weights the window
 left (a skipped layer or products a precision lower show here); every
 loss finite; no compile request inside the window.  The backward pass
 and the optimizer have no reference: see PERF.md, Open questions.
+
+A configuration whose model makes discrete choices names a witness
+(``harness/models.py``): the reference then replays, for the loss, what
+the model's forward pass chooses on that batch and those weights (the
+step returns only its loss) and, for the logits, what it chooses on the
+row, and ``correct`` also needs every choice admissible.
 """
 from __future__ import annotations
 
@@ -122,9 +128,17 @@ def run(ctx) -> dict:
               callbacks=[warm])
     first = job["warmup_steps"]
     reference = models.load_reference(config)
-    want = reference.causal_lm_loss(reference.weights_of(lm), config,
-                                    batch_at(ctx.seed, first, job, vocab))
+    batch = batch_at(ctx.seed, first, job, vocab)
+    # (the step returns only its loss: what it chose is read from the
+    # model's forward pass on the same batch and weights, and before the
+    # weights, because a compiled call re-binds the model's arrays)
+    chose = models.witness(config, model=lm, engine=None, tokens=batch,
+                           block_table=None, prompt_tokens=None)
+    want, loss_choices = models.referee(
+        reference.causal_lm_loss, reference.weights_of(lm), config, batch,
+        witness=chose)
     ctx.say(phase="warm", losses=warm.losses, reference_loss=want,
+            **({} if loss_choices is None else {"choices": loss_choices}),
             warmed_s=clock() - ctx.t_start)
 
     compiles_before = ctx.compiles.count
@@ -149,22 +163,41 @@ def run(ctx) -> dict:
             compiles_in_window=compiles_in_window)
 
     # correctness, outside the window, on the weights it left
-    row = batch_at(ctx.seed, first, job, vocab)[0]
+    row = batch[0]
     last = min(LOGIT_POSITIONS, len(row))
     got = models.forward_logits(lm, row, last)
+    chose = models.witness(config, model=lm, engine=None, tokens=row,
+                           block_table=None, prompt_tokens=None)
     # (the compiled forward re-binds the model's arrays: read them after)
-    logits = models.compare_logits(got, np.asarray(reference.logits(
-        reference.weights_of(lm), config, row, last=last)))
+    want_logits, choices = models.referee(
+        reference.logits, reference.weights_of(lm), config, row, last=last,
+        witness=chose)
+    logits = models.compare_logits(got, np.asarray(want_logits))
     logits["max_abs_diff"] = max(logits["max_abs_diff"])
     logits["argmax_agree"] = sum(logits["argmax_agree"]) / last
-    ctx.say(phase="check", logits=logits, checked_s=clock() - ctx.t_start)
+    ctx.say(phase="check", logits=logits,
+            **({} if choices is None else {"choices": choices}),
+            checked_s=clock() - ctx.t_start)
 
+    compared = {
+        "losses_not_finite": [non_finite, 0],
+        "compiles_in_window": [compiles_in_window, 0],
+        "loss_relative_difference": [relative, models.LOSS_TOL],
+        "logit_gap": [logits["max_abs_diff"], logits["tolerance"]],
+    }
+    for name, report in (("loss_choice_shortfall", loss_choices),
+                         ("choice_shortfall", choices)):
+        if report is not None:
+            compared[name] = [report["largest_shortfall"], report["margin"]]
     return {
         "window_start": w0,
         "attempted": len(timed.ends),
         "failed": non_finite,
         "correct": bool(non_finite == 0 and compiles_in_window == 0
-                        and relative <= models.LOSS_TOL and logits["ok"]),
+                        and relative <= models.LOSS_TOL and logits["ok"]
+                        and models.chose_admissibly(loss_choices)
+                        and models.chose_admissibly(choices)),
+        "compared": compared,
         "end_to_end": {
             "train_tok_s": len(timed.ends) * tokens_per_step / seconds,
         },

@@ -1,5 +1,6 @@
 """Share of the memory roofline the decode program reaches: the least
-bytes a decode step must read (``harness/bytes.py``: every matrix of the
+bytes a decode step must read (``harness/bytes.py``, or the
+configuration's own ``costs`` file: every matrix of the
 resident model once, and the cached keys and values of the window's mean
 live context, ``decode_context_tokens`` / ``decode_iterations``) over
 the chip's published bytes per second, over the program's measured
@@ -17,6 +18,7 @@ def read(run):
     measured_ms = program_trace.program_ms(run, "paged_decode_step")
     if not steps or context is None or not measured_ms:
         return None
-    least_s = step_bytes.decode_step_bytes(run["config"], context / steps) \
+    least_s = step_bytes.decode_step_bytes(
+        run["config"], context / steps, counters) \
         / device.peaks(run["device"]["kind"])["hbm_bytes_per_s"]
     return 100.0 * least_s / (measured_ms / 1e3)

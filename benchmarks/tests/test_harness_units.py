@@ -82,6 +82,30 @@ def test_reduce_trace_starts_at_the_first_span_when_the_device_ran_before():
         "bench.train_step": pytest.approx(60e-6)}
 
 
+def test_an_idle_gap_goes_to_the_program_s_span_where_the_host_was_in_one():
+    """``breakdown.idle_gaps`` names the innermost span: the program's
+    ``<part>::<phase>`` inside ``bench.engine_step``, the benchmark's own
+    where the program had none open; the slice and the busy time do not
+    move."""
+    us = 1000
+    plain = _hand_built_trace()
+    spans = [(HOST, PY, "serving::decode_dispatch", 12 * us, 10 * us),
+             (HOST, PY, "serving::decode_fetch", 22 * us, 33 * us),
+             (HOST, PY, "serving::sample_emit", 72 * us, 6 * us)]
+    r = trace_reduce.reduce_trace(plain + spans)
+    bare = trace_reduce.reduce_trace(plain)
+    assert (r["window_s"], r["busy_s"], r["device_ops"]) \
+        == (bare["window_s"], bare["busy_s"], bare["device_ops"])
+    gaps = dict(r["idle_gaps"])
+    # 50..60: fetch until 55, then nobody's; 70..80: sample_emit 72..78
+    assert gaps["serving::decode_fetch"] == pytest.approx(5e-6)
+    assert gaps["serving::sample_emit"] == pytest.approx(6e-6)
+    assert gaps["bench.engine_step"] == pytest.approx(9e-6)
+    assert gaps["bench.bookkeeping"] == pytest.approx(15e-6)
+    assert "serving::decode_dispatch" not in gaps
+    assert sum(gaps.values()) == pytest.approx(r["window_s"] - r["busy_s"])
+
+
 def test_reduce_trace_without_device_ops_is_none():
     assert trace_reduce.reduce_trace(
         [(HOST, PY, "bench.engine_step", 0, 1000)]) is None

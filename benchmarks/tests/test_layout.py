@@ -70,6 +70,48 @@ def test_a_later_pr_adds_files_and_edits_none(tmp_path):
     assert "new_count.x" not in old.readers
 
 
+def _harness_sources(root):
+    bench = os.path.join(root, "benchmarks")
+    paths = [os.path.join(bench, "run.py")] + [
+        os.path.join(bench, folder, name)
+        for folder in ("harness", "kinds")
+        for name in sorted(os.listdir(os.path.join(bench, folder)))
+        if name.endswith(".py")]
+    out = {}
+    for path in paths:
+        with open(path, "rb") as f:
+            out[os.path.relpath(path, root)] = f.read()
+    return out
+
+
+def test_a_later_pr_s_model_witness_reference_and_costs_run_as_files(
+        tmp_path, capsys, monkeypatch):
+    """A configuration that names a model class, a witness, a reference
+    and cost counts of its own comes in as files and runs through both
+    kinds (at tiny widths, on the CPU) with ``run.py``, ``harness/`` and
+    ``kinds/`` as they are."""
+    from benchmarks.harness import device
+    from benchmarks.tests import rehearsal
+
+    monkeypatch.setitem(device.PEAKS, "cpu", {"bf16_flops_per_s": 1e12})
+    root = rehearsal.tiny_root(tmp_path)
+    rehearsal.add_witnessed_cells(root)
+    assert _harness_sources(root) == _harness_sources(cells.REPO_ROOT)
+    for cell, kind in ((rehearsal.SERVED_CELL, "closed_loop_serve"),
+                       (rehearsal.ROUTED_CELL, "train_job")):
+        loaded = cells.load_cell(cell, root)
+        assert loaded.traffic["kind"] == kind
+        assert {"model", "witness", "reference", "costs"} <= set(
+            loaded.config)
+        line = rehearsal.rehearse(cell, root, seconds=0.3, trace=True)
+        assert line["correct"] is True and line["failed"] == 0
+        out = capsys.readouterr().out
+        assert '"choices": {"ok": true' in out
+    # the one utilization metric read the routed configuration's own
+    # count of operations (the dense formula would find no such keys)
+    assert "train_mfu_pct" in line["metrics"]
+
+
 def test_no_harness_file_names_a_cell_or_its_parts():
     """No registry: the harness and ``run.py`` hold no name of a cell,
     configuration, mix, kind or layer metric."""

@@ -137,7 +137,7 @@ def test_scopes_of_an_op_name():
 
 
 # ---- a hand-written .xplane.pb: the protobuf reader, the HLO's scopes,
-# ---- and the proof that a trace is this run's
+# ---- and the way from a run to its trace
 def _varint(n):
     out = bytearray()
     while True:
@@ -263,15 +263,20 @@ def test_the_hlo_s_scopes_are_read_from_the_metadata_plane(traced_root):
     assert scopes["dot.3"] == () and scopes["add.9"] == ("optimizer_step",)
 
 
-def test_a_written_trace_is_read_and_a_foreign_one_refused(traced_root):
+def test_a_written_trace_is_read_from_the_path_the_run_carries(traced_root):
     root, path = traced_root
     events = program_trace.read_events(path)
     assert ("/host:CPU", "python3", "serving::decode_fetch", 12000, 45000,
             {"request_id": "r1"}) in events
     mine = trace_reduce.reduce_trace(trace_reduce.read_xplane(path))
-    run = {"trace": mine}
-    t = program_trace.load(run, root)
-    assert t is not None and t is program_trace.load(run, root)   # once
+    # the gap 50..60 falls in the program's span until 57, then in the
+    # benchmark's around it: the breakdown names the innermost
+    assert dict(mine["idle_gaps"]) == {
+        "serving::decode_fetch": pytest.approx(7e-6),
+        "bench.engine_step": pytest.approx(3e-6)}
+    run = {"trace": mine, "trace_path": path}
+    t = program_trace.load(run)
+    assert t is not None and t is program_trace.load(run)   # parsed once
     assert (t.busy_s, t.window_s) == (mine["busy_s"], mine["window_s"])
     assert t.window_s == pytest.approx(40e-6)       # 20..60
     assert t.idle_by_span == {
@@ -280,11 +285,13 @@ def test_a_written_trace_is_read_and_a_foreign_one_refused(traced_root):
     assert t.program_runs == {"s": [pytest.approx(30e-6)]}
     assert t.scope_seconds("optimizer_step") == pytest.approx(10e-6)
     assert t.scope_seconds("kv_write") == pytest.approx(10e-6)
-    # another run's numbers, no trace in the run, no file: never read
-    other = {"trace": dict(mine, busy_s=mine["busy_s"] + 1e-9)}
-    assert program_trace.load(other, root) is None
-    assert program_trace.load({"trace": None}, root) is None
-    assert program_trace.load(run, os.path.join(root, "nowhere")) is None
+    # a run that was not traced, or whose file is gone: nothing is read,
+    # and no other file under .bench_trace/ is looked for
+    assert program_trace.load({"trace": mine}) is None
+    assert program_trace.load({"trace": None, "trace_path": None}) is None
+    assert program_trace.load(
+        {"trace": mine, "trace_path": os.path.join(root, "nowhere.pb")}
+    ) is None
     for reader in (program_trace.program_ms, program_trace.scope_pct):
         assert reader({"trace": None}, "s") is None
     assert program_trace.idle_pct_inside({}, ("serving::admit",)) is None
