@@ -50,8 +50,18 @@ def _pick_head(page, h):
     return jnp.sum(jnp.where(heads == h, page, 0.0), axis=1)
 
 
+def _visible_upto(q_pos, mask_block):
+    """The last key position a query at ``q_pos`` sees: itself under the
+    causal mask (``mask_block`` 1), the END of its own block of
+    ``mask_block`` positions under a block-causal one (blocks aligned at
+    multiples of ``mask_block``)."""
+    if mask_block == 1:
+        return q_pos
+    return (q_pos // mask_block + 1) * mask_block - 1
+
+
 def _chunk_kernel(bt_ref, pos_ref, q_ref, k_ref, v_ref, *rest,
-                  bs, chunk, n_pages, kv_dtype=None):
+                  bs, chunk, n_pages, kv_dtype=None, mask_block=1):
     if kv_dtype is not None:
         ks_ref, vs_ref, o_ref, acc_ref, m_ref, l_ref = rest
     else:
@@ -92,7 +102,8 @@ def _chunk_kernel(bt_ref, pos_ref, q_ref, k_ref, v_ref, *rest,
     k_pos = p * bs + jax.lax.broadcasted_iota(jnp.int32, scores.shape, 1)
     q_pos = pos_ref[b] + \
         jax.lax.broadcasted_iota(jnp.int32, scores.shape, 0) % chunk
-    scores = jnp.where(k_pos <= q_pos, scores, NEG_INF)
+    scores = jnp.where(k_pos <= _visible_upto(q_pos, mask_block), scores,
+                       NEG_INF)
 
     m_cur = jnp.max(scores, axis=-1, keepdims=True)     # [RT, 1]
     m_new = jnp.maximum(m_ref[:], m_cur)
@@ -111,7 +122,8 @@ def _chunk_kernel(bt_ref, pos_ref, q_ref, k_ref, v_ref, *rest,
 
 
 def _pallas_chunked(q_g, k_pool, v_pool, block_table, positions, chunk,
-                    interpret, k_scale=None, v_scale=None, kv_dtype=None):
+                    interpret, k_scale=None, v_scale=None, kv_dtype=None,
+                    mask_block=1):
     """q_g: grouped, ROTATED, pre-scaled [B, KVH, RT, D] f32 queries;
     returns the normalized context [B, KVH, RT, D] f32."""
     B, KVH, RT, D = q_g.shape
@@ -160,7 +172,8 @@ def _pallas_chunked(q_g, k_pool, v_pool, block_table, positions, chunk,
     kv_bytes = 2 * B * L * KVH * D * esize * KVH
     return pl.pallas_call(
         functools.partial(_chunk_kernel, bs=bs, chunk=chunk,
-                          n_pages=nbs, kv_dtype=kv_dtype),
+                          n_pages=nbs, kv_dtype=kv_dtype,
+                          mask_block=mask_block),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, KVH, RT, D), jnp.float32),
         compiler_params=pltpu.CompilerParams(
@@ -176,7 +189,7 @@ def _pallas_chunked(q_g, k_pool, v_pool, block_table, positions, chunk,
 
 
 def _xla_chunked(q_g, k_pool, v_pool, block_table, positions, chunk,
-                 k_scale=None, v_scale=None, kv_dtype=None):
+                 k_scale=None, v_scale=None, kv_dtype=None, mask_block=1):
     """Same grouped-query chunk attention in plain XLA: q_g is the
     ROTATED and pre-scaled [B, KVH, RT, D] f32 query (scale folded in,
     exactly as the caller hands the kernel)."""
@@ -200,7 +213,8 @@ def _xla_chunked(q_g, k_pool, v_pool, block_table, positions, chunk,
                         preferred_element_type=jnp.float32)
     k_pos = jnp.arange(L)
     q_pos = positions[:, None] + jnp.arange(RT) % chunk  # [B, RT]
-    valid = k_pos[None, None, None, :] <= q_pos[:, None, :, None]
+    valid = k_pos[None, None, None, :] <= \
+        _visible_upto(q_pos, mask_block)[:, None, :, None]
     scores = jnp.where(valid, scores, NEG_INF)
     m = jnp.max(scores, axis=-1, keepdims=True)
     pexp = jnp.exp(scores - m)
@@ -213,7 +227,7 @@ def _xla_chunked(q_g, k_pool, v_pool, block_table, positions, chunk,
 def fused_chunked_attention(q, k_pool, v_pool, block_table, positions,
                             *, use_pallas=None, interpret=None,
                             k_scale=None, v_scale=None,
-                            kv_cache_dtype=None):
+                            kv_cache_dtype=None, mask_block=1):
     """Paged attention for one prefill chunk, fused end to end.
 
     q: [B, T, H, D] ROTATED queries for the chunk; k_pool/v_pool:
@@ -230,6 +244,11 @@ def fused_chunked_attention(q, k_pool, v_pool, block_table, positions,
     f32 sidecars; dequant happens at the kernel's block-DMA boundary
     (and identically in the XLA fallback).  The caller has already
     scatter-quantized the chunk's k/v into the pools.
+
+    ``mask_block`` > 1 makes the mask BLOCK-causal: a query sees the
+    keys up to the end of its own block of ``mask_block`` positions
+    (a model that denoises a block of positions together), so the chunk
+    must end on a block boundary; 1 is the causal mask.
 
     On TPU the gather + mask + softmax + context is one Pallas kernel
     with an online softmax; elsewhere the numerically-identical XLA
@@ -261,11 +280,12 @@ def fused_chunked_attention(q, k_pool, v_pool, block_table, positions,
         out = _pallas_chunked(q_g, k_pool, v_pool, block_table,
                               positions, T, interpret,
                               k_scale=k_scale, v_scale=v_scale,
-                              kv_dtype=kv_cache_dtype)
+                              kv_dtype=kv_cache_dtype,
+                              mask_block=mask_block)
     else:
         out = _xla_chunked(q_g, k_pool, v_pool, block_table, positions,
                            T, k_scale=k_scale, v_scale=v_scale,
-                           kv_dtype=kv_cache_dtype)
+                           kv_dtype=kv_cache_dtype, mask_block=mask_block)
     return out.reshape(B, KVH, rep, T, D).transpose(0, 3, 1, 2, 4) \
         .reshape(B, T, H, D).astype(q.dtype)
 

@@ -549,6 +549,48 @@ def fused_paged_decode(q, k_new, v_new, k_pool, v_pool, block_table,
     return out, new_k_pool, new_v_pool
 
 
+def paged_context_partials(q_rot, k_pool, v_pool, block_table, last_pos,
+                           *, num_splits=None, use_pallas=None,
+                           interpret=None):
+    """Unnormalized attention partials of a step whose queries share ONE
+    cached context per sequence (a block of positions denoised together,
+    a verify window): ``q_rot [B, KVH, R, D]`` are the ROTATED, unscaled
+    queries, ``R`` rows a KV head (the GQA group times the step's
+    positions); every row of sequence ``b`` sees the cached keys at
+    ``k_pos <= last_pos[b]`` and nothing of the step itself.  Returns
+    ``(acc [B, S, KVH, R, D], m [B, S, KVH, R], l [B, S, KVH, R])`` in
+    float32 for :func:`_combine_splits`; the caller appends the step's
+    own keys as one more split.
+
+    The walk is the decode kernel's (live pages only, a compute block of
+    pages at a time under the next block's copies): its in-kernel
+    rotation is given the identity."""
+    from ..core.flags import flag
+    from .fusion import pallas_interpret_forced
+
+    B, KVH, R, D = q_rot.shape
+    nbs = block_table.shape[1]
+    last_pos = jnp.asarray(last_pos, jnp.int32)
+    scale = 1.0 / math.sqrt(D)
+    if use_pallas is None:
+        if pallas_interpret_forced():
+            use_pallas, interpret = True, True
+        else:
+            use_pallas = bool(flag("use_pallas_kernels")) and \
+                jax.default_backend() == "tpu"
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    if num_splits is None or nbs % num_splits:
+        num_splits = _default_splits(nbs)
+    if use_pallas:
+        return _pallas_partials(
+            q_rot, jnp.ones((B, D // 2), jnp.float32),
+            jnp.zeros((B, D // 2), jnp.float32), k_pool, v_pool,
+            block_table, last_pos, num_splits, scale, interpret)
+    return _xla_partials(q_rot.astype(jnp.float32) * scale, k_pool,
+                         v_pool, block_table, last_pos, num_splits)
+
+
 def paged_decode_reference(q, k_new, v_new, k_pool, v_pool, block_table,
                            positions, cos, sin, *, k_scale=None,
                            v_scale=None, kv_cache_dtype=None):

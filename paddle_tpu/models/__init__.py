@@ -7,3 +7,5 @@ from .gpt_moe import MoEConfig, MoEForCausalLM  # noqa: F401
 from .unet import UNet2DConditionModel, UNetConfig  # noqa: F401
 from . import generation  # noqa: F401
 from .generation import generate  # noqa: F401
+from .sdar_moe import (DroplessMoE, SDARMoEConfig,  # noqa: F401
+                       SDARMoEForCausalLM)

@@ -25,7 +25,8 @@ from ..core.tensor import Tensor, to_tensor
 def _cache_dims(model):
     """(kv_heads, head_dim, dtype) shared by both cache layouts."""
     cfg = model.config
-    head_dim = cfg.hidden_size // cfg.num_attention_heads
+    head_dim = getattr(cfg, "head_dim", None) \
+        or cfg.hidden_size // cfg.num_attention_heads
     kv_heads = getattr(cfg, "num_key_value_heads", None) \
         or cfg.num_attention_heads
     dtype = jnp.bfloat16 if cfg.dtype == "bfloat16" else jnp.float32
@@ -366,13 +367,18 @@ def make_prefill_step(model):
     return prefill_step
 
 
-def _wrap_paged(pools, block_tables, kv_dtype):
+def _wrap_paged(pools, block_tables, kv_dtype, model=None):
     """Pool entries -> PagedKVCache views: (k, v) tuples for full-
     precision pools, (k, v, k_scale, v_scale) for quantized ones
-    (serving/cache.py BlockKVPool.layers).  Called at TRACE time only —
-    the branch is on the build-time kv_dtype constant, never a traced
-    value, and lives outside the H106-audited step source."""
+    (serving/cache.py BlockKVPool.layers); a model whose entries hold
+    more than K and V (``pool_sidecars``) builds its own views.  Called
+    at TRACE time only — the branch is on the build-time kv_dtype
+    constant, never a traced value, and lives outside the H106-audited
+    step source."""
     from .llama import PagedKVCache
+
+    if model is not None and hasattr(model, "paged_cache_views"):
+        return model.paged_cache_views(pools, block_tables)
 
     if kv_dtype is not None:
         return [PagedKVCache(k, v, block_tables, ks, vs,
@@ -381,9 +387,11 @@ def _wrap_paged(pools, block_tables, kv_dtype):
     return [PagedKVCache(k, v, block_tables) for k, v in pools]
 
 
-def _unwrap_paged(caches, kv_dtype):
+def _unwrap_paged(caches, kv_dtype, model=None):
     """Inverse of :func:`_wrap_paged`: repack updated cache views into
     pool-entry tuples for the engine to rebind."""
+    if model is not None and hasattr(model, "paged_pool_entries"):
+        return model.paged_pool_entries(caches)
     if kv_dtype is not None:
         return [(c.k, c.v, c.k_scale, c.v_scale) for c in caches]
     return [(c.k, c.v) for c in caches]
@@ -500,6 +508,12 @@ def make_chunked_prefill_step(model, fused=None, kv_cache_dtype=None):
 
     from ..core.dispatch import no_grad_ctx
 
+    if getattr(model, "block_diffusion", None) is not None:
+        step = _make_block_chunk_step(model, fused)
+        setattr(model, attr, step)
+        setattr(model, attr + "_fp", fp)
+        return step
+
     # see make_paged_decode_step: keep the build-time ternary out of
     # the H106-audited step source
     kind = ("chunked_prefill_fused" if fused else "chunked_prefill") \
@@ -523,6 +537,124 @@ def make_chunked_prefill_step(model, fused=None, kv_cache_dtype=None):
     setattr(model, attr, chunked_prefill_step)
     setattr(model, attr + "_fp", fp)
     return chunked_prefill_step
+
+
+def unmask_schedule(block_length: int, denoising_steps: int):
+    """Positions a denoise step unmasks under the static rule:
+    ``block_length / denoising_steps`` each, the remainder to the first
+    steps."""
+    base, extra = divmod(block_length, denoising_steps)
+    return [base + (1 if t < extra else 0) for t in range(denoising_steps)]
+
+
+def unmask_select(logits, ids, masked, n_unmask, tau):
+    """One denoise step's choice, on the device.  ``logits [S, L, V]``
+    float32, ``ids [S, L]``, ``masked [S, L]`` bool, ``n_unmask [S]``,
+    ``tau [S]``.  At every still-masked position the candidate is the
+    argmax and its confidence the softmax probability of it; a position
+    is unmasked where its confidence passes ``tau`` or it is among the
+    ``n_unmask`` most confident masked positions (ties to the earlier
+    position).  The static rule passes ``tau`` > 1.  Returns ``(ids,
+    masked)`` after the step."""
+    top = jnp.max(logits, axis=-1)
+    cand = jnp.argmax(logits, axis=-1).astype(ids.dtype)
+    conf = 1.0 / jnp.sum(jnp.exp(logits - top[..., None]), axis=-1)
+    conf = jnp.where(masked, conf, -1.0)
+    # rank among the block's positions, most confident first
+    better = (conf[:, None, :] > conf[:, :, None]) | (
+        (conf[:, None, :] == conf[:, :, None])
+        & (jnp.arange(conf.shape[1])[None, None, :]
+           < jnp.arange(conf.shape[1])[None, :, None]))
+    rank = jnp.sum(better, axis=-1)
+    take = masked & ((rank < n_unmask[:, None]) | (conf > tau[:, None]))
+    return jnp.where(take, cand, ids), masked & ~take
+
+
+def _make_block_chunk_step(model, fused):
+    """The chunk program of a model that generates by diffusion over
+    blocks (``model.block_diffusion``): the same name, lane and call
+    signature as :func:`make_chunked_prefill_step`'s, so the engine's
+    prefill lane, the trace and the metrics read it as they read any
+    chunk.  A chunk holds WHOLE blocks of the prompt (``last_index + 1``
+    a multiple of the block length), attends under the block-causal
+    mask and writes K/V and the routing witness to the pool.  Nothing is
+    predicted from a prompt's whole blocks, so no logits are formed: the
+    first result is ``stats [3] int32`` (experts read, assignments, the
+    busiest expert's assignments, summed over layers)."""
+    from ..core.dispatch import no_grad_ctx
+    from ..kernels.fusion import serving_fusion
+
+    @functools.partial(jit_with_weights, model)
+    @functools.partial(register_decode_step, kind="block_chunked_prefill")
+    def chunked_prefill_step(ids, pools, block_table, start, last_index):
+        with no_grad_ctx(), serving_fusion(fused):
+            wrapped = _wrap_paged(pools, block_table, None, model)
+            valid = (jnp.arange(ids.shape[1]) <= last_index)[None, :]
+            stats, new_caches = model.prefill_chunk(ids, valid, wrapped,
+                                                    start)
+            return stats, _unwrap_paged(new_caches, None, model)
+
+    return chunked_prefill_step
+
+
+def make_paged_block_step(model, fused=None):
+    """The step of a model that generates by diffusion over blocks: one
+    block of ``L = block_length`` positions a slot, ONE compiled program
+    for every state a slot can be in.
+
+    step(ids[S, L] int32, masked[S, L] bool, start[S] int32, mode[S]
+    int32, n_unmask[S] int32, tau[S] f32, pools, block_tables[S,
+    max_blocks] int32) -> (small, probe, new_pools).
+
+    ``mode``: 0 an idle slot (reads no expert, writes nothing), 1
+    DENOISING (the block's positions, masks included, are run against
+    the slot's cached positions ``< start`` and the block itself; the
+    step unmasks the ``n_unmask`` most confident masked positions and
+    every one whose confidence passes ``tau``; K/V dropped), 2
+    COMMITTING (the block's tokens are final: the same forward, K/V and
+    the routing witness written to the pool at ``start .. start+L-1``;
+    nothing is unmasked).  The choice is made on the device.
+
+    ``small`` is all that goes to the host, one int32 vector: the
+    block's ids after the step ``[S*L]``, its mask ``[S*L]``, then the
+    routing stats ``[3]`` (see :func:`_make_block_chunk_step`).
+    ``probe`` stays on the device unless a check reads it: ``logits
+    [L, V]`` float32 of slot 0 and ``chosen [layers, S, L, k]``, what
+    the routers chose for the positions in flight."""
+    from ..kernels.fusion import resolve_serving_fusion, serving_fusion
+
+    fused = resolve_serving_fusion(fused)
+    attr = "_paged_block_step_fused" if fused else "_paged_block_step"
+    step = getattr(model, attr, None)
+    if step is not None and _fingerprint_matches(
+            model, getattr(model, attr + "_fp", None)):
+        return step
+    fp = _weights_fingerprint(model)
+
+    from ..core.dispatch import no_grad_ctx
+
+    @functools.partial(jit_with_weights, model)
+    @functools.partial(register_decode_step, kind="paged_block")
+    def paged_block_step(ids, masked, start, mode, n_unmask, tau, pools,
+                         block_tables):
+        with no_grad_ctx(), serving_fusion(fused):
+            wrapped = _wrap_paged(pools, block_tables, None, model)
+            logits, chosen, stats, new_caches = model.block_step(
+                ids, wrapped, start, mode == 2, mode != 0)
+            with jax.named_scope("unmask_select"):
+                new_ids, new_masked = unmask_select(
+                    logits, ids, masked & (mode == 1)[:, None], n_unmask,
+                    tau)
+                new_masked = new_masked | (masked & (mode != 1)[:, None])
+                small = jnp.concatenate([
+                    new_ids.reshape(-1).astype(jnp.int32),
+                    new_masked.reshape(-1).astype(jnp.int32), stats])
+            probe = {"logits": logits[0], "chosen": chosen}
+            return small, probe, _unwrap_paged(new_caches, None, model)
+
+    setattr(model, attr, paged_block_step)
+    setattr(model, attr + "_fp", fp)
+    return paged_block_step
 
 
 def make_moe_block_step(model):

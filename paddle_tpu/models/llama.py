@@ -229,6 +229,26 @@ jax.tree_util.register_pytree_node(
     PagedKVCache.tree_unflatten)
 
 
+def paged_scatter(pool, new, block_table, pos, wmask=None):
+    """Write per-position rows into a block pool through the table:
+    pool [nb, bs, ...]; new [B, T, ...] → flat row index
+    block_table[b, pos//bs]*bs + pos%bs per position ``pos [B, T]``.
+    The column clamp keeps padded positions past the table width in
+    range (their write is already redirected to garbage by ``wmask``
+    before it could land anywhere real); where ``wmask [B, T]`` is
+    False the row lands in the reserved garbage block 0."""
+    nb, bs = pool.shape[0], pool.shape[1]
+    rows = jnp.arange(block_table.shape[0])[:, None]
+    col = jnp.minimum(pos // bs, block_table.shape[1] - 1)
+    idx = block_table[rows, col] * bs + pos % bs            # [B, T]
+    if wmask is not None:
+        idx = jnp.where(wmask, idx, 0)
+    flat = pool.reshape(nb * bs, *pool.shape[2:])
+    flat = flat.at[idx.reshape(-1)].set(
+        new.reshape(-1, *new.shape[2:]).astype(pool.dtype))
+    return flat.reshape(pool.shape)
+
+
 class LlamaRMSNorm(nn.Layer):
     def __init__(self, hidden_size, eps=1e-5):
         super().__init__()
@@ -392,22 +412,7 @@ class LlamaAttention(nn.Layer):
                 wmask = jnp.asarray(m).astype(bool)         # [B, T]
 
             def _scatter(pool, new):
-                # pool [nb, bs, kvh, hd]; new [B, T, kvh, hd] → flat row
-                # index block_table[b, pos//bs]*bs + pos%bs per position.
-                # The column clamp keeps padded positions past the table
-                # width in range (their write is already redirected to
-                # garbage by wmask before it could land anywhere real).
-                nb = pool.shape[0]
-                rows = jnp.arange(bt.shape[0])[:, None]
-                col = jnp.minimum(pos // bs, bt.shape[1] - 1)
-                idx = bt[rows, col] * bs + pos % bs         # [B, T]
-                if wmask is not None:
-                    idx = jnp.where(wmask, idx, 0)
-                flat = pool.reshape(nb * bs, pool.shape[2], pool.shape[3])
-                flat = flat.at[idx.reshape(-1)].set(
-                    new.reshape(-1, new.shape[2],
-                                new.shape[3]).astype(pool.dtype))
-                return flat.reshape(pool.shape)
+                return paged_scatter(pool, new, bt, pos, wmask)
 
             k_sc = v_sc = None
             if cache.kv_dtype is not None:

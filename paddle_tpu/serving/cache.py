@@ -64,7 +64,8 @@ class BlockKVPool:
     def __init__(self, num_layers: int, num_blocks: int, block_size: int,
                  kv_heads: int, head_dim: int, dtype=jnp.float32,
                  enable_prefix_cache: bool = True,
-                 kv_cache_dtype: Optional[str] = None):
+                 kv_cache_dtype: Optional[str] = None,
+                 sidecars: Sequence[tuple] = ()):
         if num_blocks < 2:
             raise ValueError("need >= 2 blocks (block 0 is the reserved "
                              "garbage sink)")
@@ -97,6 +98,15 @@ class BlockKVPool:
                 (z, z, s, s) for _ in range(num_layers)]
         else:
             self.layers = [(z, z) for _ in range(num_layers)]
+        # what a model keeps per cached position beside K and V (the
+        # experts its router chose, say): ``(shape, dtype)`` each, one
+        # more ``[num_blocks, block_size, *shape]`` array an entry,
+        # written by the model's step programs at the positions they
+        # write K/V and moved with its block by copy-on-write
+        if sidecars:
+            extra = tuple(jnp.zeros((num_blocks, block_size) + tuple(shape),
+                                    dt) for shape, dt in sidecars)
+            self.layers = [entry + extra for entry in self.layers]
         # LIFO free list over blocks 1..n-1 (block 0 reserved)
         self._free: List[int] = list(range(num_blocks - 1, 0, -1))
         # block id -> set of owning request ids (refcount = len)

@@ -37,8 +37,9 @@ from typing import Any, Dict, List, Optional
 import numpy as np
 
 from ..models.generation import (_cache_dims, make_chunked_prefill_step,
+                                 make_paged_block_step,
                                  make_paged_decode_step,
-                                 normalize_stop_sequences)
+                                 normalize_stop_sequences, unmask_schedule)
 from ..observability import warn_on_retrace
 from .cache import BlockKVPool, PoolExhausted
 from .metrics import ServingMetrics
@@ -169,9 +170,30 @@ class ServingConfig:
     kv_pool_bytes: Optional[int] = None
 
 
+# what a slot of a block-diffusion model is doing (the ``mode`` input of
+# ``models/generation.py::make_paged_block_step``)
+_BLOCK_IDLE, _BLOCK_DENOISE, _BLOCK_COMMIT = 0, 1, 2
+
+
 class Engine:
     """Continuous-batching engine for any causal LM following the
-    cache contract of models/llama.py (StaticKVCache + PagedKVCache)."""
+    cache contract of models/llama.py (StaticKVCache + PagedKVCache),
+    and for a model that generates by DIFFUSION OVER BLOCKS (one that
+    has ``block_diffusion`` generation settings: models/sdar_moe.py).
+
+    A block model is served by the same loop: admission, the pool, the
+    chunked prefill lane (the prompt's WHOLE blocks of ``L =
+    block_length`` tokens; what is left over becomes known positions of
+    the first generated block), then ``_block_iteration`` in place of
+    the one-token decode: a slot holds a block of ``L`` positions of
+    which some are still masked; a step finalises 0..L of them, out of
+    order, chosen on the device; when none is left the block is run
+    once more with its final tokens and its K/V written (the commit
+    pass, a step of its own in the same program), and the next block
+    begins at the next multiple of ``L``.  A block's tokens reach
+    ``on_token`` in order, as soon as they are final and contiguous.
+    Greedy only; ``L``, the denoising steps, the remasking rule and its
+    threshold are the MODEL's generation settings."""
 
     def __init__(self, model, config: Optional[ServingConfig] = None):
         from ..kernels.kv_quant import resolve_kv_cache_dtype
@@ -186,6 +208,9 @@ class Engine:
 
             quantize_model_weights(model, cfg.weight_dtype)
         kv_heads, head_dim, dtype = _cache_dims(model)
+        self.block = getattr(model, "block_diffusion", None)
+        if self.block is not None:
+            self._check_block_model()
         model_max = getattr(model.config, "max_position_embeddings", None)
         self.max_model_len = min(
             cfg.max_model_len or model_max or 1 << 30,
@@ -239,7 +264,9 @@ class Engine:
             num_layers, self.num_blocks, cfg.block_size,
             kv_heads, head_dim, dtype,
             enable_prefix_cache=cfg.enable_prefix_cache,
-            kv_cache_dtype=self.kv_cache_dtype)
+            kv_cache_dtype=self.kv_cache_dtype,
+            sidecars=model.pool_sidecars()
+            if hasattr(model, "pool_sidecars") else ())
         self.scheduler = Scheduler(self.pool,
                                    max_queue_len=cfg.max_queue_len)
         self.metrics = ServingMetrics()
@@ -264,6 +291,18 @@ class Engine:
         self._top_ps = np.ones((S,), np.float32)
         self._keys = np.zeros((S, 2), np.uint32)      # per-request base keys
         self._counters = np.zeros((S,), np.int32)     # next token index
+        if self.block is not None:
+            # a slot's block in flight: its tokens, which of them are
+            # still masked (a state, not a comparison with the mask id:
+            # a prompt may hold that id), what the slot does next and
+            # how many denoise steps the block has had
+            L = self.block.block_length
+            self._blk_ids = np.zeros((S, L), np.int32)
+            self._blk_masked = np.zeros((S, L), bool)
+            self._blk_mode = np.zeros((S,), np.int32)
+            self._blk_step = np.zeros((S,), np.int32)
+            # routing stats of chunk programs, still on the device
+            self._route_stats = []
         # runtime SPMD: shard weights + KV pool BEFORE the step makers
         # below — the steps capture the weights as jit constants, so the
         # rebind here is what makes the compiled programs multi-device
@@ -282,6 +321,8 @@ class Engine:
         # [1, chunk_tokens] shape for EVERY prompt length, where the old
         # bucketed prefill compiled one program per length bucket.
         self._decode_step = warn_on_retrace(
+            make_paged_block_step(model, fused=cfg.fused_kernels)
+            if self.block is not None else
             make_paged_decode_step(model, fused=cfg.fused_kernels,
                                    kv_cache_dtype=self.kv_cache_dtype),
             after=1, label="serving::decode_step",
@@ -291,11 +332,12 @@ class Engine:
                                       kv_cache_dtype=self.kv_cache_dtype),
             after=1, label="serving::prefill_step",
             on_retrace="raise" if cfg.strict_no_retrace else "count")
-        self._sampled_decode_step = warn_on_retrace(
-            make_sampled_decode_step(model, fused=cfg.fused_kernels,
-                                     kv_cache_dtype=self.kv_cache_dtype),
-            after=1, label="serving::sampled_decode_step",
-            on_retrace="raise" if cfg.strict_no_retrace else "count")
+        self._sampled_decode_step = None if self.block is not None \
+            else warn_on_retrace(
+                make_sampled_decode_step(model, fused=cfg.fused_kernels,
+                                         kv_cache_dtype=self.kv_cache_dtype),
+                after=1, label="serving::sampled_decode_step",
+                on_retrace="raise" if cfg.strict_no_retrace else "count")
         # every ADDITIONAL compiled step gets its own watchdog: the
         # per-EWMA compile_s carve-out only exempts ONE first call, so
         # sharing the decode/prefill watchdogs would record the second
@@ -325,6 +367,9 @@ class Engine:
                 "draft_propose_step")
             self._spec_verify_wd = self.overload.extra_watchdog(
                 "spec_verify_step")
+        if self.block is not None:
+            self._unmask_schedule = unmask_schedule(
+                self.block.block_length, self.block.denoising_steps)
         self._finished: Dict[str, Request] = {}
         self._ids = itertools.count()
         self._evictions_seen = 0    # pool counter already mirrored
@@ -332,6 +377,27 @@ class Engine:
             else None
         self.shardplan_reports = self._shardplan_startup() \
             if cfg.shardplan is not None else None
+
+    def _check_block_model(self):
+        """What the block iteration does not do yet is refused here,
+        with an error that says so."""
+        cfg, L = self.config, self.block.block_length
+        for what, given in (
+                ("speculative decoding", cfg.speculative),
+                ("a quantized KV cache", cfg.kv_cache_dtype),
+                ("weight-only quantization", cfg.weight_dtype),
+                ("a runtime mesh", cfg.mesh)):
+            if given is not None:
+                raise ValueError(
+                    f"{what} is not supported for a model that generates "
+                    "by diffusion over blocks (greedy block denoising "
+                    "only in this engine)")
+        if cfg.block_size % L or cfg.chunk_tokens % L:
+            raise ValueError(
+                f"block_size ({cfg.block_size}) and chunk_tokens "
+                f"({cfg.chunk_tokens}) must be multiples of the model's "
+                f"block_length ({L}): K/V blocks, chunks and generated "
+                "blocks are aligned")
 
     def _shardplan_startup(self):
         """Statically plan the decode and chunked-prefill programs on
@@ -502,6 +568,11 @@ class Engine:
         params = resolve_sampling(sampling, temperature=temperature,
                                   do_sample=do_sample, top_k=top_k,
                                   top_p=top_p, seed=seed)
+        if params is not None and self.block is not None:
+            raise ValueError(
+                "sampling is not supported for a model that generates by "
+                "diffusion over blocks: its denoise step picks the argmax "
+                "at every masked position (greedy only)")
         prompt = np.asarray(
             prompt.numpy() if hasattr(prompt, "numpy") else prompt,
             np.int32).reshape(-1)
@@ -588,7 +659,10 @@ class Engine:
         chunks_before = metrics.prefill_chunks_run
         self._prefill_tick()
         if any(r is not None and r.state == RUNNING for r in self._slots):
-            self._decode_iteration()
+            if self.block is not None:
+                self._block_iteration()
+            else:
+                self._decode_iteration()
         with metrics.phase("pool_sync"):
             self._sync_pool_metrics()
         metrics.engine_steps += 1
@@ -643,11 +717,19 @@ class Engine:
         matched, need, _ = self.pool.admission_plan(req.prompt,
                                                     extra_tokens=0)
         bs = self.config.block_size
-        cached_len = min(len(matched) * bs, req.prompt_len - 1)
+        if self.block is not None:
+            # the prompt's whole blocks are prefilled; nothing is
+            # predicted from them, so all of a cached prefix is reused
+            L = self.block.block_length
+            req.prefill_end = req.prompt_len // L * L
+            cached_len = min(len(matched) * bs, req.prefill_end)
+        else:
+            req.prefill_end = req.prompt_len
+            cached_len = min(len(matched) * bs, req.prompt_len - 1)
         matched = matched[:self.pool.blocks_for(cached_len)] \
             if cached_len else []
         self.pool.acquire(req.request_id, matched)
-        n = self.pool.blocks_for(req.prompt_len)
+        n = self.pool.blocks_for(req.prefill_end)
         try:
             suffix = self.pool.allocate(req.request_id, n - len(matched))
         except PoolExhausted:
@@ -674,6 +756,8 @@ class Engine:
         self.metrics.on_admit(req.request_id)
         self.metrics.on_prefix_lookup(req.request_id, cached_len,
                                       req.prompt_len)
+        if self.block is not None and cached_len >= req.prefill_end:
+            self._block_begin(req)      # nothing left to prefill
         return True
 
     def _prefill_tick(self):
@@ -719,15 +803,22 @@ class Engine:
         block the chunk writes into; the prompt's last chunk yields the
         first token."""
         start = req.prefill_pos
-        n_tok = min(self.chunk_tokens, req.prompt_len - start)
+        n_tok = min(self.chunk_tokens, req.prefill_end - start)
         with self.metrics.phase("prefill_dispatch",
                                 request_id=req.request_id, start=start,
                                 tokens=n_tok):
             last = self._dispatch_chunk(req, start, n_tok)
-        if req.prefill_pos < req.prompt_len:
+        if self.block is not None:
+            # a block model's chunk returns its routing stats, which
+            # stay on the device until the next block step is fetched
+            self._route_stats.append(last)
+        if req.prefill_pos < req.prefill_end:
             return
         with self.metrics.phase("first_token", request_id=req.request_id):
-            self._first_token(req, last)
+            if self.block is not None:
+                self._block_begin(req)
+            else:
+                self._first_token(req, last)
 
     def _dispatch_chunk(self, req: Request, start: int, n_tok: int):
         """Phase ``prefill_dispatch``: copy-on-write checks, the chunk's
@@ -750,6 +841,11 @@ class Engine:
         ids = np.zeros((1, C), np.int32)
         ids[0, :n_tok] = req.prompt[start:start + n_tok]
         bt = self._block_tables[req.slot:req.slot + 1]
+        if self.block is not None:
+            # nothing reads this chunk's result before the table's row is
+            # written again (no first-token fetch): the program gets a
+            # copy, not a view the host may change under it
+            bt = bt.copy()
         # watchdog-wrapped dispatch (serving/overload.py): monotonic
         # budget + bounded retry; the compiled step is pure, so a retry
         # recomputes the identical chunk from the unchanged pool.  The
@@ -889,6 +985,7 @@ class Engine:
         self._lengths[slot] = 0
         self._pending[slot] = 0
         self._clear_sampling_slot(slot)
+        self._clear_block_slot(slot)
         self.scheduler.requeue_preempted(victim)
 
     def _clear_sampling_slot(self, slot: int):
@@ -1108,6 +1205,136 @@ class Engine:
             self.metrics.on_spec_step(k_draft * len(active),
                                       accepted_drafts)
 
+    # ------------------------------------------- diffusion over blocks
+    def _clear_block_slot(self, slot: int):
+        if self.block is not None:
+            self._blk_mode[slot] = _BLOCK_IDLE
+            self._blk_step[slot] = 0
+            self._blk_masked[slot] = False
+            self._blk_ids[slot] = 0
+
+    def _block_open(self, slot: int, known=()):
+        """The slot's next block, at ``_lengths[slot]``: ``known``
+        tokens (a prompt's tail) then masks."""
+        self._blk_ids[slot] = self.block.mask_token_id
+        self._blk_ids[slot, :len(known)] = known
+        self._blk_masked[slot] = True
+        self._blk_masked[slot, :len(known)] = False
+        self._blk_mode[slot] = _BLOCK_DENOISE
+        self._blk_step[slot] = 0
+
+    def _block_begin(self, req: Request):
+        """The prompt's whole blocks are in the pool: the request joins
+        the bucket with its first block open, the prompt's tail as its
+        known positions."""
+        req.state = RUNNING
+        req.generated = []
+        self._lengths[req.slot] = req.prefill_end
+        self._block_open(req.slot, req.prompt[req.prefill_end:])
+        self.metrics.on_prefill_complete(req.request_id,
+                                         req.prefill_chunks)
+        self.pool.register_prefix(req.request_id,
+                                  req.prompt[:req.prefill_end], req.blocks)
+
+    def _block_iteration(self):
+        """One step of the block program over the bucket: denoising
+        slots get tokens unmasked (chosen on the device), committing
+        slots get their block's K/V written.  Only the blocks' ids, their
+        masks and three routing counts come back to the host."""
+        blk, metrics = self.block, self.metrics
+        L, S = blk.block_length, self.config.max_batch_size
+        phase = metrics.phase
+        # (block-aligned: a slot's frontier is a multiple of L)
+        active, bt = self._decode_prepare(horizon=L)
+        if not active:
+            return
+        with phase("decode_prepare"):
+            mode, n_unmask, tau = self._block_step_inputs(active)
+
+        def _timed_block(*args):
+            with phase("decode_dispatch", slots=len(active)):
+                small, _probe, pools = self._decode_step(*args)
+            with phase("decode_fetch"):
+                stats = [np.asarray(s) for s in self._route_stats]
+                return np.asarray(small), stats, pools
+
+        small, chunk_stats, new_pools = self.overload.decode_watchdog.call(
+            _timed_block, self._blk_ids, self._blk_masked, self._lengths,
+            mode, n_unmask, tau, self._target_pools(), bt)
+        with phase("sample_emit"):
+            self._rebind_target(new_pools)
+            self._route_stats = []
+            for stats in chunk_stats + [small[2 * S * L:]]:
+                metrics.on_route_stats(*(int(v) for v in stats))
+            new_ids = small[:S * L].reshape(S, L)
+            new_masked = small[S * L:2 * S * L].reshape(S, L) != 0
+            committing = sum(1 for r in active
+                             if mode[r.slot] == _BLOCK_COMMIT)
+            metrics.on_block_step(len(active), committing,
+                                  int(self._lengths.sum()))
+            metrics.on_decode_iteration(len(active), S,
+                                        self.pool.utilization())
+            for req in active:
+                slot = req.slot
+                if mode[slot] == _BLOCK_COMMIT:
+                    # the block's K/V are in the pool: the next begins
+                    self._lengths[slot] += L
+                    self._block_open(slot)
+                    metrics.blocks_committed += 1
+                    continue
+                metrics.tokens_unmasked += int(
+                    self._blk_masked[slot].sum() - new_masked[slot].sum())
+                self._blk_ids[slot] = new_ids[slot]
+                self._blk_masked[slot] = new_masked[slot]
+                self._blk_step[slot] += 1
+                if not new_masked[slot].any():
+                    self._blk_mode[slot] = _BLOCK_COMMIT
+                self._emit_block_tokens(req)
+
+    def _block_step_inputs(self, active):
+        """``(mode, n_unmask, tau)`` of the next block step: what each
+        slot does, how many positions a denoising slot unmasks at least
+        (the static schedule; the last step a block may take unmasks
+        what is left) and the confidence that unmasks more (the dynamic
+        rule; 2.0, which no confidence passes, under the static one)."""
+        blk, schedule = self.block, self._unmask_schedule
+        S = self.config.max_batch_size
+        n_unmask = np.zeros((S,), np.int32)
+        tau = np.full((S,), 2.0, np.float32)
+        for req in active:
+            slot = req.slot
+            if self._blk_mode[slot] != _BLOCK_DENOISE:
+                continue
+            step = int(self._blk_step[slot])
+            n_unmask[slot] = blk.block_length \
+                if step >= len(schedule) - 1 else schedule[step]
+            if blk.remasking == "low_confidence_dynamic":
+                tau[slot] = blk.confidence_threshold
+        return self._blk_mode.copy(), n_unmask, tau
+
+    def _emit_block_tokens(self, req: Request):
+        """Hand the block's tokens that are final and contiguous with
+        what was already emitted to ``on_token``, in order.  A request
+        may end inside a block (``length``, ``eos``, a stop sequence):
+        the block's trailing positions are dropped and ``_retire``
+        returns every block."""
+        slot = req.slot
+        start = int(self._lengths[slot])
+        at = req.prompt_len + req.num_generated - start
+        while at < self.block.block_length and not self._blk_masked[slot, at]:
+            tok = int(self._blk_ids[slot, at])
+            if not req.generated:
+                self.metrics.on_first_token(req.request_id)
+            self._append_token(req, tok)
+            if not self._emit_token(req, tok):
+                self._retire(req, "error")
+                return
+            reason = self.scheduler.finish_reason(req)
+            if reason is not None:
+                self._retire(req, reason)
+                return
+            at += 1
+
     def _rollback_blocks(self, req: Request):
         """Truncate ``req``'s KV back to its accepted frontier: blocks
         wholly past the next write position were only ever filled with
@@ -1148,6 +1375,7 @@ class Engine:
             self._lengths[slot] = 0
             self._pending[slot] = 0
             self._clear_sampling_slot(slot)
+            self._clear_block_slot(slot)
         self.metrics.on_finish(req.request_id, req.num_generated, reason)
         if req.on_token is not None:
             self.metrics.on_stream_end()
@@ -1177,6 +1405,8 @@ class Engine:
         """Jit-cache entries of the sampled decode step — 0 for a
         greedy-only workload (the step never runs), 1 after the first
         sampled iteration, forever (the same no-retrace contract)."""
+        if self._sampled_decode_step is None:
+            return 0
         return self._sampled_decode_step._cache_size()
 
     def spec_cache_sizes(self) -> Dict[str, int]:

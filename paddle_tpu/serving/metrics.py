@@ -122,6 +122,21 @@ class ServingMetrics:
         self.admissions = 0             # requests admitted a first time
         self.queue_wait_ns = 0          # first admission - submit
         self.lane_wait_ns = 0           # first chunk - first admission
+        # a model that generates by diffusion over blocks (the engine's
+        # block iteration; all stay 0 for a one-token decode)
+        self.block_steps = 0            # runs of the block program
+        self.block_slot_steps = 0       # live slots over those runs
+        self.commit_slot_steps = 0      # of them, slots committing a block
+        self.tokens_unmasked = 0        # positions a denoise step finalised
+        self.blocks_committed = 0       # blocks whose K/V were written
+        self.block_context_tokens = 0   # sum of cached lengths per run
+        # routed experts, counted on the device by every step program
+        # that runs them (block steps and prefill chunks), summed over
+        # layers: experts with at least one token, (token, expert)
+        # assignments, and the busiest expert's assignments
+        self.experts_read = 0
+        self.expert_assignments = 0
+        self.expert_assignments_max = 0
         # prefix cache / chunked prefill
         self.prefix_cache_hits = 0      # admissions reusing >= 1 block
         self.prefix_cache_misses = 0    # admissions reusing none
@@ -450,6 +465,22 @@ class ServingMetrics:
                       "paged KV cache pages in use, last iteration").set(
                           cache_utilization)
 
+    def on_block_step(self, active: int, committing: int,
+                      context_tokens: int):
+        """One run of the block program over ``active`` live slots, of
+        which ``committing`` wrote their block's K/V."""
+        self.block_steps += 1
+        self.block_slot_steps += active
+        self.commit_slot_steps += committing
+        self.block_context_tokens += context_tokens
+
+    def on_route_stats(self, experts_read: int, assignments: int,
+                       assignments_max: int):
+        """What one step program's routed layers read, from the device."""
+        self.experts_read += experts_read
+        self.expert_assignments += assignments
+        self.expert_assignments_max += assignments_max
+
     # --------------------------------------------------------- export
     def as_dict(self) -> dict:
         n = max(self._gauge_samples, 1)
@@ -483,6 +514,15 @@ class ServingMetrics:
                 "admissions": self.admissions,
                 "queue_wait_ns": self.queue_wait_ns,
                 "lane_wait_ns": self.lane_wait_ns,
+                "block_steps": self.block_steps,
+                "block_slot_steps": self.block_slot_steps,
+                "commit_slot_steps": self.commit_slot_steps,
+                "tokens_unmasked": self.tokens_unmasked,
+                "blocks_committed": self.blocks_committed,
+                "block_context_tokens": self.block_context_tokens,
+                "experts_read": self.experts_read,
+                "expert_assignments": self.expert_assignments,
+                "expert_assignments_max": self.expert_assignments_max,
                 **{f"step_ns.{p}": ns for p, ns in self.step_ns.items()},
             },
             "gauges": {

@@ -107,6 +107,10 @@ class Request:
     prefill_pos: int = 0
     cached_tokens: int = 0
     prefill_chunks: int = 0
+    # where this admission's prefill ends: the prompt's length, or its
+    # whole blocks for a model that generates by diffusion over blocks
+    # (the tail then opens the first generated block)
+    prefill_end: int = 0
 
     def __post_init__(self):
         self.prompt = np.asarray(self.prompt, np.int32).reshape(-1)

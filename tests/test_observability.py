@@ -400,6 +400,10 @@ class TestServingMirror:
         "engine_steps", "prefill_steps", "prefill_chunks_run",
         "decode_context_tokens", "prompt_tokens", "cached_prompt_tokens",
         "admissions", "queue_wait_ns", "lane_wait_ns",
+        # the block iteration and the routed experts (ISSUE 30)
+        "block_steps", "block_slot_steps", "commit_slot_steps",
+        "tokens_unmasked", "blocks_committed", "block_context_tokens",
+        "experts_read", "expert_assignments", "expert_assignments_max",
     } | {f"step_ns.{phase}" for phase in (
         "admit", "prefill_dispatch", "first_token", "decode_prepare",
         "decode_dispatch", "decode_fetch", "sample_emit", "pool_sync")}

@@ -464,16 +464,42 @@ class TestWeightOnlyQuant:
             resolve_weight_dtype("int4")
 
     def test_weight_quantized_engine_near_parity(self):
-        """w8 drift on the tiny model leaves greedy argmax unchanged
-        (absmax per-channel on well-conditioned init weights) — and the
-        quantized fleet still zero-retraces and leaks nothing."""
+        """w8 + int8-KV drift on the tiny model, held by LOGITS along the
+        fp32 engine's own tokens (teacher-forced): every step within
+        2^-5 of the largest fp32 logit, and the same greedy token
+        wherever the fp32 row's lead is larger than that drift can close
+        — and the quantized engine still zero-retraces and leaks nothing.
+
+        The tolerance: int8 codes with absmax scales (per output channel
+        for weights, per cached row for K/V) resolve 2^-8 of their range;
+        2^-5 is eight such steps, the allowance the benchmark gives a
+        bf16 path for the same reason (``LOGIT_TOL``).  Measured here:
+        1.1 %.  This used to compare greedy TOKENS: the second prompt's
+        two best first-token logits are 1.3720 and 1.3684, 0.2 % of the
+        largest apart, so the 1.1 % drift flips that argmax and every
+        later token follows — a coin the seed tossed, not parity."""
+        from logit_check import (assert_logits_within,
+                                 causal_engine_logits, decided)
+
+        tol = 2.0 ** -5
         prompts = _prompts([7, 10], seed=5)
-        ref = Engine(_parity_model(), _config())
-        want = _gen(ref, prompts, 6)
+        want_tokens = _gen(Engine(_parity_model(), _config()), prompts, 6)
         eng = Engine(_tiny_model(), _config(weight_dtype="int8",
                                             kv_cache_dtype="int8"))
+        flipped = 0
+        for prompt, tokens in zip(prompts, want_tokens):
+            want = causal_engine_logits(
+                Engine(_parity_model(), _config()), prompt, tokens[:-1])
+            got = causal_engine_logits(eng, prompt, tokens[:-1])
+            assert want.argmax(-1).tolist() == tokens
+            assert_logits_within(got, want, tol, "w8 + int8 KV")
+            sure = decided(want, tol)
+            assert (got.argmax(-1)[sure] == want.argmax(-1)[sure]).all()
+            flipped += int((got.argmax(-1) != want.argmax(-1)).sum())
+        assert flipped <= 2     # the near-ties, not a different model
         got = _gen(eng, prompts, 6)
-        assert got == want
+        assert [len(g) for g in got] == [6, 6]
+        assert got[0] == want_tokens[0]
         assert eng._decode_step.retraces == 0
         eng.pool.check_leaks()
 

@@ -189,3 +189,50 @@ def test_fused_chunked_attention(compile_for_chip, kv_dtype, pool_dtype):
         ((1,), i32), *scales)
     assert "tpu_custom_call" in text and "fused_chunked_prefill" in text
 
+
+
+# ---- a block-diffusion model's kernels at SDAR-30B-A3B widths: 32 heads,
+# 4 KV heads, head 128, hidden 2048, 128 experts of width 768, a block of 4
+# positions a slot over 32 slots, a pool of 4,096 blocks and a 256-page table
+SDAR_KVH, SDAR_HIDDEN, EXPERTS, EXPERT_WIDTH, TOP_K = 4, 2048, 128, 768, 8
+SDAR_POOL = ((4096, BLOCK, SDAR_KVH, D), bf16)
+
+
+@pytest.mark.parametrize("tokens", [32 * 4, CHUNK], ids=["block", "chunk"])
+def test_grouped_experts(compile_for_chip, tokens):
+    me = _kernel("moe_experts")
+
+    def experts(x, router, wg, wu, wd):
+        chosen, gates = me.route_topk(x, router, TOP_K)
+        out, stats = me.grouped_experts(x, chosen, gates, wg, wu, wd,
+                                        use_pallas=True, interpret=False)
+        return out, stats.as_vector()
+
+    w = ((EXPERTS, EXPERT_WIDTH, SDAR_HIDDEN), bf16)
+    text = compile_for_chip(experts, ((tokens, SDAR_HIDDEN), bf16),
+                            ((SDAR_HIDDEN, EXPERTS), bf16), w, w, w)
+    assert "tpu_custom_call" in text and "moe_grouped_experts" in text
+    # no loop around the kernel: a trace's rows then add up
+    assert " while(" not in text
+
+
+def test_block_causal_chunked_attention(compile_for_chip):
+    cp = _kernel("chunked_prefill")
+    text = compile_for_chip(
+        lambda q, kp, vp, table, pos: cp.fused_chunked_attention(
+            q, kp, vp, table, pos, use_pallas=True, interpret=False,
+            mask_block=4),
+        ((1, CHUNK, H, D), bf16), SDAR_POOL, SDAR_POOL, ((1, 256), i32),
+        ((1,), i32))
+    assert "tpu_custom_call" in text and "fused_chunked_prefill" in text
+
+
+def test_context_partials_of_a_block_of_positions(compile_for_chip):
+    pa = _kernel("paged_attention")
+    rows = H // SDAR_KVH * 4            # the GQA group times the block
+    text = compile_for_chip(
+        lambda q, kp, vp, table, last: pa.paged_context_partials(
+            q, kp, vp, table, last, use_pallas=True, interpret=False),
+        ((32, SDAR_KVH, rows, D), bf16), SDAR_POOL, SDAR_POOL,
+        ((32, 256), i32), ((32,), i32))
+    assert "tpu_custom_call" in text and "fused_paged_decode" in text
