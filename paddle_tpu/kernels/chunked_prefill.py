@@ -9,12 +9,24 @@ matmuls, while only the projection epilogues around them fuse.
 
 The kernel attends one query CHUNK (T tokens per sequence, already
 RoPE-rotated and scattered into the pools by the caller) over each
-sequence's paged KV context in one pass: the block table rides in as a
-scalar-prefetch operand, each grid step DMAs exactly one KV block from
-the pool, and an online (flash) softmax keeps the running max/sum and
-accumulator for all T queries in VMEM.  GQA never materializes the
-repeat: queries are grouped [B, KVH, rep*T, D] so every q row of a
-group shares the group's KV block.
+sequence's paged KV context in one pass.  The walk follows the chunk's
+own context, not the table's width: sequence ``b`` has ``ceil((
+positions[b] + T) / bs)`` live pages (no query of the chunk sees a key
+past ``positions[b] + T - 1``, under the causal and the block-causal
+mask alike), and no table entry and no page past them is read.  It is
+the decode kernel's walk (``paged_attention._PageWalk``): the pools
+stay in HBM, the block table and the chunk starts ride in as
+scalar-prefetch operands, and a COMPUTE BLOCK of ``G = max(1, 128 //
+bs)`` pages at a time is copied into a double-buffered VMEM scratch,
+one online (flash) softmax update a compute block with the next
+block's copies in flight under it.  Compute blocks that end before
+``positions[b]`` are wholly visible and skip the mask's compare.
+
+GQA never materializes the repeat: queries are grouped [B, KVH, rep*T,
+D] so every q row of a group shares the group's keys.  A grid cell is
+one sequence and one TILE of those rows with all their KV heads (the
+running max/sum and accumulator of a tile stay in VMEM), so a page is
+read once a row tile.
 
 Numerics contract: ``_xla_chunked`` is the same grouped-query math in
 plain XLA ops (identical masking, f32 accumulation, full softmax in
@@ -36,18 +48,11 @@ from jax.experimental.pallas import tpu as pltpu
 
 from .costs import KernelCost, register_kernel_cost
 from .kv_quant import decode_codes
+from .paged_attention import (_PageWalk, _online_softmax, _pool_streams,
+                              _split_walk_refs)
 
 KERNEL_NAME = "fused_chunked_prefill"
 NEG_INF = -1e30
-
-
-def _pick_head(page, h):
-    """Head ``h`` of one [bs, KVH, D] f32 page -> [bs, D].  ``h`` is a
-    grid index, and Mosaic takes no dynamic index on a tiled (sublane)
-    dim, so the pick is a mask and a sum over KVH — exact, since every
-    other term is zero."""
-    heads = jax.lax.broadcasted_iota(jnp.int32, page.shape, 1)
-    return jnp.sum(jnp.where(heads == h, page, 0.0), axis=1)
 
 
 def _visible_upto(q_pos, mask_block):
@@ -60,124 +65,121 @@ def _visible_upto(q_pos, mask_block):
     return (q_pos // mask_block + 1) * mask_block - 1
 
 
-def _chunk_kernel(bt_ref, pos_ref, q_ref, k_ref, v_ref, *rest,
-                  bs, chunk, n_pages, kv_dtype=None, mask_block=1):
-    if kv_dtype is not None:
-        ks_ref, vs_ref, o_ref, acc_ref, m_ref, l_ref = rest
-    else:
-        o_ref, acc_ref, m_ref, l_ref = rest
-        ks_ref = vs_ref = None
+def _row_tile(RT, KVH):
+    """Query rows of one grid cell: all ``RT`` where a ``[KVH, rows,
+    128]`` f32 tile (the scores of one compute block; q, the accumulator
+    and the output are as large) stays within 1 MiB, else the largest
+    divisor of ``RT`` that does and is a whole number of sublanes."""
+    cap = max(8, 2048 // KVH)
+    if RT <= cap:
+        return RT
+    fits = [r for r in range(8, cap + 1, 8) if RT % r == 0]
+    return fits[-1] if fits else RT
+
+
+def _chunk_kernel(bt_ref, pos_ref, q_ref, *rest, chunk, kv_dtype=None,
+                  mask_block=1):
+    hbm_refs, (o_ref, acc_ref, m_ref, l_ref), bufs, sems = \
+        _split_walk_refs(rest, kv_dtype)
     b = pl.program_id(0)
-    h = pl.program_id(1)
-    p = pl.program_id(2)
+    rows = acc_ref.shape[1]
+    bs = bufs[0].shape[2]
 
-    @pl.when(p == 0)
-    def _init():
-        acc_ref[:] = jnp.zeros_like(acc_ref)
-        m_ref[:] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[:] = jnp.zeros_like(l_ref)
+    # the chunk's context: the pages that hold a key some query of the
+    # chunk may see (k_pos <= pos + chunk - 1), clamped to the table.
+    # The walk never reads a table entry, or fetches a page, past them.
+    pos = pos_ref[b]
+    live = jnp.minimum((pos + chunk + bs - 1) // bs, bt_ref.shape[1])
+    walk = _PageWalk(bt_ref, b, 0, live, hbm_refs, bufs, sems, kv_dtype)
+    K = walk.G * bs                                     # keys a block
 
-    # q rows are [rep * chunk, D] with row r * chunk + t; scale is
-    # already folded into q by the caller, so the score math is a bare
-    # dot against this page's gathered block.  Quantized pools dequant
-    # right at the DMA boundary: the int8 block just landed in VMEM and
-    # the per-row scale multiply rides the same f32 upcast.
-    qv = q_ref[0, 0].astype(jnp.float32)                # [RT, D]
-    if kv_dtype is not None:
-        kb = _pick_head(decode_codes(k_ref[0], kv_dtype), h) * \
-            ks_ref[0].T                                 # [bs, D]
-        vb = _pick_head(decode_codes(v_ref[0], kv_dtype), h) * \
-            vs_ref[0].T
-    else:
-        kb = _pick_head(k_ref[0].astype(jnp.float32), h)    # [bs, D]
-        vb = _pick_head(v_ref[0].astype(jnp.float32), h)
+    acc_ref[:] = jnp.zeros_like(acc_ref)
+    m_ref[:] = jnp.full_like(m_ref, NEG_INF)
+    l_ref[:] = jnp.zeros_like(l_ref)
 
-    scores = jax.lax.dot_general(
-        qv, kb, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)             # [RT, bs]
+    # q rows are [rep * chunk, D] with row r * chunk + t, tiled over the
+    # grid's second axis; scale is already folded into q by the caller,
+    # so the score math is a bare dot against the block's keys
+    row = pl.program_id(1) * rows + \
+        jax.lax.broadcasted_iota(jnp.int32, (1, rows, K), 1)
+    visible = _visible_upto(pos + row % chunk, mask_block)
+    key = jax.lax.broadcasted_iota(jnp.int32, (1, rows, K), 2)
 
-    # causal chunk mask: key position vs this row's query position
-    # pos_ref[b] + t.  Page 0 always holds key position 0, so m stays
-    # anchored to a real score and masked lanes underflow to exp(-inf).
-    k_pos = p * bs + jax.lax.broadcasted_iota(jnp.int32, scores.shape, 1)
-    q_pos = pos_ref[b] + \
-        jax.lax.broadcasted_iota(jnp.int32, scores.shape, 0) % chunk
-    scores = jnp.where(k_pos <= _visible_upto(q_pos, mask_block), scores,
-                       NEG_INF)
+    walk.start()
 
-    m_cur = jnp.max(scores, axis=-1, keepdims=True)     # [RT, 1]
-    m_new = jnp.maximum(m_ref[:], m_cur)
-    alpha = jnp.exp(m_ref[:] - m_new)
-    pexp = jnp.exp(scores - m_new)
-    acc_ref[:] = acc_ref[:] * alpha + jax.lax.dot_general(
-        pexp, vb, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)             # [RT, D]
-    l_ref[:] = l_ref[:] * alpha + jnp.sum(pexp, axis=-1, keepdims=True)
-    m_ref[:] = m_new
+    def compute_block(j, _, masked):
+        slot = walk.arrive(j)
+        scores = jax.lax.dot_general(
+            q_ref[0], walk.keys(slot), (((2,), (2,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32)         # [KVH, rows, K]
+        if masked:
+            # causal chunk mask: key position vs this row's query
+            # position.  Block 0 always holds key position 0, so m stays
+            # anchored to a real score and masked lanes underflow to
+            # exp(-inf); a dead page's keys lie past every query.
+            scores = jnp.where(j * K + key <= visible, scores, NEG_INF)
+        m_ref[:], l_ref[:], acc_ref[:] = _online_softmax(
+            scores, walk.values(slot), m_ref[:], l_ref[:], acc_ref[:])
 
-    @pl.when(p == n_pages - 1)
-    def _emit():
-        o_ref[0, 0] = (acc_ref[:] /
-                       jnp.maximum(l_ref[:], 1e-30)).astype(o_ref.dtype)
+    # compute blocks that end before ``pos`` are seen whole by every query
+    # of the chunk: only the ones that reach it pay for the compare
+    clear = jnp.minimum(pos // K, walk.num_blocks)
+    jax.lax.fori_loop(0, clear,
+                      functools.partial(compute_block, masked=False), None)
+    jax.lax.fori_loop(clear, walk.num_blocks,
+                      functools.partial(compute_block, masked=True), None)
+    o_ref[0] = (acc_ref[:] /
+                jnp.maximum(l_ref[:], 1e-30)).astype(o_ref.dtype)
 
 
+@functools.partial(jax.jit, static_argnames=("chunk", "interpret",
+                                             "kv_dtype", "mask_block"))
 def _pallas_chunked(q_g, k_pool, v_pool, block_table, positions, chunk,
                     interpret, k_scale=None, v_scale=None, kv_dtype=None,
                     mask_block=1):
     """q_g: grouped, ROTATED, pre-scaled [B, KVH, RT, D] f32 queries;
-    returns the normalized context [B, KVH, RT, D] f32."""
+    returns the normalized context [B, KVH, RT, D] f32.
+
+    Jitted so that a model's layers share ONE trace and ONE lowering of
+    the kernel (see ``paged_attention._pallas_partials``)."""
     B, KVH, RT, D = q_g.shape
     bs = k_pool.shape[1]
     nbs = block_table.shape[1]
+    rows = _row_tile(RT, KVH)
+    pool_specs, pool_operands, pool_scratch = _pool_streams(
+        k_pool, v_pool, k_scale, v_scale, kv_dtype)
 
-    in_specs = [
-        pl.BlockSpec((1, 1, RT, D),
-                     lambda b, h, p, bt, pos: (b, h, 0, 0)),
-        # the page rides in with ALL its KV heads and the kernel picks
-        # head h: a one-head (1, bs, 1, D) block of the [nb, bs, KVH, D]
-        # pool is not (8, 128)-tileable on its (KVH, D) minor dims
-        pl.BlockSpec((1, bs, KVH, D),
-                     lambda b, h, p, bt, pos: (bt[b, p], 0, 0, 0)),
-        pl.BlockSpec((1, bs, KVH, D),
-                     lambda b, h, p, bt, pos: (bt[b, p], 0, 0, 0)),
-    ]
-    operands = [q_g, k_pool, v_pool]
-    if kv_dtype is not None:
-        # per-row scale sidecars ride the same block-table indexing as
-        # the pools they describe ([nb, bs] -> one (1, bs) row strip)
-        in_specs += [
-            pl.BlockSpec((1, 1, bs),
-                         lambda b, h, p, bt, pos: (bt[b, p], 0, 0)),
-            pl.BlockSpec((1, 1, bs),
-                         lambda b, h, p, bt, pos: (bt[b, p], 0, 0)),
-        ]
-        operands += [k_scale[:, None, :], v_scale[:, None, :]]
+    def q_tile(b, t, bt, pos):
+        return (b, 0, t, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(B, KVH, nbs),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, RT, D),
-                               lambda b, h, p, bt, pos: (b, h, 0, 0)),
+        grid=(B, RT // rows),
+        in_specs=[pl.BlockSpec((1, KVH, rows, D), q_tile), *pool_specs],
+        out_specs=pl.BlockSpec((1, KVH, rows, D), q_tile),
         scratch_shapes=[
-            pltpu.VMEM((RT, D), jnp.float32),
-            pltpu.VMEM((RT, 1), jnp.float32),
-            pltpu.VMEM((RT, 1), jnp.float32),
+            pltpu.VMEM((KVH, rows, D), jnp.float32),
+            pltpu.VMEM((KVH, rows, 1), jnp.float32),
+            pltpu.VMEM((KVH, rows, 1), jnp.float32),
+            *pool_scratch,
         ],
     )
+    # priced for the whole table, the worst case: shapes cannot see the
+    # chunk starts that bound the walk
     L = nbs * bs
     esize = jnp.dtype(k_pool.dtype).itemsize
-    scale_bytes = 2 * B * KVH * L * 4 if kv_dtype is not None else 0
-    # every one of the KVH head steps DMAs the whole page (see in_specs)
-    kv_bytes = 2 * B * L * KVH * D * esize * KVH
+    # every row tile walks the pages again; a quantized pool also
+    # streams one f32 scale per (pool, token) row
+    kv_bytes = 2 * B * L * KVH * D * esize * (RT // rows)
+    scale_bytes = 2 * B * L * 4 * (RT // rows) if kv_dtype is not None \
+        else 0
     return pl.pallas_call(
-        functools.partial(_chunk_kernel, bs=bs, chunk=chunk,
-                          n_pages=nbs, kv_dtype=kv_dtype,
+        functools.partial(_chunk_kernel, chunk=chunk, kv_dtype=kv_dtype,
                           mask_block=mask_block),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, KVH, RT, D), jnp.float32),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"))
+            dimension_semantics=("parallel", "parallel"))
         if not interpret else None,
         cost_estimate=pl.CostEstimate(
             flops=4 * B * KVH * RT * D * L,
@@ -185,7 +187,7 @@ def _pallas_chunked(q_g, k_pool, v_pool, block_table, positions, chunk,
             transcendentals=B * KVH * RT * L),
         interpret=interpret,
         name=KERNEL_NAME,
-    )(block_table, positions, *operands)
+    )(block_table, positions, q_g, *pool_operands)
 
 
 def _xla_chunked(q_g, k_pool, v_pool, block_table, positions, chunk,
@@ -310,14 +312,16 @@ def _chunked_prefill_cost(in_avals, out_avals):
     in_bytes = sum(
         float(np.prod(shape, dtype=np.int64)) * np.dtype(dt).itemsize
         for shape, dt in in_avals[:3])                  # table/pos/q
-    # the pools are read THROUGH the block table: B*L rows each, not
-    # the whole pool allocation (esize already reflects int8 when the
-    # pool is quantized) — but once per kv-head grid step, since a page
-    # rides in with all its heads; per-row f32 scale sidecars ride
-    # along per kv-head grid step when present
-    kv_bytes = 2.0 * B * L * KVH * D * esize * KVH
+    # the pools are read THROUGH the block table: at most B*L rows each,
+    # not the whole pool allocation (esize already reflects int8 when the
+    # pool is quantized), once per tile of query rows.  The kernel walks
+    # only the chunk's live pages, but shapes cannot see where a chunk
+    # starts: this is the worst case, the table's last chunk.  Per-row
+    # f32 scale sidecars ride along when present
+    tiles = RT // _row_tile(RT, KVH)
+    kv_bytes = 2.0 * B * L * KVH * D * esize * tiles
     if len(in_avals) > 5:
-        kv_bytes += 2.0 * B * KVH * L * \
+        kv_bytes += 2.0 * B * L * tiles * \
             np.dtype(in_avals[5][1]).itemsize
     out_bytes = sum(
         float(np.prod(shape, dtype=np.int64)) * np.dtype(dt).itemsize

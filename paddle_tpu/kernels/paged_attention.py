@@ -103,20 +103,159 @@ def _scatter_token_quant(pool, scales, new, block_table, positions,
 # split-K partials: Pallas kernel
 # ---------------------------------------------------------------------------
 
-def _decode_kernel(bt_ref, pos_ref, q_ref, cos_ref, sin_ref, k_hbm, v_hbm,
-                   *rest, bs, pages_per_split, scale, kv_dtype=None):
-    # the pools (and a quantized pool's scale rows) stay in HBM; each
-    # stream has a [2, G, page] VMEM buffer and a DMA semaphore a slot
+class _PageWalk:
+    """The walk over one sequence's live pages that the serving kernels
+    share: pages ``[lo, hi)`` of row ``b`` of the block table, a compute
+    block of ``G`` pages at a time, copied from the pools in HBM into
+    the double-buffered VMEM scratch that ``_pool_streams`` laid out
+    (one ``[2, G, page]`` buffer and one DMA semaphore a slot for each
+    stream: K, V, and a quantized pool's two scale rows).  No table
+    entry and no page outside ``[lo, hi)`` is read.  A kernel calls
+    ``start()``, then in a ``fori_loop`` over ``num_blocks`` takes each
+    block's ``slot = arrive(j)`` and reads ``keys(slot)`` and
+    ``values(slot)``: block ``j + 1`` is in flight meanwhile."""
+
+    def __init__(self, bt_ref, b, lo, hi, hbm_refs, bufs, sems, kv_dtype):
+        self.bt_ref, self.b, self.lo, self.hi = bt_ref, b, lo, hi
+        self.streams = tuple(zip(hbm_refs, bufs))
+        self.sems, self.kv_dtype = sems, kv_dtype
+        self.k_buf, self.v_buf = bufs[:2]
+        self.ks_buf, self.vs_buf = bufs[2:] if kv_dtype is not None \
+            else (None, None)
+        self.G, self.bs = self.k_buf.shape[1], self.k_buf.shape[2]
+        self.num_blocks = jnp.maximum(hi - lo + self.G - 1, 0) // self.G
+
+    def _page_copies(self, slot, g, block):
+        return [pltpu.make_async_copy(hbm.at[block], buf.at[slot, g],
+                                      self.sems.at[i, slot])
+                for i, (hbm, buf) in enumerate(self.streams)]
+
+    def _live_in_block(self, j):
+        return jnp.minimum(self.hi - (self.lo + j * self.G), self.G)
+
+    def _fetch(self, j, slot):
+        """Start the copies of compute block ``j`` into ``slot``."""
+        G, v_buf, vs_buf = self.G, self.v_buf, self.vs_buf
+
+        def start(g, _):
+            block = self.bt_ref[self.b, self.lo + j * G + g]
+            for copy in self._page_copies(slot, g, block):
+                copy.start()
+
+        # a dead page of the last compute block: its keys are masked
+        # by the caller, and zeroed values keep 0 * stale from being a NaN
+        def zero(g, _):
+            v_buf[slot, g] = jnp.zeros(v_buf.shape[2:], v_buf.dtype)
+            if vs_buf is not None:
+                vs_buf[slot, g] = jnp.zeros(vs_buf.shape[2:], vs_buf.dtype)
+
+        n = self._live_in_block(j)
+        jax.lax.fori_loop(0, n, start, None)
+        jax.lax.fori_loop(n, G, zero, None)
+
+    def _wait(self, j, slot):
+        def wait_page(g, _):
+            # a wait needs the copy's shape only, not its source
+            for copy in self._page_copies(slot, g, 0):
+                copy.wait()
+
+        jax.lax.fori_loop(0, self._live_in_block(j), wait_page, None)
+
+    def start(self):
+        """Before the loop over compute blocks: the first block's copies."""
+        @pl.when(self.num_blocks > 0)
+        def _prologue():
+            self._fetch(0, 0)
+
+    def arrive(self, j):
+        """The slot that holds compute block ``j``, its copies landed."""
+        slot = jax.lax.rem(j, 2)
+
+        # the next block's copies fly under this block's arithmetic
+        @pl.when(j + 1 < self.num_blocks)
+        def _prefetch():
+            self._fetch(j + 1, 1 - slot)
+
+        self._wait(j, slot)
+        return slot
+
+    def _keys_major(self, buf, scale_buf, slot):
+        """One compute block as f32 [KVH, G * bs, D].  A quantized pool
+        dequantizes HERE, at the DMA boundary: codes * per-row scale, so
+        the wide KV copy never exists in HBM (ISSUE 20)."""
+        G, bs = self.G, self.bs
+        if scale_buf is None:
+            x = buf[slot].reshape(G * bs, *buf.shape[3:])
+            return jnp.swapaxes(x.astype(jnp.float32), 0, 1)
+        # a page's [1, bs] scale row turns into a [bs, 1] column (Mosaic
+        # has no layout for [bs] -> [bs, 1, 1]) and meets the codes
+        # AFTER the swap: the same products, elementwise
+        return jnp.concatenate(
+            [jnp.swapaxes(decode_codes(buf[slot, g], self.kv_dtype), 0, 1)
+             * scale_buf[slot, g][:, :bs].T[None] for g in range(G)],
+            axis=1)
+
+    def keys(self, slot):
+        return self._keys_major(self.k_buf, self.ks_buf, slot)
+
+    def values(self, slot):
+        return self._keys_major(self.v_buf, self.vs_buf, slot)
+
+
+def _split_walk_refs(rest, kv_dtype):
+    """A walking kernel's refs after its own inputs, in ``pallas_call``
+    order: the pools in HBM (``_pool_streams``' operands), the kernel's
+    outputs and own scratch, then ``_pool_streams``' scratch: the pools'
+    VMEM buffers and the DMA semaphores.  Returns ``(hbm_refs, own,
+    bufs, sems)``."""
+    n = 4 if kv_dtype is not None else 2
+    return rest[:n], rest[n:-n - 1], rest[-n - 1:-1], rest[-1]
+
+
+def _pool_streams(k_pool, v_pool, k_scale, v_scale, kv_dtype):
+    """What a walking kernel's ``pallas_call`` needs for its pools:
+    ``(in_specs, operands, scratch_shapes)``.  The pools (and a
+    quantized pool's scale rows) stay in HBM; each stream has a
+    ``[2, G, page]`` VMEM buffer and a DMA semaphore a slot."""
+    bs, KVH, D = k_pool.shape[1:]
+    # a compute block is about 128 keys, whatever the pool's page size:
+    # 8 pages at the served ``block_size`` 16
+    G = max(1, _LANES // bs)
+    in_specs = [pl.BlockSpec(memory_space=pl.ANY)] * 2
+    operands = [k_pool, v_pool]
+    scratch = [pltpu.VMEM((2, G, bs, KVH, D), k_pool.dtype),
+               pltpu.VMEM((2, G, bs, KVH, D), v_pool.dtype)]
     if kv_dtype is not None:
-        (ks_hbm, vs_hbm, o_ref, m_out_ref, l_out_ref,
-         k_buf, v_buf, ks_buf, vs_buf, sems) = rest
-        streams = ((k_hbm, k_buf), (v_hbm, v_buf),
-                   (ks_hbm, ks_buf), (vs_hbm, vs_buf))
-    else:
-        o_ref, m_out_ref, l_out_ref, k_buf, v_buf, sems = rest
-        ks_buf = vs_buf = None
-        streams = ((k_hbm, k_buf), (v_hbm, v_buf))
-    G = k_buf.shape[1]
+        # a page's [bs] f32 scale row rides with the page, one more copy;
+        # Mosaic slices an HBM array only in whole 128-lane rows
+        lanes = -(-bs // _LANES) * _LANES
+        in_specs += [pl.BlockSpec(memory_space=pl.ANY)] * 2
+        operands += [jnp.pad(sc, ((0, 0), (0, lanes - bs)))[:, None, :]
+                     for sc in (k_scale, v_scale)]
+        scratch += [pltpu.VMEM((2, G, 1, lanes), jnp.float32)] * 2
+    # one DMA semaphore a stream and slot
+    scratch.append(pltpu.SemaphoreType.DMA((len(scratch), 2)))
+    return in_specs, operands, scratch
+
+
+def _online_softmax(scores, values, m, l, acc):
+    """One online-softmax update: ``scores [KVH, R, K]`` (masked keys at
+    NEG_INF) against ``values [KVH, K, D]``, folded into the running max
+    ``m``, exp-sum ``l`` (``[KVH, R, 1]``) and accumulator ``acc``."""
+    m_new = jnp.maximum(m, jnp.max(scores, axis=-1, keepdims=True))
+    alpha = jnp.exp(m - m_new)
+    pexp = jnp.exp(scores - m_new)
+    acc = acc * alpha + jax.lax.dot_general(
+        pexp, values, (((2,), (1,)), ((0,), (0,))),
+        preferred_element_type=jnp.float32)             # [KVH, R, D]
+    l = l * alpha + jnp.sum(pexp, axis=-1, keepdims=True)
+    return m_new, l, acc
+
+
+def _decode_kernel(bt_ref, pos_ref, q_ref, cos_ref, sin_ref, *rest,
+                   bs, pages_per_split, scale, kv_dtype=None):
+    hbm_refs, (o_ref, m_out_ref, l_out_ref), bufs, sems = \
+        _split_walk_refs(rest, kv_dtype)
     b = pl.program_id(0)
     s = pl.program_id(1)
 
@@ -126,56 +265,10 @@ def _decode_kernel(bt_ref, pos_ref, q_ref, cos_ref, sin_ref, k_hbm, v_hbm,
     pos = pos_ref[b]
     live = jnp.minimum(pos // bs + 1, bt_ref.shape[1])
     lo = s * pages_per_split
-    hi = jnp.minimum(lo + pages_per_split, live)
-    num_blocks = jnp.maximum(hi - lo + G - 1, 0) // G
+    walk = _PageWalk(bt_ref, b, lo, jnp.minimum(lo + pages_per_split, live),
+                     hbm_refs, bufs, sems, kv_dtype)
+    G, num_blocks = walk.G, walk.num_blocks
     key_limit = jnp.minimum(pos + 1, (lo + pages_per_split) * bs)
-
-    def page_copies(slot, g, block):
-        return [pltpu.make_async_copy(hbm.at[block], buf.at[slot, g],
-                                      sems.at[i, slot])
-                for i, (hbm, buf) in enumerate(streams)]
-
-    def live_in_block(j):
-        return jnp.minimum(hi - (lo + j * G), G)
-
-    def fetch(j, slot):
-        def start(g, _):
-            for copy in page_copies(slot, g, bt_ref[b, lo + j * G + g]):
-                copy.start()
-
-        # a dead page of the last compute block: its keys are masked
-        # below, and zeroed values keep 0 * stale from being a NaN
-        def zero(g, _):
-            v_buf[slot, g] = jnp.zeros(v_buf.shape[2:], v_buf.dtype)
-            if vs_buf is not None:
-                vs_buf[slot, g] = jnp.zeros(vs_buf.shape[2:], vs_buf.dtype)
-
-        n = live_in_block(j)
-        jax.lax.fori_loop(0, n, start, None)
-        jax.lax.fori_loop(n, G, zero, None)
-
-    def wait(j, slot):
-        def wait_page(g, _):
-            # a wait needs the copy's shape only, not its source
-            for copy in page_copies(slot, g, 0):
-                copy.wait()
-
-        jax.lax.fori_loop(0, live_in_block(j), wait_page, None)
-
-    def keys_major(buf, scale_buf, slot):
-        """One compute block as f32 [KVH, G * bs, D].  A quantized pool
-        dequantizes HERE, at the DMA boundary: codes * per-row scale, so
-        the wide KV copy never exists in HBM (ISSUE 20)."""
-        if scale_buf is None:
-            x = buf[slot].reshape(G * bs, *buf.shape[3:])
-            return jnp.swapaxes(x.astype(jnp.float32), 0, 1)
-        # a page's [1, bs] scale row turns into a [bs, 1] column (Mosaic
-        # has no layout for [bs] -> [bs, 1, 1]) and meets the codes
-        # AFTER the swap: the same products, elementwise
-        return jnp.concatenate(
-            [jnp.swapaxes(decode_codes(buf[slot, g], kv_dtype), 0, 1)
-             * scale_buf[slot, g][:, :bs].T[None] for g in range(G)],
-            axis=1)
 
     # rotate + pre-scale q once per (batch, split) cell: RoPE lives
     # inside the kernel, and folding 1/sqrt(D) into q here keeps the
@@ -184,37 +277,17 @@ def _decode_kernel(bt_ref, pos_ref, q_ref, cos_ref, sin_ref, k_hbm, v_hbm,
                          cos_ref[0].astype(jnp.float32),    # [1, half]
                          sin_ref[0].astype(jnp.float32)) * scale
 
-    @pl.when(num_blocks > 0)
-    def _prologue():
-        fetch(0, 0)
+    walk.start()
 
     def compute_block(j, carry):
-        m, l, acc = carry
-        slot = jax.lax.rem(j, 2)
-
-        # the next block's copies fly under this block's arithmetic
-        @pl.when(j + 1 < num_blocks)
-        def _prefetch():
-            fetch(j + 1, 1 - slot)
-
-        wait(j, slot)
+        slot = walk.arrive(j)
         scores = jax.lax.dot_general(
-            q_rot, keys_major(k_buf, ks_buf, slot),
-            (((2,), (2,)), ((0,), (0,))),
+            q_rot, walk.keys(slot), (((2,), (2,)), ((0,), (0,))),
             preferred_element_type=jnp.float32)         # [KVH,rep,G*bs]
         k_pos = (lo + j * G) * bs + jax.lax.broadcasted_iota(
             jnp.int32, scores.shape, 2)
         scores = jnp.where(k_pos < key_limit, scores, NEG_INF)
-
-        m_new = jnp.maximum(m, jnp.max(scores, axis=-1, keepdims=True))
-        alpha = jnp.exp(m - m_new)
-        pexp = jnp.exp(scores - m_new)
-        acc = acc * alpha + jax.lax.dot_general(
-            pexp, keys_major(v_buf, vs_buf, slot),
-            (((2,), (1,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32)         # [KVH, rep, D]
-        l = l * alpha + jnp.sum(pexp, axis=-1, keepdims=True)
-        return m_new, l, acc
+        return _online_softmax(scores, walk.values(slot), *carry)
 
     KVH, rep, D = q_rot.shape
     # a split with no live page emits (NEG_INF, 0, 0): the combine
@@ -246,10 +319,9 @@ def _pallas_partials(q, cos_b, sin_b, k_pool, v_pool, block_table,
     bs = k_pool.shape[1]
     nbs = block_table.shape[1]
     P = nbs // num_splits
-    # a compute block is about 128 keys, whatever the pool's page size:
-    # 8 pages at the served ``block_size`` 16
-    G = max(1, 128 // bs)
     half = D // 2
+    pool_specs, pool_operands, scratch = _pool_streams(
+        k_pool, v_pool, k_scale, v_scale, kv_dtype)
 
     in_specs = [
         pl.BlockSpec((1, KVH, rep, D), lambda b, s, bt, pos: (b, 0, 0, 0)),
@@ -257,22 +329,9 @@ def _pallas_partials(q, cos_b, sin_b, k_pool, v_pool, block_table,
         # (1, half) block of a [B, half] array is not (8, 128)-tileable
         pl.BlockSpec((1, 1, half), lambda b, s, bt, pos: (b, 0, 0)),
         pl.BlockSpec((1, 1, half), lambda b, s, bt, pos: (b, 0, 0)),
-        pl.BlockSpec(memory_space=pl.ANY),
-        pl.BlockSpec(memory_space=pl.ANY),
+        *pool_specs,
     ]
-    operands = [q, cos_b[:, None, :], sin_b[:, None, :], k_pool, v_pool]
-    scratch = [pltpu.VMEM((2, G, bs, KVH, D), k_pool.dtype),
-               pltpu.VMEM((2, G, bs, KVH, D), v_pool.dtype)]
-    if kv_dtype is not None:
-        # a page's [bs] f32 scale row rides with the page, one more copy;
-        # Mosaic slices an HBM array only in whole 128-lane rows
-        lanes = -(-bs // _LANES) * _LANES
-        in_specs += [pl.BlockSpec(memory_space=pl.ANY)] * 2
-        operands += [jnp.pad(sc, ((0, 0), (0, lanes - bs)))[:, None, :]
-                     for sc in (k_scale, v_scale)]
-        scratch += [pltpu.VMEM((2, G, 1, lanes), jnp.float32)] * 2
-    # one DMA semaphore a stream and slot
-    scratch.append(pltpu.SemaphoreType.DMA((len(scratch), 2)))
+    operands = [q, cos_b[:, None, :], sin_b[:, None, :], *pool_operands]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,

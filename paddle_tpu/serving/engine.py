@@ -827,7 +827,7 @@ class Engine:
         real token."""
         bs = self.config.block_size
         C = self.chunk_tokens
-        self.metrics.on_prefill_dispatch(req.request_id)
+        self.metrics.on_prefill_dispatch(req.request_id, start, n_tok)
         # blocks this chunk writes: CoW any that are shared/registered
         # (a cache hit whose last block the final recompute token lands
         # in, or blocks registered by a previous admission)

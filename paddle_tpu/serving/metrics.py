@@ -118,6 +118,7 @@ class ServingMetrics:
         self.engine_steps = 0
         self.prefill_steps = 0          # steps that ran >= 1 chunk
         self.prefill_chunks_run = 0     # raised per chunk dispatched
+        self.prefill_context_tokens = 0  # sum of start + tokens per chunk
         self.decode_context_tokens = 0  # sum of active lengths per step
         self.admissions = 0             # requests admitted a first time
         self.queue_wait_ns = 0          # first admission - submit
@@ -186,9 +187,12 @@ class ServingMetrics:
         the name), and its host time into ``step_ns[name]``."""
         return _Phase(self, name, metadata)
 
-    def on_prefill_dispatch(self, request_id: str):
-        """One chunk is about to be dispatched for ``request_id``."""
+    def on_prefill_dispatch(self, request_id: str, start: int, tokens: int):
+        """One chunk of ``tokens`` prompt tokens at positions ``start ..``
+        is about to be dispatched for ``request_id``."""
         self.prefill_chunks_run += 1
+        # the key positions the chunk kernel's walk covers
+        self.prefill_context_tokens += start + tokens
         t = self.requests[request_id]
         if t.first_chunk_ns == 0:
             t.first_chunk_ns = _now_ns()
@@ -508,6 +512,7 @@ class ServingMetrics:
                 "engine_steps": self.engine_steps,
                 "prefill_steps": self.prefill_steps,
                 "prefill_chunks_run": self.prefill_chunks_run,
+                "prefill_context_tokens": self.prefill_context_tokens,
                 "decode_context_tokens": self.decode_context_tokens,
                 "prompt_tokens": self._prompt_tokens_sum,
                 "cached_prompt_tokens": self._cached_tokens_sum,

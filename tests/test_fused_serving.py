@@ -11,6 +11,7 @@ leaf (no S210); bad cost annotations fail loudly at registration.
 """
 import functools
 import json
+import math
 import os
 
 import jax
@@ -25,7 +26,8 @@ from paddle_tpu.kernels.costs import (KernelCost, register_kernel_cost,
 from paddle_tpu.kernels.fused_norm_linear import (fused_norm_linear,
                                                   fused_rmsnorm_linear,
                                                   rms_scale)
-from paddle_tpu.kernels.kv_quant import quantize_kv
+from paddle_tpu.kernels.chunked_prefill import fused_chunked_attention
+from paddle_tpu.kernels.kv_quant import decode_codes, quantize_kv
 from paddle_tpu.kernels.paged_attention import (fused_paged_decode,
                                                 paged_decode_reference)
 from paddle_tpu.models import LlamaConfig, LlamaForCausalLM
@@ -143,6 +145,34 @@ _POOL_KINDS = [None, "int8", "fp8"]     # None: bf16 values in f32 pools
 _POOL_IDS = ["bf16", "int8", "fp8"]
 
 
+def _bf16_values(rng, *shape):
+    x = jnp.asarray(rng.randn(*shape), jnp.bfloat16)
+    return np.array(x.astype(jnp.float32))
+
+
+def _trapped_pools(rng, shape, kv_dtype):
+    """K and V pools ``[nb, bs, KVH, D]`` whose block 0 is the garbage
+    block (1e3 keys, -1e3 values) and whose last block is the poison (1e3
+    keys, NaN values); quantized to ``kv_dtype`` where given, the poison
+    then in the scale rows.  Returns ``(k_pool, v_pool, kw)``."""
+    nb, bs, KVH, D = shape
+    k_pool, v_pool = _bf16_values(rng, *shape), _bf16_values(rng, *shape)
+    k_pool[0], v_pool[0] = 1e3, -1e3
+    k_pool[-1], v_pool[-1] = 1e3, np.nan
+    if kv_dtype is None:
+        return k_pool, v_pool, {}
+    pools = []
+    for pool in (k_pool, v_pool):
+        pool[-1] = 1e3                  # the poison rides in the scale
+        codes, scale = quantize_kv(
+            jnp.asarray(pool).reshape(nb * bs, KVH, D), kv_dtype)
+        scale = scale.reshape(nb, bs).at[-1].set(jnp.nan)
+        pools.append((codes.reshape(pool.shape), scale))
+    (k_pool, k_scale), (v_pool, v_scale) = pools
+    return k_pool, v_pool, dict(k_scale=k_scale, v_scale=v_scale,
+                                kv_cache_dtype=kv_dtype)
+
+
 def _ragged_operands(bs, kv_dtype=None, dead="own", seed=0, KVH=2, rep=2,
                      D=8):
     """One batch with every edge of the walk in it: an idle slot (length
@@ -162,31 +192,13 @@ def _ragged_operands(bs, kv_dtype=None, dead="own", seed=0, KVH=2, rep=2,
     B, H = len(positions), KVH * rep
     nb = 2 + B * nbs                    # garbage block, B rows, poison
     rng = np.random.RandomState(seed)
-
-    def bf16_values(*shape):
-        x = jnp.asarray(rng.randn(*shape), jnp.bfloat16)
-        return np.array(x.astype(jnp.float32))
-
-    q, k_new, v_new = (bf16_values(B, 1, n, D) for n in (H, KVH, KVH))
-    k_pool, v_pool = bf16_values(nb, bs, KVH, D), bf16_values(nb, bs, KVH, D)
-    k_pool[0], v_pool[0] = 1e3, -1e3
-    k_pool[-1], v_pool[-1] = 1e3, np.nan
+    q, k_new, v_new = (_bf16_values(rng, B, 1, n, D) for n in (H, KVH, KVH))
     table = (1 + np.arange(B * nbs)).reshape(B, nbs).astype(np.int32)
     if dead != "own":
         past = np.arange(nbs)[None, :] > positions[:, None] // bs
         table[past] = {"poison": nb - 1, "outside": nb + 3}[dead]
     table[0] = 0                        # the idle slot
-    kw = {}
-    if kv_dtype is not None:
-        pools = []
-        for pool in (k_pool, v_pool):
-            pool[-1] = 1e3              # the poison rides in the scale
-            codes, scale = quantize_kv(
-                jnp.asarray(pool).reshape(nb * bs, KVH, D), kv_dtype)
-            scale = scale.reshape(nb, bs).at[-1].set(jnp.nan)
-            pools.append((codes.reshape(pool.shape), scale))
-        (k_pool, kw["k_scale"]), (v_pool, kw["v_scale"]) = pools
-        kw["kv_cache_dtype"] = kv_dtype
+    k_pool, v_pool, kw = _trapped_pools(rng, (nb, bs, KVH, D), kv_dtype)
     args = (q, k_new, v_new, k_pool, v_pool, table, positions,
             *_rope_tables(nbs * bs + 1, D))
     return tuple(jnp.asarray(a) for a in args), kw
@@ -224,6 +236,149 @@ class TestPagedDecodeRaggedWalk:
                 **kw)[0]))
         assert np.isfinite(outs[0]).all()
         np.testing.assert_array_equal(outs[1], outs[0])
+
+
+# ---------------------------------------------------------------------------
+# prefill chunks: the Pallas walk is bounded by the chunk's own context
+# ---------------------------------------------------------------------------
+
+_CHUNK = 24                             # query tokens a sequence
+
+
+def _chunk_walk_operands(bs, kv_dtype=None, dead="own", mask_block=1,
+                         KVH=2, rep=4, D=8, seed=0):
+    """One batch of chunks with every edge of the walk in it: a chunk
+    that starts at 0, contexts that end in the middle of a page, in the
+    middle of a compute block, on a compute block's last key and one key
+    into the next, the table's last chunk, and one whose padded tail
+    runs a page past the table's end (the clamp).
+
+    ``dead`` says what the table holds PAST each chunk's live pages
+    (``ceil((start + T) / bs)`` of them), as in ``_ragged_operands``.
+    Block 0 is the garbage block; the last is the poison."""
+    G = max(1, 128 // bs)
+    K, T = G * bs, _CHUNK
+    nbs = 3 * G
+    ends = [T, K + bs + bs // 2, K + K // 2, 2 * K, K + 1, nbs * bs,
+            nbs * bs + bs]
+    starts = np.array([e - T for e in ends], np.int32)
+    starts -= starts % mask_block       # a chunk holds whole mask blocks
+    B, H = len(starts), KVH * rep
+    nb = 2 + B * nbs
+    rng = np.random.RandomState(seed)
+    q = _bf16_values(rng, B, T, H, D)
+    table = (1 + np.arange(B * nbs)).reshape(B, nbs).astype(np.int32)
+    if dead != "own":
+        past = np.arange(nbs)[None, :] >= -(-(starts + T) // bs)[:, None]
+        table[past] = {"poison": nb - 1, "outside": nb + 3}[dead]
+    k_pool, v_pool, kw = _trapped_pools(rng, (nb, bs, KVH, D), kv_dtype)
+    args = (q, k_pool, v_pool, table, starts)
+    return tuple(jnp.asarray(a) for a in args), kw
+
+
+def _chunk_gather_reference(q, k_pool, v_pool, table, starts, *,
+                            mask_block=1, k_scale=None, v_scale=None,
+                            kv_cache_dtype=None):
+    """models/llama.py's unfused ``_paged_attn`` gather path (a quantized
+    pool dequantized whole, the KV heads repeated to H, a full softmax),
+    with the block-causal mask of models/sdar_moe.py where asked."""
+    B, T, H, D = q.shape
+    if kv_cache_dtype is not None:
+        k_pool = decode_codes(k_pool, kv_cache_dtype) \
+            * k_scale[:, :, None, None]
+        v_pool = decode_codes(v_pool, kv_cache_dtype) \
+            * v_scale[:, :, None, None]
+    rep = H // k_pool.shape[2]
+    kb = jnp.repeat(k_pool[table].reshape(B, -1, *k_pool.shape[2:]), rep, 2)
+    vb = jnp.repeat(v_pool[table].reshape(B, -1, *v_pool.shape[2:]), rep, 2)
+    scores = jnp.einsum("bthd,bshd->bhts", q, kb) / math.sqrt(D)
+    q_pos = starts[:, None] + jnp.arange(T)
+    last_seen = q_pos - q_pos % mask_block + mask_block - 1
+    seen = jnp.arange(kb.shape[1])[None, None, :] <= last_seen[:, :, None]
+    probs = jax.nn.softmax(jnp.where(seen[:, None], scores, -1e30), axis=-1)
+    return jnp.einsum("bhts,bshd->bthd", probs, vb)
+
+
+class TestChunkedPrefillLiveWalk:
+    def _three_way(self, args, kw, mask_block):
+        ref = _chunk_gather_reference(*args, mask_block=mask_block, **kw)
+        for use_pallas in (True, False):
+            got = fused_chunked_attention(*args, use_pallas=use_pallas,
+                                          interpret=True,
+                                          mask_block=mask_block, **kw)
+            np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
+                                       rtol=2e-5, atol=2e-5)
+
+    @pytest.mark.parametrize("kv_dtype", _POOL_KINDS, ids=_POOL_IDS)
+    @pytest.mark.parametrize("mask_block", [1, 4])
+    @pytest.mark.parametrize("bs", [4, 16])
+    def test_three_way_parity(self, bs, mask_block, kv_dtype):
+        args, kw = _chunk_walk_operands(bs, kv_dtype, mask_block=mask_block)
+        self._three_way(args, kw, mask_block)
+
+    @pytest.mark.parametrize("mask_block", [1, 4])
+    @pytest.mark.parametrize("KVH,rep", [(1, 8), (4, 1)],
+                             ids=["gqa8", "mha"])
+    def test_three_way_parity_by_heads(self, KVH, rep, mask_block):
+        args, kw = _chunk_walk_operands(16, mask_block=mask_block, KVH=KVH,
+                                        rep=rep)
+        self._three_way(args, kw, mask_block)
+
+    @pytest.mark.parametrize("kv_dtype", _POOL_KINDS, ids=_POOL_IDS)
+    @pytest.mark.parametrize("mask_block", [1, 4])
+    @pytest.mark.parametrize("dead", ["poison", "outside"])
+    def test_walk_ends_at_the_context(self, dead, mask_block, kv_dtype):
+        # table entries past a chunk's live pages are never read: a
+        # fetched NaN value would survive its zero weight (0 * NaN), and
+        # the interpreter clamps an id beyond the pool onto the NaN block
+        outs = []
+        for past in ("own", dead):
+            args, kw = _chunk_walk_operands(16, kv_dtype, dead=past,
+                                            mask_block=mask_block)
+            outs.append(np.asarray(fused_chunked_attention(
+                *args, use_pallas=True, interpret=True,
+                mask_block=mask_block, **kw)))
+        assert np.isfinite(outs[0]).all()
+        np.testing.assert_array_equal(outs[1], outs[0])
+
+    @pytest.mark.parametrize("mask_block", [1, 4])
+    def test_padded_tail_over_unallocated_pages(self, mask_block):
+        # a prompt's last chunk: the pages past its real tokens were
+        # never allocated (the table says 0, the garbage block, where the
+        # padding's K/V went); the real rows see none of it
+        bs, real = 16, 8
+        args, kw = _chunk_walk_operands(bs, mask_block=mask_block)
+        q, k_pool, v_pool, table, starts = args
+        past = np.arange(table.shape[1])[None, :] \
+            >= -(-(np.asarray(starts) + real) // bs)[:, None]
+        padded = jnp.where(past, 0, table)
+        outs = [np.asarray(fused_chunked_attention(
+            q, k_pool, v_pool, t, starts, use_pallas=True, interpret=True,
+            mask_block=mask_block)) for t in (table, padded)]
+        ref = _chunk_gather_reference(q, k_pool, v_pool, padded, starts,
+                                      mask_block=mask_block)
+        np.testing.assert_array_equal(outs[1][:, :real], outs[0][:, :real])
+        # the padding's rows average the garbage block's 1e3s, the same
+        # discarded garbage on every path
+        np.testing.assert_allclose(outs[1], np.asarray(ref), rtol=2e-4,
+                                   atol=2e-5)
+
+    @pytest.mark.parametrize("KVH,rep,tiles", [(8, 4, 2), (32, 1, 3)])
+    def test_query_rows_tiled_over_the_grid(self, KVH, rep, tiles):
+        # more rows than one cell holds: each row tile walks the pages
+        # itself, and a tile's rows keep their own positions
+        from paddle_tpu.kernels.chunked_prefill import _row_tile
+
+        T, bs, D = tiles * 2048 // (KVH * rep), 16, 8
+        assert (rep * T) // _row_tile(rep * T, KVH) == tiles
+        rng = np.random.RandomState(1)
+        q = jnp.asarray(rng.randn(2, T, KVH * rep, D), jnp.float32)
+        k_pool, v_pool = (jnp.asarray(rng.randn(40, bs, KVH, D), jnp.float32)
+                          for _ in range(2))
+        table = jnp.asarray(rng.permutation(39)[:2 * 16].reshape(2, 16) + 1,
+                            jnp.int32)
+        starts = jnp.asarray([0, 16 * bs - T], jnp.int32)
+        self._three_way((q, k_pool, v_pool, table, starts), {}, 1)
 
 
 # ---------------------------------------------------------------------------

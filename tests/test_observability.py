@@ -400,6 +400,8 @@ class TestServingMirror:
         "engine_steps", "prefill_steps", "prefill_chunks_run",
         "decode_context_tokens", "prompt_tokens", "cached_prompt_tokens",
         "admissions", "queue_wait_ns", "lane_wait_ns",
+        # what the chunk kernel's walk covers (ISSUE 31)
+        "prefill_context_tokens",
         # the block iteration and the routed experts (ISSUE 30)
         "block_steps", "block_slot_steps", "commit_slot_steps",
         "tokens_unmasked", "blocks_committed", "block_context_tokens",

@@ -18,7 +18,7 @@ from paddle_tpu.serving.metrics import PHASES
 
 NEW_COUNTERS = (
     "engine_steps", "prefill_steps", "prefill_chunks_run",
-    "decode_context_tokens", "prompt_tokens", "cached_prompt_tokens",
+    "prefill_context_tokens", "decode_context_tokens", "prompt_tokens", "cached_prompt_tokens",
     "admissions", "queue_wait_ns", "lane_wait_ns")
 SPANS = {"serving::" + p for p in PHASES}
 MODEL_SCOPES = ("embed", "attn_qkv", "kv_write", "attn", "attn_out", "mlp",
@@ -73,6 +73,8 @@ def test_counters_of_a_workload_worked_out_by_hand(model):
     c = eng.metrics.as_dict()["counters"]
     assert steps == c["engine_steps"] == 4
     assert c["prefill_steps"] == 3 and c["prefill_chunks_run"] == 3
+    # each chunk's start + tokens: the keys its kernel's walk covers
+    assert c["prefill_context_tokens"] == (0 + 4) + (4 + 2) + (0 + 3)
     assert c["prompt_tokens"] == 9
     assert c["cached_prompt_tokens"] == 0
     assert c["decode_iterations"] == 3

@@ -173,22 +173,40 @@ def test_fused_paged_decode(compile_for_chip, kv_dtype, pool_dtype, batch,
     assert "tpu_custom_call" in text and "fused_paged_decode" in text
 
 
+# the later cases are the benchmark's serving cells: a table of 256
+# pages; Mistral's 8 KV heads over 2,048 blocks, SDAR's 4 over 4,096
+# under the block-causal mask
+@pytest.mark.parametrize("kvh,pages,num_blocks,mask_block",
+                         [(KVH, PAGES, NUM_BLOCKS, 1), (KVH, 256, 2048, 1),
+                          (4, 256, 4096, 4)],
+                         ids=["table128", "mistral-served", "sdar-served"])
 @pytest.mark.parametrize("kv_dtype,pool_dtype", _POOLS, ids=_POOL_IDS)
-def test_fused_chunked_attention(compile_for_chip, kv_dtype, pool_dtype):
+def test_fused_chunked_attention(compile_for_chip, kv_dtype, pool_dtype,
+                                 kvh, pages, num_blocks, mask_block):
     cp = _kernel("chunked_prefill")
-    pools, scales = _pool_avals(pool_dtype, kv_dtype)
+    pool = ((num_blocks, BLOCK, kvh, D), pool_dtype)
+    scales = [((num_blocks, BLOCK), f32)] * 2 if kv_dtype else []
 
     def chunk(q, kp, vp, table, pos, *sc):
         ks, vs = sc if sc else (None, None)
         return cp.fused_chunked_attention(
             q, kp, vp, table, pos, use_pallas=True, interpret=False,
-            k_scale=ks, v_scale=vs, kv_cache_dtype=kv_dtype)
+            k_scale=ks, v_scale=vs, kv_cache_dtype=kv_dtype,
+            mask_block=mask_block)
 
     text = compile_for_chip(
-        chunk, ((1, CHUNK, H, D), bf16), *pools, ((1, PAGES), i32),
+        chunk, ((1, CHUNK, H, D), bf16), pool, pool, ((1, pages), i32),
         ((1,), i32), *scales)
     assert "tpu_custom_call" in text and "fused_chunked_prefill" in text
-
+    # the walk's buffers, q, the accumulator and a score tile together:
+    # within the 16 MiB a kernel may scope, with room for what XLA fuses
+    # into its operands inside a whole step program
+    used = [int(n) for line in text.splitlines()
+            if "tpu_custom_call" in line and "fused_chunked_prefill" in line
+            for n in re.findall(r'"used_scoped_memory_configs":\[\{'
+                                r'"memory_space":"1","offset":"0",'
+                                r'"size":"(\d+)"', line)]
+    assert used and max(used) <= 14 << 20
 
 
 # ---- a block-diffusion model's kernels at SDAR-30B-A3B widths: 32 heads,
