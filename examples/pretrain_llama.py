@@ -1,4 +1,4 @@
-"""Hybrid-parallel Llama pretraining example (BASELINE config 3 shape).
+"""Hybrid-parallel Llama pretraining example.
 
 Single chip:       python examples/pretrain_llama.py
 8 virtual devices: JAX_PLATFORMS=cpu \

@@ -1,5 +1,5 @@
-"""End-to-end eager + compiled training example (BASELINE config 1 shape:
-vision model, single chip).  Synthetic data stands in for MNIST when no
+"""End-to-end eager + compiled training example (a vision model on a
+single chip).  Synthetic data stands in for MNIST when no
 local dataset is staged (no network egress).
 
 Run:  python examples/train_mnist.py [--steps 200]

@@ -159,8 +159,8 @@ _DIFF_DTYPE_CACHE = {}
 # ---------------------------------------------------------------------------
 # Analytic eager VJP rules: jax.vjp re-linearizes the op on EVERY eager call
 # (measured ~3050 us/op on this image's CPU for a 6-op fwd+bwd training
-# chain vs ~250 us/op with the rules — 11.9x; gated by
-# tools/check_eager_overhead.py), which is pure overhead when the backward
+# chain vs ~250 us/op with the rules — 11.9x), which is pure
+# overhead when the backward
 # is a closed form.  We record the closed form directly and skip jax.vjp —
 # the analog of the reference's codegen'd per-op GradNode pairs
 # (imperative/tracer.cc TraceOpImpl + generated grad ops).  jax.vjp remains

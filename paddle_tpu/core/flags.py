@@ -66,13 +66,8 @@ def flag(name: str):
 define_flag("check_nan_inf", False, "check every op output for nan/inf")
 define_flag("eager_op_jit", True, "jit-compile eager per-op computations")
 define_flag("allocator_strategy", "auto_growth", "kept for API parity; XLA owns HBM")
-define_flag("use_pallas_kernels", True, "use Pallas kernels for fused ops on TPU")
 define_flag("use_autotune", False, "search + cache kernel tile sizes "
             "(reference: phi/kernels/autotune switch_autotune)")
-define_flag("use_fused_serving", True,
-            "fused paged-attention decode + RMSNorm->matmul epilogues on "
-            "the serving hot path (TPU default; CPU runs the XLA fallback "
-            "only when forced via ServingConfig(fused_kernels=True))")
 define_flag("benchmark", False, "synchronize after every op (timing mode)")
 define_flag("flash_block_q", 0,
             "override flash-attention q-block size (0 = default/autotune)")

@@ -51,6 +51,20 @@ def get_mesh() -> Optional[Mesh]:
     return _GLOBAL_MESH
 
 
+def mesh_live() -> bool:
+    """A device mesh is in force at this trace: the global fleet mesh, a
+    registered ``MeshExecutor``'s, or a manual-mp ``shard_map`` stage.
+    The Pallas kernels have no partitioning rule (Mosaic: "kernels
+    cannot be automatically partitioned"), so under a mesh every kernel
+    site takes its XLA form until the kernels are wrapped in
+    ``shard_map`` (ROADMAP Speed 9)."""
+    from .executor import active_mesh
+    from .parallel_layers import manual_axis
+
+    return get_mesh() is not None or active_mesh() is not None \
+        or manual_axis("mp")[0] is not None
+
+
 def reset_mesh():
     """Clear the process-global mesh + HCG (the teardown half of
     fleet.init; reference analog: fleet_base stop_worker releasing the

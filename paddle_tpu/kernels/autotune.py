@@ -11,7 +11,7 @@ static defaults and never pay the search.
 
 Cache keys are CHIP-QUALIFIED: the same op/shape tunes differently on
 v5e vs v6e vs the CPU fallback, so the accelerator kind is stamped
-into every key.  ``--retune`` (bench.py) or PADDLE_TPU_RETUNE=1 is the
+into every key.  PADDLE_TPU_RETUNE=1 (or ``set_retune``) is the
 escape hatch: cached winners are ignored and re-measured once, then
 the fresh result overwrites the disk cache.
 """
@@ -45,7 +45,7 @@ def _chip() -> str:
 
 
 def set_retune(enabled: bool):
-    """Ignore cached winners and re-measure (bench --retune)."""
+    """Ignore cached winners and re-measure."""
     global _retune
     _retune = bool(enabled)
 

@@ -256,8 +256,7 @@ def fused_chunked_attention(q, k_pool, v_pool, block_table, positions,
     with an online softmax; elsewhere the numerically-identical XLA
     lowering runs instead.
     """
-    from ..core.flags import flag
-    from .fusion import pallas_interpret_forced
+    from .fusion import pallas_lowering
 
     B, T, H, D = q.shape
     KVH = k_pool.shape[2]
@@ -265,14 +264,7 @@ def fused_chunked_attention(q, k_pool, v_pool, block_table, positions,
     positions = jnp.asarray(positions, jnp.int32)
     scale = 1.0 / math.sqrt(D)
 
-    if use_pallas is None:
-        if pallas_interpret_forced():
-            use_pallas, interpret = True, True
-        else:
-            use_pallas = bool(flag("use_pallas_kernels")) and \
-                jax.default_backend() == "tpu"
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    use_pallas, interpret = pallas_lowering(use_pallas, interpret)
 
     # GQA grouping: head h = kvh * rep + r, so the grouped row index is
     # r * T + t and every row of group kvh reads KV head kvh

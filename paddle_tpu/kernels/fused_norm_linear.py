@@ -155,18 +155,11 @@ def fused_norm_linear(x, row_scale, norm_weight, w, activation="none",
     ONCE and shared by every projection off the same normalized
     activation); norm_weight: [K]; w: [K, N].
     """
-    from ..core.flags import flag
-    from .fusion import pallas_interpret_forced
+    from .fusion import pallas_lowering
 
     if activation not in _ACTS:
         raise ValueError(f"unsupported activation {activation!r}")
-    if use_pallas is None and pallas_interpret_forced():
-        use_pallas, interpret = True, True
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    if use_pallas is None:
-        use_pallas = bool(flag("use_pallas_kernels")) and \
-            jax.default_backend() == "tpu"
+    use_pallas, interpret = pallas_lowering(use_pallas, interpret)
     lead = x.shape[:-1]
     K = x.shape[-1]
     x2d = x.reshape(-1, K)

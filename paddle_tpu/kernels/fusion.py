@@ -1,22 +1,25 @@
-"""Serving-fusion mode: one switch for the fused decode hot path.
+"""Which form of a serving step is traced, and how its kernels lower.
 
-The fused paged-attention decode kernel and the RMSNorm->matmul
-epilogue fusions change WHICH program the model traces to, so the
-decision must be made at trace time and must be consistent for the
-lifetime of a compiled step (the zero-retrace contract).  The step
-builders in models/generation.py resolve the mode ONCE per step and
-pin it around the traced body with ``serving_fusion(...)``; the model
-code consults ``fusion_enabled()`` wherever the fused and unfused
-paths fork.
+The fused paged-attention decode kernel, the fused chunk kernel and the
+RMSNorm->matmul fusions change WHICH program the model traces to, so
+the decision is made at trace time and holds for the lifetime of a
+compiled step (the zero-retrace contract).  The step builders in
+models/generation.py resolve the mode ONCE per step and pin it around
+the traced body with ``serving_fusion(...)``; the model code asks
+``fusion_enabled()`` wherever the fused and the gather paths fork.
 
-Resolution order:
-  1. an active ``serving_fusion(...)`` context (the step builders);
-  2. else the default: FLAGS_use_fused_serving AND a TPU backend.
+The path (``fusion_enabled``):
+  1. never fused while a mesh is live (the kernels have no
+     partitioning rule; asked at trace time, because a mesh may be
+     installed after a step is built);
+  2. else the mode pinned by ``serving_fusion(...)``;
+  3. else fused, on every backend.
 
-On CPU the fused path lowers to the numerically-identical XLA
-fallback, so forcing it on (``serving_fusion(True)`` /
-``ServingConfig(fused_kernels=True)``) is how tier-1 and CI cover the
-exact fused math without a TPU.
+The lowering (``pallas_lowering``): the fused math runs as its Pallas
+kernels on a TPU and as the numerically-identical XLA lowering
+elsewhere, so tier-1 on the CPU guards by default the math the chip
+runs.  ``force_pallas_interpret()`` puts the real ``pallas_call`` into a
+trace on any backend, for the analysers.
 """
 from __future__ import annotations
 
@@ -28,28 +31,21 @@ import jax
 _tls = threading.local()
 
 
-def _default_enabled() -> bool:
-    from ..core.flags import flag
-
-    return bool(flag("use_fused_serving")) and \
-        jax.default_backend() == "tpu"
-
-
 def fusion_enabled() -> bool:
-    """The trace-time fused/unfused fork the model code consults."""
-    override = getattr(_tls, "override", None)
-    if override is not None:
-        return bool(override)
-    return _default_enabled()
+    """The trace-time fork between the fused and the gather path that
+    the model code asks."""
+    from ..distributed.mesh import mesh_live
+
+    if getattr(_tls, "override", None) is False:
+        return False
+    return not mesh_live()
 
 
 def resolve_serving_fusion(fused=None) -> bool:
-    """Pin a step's fusion mode: an explicit request wins, else the
-    flag/backend default.  Called once per step build so the compiled
-    program never flips mode between calls."""
-    if fused is None:
-        return _default_enabled()
-    return bool(fused)
+    """Pin a step's fusion mode: an explicit request wins, else fused.
+    Called once per step build so the compiled program never flips mode
+    between calls."""
+    return True if fused is None else bool(fused)
 
 
 @contextlib.contextmanager
@@ -64,26 +60,30 @@ def serving_fusion(enabled: bool):
         _tls.override = prev
 
 
-def pallas_interpret_forced() -> bool:
-    """True inside a ``force_pallas_interpret()`` context: the fused
-    kernels resolve ``use_pallas=True, interpret=True`` regardless of
-    backend, so the traced program carries the REAL pallas_call leaves.
-    Off-TPU the fused steps normally lower to the XLA fallback, which is
-    right for execution but blinds static analysis: the fusion miner's
-    F004 already-fused accounting and the priced-pallas CI gates need
-    the kernel to appear in the jaxpr on any backend."""
-    return bool(getattr(_tls, "force_interpret", False))
-
-
 @contextlib.contextmanager
 def force_pallas_interpret(enabled: bool = True):
-    """Trace-time context: fused kernels that would pick the XLA
-    fallback off-TPU take the Pallas path in interpret mode instead
-    (analysis-only — interpret execution is slow and never the serving
-    path)."""
+    """Trace-time context: kernels that would pick the XLA fallback
+    off-TPU resolve ``use_pallas=True, interpret=True`` instead, so the
+    traced program carries the REAL pallas_call leaves.  Off-TPU the
+    fused steps normally lower to the XLA fallback, which is right for
+    execution but blinds static analysis: the fusion miner's F004
+    already-fused accounting and the priced-pallas CI gates need the
+    kernel to appear in the jaxpr on any backend (analysis-only —
+    interpret execution is slow and never the serving path)."""
     prev = getattr(_tls, "force_interpret", None)
     _tls.force_interpret = bool(enabled)
     try:
         yield
     finally:
         _tls.force_interpret = prev
+
+
+def pallas_lowering(use_pallas=None, interpret=None):
+    """``(use_pallas, interpret)`` for a kernel wrapper: what the caller
+    passed, else the forced-interpret context, else Pallas exactly on a
+    TPU backend and ``interpret`` exactly off it."""
+    if use_pallas is None and getattr(_tls, "force_interpret", False):
+        return True, True
+    on_tpu = jax.default_backend() == "tpu"
+    return (on_tpu if use_pallas is None else bool(use_pallas),
+            not on_tpu if interpret is None else bool(interpret))

@@ -217,8 +217,7 @@ def grouped_experts(x, chosen, gates, w_gate, w_up, w_down, *,
     an assignment to an expert that is not held adds nothing here (its
     holder adds it).  ``token_valid [T]`` False leaves a token out: it
     reads no expert and its output row is zero."""
-    from ..core.flags import flag
-    from .fusion import pallas_interpret_forced
+    from .fusion import pallas_lowering
 
     T, H = x.shape
     K = chosen.shape[1]
@@ -242,14 +241,7 @@ def grouped_experts(x, chosen, gates, w_gate, w_up, w_down, *,
     # (a row of padding names assignment T * K: the zero row)
     x_rows = x_ext[row_src // K]
 
-    if use_pallas is None:
-        if pallas_interpret_forced():
-            use_pallas, interpret = True, True
-        else:
-            use_pallas = bool(flag("use_pallas_kernels")) and \
-                jax.default_backend() == "tpu"
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    use_pallas, interpret = pallas_lowering(use_pallas, interpret)
     if use_pallas:
         if block_m is None:
             block_m = next(b for b in (256, 128, M) if M % b == 0)

@@ -36,9 +36,7 @@ fused-step math with no pallas_call in the program.  The unfused
 reference (``paged_decode_reference``) reproduces models/llama.py's
 scatter/gather path for parity tests.
 
-``num_splits`` is autotuned (FLAGS_use_autotune) through
-kernels/autotune keyed on (chip, head_dim, kv_block_size,
-max_blocks_per_seq, dtype) and persisted to the JSON cache.
+``num_splits`` defaults to ``_default_splits`` of the table's depth.
 """
 from __future__ import annotations
 
@@ -429,7 +427,7 @@ def _combine_splits(acc, m, l):
 
 
 # ---------------------------------------------------------------------------
-# autotuning
+# split-K width
 # ---------------------------------------------------------------------------
 
 def _split_candidates(nbs):
@@ -444,64 +442,6 @@ def _default_splits(nbs):
         if s <= max(1, nbs // 2) and s <= 4:
             best = s
     return best
-
-
-def _autotuned_splits(q, k_pool, block_table, interpret):
-    """num_splits via the autotune cache (FLAGS_use_autotune), keyed on
-    (chip, head_dim, kv_block_size, max_blocks_per_seq, dtype) — chip
-    is stamped into the key by kernels/autotune itself."""
-    from ..core.flags import flag
-    from . import autotune as at
-
-    nbs = block_table.shape[1]
-    if not flag("use_autotune"):
-        return _default_splits(nbs)
-    D = q.shape[-1]
-    bs = k_pool.shape[1]
-    key = (D, bs, nbs, str(k_pool.dtype))
-    if isinstance(q, jax.core.Tracer):
-        hit = at.lookup("paged_attn_decode", key)
-        return hit[0] if hit else _default_splits(nbs)
-    cands = _split_candidates(nbs)
-    if len(cands) == 1:
-        return cands[0]
-
-    jitted = {}
-
-    def run(cfg):
-        fn = jitted.get(cfg)
-        if fn is None:
-            fn = jax.jit(functools.partial(
-                fused_paged_decode, num_splits=cfg[0],
-                interpret=interpret))
-            jitted[cfg] = fn
-        out, kp, vp = fn(*_autotune_args)
-        jax.block_until_ready(out)
-
-    # the eager caller's actual operands double as the timing workload
-    _autotune_args = _AUTOTUNE_OPERANDS.get("args")
-    if _autotune_args is None:
-        return _default_splits(nbs)
-    best = at.autotune("paged_attn_decode", key,
-                       [(s,) for s in cands], run)
-    return best[0] if best else _default_splits(nbs)
-
-
-_AUTOTUNE_OPERANDS: dict = {}
-
-
-def autotune_paged_decode(q, k_new, v_new, k_pool, v_pool, block_table,
-                          positions, cos, sin):
-    """Eagerly search num_splits for these operand shapes and persist
-    the winner (bench.py / warmup entry point — under a jit trace the
-    kernel can only LOOK UP a previously-persisted winner)."""
-    _AUTOTUNE_OPERANDS["args"] = (q, k_new, v_new, k_pool, v_pool,
-                                  block_table, positions, cos, sin)
-    try:
-        return _autotuned_splits(q, k_pool, block_table,
-                                 jax.default_backend() != "tpu")
-    finally:
-        _AUTOTUNE_OPERANDS.clear()
 
 
 # ---------------------------------------------------------------------------
@@ -535,7 +475,7 @@ def fused_paged_decode(q, k_new, v_new, k_pool, v_pool, block_table,
     DMA; the return grows to (attn_out, new_k_pool, new_v_pool,
     new_k_scale, new_v_scale).
     """
-    from ..core.flags import flag
+    from .fusion import pallas_lowering
 
     B, T, H, D = q.shape
     if T != 1:
@@ -547,19 +487,8 @@ def fused_paged_decode(q, k_new, v_new, k_pool, v_pool, block_table,
     positions = jnp.asarray(positions, jnp.int32)
     scale = 1.0 / math.sqrt(D)
 
-    from .fusion import pallas_interpret_forced
-
-    if use_pallas is None:
-        if pallas_interpret_forced():
-            use_pallas, interpret = True, True
-        else:
-            use_pallas = bool(flag("use_pallas_kernels")) and \
-                jax.default_backend() == "tpu"
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    if num_splits is None:
-        num_splits = _autotuned_splits(q, k_pool, block_table, interpret)
-    if nbs % num_splits:
+    use_pallas, interpret = pallas_lowering(use_pallas, interpret)
+    if num_splits is None or nbs % num_splits:
         num_splits = _default_splits(nbs)
 
     # per-sequence RoPE rows + scatter of the rotated new token (tiny:
@@ -624,21 +553,13 @@ def paged_context_partials(q_rot, k_pool, v_pool, block_table, last_pos,
     The walk is the decode kernel's (live pages only, a compute block of
     pages at a time under the next block's copies): its in-kernel
     rotation is given the identity."""
-    from ..core.flags import flag
-    from .fusion import pallas_interpret_forced
+    from .fusion import pallas_lowering
 
     B, KVH, R, D = q_rot.shape
     nbs = block_table.shape[1]
     last_pos = jnp.asarray(last_pos, jnp.int32)
     scale = 1.0 / math.sqrt(D)
-    if use_pallas is None:
-        if pallas_interpret_forced():
-            use_pallas, interpret = True, True
-        else:
-            use_pallas = bool(flag("use_pallas_kernels")) and \
-                jax.default_backend() == "tpu"
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    use_pallas, interpret = pallas_lowering(use_pallas, interpret)
     if num_splits is None or nbs % num_splits:
         num_splits = _default_splits(nbs)
     if use_pallas:

@@ -1,4 +1,4 @@
-"""Model zoo beyond vision: LLM/MoE/diffusion families (BASELINE configs 2-5)."""
+"""Model zoo beyond vision: LLM/MoE/diffusion families."""
 from .llama import (LlamaConfig, LlamaDecoderLayer, LlamaForCausalLM,  # noqa: F401
                     LlamaModel)
 from .bert import (BertConfig, BertForPretraining,  # noqa: F401

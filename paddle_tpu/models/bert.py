@@ -1,5 +1,5 @@
 # lint-tpu: disable-file=L004 -- grandfathered direct jax use; new backend code belongs under core/ ops/ kernels/ static/ distributed/ (README: Repo lint)
-"""BERT (BASELINE.md config 2: BERT-base pretraining, Fleet data-parallel).
+"""BERT (BERT-base pretraining, Fleet data-parallel).
 
 Architecture per the original BERT; built from the framework's transformer
 layers so it exercises MultiHeadAttention/TransformerEncoder the way

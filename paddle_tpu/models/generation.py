@@ -182,28 +182,9 @@ def match_stop(generated, stop_sequences) -> bool:
     return False
 
 
-def _weights_fingerprint(model):
-    """Identity fingerprint of every parameter buffer.  Any rebind of a
-    param's backing array (optimizer step, set_state_dict, checkpoint
-    load) changes the tuple, invalidating decode steps that captured the
-    old weights as jit constants (ADVICE r2: a stale compiled step would
-    otherwise silently serve pre-update weights).
-
-    Held as WEAKREFS, not id()s: CPython reuses freed addresses, and
-    set_state_dict frees each old array right before allocating its
-    same-sized replacement, so an id tuple can collide with the cached
-    one and keep serving pre-update weights (ADVICE r3).  A weakref to a
-    freed array returns None and can never match; holding the refs does
-    not extend the old arrays' lifetime."""
-    return tuple(weakref.ref(p._value) for p in model.parameters())
-
-
-def _fingerprint_matches(model, fp):
-    if fp is None:
-        return False
-    params = model.parameters()
-    return len(fp) == len(params) and all(
-        r() is p._value for r, p in zip(fp, params))
+def _weight_tensors(model):
+    return [t for _, t in model.named_parameters()] + \
+        [t for _, t in model.named_buffers()]
 
 
 class jit_with_weights:
@@ -218,15 +199,14 @@ class jit_with_weights:
     step's own (minus the weights), so engines, ``warn_on_retrace`` and
     the analyzers use it as they would the ``jax.jit`` product; traced
     from outside (``jax.make_jaxpr(step)``) the live weights surface as
-    the trace's top-level consts.  The step builders stack it, as
-    ``functools.partial(jit_with_weights, model)``, over
-    ``register_decode_step`` where ``jax.jit`` used to go."""
+    the trace's top-level consts.  :func:`cached_step` builds every
+    step of this package through it."""
 
     def __init__(self, model, fn):
         # rebinding a tensor's ``_value`` (optimizer step, load, mesh
-        # placement) is seen by the next call; a NEW parameter is not
-        self._tensors = [t for _, t in model.named_parameters()] + \
-            [t for _, t in model.named_buffers()]
+        # placement) is seen by the next call; a NEW parameter or buffer
+        # is not, which ``holds`` tells ``cached_step``
+        self._tensors = _weight_tensors(model)
         functools.update_wrapper(self, fn)
 
         def with_weights(weights, *args):
@@ -245,6 +225,13 @@ class jit_with_weights:
         with_weights.__name__ = with_weights.__qualname__ = fn.__name__
         self._jitted = jax.jit(with_weights)
 
+    def holds(self, model) -> bool:
+        """The model's parameters and buffers are still the very
+        ``Tensor`` objects this step feeds its program."""
+        now = _weight_tensors(model)
+        return len(now) == len(self._tensors) and all(
+            a is b for a, b in zip(now, self._tensors))
+
     def _weights(self):
         return [t._value for t in self._tensors]
 
@@ -258,6 +245,28 @@ class jit_with_weights:
         return self._jitted._cache_size()
 
 
+def cached_step(model, key, fn):
+    """The one table of compiled steps, kept on the model: ``key`` is
+    ``(kind, fused, kv_dtype, *extras)`` and ``fn`` the raw step, which
+    is registered under its kind and compiled with
+    :class:`jit_with_weights` the first time its key is asked for.  The
+    same key then returns the same object, with its executables, to
+    every engine and every ``generate()`` call: a fresh wrapper per call
+    would retrace and recompile the whole transformer per request.
+
+    A step takes the weights as arguments, so a rebound weight needs no
+    new step.  Only a model whose parameters or buffers are no longer
+    the tensors the step holds (``quantize_model_weights`` registers new
+    buffers) gets a fresh one, checked here, where a step is asked for,
+    and never where it runs."""
+    table = vars(model).setdefault("_compiled_steps", {})
+    step = table.get(key)
+    if step is None or not step.holds(model):
+        step = table[key] = jit_with_weights(
+            model, register_decode_step(fn, kind=key[0]))
+    return step
+
+
 def make_decode_step(model):
     """One jit-compiled single-token decode step over static caches.
 
@@ -266,26 +275,16 @@ def make_decode_step(model):
     TRACED scalar and the caches are fixed-size, so every decode step of
     every generation with the same (B, max_len) hits ONE executable —
     the TPU serving property the reference gets from
-    fused_multi_transformer's decode kernel.  Model weights are captured
-    as jit constants (inference: they never change under the trace).
-
-    The wrapper is cached ON THE MODEL keyed by a weights fingerprint:
-    jax.jit's own cache then holds one executable per (B, max_len) across
-    generate() calls — a fresh wrapper per call would retrace + recompile
-    the whole transformer every request, while an un-fingerprinted one
-    would keep serving stale weights after training/set_state_dict."""
-    step = getattr(model, "_decode_step", None)
-    if step is not None and _fingerprint_matches(
-            model, getattr(model, "_decode_step_fp", None)):
-        return step
-    fp = _weights_fingerprint(model)
-
+    fused_multi_transformer's decode kernel.  The weights ride in as
+    arguments (:class:`jit_with_weights`), so after training or
+    ``set_state_dict`` the same step, with the executables it has, serves
+    the new weights; :func:`cached_step` keeps it on the model, and
+    jax.jit's own cache then holds one executable per (B, max_len)
+    across generate() calls."""
     from .llama import StaticKVCache
 
     from ..core.dispatch import no_grad_ctx
 
-    @functools.partial(jit_with_weights, model)
-    @functools.partial(register_decode_step, kind="decode")
     def decode_step(tok, caches, offset):
         with no_grad_ctx():
             wrapped = [StaticKVCache(k, v) for k, v in caches]
@@ -294,9 +293,7 @@ def make_decode_step(model):
             return (logits._value[:, -1].astype(jnp.float32),
                     [(c.k, c.v) for c in new_caches])
 
-    model._decode_step = decode_step
-    model._decode_step_fp = fp
-    return decode_step
+    return cached_step(model, ("decode", None, None), decode_step)
 
 
 def make_beam_decode_step(model):
@@ -306,18 +303,10 @@ def make_beam_decode_step(model):
     BeamSearchDecoder's gather of cell states, fluid/layers/rnn.py, over
     fused_multi_transformer's fixed CacheKV).  step(tok[BV,1], caches,
     offset, parents[BV]) -> (logits[BV,V] f32, new_caches)."""
-    step = getattr(model, "_beam_decode_step", None)
-    if step is not None and _fingerprint_matches(
-            model, getattr(model, "_beam_decode_step_fp", None)):
-        return step
-    fp = _weights_fingerprint(model)
-
     from .llama import StaticKVCache
 
     from ..core.dispatch import no_grad_ctx
 
-    @functools.partial(jit_with_weights, model)
-    @functools.partial(register_decode_step, kind="beam_decode")
     def beam_decode_step(tok, caches, offset, parents):
         with no_grad_ctx():
             wrapped = [StaticKVCache(k[parents], v[parents])
@@ -327,9 +316,8 @@ def make_beam_decode_step(model):
             return (logits._value[:, -1].astype(jnp.float32),
                     [(c.k, c.v) for c in new_caches])
 
-    model._beam_decode_step = beam_decode_step
-    model._beam_decode_step_fp = fp
-    return beam_decode_step
+    return cached_step(model, ("beam_decode", None, None),
+                       beam_decode_step)
 
 
 def make_prefill_step(model):
@@ -340,18 +328,10 @@ def make_prefill_step(model):
     -> (last_real_logits[1, V] f32, new_caches): the logits are gathered
     at the TRACED index of the last REAL prompt token, so padding never
     changes which row is returned."""
-    step = getattr(model, "_prefill_step", None)
-    if step is not None and _fingerprint_matches(
-            model, getattr(model, "_prefill_step_fp", None)):
-        return step
-    fp = _weights_fingerprint(model)
-
     from .llama import StaticKVCache
 
     from ..core.dispatch import no_grad_ctx
 
-    @functools.partial(jit_with_weights, model)
-    @functools.partial(register_decode_step, kind="prefill")
     def prefill_step(ids, caches, last_index):
         with no_grad_ctx():
             wrapped = [StaticKVCache(k, v) for k, v in caches]
@@ -362,9 +342,7 @@ def make_prefill_step(model):
             return (last.astype(jnp.float32),
                     [(c.k, c.v) for c in new_caches])
 
-    model._prefill_step = prefill_step
-    model._prefill_step_fp = fp
-    return prefill_step
+    return cached_step(model, ("prefill", None, None), prefill_step)
 
 
 def _wrap_paged(pools, block_tables, kv_dtype, model=None):
@@ -397,13 +375,6 @@ def _unwrap_paged(caches, kv_dtype, model=None):
     return [(c.k, c.v) for c in caches]
 
 
-def _kv_dtype_suffix(kv_dtype):
-    """Cache-attr / step-kind suffix: fp32 and quantized engines must
-    never share a cached compiled step (their pool treedefs differ, so
-    a shared attr would guarantee a retrace on the second engine)."""
-    return f"_{kv_dtype}" if kv_dtype is not None else ""
-
-
 def make_paged_decode_step(model, fused=None, kv_cache_dtype=None):
     """The continuous-batching decode step: one token for a BUCKET of
     sequences, each at its own position, over the shared block-pool
@@ -416,38 +387,24 @@ def make_paged_decode_step(model, fused=None, kv_cache_dtype=None):
     ``fused`` pins the serving-fusion mode (kernels/fusion) for the
     whole traced program: True forces the fused paged-attention decode
     kernel + RMSNorm epilogues (XLA fallback off-TPU), False forces the
-    unfused reference path, None resolves FLAGS_use_fused_serving once
-    at build time.  The mode is baked into the trace, so fused and
-    unfused steps are distinct cached executables.
+    gather path, None is fused.  The mode is baked into the trace, so
+    fused and gather steps are distinct cached executables.
 
     ``kv_cache_dtype`` (None / "int8" / "fp8") selects quantized pool
     entries: pools become [(k, v, k_scale, v_scale)] per layer, writes
     quantize in-trace and reads dequantize at the kernel DMA boundary
-    (kernels/kv_quant.py).  Like ``fused``, the dtype is baked into the
-    attr/kind so mixed-precision engines over one model never collide
-    on a cached step."""
+    (kernels/kv_quant.py).  Like ``fused``, the dtype is part of the
+    step's key, so mixed-precision engines over one model never collide
+    on a cached step (their pool treedefs differ: a shared step would
+    retrace for the second engine)."""
     from ..kernels.fusion import resolve_serving_fusion, serving_fusion
     from ..kernels.kv_quant import resolve_kv_cache_dtype
 
     fused = resolve_serving_fusion(fused)
     kv_dtype = resolve_kv_cache_dtype(kv_cache_dtype)
-    attr = ("_paged_decode_step_fused" if fused
-            else "_paged_decode_step") + _kv_dtype_suffix(kv_dtype)
-    step = getattr(model, attr, None)
-    if step is not None and _fingerprint_matches(
-            model, getattr(model, attr + "_fp", None)):
-        return step
-    fp = _weights_fingerprint(model)
 
     from ..core.dispatch import no_grad_ctx
 
-    # resolved OUTSIDE the step: its source is AST-audited (H106) and a
-    # build-time ternary must not read as per-token Python branching
-    kind = ("paged_decode_fused" if fused else "paged_decode") \
-        + _kv_dtype_suffix(kv_dtype)
-
-    @functools.partial(jit_with_weights, model)
-    @functools.partial(register_decode_step, kind=kind)
     def paged_decode_step(tok, pools, block_tables, lengths):
         with no_grad_ctx(), serving_fusion(fused):
             wrapped = _wrap_paged(pools, block_tables, kv_dtype)
@@ -456,9 +413,8 @@ def make_paged_decode_step(model, fused=None, kv_cache_dtype=None):
             return (logits._value[:, -1].astype(jnp.float32),
                     _unwrap_paged(new_caches, kv_dtype))
 
-    setattr(model, attr, paged_decode_step)
-    setattr(model, attr + "_fp", fp)
-    return paged_decode_step
+    return cached_step(model, ("paged_decode", fused, kv_dtype),
+                       paged_decode_step)
 
 
 def make_chunked_prefill_step(model, fused=None, kv_cache_dtype=None):
@@ -498,29 +454,13 @@ def make_chunked_prefill_step(model, fused=None, kv_cache_dtype=None):
 
     fused = resolve_serving_fusion(fused)
     kv_dtype = resolve_kv_cache_dtype(kv_cache_dtype)
-    attr = ("_chunked_prefill_step_fused" if fused
-            else "_chunked_prefill_step") + _kv_dtype_suffix(kv_dtype)
-    step = getattr(model, attr, None)
-    if step is not None and _fingerprint_matches(
-            model, getattr(model, attr + "_fp", None)):
-        return step
-    fp = _weights_fingerprint(model)
 
     from ..core.dispatch import no_grad_ctx
 
     if getattr(model, "block_diffusion", None) is not None:
-        step = _make_block_chunk_step(model, fused)
-        setattr(model, attr, step)
-        setattr(model, attr + "_fp", fp)
-        return step
+        return cached_step(model, ("block_chunked_prefill", fused, kv_dtype),
+                           _block_chunk_step(model, fused))
 
-    # see make_paged_decode_step: keep the build-time ternary out of
-    # the H106-audited step source
-    kind = ("chunked_prefill_fused" if fused else "chunked_prefill") \
-        + _kv_dtype_suffix(kv_dtype)
-
-    @functools.partial(jit_with_weights, model)
-    @functools.partial(register_decode_step, kind=kind)
     def chunked_prefill_step(ids, pools, block_table, start, last_index):
         with no_grad_ctx(), serving_fusion(fused):
             wrapped = _wrap_paged(pools, block_table, kv_dtype)
@@ -534,9 +474,8 @@ def make_chunked_prefill_step(model, fused=None, kv_cache_dtype=None):
             return (last.astype(jnp.float32),
                     _unwrap_paged(new_caches, kv_dtype))
 
-    setattr(model, attr, chunked_prefill_step)
-    setattr(model, attr + "_fp", fp)
-    return chunked_prefill_step
+    return cached_step(model, ("chunked_prefill", fused, kv_dtype),
+                       chunked_prefill_step)
 
 
 def unmask_schedule(block_length: int, denoising_steps: int):
@@ -570,7 +509,7 @@ def unmask_select(logits, ids, masked, n_unmask, tau):
     return jnp.where(take, cand, ids), masked & ~take
 
 
-def _make_block_chunk_step(model, fused):
+def _block_chunk_step(model, fused):
     """The chunk program of a model that generates by diffusion over
     blocks (``model.block_diffusion``): the same name, lane and call
     signature as :func:`make_chunked_prefill_step`'s, so the engine's
@@ -584,8 +523,6 @@ def _make_block_chunk_step(model, fused):
     from ..core.dispatch import no_grad_ctx
     from ..kernels.fusion import serving_fusion
 
-    @functools.partial(jit_with_weights, model)
-    @functools.partial(register_decode_step, kind="block_chunked_prefill")
     def chunked_prefill_step(ids, pools, block_table, start, last_index):
         with no_grad_ctx(), serving_fusion(fused):
             wrapped = _wrap_paged(pools, block_table, None, model)
@@ -617,24 +554,16 @@ def make_paged_block_step(model, fused=None):
 
     ``small`` is all that goes to the host, one int32 vector: the
     block's ids after the step ``[S*L]``, its mask ``[S*L]``, then the
-    routing stats ``[3]`` (see :func:`_make_block_chunk_step`).
+    routing stats ``[3]`` (see :func:`_block_chunk_step`).
     ``probe`` stays on the device unless a check reads it: ``logits
     [L, V]`` float32 of slot 0 and ``chosen [layers, S, L, k]``, what
     the routers chose for the positions in flight."""
     from ..kernels.fusion import resolve_serving_fusion, serving_fusion
 
     fused = resolve_serving_fusion(fused)
-    attr = "_paged_block_step_fused" if fused else "_paged_block_step"
-    step = getattr(model, attr, None)
-    if step is not None and _fingerprint_matches(
-            model, getattr(model, attr + "_fp", None)):
-        return step
-    fp = _weights_fingerprint(model)
 
     from ..core.dispatch import no_grad_ctx
 
-    @functools.partial(jit_with_weights, model)
-    @functools.partial(register_decode_step, kind="paged_block")
     def paged_block_step(ids, masked, start, mode, n_unmask, tau, pools,
                          block_tables):
         with no_grad_ctx(), serving_fusion(fused):
@@ -652,9 +581,8 @@ def make_paged_block_step(model, fused=None):
             probe = {"logits": logits[0], "chosen": chosen}
             return small, probe, _unwrap_paged(new_caches, None, model)
 
-    setattr(model, attr, paged_block_step)
-    setattr(model, attr + "_fp", fp)
-    return paged_block_step
+    return cached_step(model, ("paged_block", fused, None),
+                       paged_block_step)
 
 
 def make_moe_block_step(model):
@@ -664,24 +592,14 @@ def make_moe_block_step(model):
     [B, T, V] f32.  Off-TPU the dispatch/combine kernels resolve to
     their XLA one-hot einsum fallback, so this exact program is what
     CPU tier-1 checks for parity and the analyzers price."""
-    step = getattr(model, "_moe_block_step", None)
-    if step is not None and _fingerprint_matches(
-            model, getattr(model, "_moe_block_step_fp", None)):
-        return step
-    fp = _weights_fingerprint(model)
-
     from ..core.dispatch import no_grad_ctx
 
-    @jax.jit
-    @functools.partial(register_decode_step, kind="moe_block")
     def step(ids):
         with no_grad_ctx():
             logits = model(Tensor(ids))
             return logits._value.astype(jnp.float32)
 
-    model._moe_block_step = step
-    model._moe_block_step_fp = fp
-    return step
+    return cached_step(model, ("moe_block", None, None), step)
 
 
 def make_ring_sp_step(model, mesh=None):
@@ -692,20 +610,11 @@ def make_ring_sp_step(model, mesh=None):
     ``sp`` axis; None keeps whatever mesh is globally active — no `sp`
     axis means the dense fallback, which IS the CPU parity path.
     step(ids[B, T] int32) -> logits[B, T, V] f32."""
-    step = getattr(model, "_ring_sp_step", None)
-    if step is not None and _fingerprint_matches(
-            model, getattr(model, "_ring_sp_step_fp", None)) \
-            and getattr(model, "_ring_sp_step_mesh", None) is mesh:
-        return step
-    fp = _weights_fingerprint(model)
-
     import contextlib
 
     from ..core.dispatch import no_grad_ctx
     from ..distributed.mesh import use_mesh
 
-    @jax.jit
-    @functools.partial(register_decode_step, kind="ring_sp")
     def step(ids):
         ctx = (use_mesh(mesh) if mesh is not None
                else contextlib.nullcontext())
@@ -713,10 +622,7 @@ def make_ring_sp_step(model, mesh=None):
             logits = model(Tensor(ids))
             return logits._value.astype(jnp.float32)
 
-    model._ring_sp_step = step
-    model._ring_sp_step_fp = fp
-    model._ring_sp_step_mesh = mesh
-    return step
+    return cached_step(model, ("ring_sp", None, None, mesh), step)
 
 
 def generate(model, input_ids, max_new_tokens=32, do_sample=False,

@@ -1,6 +1,6 @@
 # lint-tpu: disable-file=L004 -- grandfathered direct jax use; new backend code belongs under core/ ops/ kernels/ static/ distributed/ (README: Repo lint)
 """MoE transformer LM — the expert-parallel pretrain config
-(BASELINE.md config 4: ERNIE-4.5-MoE / DeepSeek-V2 style).
+(ERNIE-4.5-MoE / DeepSeek-V2 style).
 
 DeepSeek-V2 recipe: dense first layer(s), then MoE FFNs with shared experts
 alongside routed experts; GQA attention; RMSNorm.  Built from the Llama

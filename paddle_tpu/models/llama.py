@@ -1,6 +1,6 @@
 # lint-tpu: disable-file=L004 -- grandfathered direct jax use; new backend code belongs under core/ ops/ kernels/ static/ distributed/ (README: Repo lint)
-"""Llama model family (Llama-2/3 architecture) — the flagship pretrain config
-(BASELINE.md config 3).
+"""Llama model family (Llama-2/3 architecture) — the flagship pretrain and
+serving model.
 
 The 2022 reference snapshot predates Llama; its closest analogs are the
 fused transformer ops (/root/reference/paddle/fluid/operators/fused/
@@ -90,28 +90,13 @@ class LlamaConfig:
         return cfg
 
 
-def _mesh_live() -> bool:
-    """A device mesh is in force at this trace: the global fleet mesh, a
-    registered ``MeshExecutor``'s, or a manual-mp ``shard_map`` stage.
-    The Pallas kernels have no partitioning rule (Mosaic: "kernels
-    cannot be automatically partitioned"), so under a mesh every kernel
-    site below takes its XLA form until the kernels are wrapped in
-    ``shard_map`` (ROADMAP Speed 5)."""
-    from ..distributed.executor import active_mesh
-    from ..distributed.mesh import get_mesh
-    from ..distributed.parallel_layers import manual_axis
-
-    return get_mesh() is not None or active_mesh() is not None \
-        or manual_axis("mp")[0] is not None
-
-
 def _pallas_kernels_on() -> bool:
     """The training-path kernels (rms_norm, fused_rope, flash
-    attention): on a TPU, by flag, outside a mesh."""
-    from ..core.flags import flag
+    attention): on a TPU, outside a mesh."""
+    from ..distributed.mesh import mesh_live
+    from ..kernels.fusion import pallas_lowering
 
-    return bool(flag("use_pallas_kernels")) and \
-        jax.default_backend() == "tpu" and not _mesh_live()
+    return pallas_lowering()[0] and not mesh_live()
 
 
 def precompute_rope(head_dim, max_pos, theta):
@@ -336,9 +321,9 @@ class LlamaAttention(nn.Layer):
 
             # the kernel consumes the whole pool through the block
             # table; under a live mesh (GSPMD sharded pools / manual-mp
-            # shard_map) it has no partitioning rule, so serve those
-            # from the unfused gather path below
-            if fusion_enabled() and not _mesh_live():
+            # shard_map) it has no partitioning rule, so fusion_enabled
+            # sends those to the gather path below
+            if fusion_enabled():
                 # fused decode hot path: RoPE + pool scatter + block
                 # gather + split-K attention in one kernel (XLA
                 # fallback off-TPU) — models/generation.py's paged
@@ -461,7 +446,7 @@ class LlamaAttention(nn.Layer):
 
                 # same mesh caveat as the fused decode intercept: the
                 # kernel reads the whole pool through the block table
-                if fusion_enabled() and not _mesh_live():
+                if fusion_enabled():
                     # fused chunked-prefill hot path: block gather +
                     # causal mask + online softmax + context in one
                     # kernel (XLA fallback off-TPU) — the #1 candidate
@@ -762,9 +747,7 @@ class LlamaDecoderLayer(nn.Layer):
             return False
         from ..kernels.fusion import fusion_enabled
 
-        if not fusion_enabled():
-            return False
-        return not _mesh_live() and isinstance(self.mlp, LlamaMLP)
+        return fusion_enabled() and isinstance(self.mlp, LlamaMLP)
 
     def forward(self, hidden, cos, sin, attn_mask=None, cache=None,
                 position_offset=0):

@@ -82,10 +82,9 @@ def load_pipeline_params(model, shared, stacked):
 
 
 def _use_pallas(cfg: LlamaConfig) -> bool:
-    from ..core.flags import flag
+    from ..kernels.fusion import pallas_lowering
 
-    return bool(cfg.use_flash_attention and flag("use_pallas_kernels")
-                and jax.default_backend() == "tpu")
+    return bool(cfg.use_flash_attention) and pallas_lowering()[0]
 
 
 def _rms(x, w, eps, use_pallas=False):
@@ -100,7 +99,7 @@ def _rms(x, w, eps, use_pallas=False):
 
 def _decoder_layer(h, lp, cos, sin, cfg: LlamaConfig, use_pallas=False):
     """Functional mirror of models/llama.py LlamaDecoderLayer.forward,
-    including its flag-gated Pallas dispatch (flash attention + fused
+    including its Pallas dispatch (flash attention + fused
     RMSNorm on TPU, reference math elsewhere)."""
     B, T = h.shape[0], h.shape[1]
     n_h = cfg.num_attention_heads

@@ -1,5 +1,5 @@
 # lint-tpu: disable-file=L004 -- grandfathered direct jax use; new backend code belongs under core/ ops/ kernels/ static/ distributed/ (README: Repo lint)
-"""Diffusion UNet with cross-attention (BASELINE.md config 5: SDXL UNet via
+"""Diffusion UNet with cross-attention (the SDXL UNet shape, served via
 the inference predictor).
 
 Compact UNet2DConditionModel: timestep sinusoidal embedding + MLP, ResNet

@@ -15,7 +15,6 @@ import jax
 import jax.numpy as jnp
 
 from ...core.dispatch import apply
-from ...core.flags import flag
 from ...core.tensor import Tensor, to_tensor
 
 
@@ -46,9 +45,10 @@ def scaled_dot_product_attention(query, key, value, attn_mask=None,
                                  name=None):
     """paddle.nn.functional.scaled_dot_product_attention: [B, T, H, D]."""
     def _sdpa(q, k, v, *maybe_mask):
+        from ...kernels.fusion import pallas_lowering
+
         mask = maybe_mask[0] if maybe_mask else None
-        if flag("use_pallas_kernels") and jax.default_backend() == "tpu" \
-                and mask is None and dropout_p == 0.0:
+        if pallas_lowering()[0] and mask is None and dropout_p == 0.0:
             from ...kernels.flash_attention import flash_attention_bthd
 
             return flash_attention_bthd(q, k, v, causal=is_causal)

@@ -15,7 +15,7 @@ Linear-family weight IN PLACE:
   build keeps resident in HBM;
 * ``layer.weight._value`` is rebound to the exact dequantization
   ``codes * scale / 127`` — the matmul-prologue dequant, materialized
-  once at quantize time so every fused op and compiled step captures
+  once at quantize time so every fused op and compiled step reads
   int8-representable weights without touching the model's fused-op
   plumbing.  Served math is therefore bit-identical to an on-the-fly
   prologue dequant.
@@ -24,11 +24,9 @@ The scale rule is the same ``_quantize_weight`` the QAT→int8 conversion
 uses (per-channel ``FakeQuantChannelWiseAbsMax`` convention), so PTQ'd
 checkpoints and serving-quantized weights cannot drift.
 
-Because the engine's step cache fingerprints weights by IDENTITY (the
-Tensor objects), an in-place ``_value`` rebind would NOT invalidate
-already-compiled steps — the quantizer explicitly drops every cached
-``_*_step*`` attribute so the next step maker recompiles against the
-quantized constants.
+A compiled step takes the weights as arguments, so the rebind is seen by
+its next call; the new buffers make the next step maker build a fresh
+step (``models/generation.py::cached_step``).
 """
 from __future__ import annotations
 
@@ -66,17 +64,6 @@ def resolve_weight_dtype(name: Optional[str]) -> Optional[str]:
             f"unsupported weight_dtype {name!r}; serving weight-only "
             f"quantization supports int8 (aliases: i8, w8) or "
             f"fp32/None") from None
-
-
-def _invalidate_cached_steps(model) -> int:
-    """Drop every compiled step the engine cached on the model — the
-    weights they captured as jit constants are stale after an in-place
-    quantize (the identity-based fingerprint cannot see the rebind)."""
-    stale = [k for k in list(vars(model))
-             if "_step" in k and not k.startswith("__")]
-    for k in stale:
-        delattr(model, k)
-    return len(stale)
 
 
 def quantize_model_weights(model, weight_dtype: Optional[str] = None):
@@ -124,14 +111,12 @@ def quantize_model_weights(model, weight_dtype: Optional[str] = None):
         fp32_bytes += int(wv.size) * 4
         quant_bytes += int(codes.size) + int(scale.size) * 4
 
-    dropped = _invalidate_cached_steps(model)
     report = {"layers": layers, "fp32_bytes": fp32_bytes,
               "quant_bytes": quant_bytes}
     model._serving_weight_dtype = scheme
     model._serving_weight_quant_report = dict(report)
     logger.info(
         "weight-only quant: %d linear layers -> %s (%.2f MiB -> "
-        "%.2f MiB resident, %d cached steps invalidated)",
-        layers, scheme, fp32_bytes / 2**20, quant_bytes / 2**20,
-        dropped)
+        "%.2f MiB resident)",
+        layers, scheme, fp32_bytes / 2**20, quant_bytes / 2**20)
     return report

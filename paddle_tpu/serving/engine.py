@@ -132,11 +132,11 @@ class ServingConfig:
     step_retry_backoff_s: float = 0.05
     # consecutive in-budget steps before DEGRADED self-heals to SERVING
     health_recovery_steps: int = 3
-    # fused serving kernels (kernels/fusion): None resolves the
-    # FLAGS_use_fused_serving default (fused on TPU, unfused elsewhere);
-    # True forces the fused paged-attention decode + RMSNorm epilogues
-    # even on CPU (the XLA fallback — how CI covers the fused math);
-    # False pins the unfused reference path on any backend.  Pinned at
+    # fused serving kernels (kernels/fusion): None and True trace the
+    # fused paged-attention decode + chunk kernels and the RMSNorm
+    # epilogues (Pallas on a TPU, their XLA lowering elsewhere; the
+    # gather path while a mesh is live); False pins the gather path,
+    # the tests' oracle, on any backend.  Pinned at
     # step-build time, so it never flips inside a compiled program.
     fused_kernels: Optional[bool] = None
     # speculative decoding (serving/speculative.py): a SpeculativeConfig
@@ -303,9 +303,9 @@ class Engine:
             self._blk_step = np.zeros((S,), np.int32)
             # routing stats of chunk programs, still on the device
             self._route_stats = []
-        # runtime SPMD: shard weights + KV pool BEFORE the step makers
-        # below — the steps capture the weights as jit constants, so the
-        # rebind here is what makes the compiled programs multi-device
+        # runtime SPMD: shard weights + KV pool BEFORE the steps first
+        # run — they take the weights as arguments, so the rebind here
+        # is what makes the compiled programs multi-device
         self.mesh_executor = None
         if cfg.mesh is not None:
             from ..distributed.executor import as_executor

@@ -2,7 +2,7 @@
 # (like router.py); new backend code belongs under core/ ops/ kernels/
 # static/ distributed/ (README: Repo lint)
 """Multi-tenant trace replay for the serving fleet router
-(``BENCH_ONLY=router_replay``; README "Serving fleet & router").
+(README "Serving fleet & router").
 
 A *trace* is a seeded, deterministic arrival schedule over a few tenant
 archetypes — the mixes a real fleet sees at once:
@@ -19,10 +19,9 @@ archetypes — the mixes a real fleet sees at once:
 ``numpy.random.RandomState(seed)`` — same seed, same trace, byte for
 byte); ``replay_trace`` feeds it through a :class:`Router` step by
 step and reports per-tenant goodput and TTFT tails plus fleet-level
-placement/cache counters.  The bench (bench.py ``router_replay``) runs
-ONE trace through an affinity fleet and a round-robin fleet and prints
-both — the affinity fleet should win on cached-token ratio and not
-lose on p99 TTFT at equal load.
+placement/cache counters.  ONE trace through an affinity fleet and a
+round-robin fleet (``tests/test_router.py``): the affinity fleet should
+win on cached-token ratio.
 """
 from __future__ import annotations
 
@@ -162,7 +161,7 @@ def replay_trace(router: Router, trace: Sequence[Arrival]) -> dict:
     Goodput follows metrics.py: tokens from requests finishing inside
     their SLO (eos/stop/length).  TTFTs come from the finishing
     replica's request timelines (compile excluded as long as the caller
-    warmed the fleet first — bench.py does)."""
+    warmed the fleet first)."""
     pending = sorted(trace, key=lambda a: a.step)
     tallies: Dict[str, _TenantTally] = {}
     by_rid: Dict[str, str] = {}

@@ -31,7 +31,6 @@ O(V log V) per row, all shapes static.
 """
 from __future__ import annotations
 
-import functools
 from dataclasses import dataclass
 from typing import Optional
 
@@ -40,8 +39,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..core.tensor import Tensor
-from ..models.generation import (_fingerprint_matches, _weights_fingerprint,
-                                 jit_with_weights, register_decode_step)
+from ..models.generation import cached_step
 
 # key-derivation tags: the draft proposal, acceptance uniform and bonus/
 # residual resample for token index i must be independent of the target
@@ -203,29 +201,17 @@ def make_sampled_decode_step(model, fused=None, kv_cache_dtype=None):
     logits, so only the chosen token ids sync back (a [S] int32 instead
     of the greedy step's [S, V] logits).  All per-slot sampling state
     rides in fixed-shape device arrays — zero retraces, zero host
-    round-trips in the token loop (H106).  Cached on the model keyed by
-    a weights fingerprint, like every other step builder."""
+    round-trips in the token loop (H106).  Kept in the model's table of
+    steps (``cached_step``), like every other step builder's."""
     from ..kernels.fusion import resolve_serving_fusion, serving_fusion
     from ..kernels.kv_quant import resolve_kv_cache_dtype
-    from ..models.generation import (_kv_dtype_suffix, _unwrap_paged,
-                                     _wrap_paged)
+    from ..models.generation import _unwrap_paged, _wrap_paged
 
     fused = resolve_serving_fusion(fused)
     kv_dtype = resolve_kv_cache_dtype(kv_cache_dtype)
-    attr = ("_sampled_decode_step_fused" if fused
-            else "_sampled_decode_step") + _kv_dtype_suffix(kv_dtype)
-    step = getattr(model, attr, None)
-    if step is not None and _fingerprint_matches(
-            model, getattr(model, attr + "_fp", None)):
-        return step
-    fp = _weights_fingerprint(model)
 
     from ..core.dispatch import no_grad_ctx
 
-    kind = "sampled_decode" + _kv_dtype_suffix(kv_dtype)
-
-    @functools.partial(jit_with_weights, model)
-    @functools.partial(register_decode_step, kind=kind)
     def sampled_decode_step(tok, pools, block_tables, lengths, temps,
                             top_ks, top_ps, keys, counters):
         with no_grad_ctx(), serving_fusion(fused):
@@ -237,6 +223,5 @@ def make_sampled_decode_step(model, fused=None, kv_cache_dtype=None):
                                  fold_keys(keys, counters))
             return toks, _unwrap_paged(new_caches, kv_dtype)
 
-    setattr(model, attr, sampled_decode_step)
-    setattr(model, attr + "_fp", fp)
-    return sampled_decode_step
+    return cached_step(model, ("sampled_decode", fused, kv_dtype),
+                       sampled_decode_step)

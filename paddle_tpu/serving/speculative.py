@@ -42,7 +42,6 @@ past the new frontier, so rejected drafts leak nothing.
 """
 from __future__ import annotations
 
-import functools
 from dataclasses import dataclass
 from typing import Any
 
@@ -50,9 +49,7 @@ import jax
 import jax.numpy as jnp
 
 from ..core.tensor import Tensor
-from ..models.generation import (_cache_dims, _fingerprint_matches,
-                                 _weights_fingerprint, jit_with_weights,
-                                 register_decode_step)
+from ..models.generation import _cache_dims, cached_step
 from .sampling import (ACCEPT_TAG, BONUS_TAG, DRAFT_TAG, filtered_probs,
                        fold_keys, sample_tokens)
 
@@ -114,15 +111,7 @@ def make_draft_propose_step(draft_model, num_draft, fused=None):
     from ..models.llama import PagedKVCache
 
     fused = resolve_serving_fusion(fused)
-    attr = f"_draft_propose_step_{num_draft}" + ("_fused" if fused else "")
-    step = getattr(draft_model, attr, None)
-    if step is not None and _fingerprint_matches(
-            draft_model, getattr(draft_model, attr + "_fp", None)):
-        return step
-    fp = _weights_fingerprint(draft_model)
 
-    @functools.partial(jit_with_weights, draft_model)
-    @functools.partial(register_decode_step, kind="draft_propose")
     def draft_propose_step(tok, pools, block_tables, lengths, temps,
                            top_ks, top_ps, keys, counters):
         with no_grad_ctx(), serving_fusion(fused):
@@ -147,9 +136,9 @@ def make_draft_propose_step(draft_model, num_draft, fused=None):
             return (jnp.transpose(props)[:, :num_draft],
                     jnp.transpose(probs, (1, 0, 2))[:, :num_draft], layers)
 
-    setattr(draft_model, attr, draft_propose_step)
-    setattr(draft_model, attr + "_fp", fp)
-    return draft_propose_step
+    return cached_step(draft_model,
+                       ("draft_propose", fused, None, num_draft),
+                       draft_propose_step)
 
 
 def _spec_acceptance(lg, proposals, draft_probs, temps, top_ks, top_ps,
@@ -223,15 +212,7 @@ def make_spec_verify_step(model, num_draft, fused=None):
     from ..models.llama import PagedKVCache
 
     fused = resolve_serving_fusion(fused)
-    attr = f"_spec_verify_step_{num_draft}" + ("_fused" if fused else "")
-    step = getattr(model, attr, None)
-    if step is not None and _fingerprint_matches(
-            model, getattr(model, attr + "_fp", None)):
-        return step
-    fp = _weights_fingerprint(model)
 
-    @functools.partial(jit_with_weights, model)
-    @functools.partial(register_decode_step, kind="spec_verify")
     def spec_verify_step(pending, proposals, draft_probs, pools,
                          block_tables, lengths, temps, top_ks, top_ps,
                          keys, counters):
@@ -248,6 +229,5 @@ def make_spec_verify_step(model, num_draft, fused=None):
                 keys, counters)
             return committed, accepted, [(c.k, c.v) for c in new_caches]
 
-    setattr(model, attr, spec_verify_step)
-    setattr(model, attr + "_fp", fp)
-    return spec_verify_step
+    return cached_step(model, ("spec_verify", fused, None, num_draft),
+                       spec_verify_step)

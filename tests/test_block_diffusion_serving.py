@@ -2,10 +2,8 @@
 engine (models/sdar_moe.py, models/generation.py::make_paged_block_step,
 serving/engine.py::_block_iteration), at tiny widths on the CPU with
 seeded random weights, against the plain reference
-(``tests/sdar_moe_reference.py``, the program's copy of
-``benchmarks/reference/sdar_moe.py``)."""
-import os
-
+(``benchmarks/reference/sdar_moe.py``, the one the benchmark's ``correct``
+uses)."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -22,10 +20,7 @@ from paddle_tpu.models.generation import (make_chunked_prefill_step,
                                           unmask_schedule, unmask_select)
 from paddle_tpu.models.sdar_moe import routing_witness
 from paddle_tpu.serving import Engine, ServingConfig
-
-import sdar_moe_reference as reference
-
-HERE = os.path.dirname(os.path.abspath(__file__))
+from benchmarks.reference import sdar_moe as reference
 
 
 def _model(seed=0, **overrides):
@@ -48,14 +43,6 @@ def _engine(model, **kw):
     kw = dict(dict(max_batch_size=3, block_size=8, num_blocks=48,
                    chunk_tokens=16), **kw)
     return Engine(model, ServingConfig(**kw))
-
-
-def test_the_reference_copies_are_one_text():
-    with open(os.path.join(HERE, "sdar_moe_reference.py")) as f:
-        ours = f.read()
-    with open(os.path.join(HERE, "..", "benchmarks", "reference",
-                           "sdar_moe.py")) as f:
-        assert ours == f.read()
 
 
 # ------------------------------------------------- the step programs

@@ -619,7 +619,7 @@ class TestAnalysisPricesPallas:
 
 
 # ---------------------------------------------------------------------------
-# autotune cache: chip-qualified keys, --retune escape hatch
+# autotune cache: chip-qualified keys, the retune escape hatch
 # ---------------------------------------------------------------------------
 
 @pytest.fixture

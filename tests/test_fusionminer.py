@@ -265,10 +265,15 @@ class TestRediscovery:
     def test_fused_steps_report_f004_coverage(self, audit_reports):
         decode = audit_reports["serving::decode_step[fused]"]
         prefill = audit_reports["serving::prefill_step[fused]"]
+        # ... and rms_norm: the final norm, the one norm no projection
+        # follows, is the Pallas rms_norm on the chip, and the forced
+        # lowering now shows every kernel the chip's program holds
         assert {c.primitives[0] for c in decode.covered} == \
-            {"fused_norm_linear", "fused_paged_decode"}
+            {"fused_norm_linear", "fused_paged_decode", "rms_norm"}
         assert {c.primitives[0] for c in prefill.covered} == \
-            {"fused_norm_linear", "fused_chunked_prefill"}
+            {"fused_norm_linear", "fused_chunked_prefill", "rms_norm"}
+        assert next(c for c in decode.covered
+                    if c.primitives[0] == "rms_norm").count == 1
         # norm fusion fires per projection bundle (q/k/v + gate/up x 2
         # layers); the attention kernels once per layer
         assert next(c for c in prefill.covered

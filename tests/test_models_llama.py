@@ -236,25 +236,29 @@ class TestGeneration:
                            use_static_cache=True).numpy()
         np.testing.assert_array_equal(a, b)
 
-    def test_decode_step_invalidated_on_weight_change(self):
-        """ADVICE r2 (medium): the cached compiled decode step captures
-        weights as jit constants; rebinding any parameter (training step,
-        set_state_dict) must invalidate it — generation after a weight
-        update must NOT reuse stale compiled weights."""
+    def test_decode_step_serves_rebound_weights(self):
+        """ADVICE r2 (medium): generation after a weight update (training
+        step, set_state_dict) must NOT serve the weights from before it.
+        The compiled decode step takes the weights as arguments, so the
+        SAME step serves the new ones, and compiles nothing for it."""
+        from paddle_tpu.models.generation import make_decode_step
+
         model = self._model()
         ids = paddle.to_tensor(np.array([[1, 2, 3]], np.int32))
-        out1 = model.generate(ids, max_new_tokens=4, temperature=0.0,
-                              use_static_cache=True).numpy()
-        step1 = model._decode_step
+        model.generate(ids, max_new_tokens=4, temperature=0.0,
+                       use_static_cache=True)
+        step = make_decode_step(model)
+        compiled = step._cache_size()
+        assert compiled == 1
         # rebind weights to shifted values (as set_state_dict would)
         sd = {k: v.numpy() + 0.05 for k, v in model.state_dict().items()}
         model.set_state_dict(sd)
-        out2 = model.generate(ids, max_new_tokens=4, temperature=0.0,
-                              use_static_cache=True).numpy()
-        assert model._decode_step is not step1, \
-            "decode step must be rebuilt after weight rebind"
+        out = model.generate(ids, max_new_tokens=4, temperature=0.0,
+                             use_static_cache=True).numpy()
+        assert make_decode_step(model) is step
+        assert step._cache_size() == compiled
         ref = model.generate(ids, max_new_tokens=4, temperature=0.0).numpy()
-        np.testing.assert_array_equal(out2, ref)
+        np.testing.assert_array_equal(out, ref)
 
     def test_static_cache_shapes_constant(self):
         """The whole point of StaticKVCache: every decode step reuses one

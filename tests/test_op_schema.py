@@ -89,35 +89,3 @@ class TestOpSchemaGate:
         with pytest.raises(KeyError):
             get_op_info("not_a_real_op")
         assert len(all_ops()) >= 300
-
-
-class TestBenchGate:
-    """Perf-regression gate tool (reference:
-    tools/check_op_benchmark_result.py semantics)."""
-
-    def _write(self, tmp_path, name, payload):
-        p = tmp_path / name
-        p.write_text(__import__("json").dumps(payload))
-        return str(p)
-
-    def test_pass_fail_and_missing(self, tmp_path):
-        import sys
-
-        sys.path.insert(0, "tools")
-        try:
-            from check_bench_result import main
-        finally:
-            sys.path.pop(0)
-        ok = self._write(tmp_path, "a.json",
-                         {"parsed": {"value": 100.0}})
-        faster = self._write(tmp_path, "b.json",
-                             {"parsed": {"value": 104.0}})
-        slower = self._write(tmp_path, "c.json",
-                             {"parsed": {"value": 90.0}})
-        errored = self._write(tmp_path, "d.json",
-                              {"parsed": None, "tail": "boom"})
-        assert main([ok, faster]) == 0
-        assert main([ok, slower]) == 3
-        assert main([ok, slower, "--threshold", "0.2"]) == 0
-        assert main([ok, errored]) == 4
-        assert main([errored, ok]) == 0  # no baseline: initial measurement

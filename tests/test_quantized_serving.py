@@ -503,20 +503,25 @@ class TestWeightOnlyQuant:
         assert eng._decode_step.retraces == 0
         eng.pool.check_leaks()
 
-    def test_quantize_invalidates_cached_steps(self):
-        """An engine compiled BEFORE weight quant must not serve stale
-        fp32 constants: the in-place quantizer drops every cached
-        ``_*_step`` attr (the identity fingerprint can't see the
-        rebind)."""
-        from paddle_tpu.models.generation import make_paged_decode_step
+    def test_engine_built_after_quantize_serves_quantized_weights(self):
+        """Steps compiled BEFORE weight quant must not make a later
+        engine serve the fp32 weights: an engine built after
+        ``quantize_model_weights`` generates what a model that was
+        quantized before anything compiled generates."""
         from paddle_tpu.quantization.serving import \
             quantize_model_weights
 
+        prompts = _prompts([7, 10], seed=5)
+        fresh = _tiny_model()
+        quantize_model_weights(fresh, "int8")
+        want = _gen(Engine(fresh, _config()), prompts, 6)
+
         model = _tiny_model()
-        make_paged_decode_step(model, fused=False)
-        assert hasattr(model, "_paged_decode_step")
+        _gen(Engine(model, _config()), prompts, 6)  # compiles over fp32
         quantize_model_weights(model, "int8")
-        assert not hasattr(model, "_paged_decode_step")
+        eng = Engine(model, _config())
+        assert _gen(eng, prompts, 6) == want
+        assert eng._decode_step.retraces == 0
 
 
 # ---------------------------------------------------------------------------
