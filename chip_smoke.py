@@ -312,7 +312,8 @@ def serve_requests(phase, eng, requests):
 def step_logits(eng, prompt, feed=None, n_decode=0):
     """Logits of the first token and of ``n_decode`` decode steps for one
     sequence, through ``eng``'s compiled steps at the engine's own shapes
-    (so nothing compiles) on a scratch view of its pool.  Decode is fed
+    (so nothing compiles) on blocks 1.. of its idle pool (a step consumes
+    the pool it is handed: the engine gets the last back).  Decode is fed
     ``feed`` where given, else its own greedy tokens; returns (logits
     [1 + n_decode, V], the tokens fed)."""
     cfg = eng.config
@@ -339,6 +340,7 @@ def step_logits(eng, prompt, feed=None, n_decode=0):
         logits, pools = eng._decode_step(tok, pools, table, lengths)
         out.append(np.asarray(logits)[0])
         lengths[0] += 1
+    eng._rebind_target(pools)
     return np.stack(out), fed
 
 

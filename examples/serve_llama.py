@@ -169,8 +169,9 @@ def overload_chaos_demo(model):
     assert all(r.finish_reason == "shed" for r in doomed)
     assert c["requests_timed_out"] == 0   # shed beats a timeout
 
-    # --- phase 2: injected hung step -> watchdog detects, retries,
-    # engine returns to SERVING
+    # --- phase 2: injected hung step -> watchdog detects the stall and
+    # keeps the late result (the step consumed its donated pool: nothing
+    # is dispatched again), engine returns to SERVING
     eng2 = Engine(model, ServingConfig(
         max_batch_size=4, block_size=4, num_blocks=64, chunk_tokens=4,
         watchdog_floor_s=0.25, watchdog_budget_mult=50.0,
@@ -181,9 +182,9 @@ def overload_chaos_demo(model):
         eng2.run_until_complete()
     h = eng2.health()
     print(f"watchdog: {h['watchdog_stalls']} stall detected, "
-          f"{h['step_retries']} retry, health={h['state']}")
+          f"{h['step_retries']} retries, health={h['state']}")
     assert req.finish_reason == "length"
-    assert h["watchdog_stalls"] == 1 and h["step_retries"] >= 1
+    assert h["watchdog_stalls"] == 1 and h["step_retries"] == 0
     assert h["state"] == SERVING          # recovered after clean steps
 
     for e in (eng, eng2):

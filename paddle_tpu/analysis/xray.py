@@ -28,8 +28,9 @@ Jaxpr-level hazards (Diagnostic codes continue hazards.py's space):
 - **H108 missing-donation** (WARNING) — a large undonated input whose
   shape/dtype matches an output: XLA must double-buffer it, costing its
   full size in HBM.  Train steps donate state via ``jit.to_static``
-  (donate_argnums=(0,)); serving steps returning fresh pools show up
-  here by design until pool donation lands.
+  (donate_argnums=(0,)) and serving steps donate the paged KV pool
+  they return (``models/generation.py::cached_step(..., donate=)``):
+  both audit clean, so an H108 on either is a regression.
 - **H109 host round-trip in compiled region** (ERROR; ``debug_callback``
   WARNING) — ``pure_callback``/``io_callback``/``outside_call``
   primitives found ANYWHERE in the jaxpr: a device→host→device round

@@ -13,6 +13,7 @@ serving); each step's math is jit-compiled by XLA.
 from __future__ import annotations
 
 import functools
+import inspect
 import weakref
 
 import jax
@@ -200,9 +201,16 @@ class jit_with_weights:
     the analyzers use it as they would the ``jax.jit`` product; traced
     from outside (``jax.make_jaxpr(step)``) the live weights surface as
     the trace's top-level consts.  :func:`cached_step` builds every
-    step of this package through it."""
+    step of this package through it.
 
-    def __init__(self, model, fn):
+    ``donate`` is the name, among ``fn``'s arguments, of a paged KV
+    pool that the step returns: the program takes it as a DONATED
+    argument, so its output pool aliases its input and the step's
+    scatter writes in place (undonated, the compiler copies the whole
+    pool first, every step).  After a call the arrays handed in are
+    deleted: the caller binds the returned pool and keeps no other."""
+
+    def __init__(self, model, fn, donate=None):
         # rebinding a tensor's ``_value`` (optimizer step, load, mesh
         # placement) is seen by the next call; a NEW parameter or buffer
         # is not, which ``holds`` tells ``cached_step``
@@ -223,7 +231,11 @@ class jit_with_weights:
         # HLO then read ``jit_paged_decode_step``, not ``jit_with_weights``
         # for every step of every model
         with_weights.__name__ = with_weights.__qualname__ = fn.__name__
-        self._jitted = jax.jit(with_weights)
+        # the weights shift the pool by one inside ``with_weights``
+        self._jitted = jax.jit(
+            with_weights,
+            donate_argnums=() if donate is None else (
+                1 + list(inspect.signature(fn).parameters).index(donate),))
 
     def holds(self, model) -> bool:
         """The model's parameters and buffers are still the very
@@ -245,11 +257,13 @@ class jit_with_weights:
         return self._jitted._cache_size()
 
 
-def cached_step(model, key, fn):
+def cached_step(model, key, fn, donate=None):
     """The one table of compiled steps, kept on the model: ``key`` is
     ``(kind, fused, kv_dtype, *extras)`` and ``fn`` the raw step, which
     is registered under its kind and compiled with
-    :class:`jit_with_weights` the first time its key is asked for.  The
+    :class:`jit_with_weights` the first time its key is asked for
+    (``donate``: the name of ``fn``'s argument that is the paged pool
+    it consumes and returns; every step over a paged pool names it).  The
     same key then returns the same object, with its executables, to
     every engine and every ``generate()`` call: a fresh wrapper per call
     would retrace and recompile the whole transformer per request.
@@ -263,7 +277,7 @@ def cached_step(model, key, fn):
     step = table.get(key)
     if step is None or not step.holds(model):
         step = table[key] = jit_with_weights(
-            model, register_decode_step(fn, kind=key[0]))
+            model, register_decode_step(fn, kind=key[0]), donate)
     return step
 
 
@@ -383,6 +397,9 @@ def make_paged_decode_step(model, fused=None, kv_cache_dtype=None):
     int32) -> (last_logits[B, V] f32, new_pools).  Every input shape is
     fixed by the engine config, so after the first call this NEVER
     retraces — the property the serving engine asserts every step.
+    ``pools`` is DONATED, here and in every step over a paged pool: the
+    returned pool is the same buffers written in place, and the arrays
+    handed in are deleted by the call, so the caller binds the result.
 
     ``fused`` pins the serving-fusion mode (kernels/fusion) for the
     whole traced program: True forces the fused paged-attention decode
@@ -414,7 +431,7 @@ def make_paged_decode_step(model, fused=None, kv_cache_dtype=None):
                     _unwrap_paged(new_caches, kv_dtype))
 
     return cached_step(model, ("paged_decode", fused, kv_dtype),
-                       paged_decode_step)
+                       paged_decode_step, donate="pools")
 
 
 def make_chunked_prefill_step(model, fused=None, kv_cache_dtype=None):
@@ -459,7 +476,7 @@ def make_chunked_prefill_step(model, fused=None, kv_cache_dtype=None):
 
     if getattr(model, "block_diffusion", None) is not None:
         return cached_step(model, ("block_chunked_prefill", fused, kv_dtype),
-                           _block_chunk_step(model, fused))
+                           _block_chunk_step(model, fused), donate="pools")
 
     def chunked_prefill_step(ids, pools, block_table, start, last_index):
         with no_grad_ctx(), serving_fusion(fused):
@@ -475,7 +492,7 @@ def make_chunked_prefill_step(model, fused=None, kv_cache_dtype=None):
                     _unwrap_paged(new_caches, kv_dtype))
 
     return cached_step(model, ("chunked_prefill", fused, kv_dtype),
-                       chunked_prefill_step)
+                       chunked_prefill_step, donate="pools")
 
 
 def unmask_schedule(block_length: int, denoising_steps: int):
@@ -582,7 +599,7 @@ def make_paged_block_step(model, fused=None):
             return small, probe, _unwrap_paged(new_caches, None, model)
 
     return cached_step(model, ("paged_block", fused, None),
-                       paged_block_step)
+                       paged_block_step, donate="pools")
 
 
 def make_moe_block_step(model):

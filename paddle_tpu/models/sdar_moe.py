@@ -124,8 +124,8 @@ class SDARMoEConfig:
 class RoutedKVCache(PagedKVCache):
     """A paged K/V view that also carries, per cached position, the
     experts the layer's router chose for it (``chosen [num_blocks,
-    block_size, k]`` int32): the routing witness of everything a step
-    program writes to the pool."""
+    block_size * k]`` int32, a row a block): the routing witness of
+    everything a step program writes to the pool."""
 
     __slots__ = ("chosen",)
 
@@ -143,6 +143,33 @@ class RoutedKVCache(PagedKVCache):
 
 jax.tree_util.register_pytree_node(
     RoutedKVCache, lambda c: c.tree_flatten(), RoutedKVCache.tree_unflatten)
+
+
+def scatter_block_rows(pool, new, block_table, start, wmask):
+    """Write ``new [B, T, k]``, the values of the consecutive positions
+    ``start[b] + t``, into a sidecar kept a row a block (``pool
+    [num_blocks, block_size * k]``) through the table: the rows the
+    positions reach are read, changed where ``wmask [B, T]`` is True and
+    written back whole, in place and in the layout the device stores
+    them in.  A row with nothing to write goes to the garbage block 0,
+    as a masked position of :func:`paged_scatter` does."""
+    B, T, k = new.shape
+    bs = pool.shape[1] // k
+    n_rows = (T + 2 * bs - 2) // bs        # what T positions can span
+    blk = start[:, None] // bs + jnp.arange(n_rows)             # [B, R]
+    # the token of each (row, offset), where there is one
+    t = blk[:, :, None] * bs + jnp.arange(bs) - start[:, None, None]
+    flat = jnp.clip(t, 0, T - 1).reshape(B, n_rows * bs)
+    live = ((t >= 0) & (t < T)).reshape(B, -1) \
+        & jnp.take_along_axis(wmask, flat, axis=1)
+    live = live.reshape(B, n_rows, bs)
+    vals = jnp.take_along_axis(new, flat[:, :, None], axis=1)
+    rows = block_table[jnp.arange(B)[:, None],
+                       jnp.minimum(blk, block_table.shape[1] - 1)]
+    rows = jnp.where(live.any(-1), rows, 0).reshape(-1)
+    old = pool[rows].reshape(B, n_rows, bs, k)
+    out = jnp.where(live[..., None], vals.reshape(old.shape), old)
+    return pool.at[rows].set(out.reshape(-1, bs * k).astype(pool.dtype))
 
 
 def _rms(x, w, eps):
@@ -467,7 +494,8 @@ class SDARMoEForCausalLM(nn.Layer):
             x, chosen, st = self._after_attention(layer, x, ctx,
                                                   token_valid)
             with jax.named_scope("kv_write"):
-                c_pool = paged_scatter(cache.chosen, chosen, bt, pos, valid)
+                c_pool = scatter_block_rows(cache.chosen, chosen, bt, start,
+                                            valid)
             new_caches.append(RoutedKVCache(k_pool, v_pool, bt, c_pool))
             stats.append(st)
         return self._sum_stats(stats), new_caches
@@ -526,7 +554,8 @@ class SDARMoEForCausalLM(nn.Layer):
             x, chosen, st = self._after_attention(layer, x, ctx,
                                                   token_valid)
             with jax.named_scope("kv_write"):
-                c_pool = paged_scatter(cache.chosen, chosen, bt, pos, write)
+                c_pool = scatter_block_rows(cache.chosen, chosen, bt, start,
+                                            write)
             new_caches.append(RoutedKVCache(k_pool, v_pool, bt, c_pool))
             stats.append(st)
             chose.append(chosen)
@@ -546,9 +575,11 @@ def routing_witness(model, engine, tokens, block_table, prompt_tokens,
     size = engine.config.block_size
     at = np.arange(prompt_tokens)
     rows = np.asarray(block_table)[at // size]
-    cached = np.stack([np.asarray(entry[2])[rows, at % size]
-                       for entry in engine.pool.layers[
-                           :model.config.num_hidden_layers]])
+    # (a row a block: a block's positions one after another)
+    cached = np.stack([
+        np.asarray(entry[2]).reshape(engine.pool.num_blocks, size, -1)[
+            rows, at % size]
+        for entry in engine.pool.layers[:model.config.num_hidden_layers]])
     if in_flight is None:
         return cached.astype(np.int32)
     return np.concatenate(

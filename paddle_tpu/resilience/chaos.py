@@ -24,6 +24,9 @@ Instrumented sites:
 - ``maybe_fail_serving_step(label)`` — serving step watchdog (hung or
   failing compiled-step ATTEMPTS: delays register as watchdog stalls,
   exceptions exercise the bounded-retry path)
+- ``maybe_fail_after_dispatch(label)`` — the same watchdog, once the
+  attempt's call has returned: the step consumed its donated KV pool,
+  so the fault cannot be retried (the lost-pool path)
 - ``poison_batch(step, arrays)``     — data path (NaN/Inf gradients)
 
 ``burst_prompts`` is the matching ARRIVAL generator: a seeded batch of
@@ -48,6 +51,7 @@ __all__ = [
     "after_save",
     "maybe_fail_request",
     "maybe_fail_serving_step",
+    "maybe_fail_after_dispatch",
     "poison_batch",
     "burst_prompts",
     "truncate_file",
@@ -122,6 +126,11 @@ class FaultPlan:
         :class:`ChaosError` instead of running — the transient device
         failure the watchdog's bounded retry must absorb (consecutive
         ordinals exhaust the retries and quarantine the engine).
+    fail_after_dispatch_at: serving-step attempt ordinals (the same
+        count as ``fail_step_at``) that raise :class:`ChaosError` AFTER
+        the attempt's call returned, still inside the watchdog's
+        window: the program has consumed its donated KV pool, so no
+        retry can run and the engine quarantines at once.
     kill_process_at: ``{step: process_index}`` — process-scoped kill:
         at ``on_step(step)``, ONLY the process whose cluster index
         (``distributed.bootstrap.process_index()``) matches dies; its
@@ -167,6 +176,7 @@ class FaultPlan:
                  step_delay_s: Union[None, float,
                                      Dict[int, float]] = None,
                  fail_step_at: Iterable[int] = (),
+                 fail_after_dispatch_at: Iterable[int] = (),
                  step_fault_scope: Optional[str] = None,
                  kill_process_at: Optional[Dict[int, int]] = None,
                  kill_save_site: Optional[str] = None,
@@ -187,6 +197,7 @@ class FaultPlan:
         self.fail_request_ids = frozenset(fail_request_ids)
         self.step_delay_s = step_delay_s
         self.fail_step_at = frozenset(fail_step_at)
+        self.fail_after_dispatch_at = frozenset(fail_after_dispatch_at)
         self.step_fault_scope = step_fault_scope
         self.kill_process_at = dict(kill_process_at or {})
         self.kill_save_site = kill_save_site
@@ -305,6 +316,18 @@ class FaultPlan:
             raise ChaosError(
                 f"injected serving step failure at attempt {n} ({label})")
 
+    def maybe_fail_after_dispatch(self, label: str):
+        """The attempt that ``maybe_fail_serving_step`` last counted has
+        returned from its call: raise if its ordinal is scheduled."""
+        if self.step_fault_scope is not None \
+                and self.step_fault_scope not in label:
+            return
+        n = self._serving_step_calls
+        if n in self.fail_after_dispatch_at:
+            self.injected.append(("serving_fail_after_dispatch", n, label))
+            raise ChaosError(
+                f"injected failure after dispatch of attempt {n} ({label})")
+
     def poison_batch(self, step: int, arrays):
         """Return ``arrays`` (a list/tuple of numpy arrays) with NaN/Inf
         written into the float entries when ``step`` is scheduled;
@@ -357,6 +380,11 @@ def maybe_fail_request(request_id: str):
 def maybe_fail_serving_step(label: str):
     if _ACTIVE is not None:
         _ACTIVE.maybe_fail_serving_step(label)
+
+
+def maybe_fail_after_dispatch(label: str):
+    if _ACTIVE is not None:
+        _ACTIVE.maybe_fail_after_dispatch(label)
 
 
 def burst_prompts(seed: int, n: int, min_len: int = 4,

@@ -43,6 +43,7 @@ attention masks it, and the hot loop stays device-resident — H106).
 """
 from __future__ import annotations
 
+import functools
 import hashlib
 from collections import OrderedDict
 from typing import Dict, List, Optional, Sequence, Set, Tuple
@@ -54,6 +55,13 @@ import numpy as np
 from ..kernels.kv_quant import (kv_bytes_per_element,
                                 kv_scale_bytes_per_block,
                                 kv_storage_dtype, resolve_kv_cache_dtype)
+
+
+def consumed(operands) -> bool:
+    """A program took these operands: a donated array among them is
+    deleted (``jax.Array.is_deleted``; host arrays are never donated)."""
+    return any(isinstance(a, jax.Array) and a.is_deleted()
+               for a in jax.tree_util.tree_leaves(operands))
 
 
 class PoolExhausted(Exception):
@@ -85,28 +93,18 @@ class BlockKVPool:
         # pool can never match blocks registered under an fp32 config
         # (or the other scheme) — the seed IS the namespace
         self._hash_seed = self.kv_dtype_tag.encode()
-        z = jnp.zeros((num_blocks, block_size, kv_heads, head_dim),
-                      self.dtype)
-        # per-layer physical pools — the arrays handed to the compiled
-        # decode step and rebound to its outputs every token.  Entries
-        # are (k, v) for full-precision pools and (k, v, k_scale,
-        # v_scale) for quantized ones: int8 code pools plus one f32
-        # absmax scale per (block, token) row (kernels/kv_quant.py)
-        if self.kv_cache_dtype is not None:
-            s = jnp.ones((num_blocks, block_size), jnp.float32)
-            self.layers: List[Tuple[jax.Array, ...]] = [
-                (z, z, s, s) for _ in range(num_layers)]
-        else:
-            self.layers = [(z, z) for _ in range(num_layers)]
         # what a model keeps per cached position beside K and V (the
         # experts its router chose, say): ``(shape, dtype)`` each, one
-        # more ``[num_blocks, block_size, *shape]`` array an entry,
-        # written by the model's step programs at the positions they
-        # write K/V and moved with its block by copy-on-write
-        if sidecars:
-            extra = tuple(jnp.zeros((num_blocks, block_size) + tuple(shape),
-                                    dt) for shape, dt in sidecars)
-            self.layers = [entry + extra for entry in self.layers]
+        # more array an entry, ONE ROW A BLOCK (``[num_blocks,
+        # block_size * size]``, a block's positions one after another:
+        # a few values a position, laid out ``[num_blocks, block_size,
+        # *shape]``, are stored by the device in another order than a
+        # row write wants and relaid around every write), written by
+        # the model's step programs at the positions they write K/V and
+        # moved with its block by copy-on-write
+        self._sidecars = [(int(np.prod(shape, dtype=np.int64)), dt)
+                          for shape, dt in sidecars]
+        self.layers: List[Tuple[jax.Array, ...]] = self._fresh_layers()
         # LIFO free list over blocks 1..n-1 (block 0 reserved)
         self._free: List[int] = list(range(num_blocks - 1, 0, -1))
         # block id -> set of owning request ids (refcount = len)
@@ -124,6 +122,46 @@ class BlockKVPool:
         self._roots: "OrderedDict[bytes, None]" = OrderedDict()
         self.evictions = 0
         self.cow_copies = 0
+
+    def _fresh_layers(self) -> List[Tuple[jax.Array, ...]]:
+        """Per-layer physical pools — the arrays handed to a compiled
+        step and rebound to its outputs every token.  Entries are (k, v)
+        for full-precision pools and (k, v, k_scale, v_scale) for
+        quantized ones: int8 code pools plus one f32 absmax scale per
+        (block, token) row (kernels/kv_quant.py); then the sidecars.
+        Every leaf is a buffer of its own: the step programs DONATE the
+        pool, and the runtime refuses to donate one buffer twice."""
+        rows = (self.num_blocks, self.block_size)
+
+        def entry():
+            kv = tuple(jnp.zeros(rows + (self.kv_heads, self.head_dim),
+                                 self.dtype) for _ in range(2))
+            if self.kv_cache_dtype is not None:
+                kv += tuple(jnp.ones(rows, jnp.float32) for _ in range(2))
+            return kv + tuple(
+                jnp.zeros((self.num_blocks, self.block_size * size), dt)
+                for size, dt in self._sidecars)
+
+        return [entry() for _ in range(self.num_layers)]
+
+    def lost(self) -> bool:
+        """A step program consumed the pool and gave none back (it
+        failed after it took its donated operands): some leaf is a
+        deleted array."""
+        return consumed(self.layers)
+
+    def reset(self):
+        """Fresh zeroed buffers and an empty prefix index: what
+        ``Engine.revive()`` does about a lost pool, once no request
+        references a block (the cached K/V went with the buffers, so an
+        indexed block would serve zeros)."""
+        self.check_leaks()
+        self.layers = self._fresh_layers()
+        self._free = list(range(self.num_blocks - 1, 0, -1))
+        self._hash_index.clear()
+        self._block_hash.clear()
+        self._cached_free.clear()
+        self._roots.clear()
 
     # ------------------------------------------------------- accounting
     @property
@@ -425,6 +463,7 @@ class BlockKVPool:
         return new
 
     def _copy_block(self, src: int, dst: int):
+        # ``layers`` is donated, as to a step program: one live pool
         new = _copy_block_impl(tuple(self.layers), np.int32(src),
                                np.int32(dst))
         self.layers = [tuple(entry) for entry in new]
@@ -480,7 +519,7 @@ class BlockKVPool:
         }
 
 
-@jax.jit
+@functools.partial(jax.jit, donate_argnums=(0,))
 def _copy_block_impl(layers, src, dst):
     # one executable per pool geometry: src/dst ride in as traced
     # scalars.  Entries are (k, v) or (k, v, k_scale, v_scale) — a CoW

@@ -639,8 +639,8 @@ class Engine:
         True while there is work left (running, prefilling or waiting).
 
         Raises :class:`EngineQuarantined` when the engine is FAILED
-        (step watchdog exhausted its retries on exceptions) — call
-        :meth:`revive` after operator intervention."""
+        (step watchdog out of retries, or a step failed holding the
+        pool) — call :meth:`revive` after operator intervention."""
         if self.overload.health.failed:
             raise EngineQuarantined(
                 f"engine quarantined FAILED "
@@ -847,9 +847,9 @@ class Engine:
             # copy, not a view the host may change under it
             bt = bt.copy()
         # watchdog-wrapped dispatch (serving/overload.py): monotonic
-        # budget + bounded retry; the compiled step is pure, so a retry
-        # recomputes the identical chunk from the unchanged pool.  The
-        # pool rebind below happens only after a successful attempt.
+        # budget; the program consumes the pool it is handed (donated),
+        # so what comes back is bound at once, and only a failure from
+        # before the program took it is retried
         last, new_pools = self.overload.prefill_watchdog.call(
             self._prefill_step, ids, self._target_pools(), bt,
             np.asarray([start], np.int32), np.int32(n_tok - 1))
@@ -1039,9 +1039,8 @@ class Engine:
 
         # the np.asarray device→host sync happens INSIDE the timed
         # closure so the watchdog budget covers device execution, not
-        # just dispatch; retries recompute the same pure step on the
-        # unchanged pool (the rebind below is post-success) and show as
-        # a second dispatch/fetch pair of spans
+        # just dispatch; a fault that surfaces in it finds the donated
+        # pool consumed (the watchdog quarantines, ``revive`` rebuilds)
         def _timed_decode(tokens, layers, tables, lengths):
             with phase("decode_dispatch", slots=len(active)):
                 out, pools = self._decode_step(tokens, layers, tables,
@@ -1426,10 +1425,28 @@ class Engine:
         return self.overload.snapshot(self)
 
     def revive(self):
-        """Operator override after a FAILED quarantine (step watchdog
-        out of retries): clear health back to SERVING so ``submit`` and
-        ``step`` accept work again.  The caller owns deciding the
-        underlying fault is gone."""
+        """Operator override after a FAILED quarantine: clear health
+        back to SERVING so ``submit`` and ``step`` accept work again.
+        The caller owns deciding the underlying fault is gone.
+
+        Where the quarantine came from a step that failed holding the
+        donated pool (counter ``pool_lost``), the cached K/V of every
+        request went with it: the pool gets fresh zeroed buffers and an
+        empty prefix index, and every running or mid-prefill request
+        goes back to the head of the queue through the recompute path
+        of preemption, so it still ends with the tokens of a clean run
+        (as after any preemption, ``on_token`` is handed the recomputed
+        tokens from the first)."""
+        if self.pool.lost():
+            for req in sorted(self.scheduler.running,
+                              key=lambda r: r.ordinal, reverse=True):
+                self._preempt(req)
+            self.pool.reset()
+            if self.mesh_executor is not None:
+                self.pool.layers = self.mesh_executor.shard_kv_layers(
+                    self.pool.layers)
+            if self.block is not None:
+                self._route_stats = []
         self.overload.health.revive()
 
     def pending_prefill_tokens(self) -> int:

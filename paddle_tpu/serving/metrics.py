@@ -151,6 +151,7 @@ class ServingMetrics:
         #                             their deadline (or had none)
         self.watchdog_stalls = 0    # step attempts over the budget
         self.step_retries = 0       # watchdog retry attempts
+        self.pool_lost = 0          # steps that failed holding the pool
         self.degradation_level = 0  # gauge: current ladder level
         self.health_state = 0       # gauge: 0 serving / 1 degraded / 2 failed
         # speculative decoding (serving/speculative.py)
@@ -407,13 +408,25 @@ class ServingMetrics:
                         "latency budget").inc(step=label)
 
     def on_step_retry(self, label: str):
-        """One bounded-retry attempt after a stall or step exception."""
+        """One bounded-retry attempt after a step exception that left
+        its operands live."""
         self.step_retries += 1
         reg = self._obs()
         if reg is not None:
             reg.counter("serving_step_retries_total",
-                        "compiled-step retries (stall or transient "
-                        "exception)").inc(step=label)
+                        "compiled-step retries (transient exception "
+                        "before the program took its operands)"
+                        ).inc(step=label)
+
+    def on_pool_lost(self, label: str):
+        """A step failed after it consumed its donated KV pool: the
+        engine is FAILED until ``revive()`` rebuilds the pool."""
+        self.pool_lost += 1
+        reg = self._obs()
+        if reg is not None:
+            reg.counter("serving_pool_lost_total",
+                        "compiled steps that failed after consuming "
+                        "the donated KV pool").inc(step=label)
 
     def on_degradation_level(self, level: int):
         """Degradation ladder moved to ``level`` (0 = normal)."""
@@ -507,6 +520,7 @@ class ServingMetrics:
                 "goodput_tokens": self.goodput_tokens,
                 "watchdog_stalls": self.watchdog_stalls,
                 "step_retries": self.step_retries,
+                "pool_lost": self.pool_lost,
                 "spec_tokens_drafted": self.spec_tokens_drafted,
                 "spec_tokens_accepted": self.spec_tokens_accepted,
                 "engine_steps": self.engine_steps,

@@ -31,15 +31,21 @@ untouched):
 * **Step watchdog** (:class:`StepWatchdog`): wraps each host-side call
   into the compiled prefill/decode steps with a monotonic-clock budget
   (``watchdog_budget_mult`` × the step's EWMA latency, floored by
-  ``watchdog_floor_s`` so the first-call compile never trips it).  A
-  stall or a transient step exception gets bounded retries with
-  exponential backoff — the compiled steps are pure functions of their
-  inputs, so a retry recomputes the identical result from the identical
-  operands — after which the engine is quarantined: ``DEGRADED`` when
-  it still produces results (slow), ``FAILED`` when retries exhaust on
-  exceptions (:class:`EngineQuarantined` propagates out of ``step()``).
+  ``watchdog_floor_s`` so the first-call compile never trips it).  The
+  step programs DONATE the KV pool (models/generation.py), so a call
+  that was dispatched has consumed its operands, and what the watchdog
+  may do follows from that.  A *stall* (the attempt returned, late) is
+  counted, marks the engine ``DEGRADED`` and KEEPS its result: the step
+  completed and advanced the pool, nothing is dispatched again.  An
+  exception raised while the operands are still live (before the
+  program took them) gets bounded retries with exponential backoff,
+  then ``FAILED`` (:class:`EngineQuarantined` propagates out of
+  ``step()``).  An exception after the operands were consumed leaves
+  nothing to retry on: ``FAILED`` at once, counter ``pool_lost``.
   ``DEGRADED`` self-heals after ``health_recovery_steps`` consecutive
-  in-budget steps; ``FAILED`` needs an explicit ``Engine.revive()``.
+  in-budget steps; ``FAILED`` needs an explicit ``Engine.revive()``,
+  which also gives a lost pool fresh buffers and sends the stranded
+  requests back through recompute.
 """
 from __future__ import annotations
 
@@ -47,6 +53,8 @@ import logging
 import math
 import time
 from typing import Callable, List, Optional, Tuple
+
+from .cache import consumed
 
 log = logging.getLogger("paddle_tpu.serving")
 
@@ -64,7 +72,8 @@ LADDER_LEVELS = ("normal", "evict_cache", "shrink_prefill",
 
 class EngineQuarantined(RuntimeError):
     """The step watchdog exhausted its bounded retries on step
-    exceptions: the engine is quarantined FAILED and refuses work until
+    exceptions, or a step failed after it had consumed the pool: the
+    engine is quarantined FAILED and refuses work until
     ``Engine.revive()``."""
 
 
@@ -100,7 +109,8 @@ class EngineHealth:
 
     DEGRADED (stalls detected, engine still producing) self-heals after
     ``recovery_steps`` consecutive in-budget steps; FAILED (retries
-    exhausted on step exceptions) is sticky until ``revive()``."""
+    exhausted on step exceptions, or the pool lost) is sticky until
+    ``revive()``."""
 
     def __init__(self, metrics=None, recovery_steps: int = 3):
         self.state = SERVING
@@ -158,9 +168,10 @@ class StepWatchdog:
 
     Timing wraps the host-side dispatch only — no synchronization is
     added inside a traced program, so registered step jaxprs stay
-    H106-clean.  The chaos serving-step hook fires INSIDE the timed
-    window (before the device call) so injected delays register as
-    stalls and injected exceptions exercise the retry path."""
+    H106-clean.  The chaos serving-step hooks fire INSIDE the timed
+    window: one before the device call (injected delays register as
+    stalls, injected exceptions exercise the retry path, operands
+    live), one after it returned (a fault once the pool is consumed)."""
 
     def __init__(self, label: str, ewma: LatencyEWMA, health: EngineHealth,
                  metrics, *, budget_mult: float, floor_s: float,
@@ -184,15 +195,17 @@ class StepWatchdog:
         return max(self.floor_s, self.budget_mult * self.ewma.value)
 
     def call(self, fn: Callable, *args):
-        """Run ``fn(*args)`` under the budget with bounded retries.
+        """Run ``fn(*args)`` under the budget.
 
         Stall (slow but successful) → count it, mark the engine
-        DEGRADED, retry; if every attempt stalls, keep the LAST result
-        (degrade, don't fail — the step did complete).  Exception →
-        retry with exponential backoff; exhausted → quarantine FAILED
-        and raise :class:`EngineQuarantined`.  Retries re-dispatch the
-        same pure compiled program on the same operands: identical
-        result, jit-cache hit, zero retraces."""
+        DEGRADED and keep the result: the step completed and its
+        donated pool is gone, so running it again could only buy the
+        same result later.  Exception with the operands live → retry
+        with exponential backoff (the same program on the same
+        operands: jit-cache hit, zero retraces); exhausted → quarantine
+        FAILED and raise :class:`EngineQuarantined`.  Exception with the
+        operands consumed → no retry can run: ``pool_lost``, FAILED at
+        once."""
         from ..observability import RetraceError
         from ..resilience import chaos
 
@@ -204,9 +217,17 @@ class StepWatchdog:
             try:
                 chaos.maybe_fail_serving_step(self.label)
                 out = fn(*args)
+                chaos.maybe_fail_after_dispatch(self.label)
             except RetraceError:
                 raise       # contract violation, not a transient fault
             except Exception as e:  # noqa: BLE001 — bounded retry
+                if consumed(args):
+                    self.metrics.on_pool_lost(self.label)
+                    self.health.on_failure(self.label, e)
+                    raise EngineQuarantined(
+                        f"{self.label}: failed after the program took "
+                        f"its pool ({e!r}); engine quarantined FAILED, "
+                        "revive() rebuilds the pool") from e
                 last_error = e
                 self.retries += 1
                 self.metrics.on_step_retry(self.label)
@@ -219,11 +240,7 @@ class StepWatchdog:
                 self.stalls += 1
                 self.metrics.on_watchdog_stall(self.label)
                 self.health.on_stall(self.label, dt, budget)
-                if attempt < self.max_retries:
-                    self.retries += 1
-                    self.metrics.on_step_retry(self.label)
-                    continue
-                return out      # every attempt stalled: degrade, keep it
+                return out      # late, but done: degrade, keep it
             self.ewma.observe(dt)
             self.health.on_clean_step()
             return out

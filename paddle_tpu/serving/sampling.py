@@ -224,4 +224,4 @@ def make_sampled_decode_step(model, fused=None, kv_cache_dtype=None):
             return toks, _unwrap_paged(new_caches, kv_dtype)
 
     return cached_step(model, ("sampled_decode", fused, kv_dtype),
-                       sampled_decode_step)
+                       sampled_decode_step, donate="pools")

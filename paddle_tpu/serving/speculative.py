@@ -138,7 +138,7 @@ def make_draft_propose_step(draft_model, num_draft, fused=None):
 
     return cached_step(draft_model,
                        ("draft_propose", fused, None, num_draft),
-                       draft_propose_step)
+                       draft_propose_step, donate="pools")
 
 
 def _spec_acceptance(lg, proposals, draft_probs, temps, top_ks, top_ps,
@@ -230,4 +230,4 @@ def make_spec_verify_step(model, num_draft, fused=None):
             return committed, accepted, [(c.k, c.v) for c in new_caches]
 
     return cached_step(model, ("spec_verify", fused, None, num_draft),
-                       spec_verify_step)
+                       spec_verify_step, donate="pools")

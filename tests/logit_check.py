@@ -60,4 +60,6 @@ def causal_engine_logits(eng, prompt, feed):
         logits, pools = decode(tok.copy(), pools, table, lengths.copy())
         out.append(np.asarray(logits)[0])
         lengths[0] += 1
+    # a step consumes the pool it is handed: the engine gets the last
+    eng.pool.layers = [tuple(entry) for entry in pools]
     return np.stack(out)

@@ -163,8 +163,9 @@ def test_block_program_logits_of_the_probe_slot():
         ids[0, 2:] = gen.mask_token_id
         masked[0] = [False, False, True, True]
         start[0], mode[0], n_unmask[0] = len(committed), 1, 1
-        _, probe, _ = block(ids, masked, start, mode, n_unmask, tau,
-                            eng.pool.layers, table)
+        _, probe, pools = block(ids, masked, start, mode, n_unmask, tau,
+                                eng.pool.layers, table)
+        eng.pool.layers = [tuple(e) for e in pools]
         got = np.asarray(probe["logits"])
         row = np.asarray(committed + list(ids[0]), np.int32)
         want = np.asarray(reference.logits(weights, cfg, row, L))

@@ -394,7 +394,7 @@ class TestServingMirror:
         "decode_iterations", "prefills",
         "prefix_cache_hits", "prefix_cache_misses",
         "prefix_cache_evictions", "prefill_chunks",
-        "watchdog_stalls", "step_retries",
+        "watchdog_stalls", "step_retries", "pool_lost",
         "spec_tokens_drafted", "spec_tokens_accepted",
         # inside Engine.step() (ISSUE 26)
         "engine_steps", "prefill_steps", "prefill_chunks_run",

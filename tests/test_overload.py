@@ -334,10 +334,14 @@ class TestWatchdog:
         assert req.finish_reason == "length"
         np.testing.assert_array_equal(
             req.output_ids(), _reference(model, p, max_new_tokens=6))
+        # a step that returned late has advanced the (donated) pool: its
+        # result is kept and nothing is dispatched again
         wd = eng.overload.decode_watchdog
-        assert wd.stalls == 1 and wd.retries == 1
+        assert wd.stalls == 1 and wd.retries == 0
         c = eng.stats()["counters"]
-        assert c["watchdog_stalls"] == 1 and c["step_retries"] == 1
+        assert c["watchdog_stalls"] == 1 and c["step_retries"] == 0
+        assert c["pool_lost"] == 0
+        assert plan._serving_step_calls == 1 + 5   # a chunk, five decodes
         # DEGRADED was entered on the stall, then self-healed after
         # health_recovery_steps clean steps
         assert eng.health()["state"] == SERVING
@@ -385,6 +389,71 @@ class TestWatchdog:
         np.testing.assert_array_equal(
             req.output_ids(), _reference(model, p, max_new_tokens=4))
         eng.pool.check_leaks()
+
+    @pytest.mark.parametrize("attempt, label, sampled", [
+        (2, "serving::prefill_step", False),      # the second chunk
+        (4, "serving::decode_step", False),       # the second decode
+        (4, "serving::sampled_decode_step", True),
+    ])
+    def test_fault_after_dispatch_loses_the_pool_and_revive_rebuilds_it(
+            self, model, attempt, label, sampled):
+        """A step that fails once its call returned has consumed the
+        donated pool: no retry can run.  FAILED at once, ``pool_lost``
+        counted; ``revive()`` gives the pool fresh buffers and the
+        stranded request is recomputed to the tokens of a clean run."""
+        eng = Engine(model, _config(step_max_retries=2,
+                                    step_retry_backoff_s=0.01))
+        (p,) = _prompts([8], seed=12)
+        kw = dict(temperature=0.7, seed=5) if sampled else {}
+        # a clean engine's tokens (sampling is a function of the seed)
+        clean = Engine(model, _config())
+        want = clean.generate([p], max_new_tokens=4, **kw)[0]
+        req = eng.submit(p, max_new_tokens=4, **kw)
+        with FaultPlan(fail_after_dispatch_at={attempt}) as plan:
+            with pytest.raises(EngineQuarantined, match="took its pool"):
+                eng.run_until_complete()
+        assert ("serving_fail_after_dispatch", attempt, label) \
+            in plan.injected
+        assert plan._serving_step_calls == attempt      # no retry ran
+        h = eng.health()
+        assert h["state"] == FAILED and "ChaosError" in h["last_error"]
+        c = eng.stats()["counters"]
+        assert c["pool_lost"] == 1 and c["step_retries"] == 0
+        assert eng.pool.lost()
+        with pytest.raises(EngineQuarantined):
+            eng.step()
+        eng.revive()
+        assert eng.health()["state"] == SERVING
+        assert not eng.pool.lost()
+        assert eng.pool.num_cached == 0 and not eng.pool._hash_index
+        assert req.preemptions == 1
+        eng.run_until_complete()
+        assert req.finish_reason == "length"
+        np.testing.assert_array_equal(req.output_ids(), want)
+        if not sampled:
+            np.testing.assert_array_equal(
+                want, _reference(model, p, max_new_tokens=4))
+        eng.pool.check_leaks()
+        assert eng._decode_step.retraces == 0
+        assert eng._prefill_step.retraces == 0
+
+    def test_revive_with_the_pool_intact_keeps_it(self, model):
+        """Retries exhausted before any program ran: the operands are
+        live, ``revive()`` touches neither the pool nor the requests."""
+        eng = Engine(model, _config(step_max_retries=0))
+        (p,) = _prompts([8], seed=13)
+        req = eng.submit(p, max_new_tokens=3)
+        with FaultPlan(fail_step_at={2}):
+            with pytest.raises(EngineQuarantined):
+                eng.run_until_complete()
+        before = [id(a) for entry in eng.pool.layers for a in entry]
+        eng.revive()
+        assert [id(a) for entry in eng.pool.layers for a in entry] == before
+        assert req.preemptions == 0
+        assert eng.stats()["counters"]["pool_lost"] == 0
+        eng.run_until_complete()
+        np.testing.assert_array_equal(
+            req.output_ids(), _reference(model, p, max_new_tokens=3))
 
     def test_endpoint_health_snapshot(self, model):
         ep = Endpoint(model, _config())

@@ -377,6 +377,42 @@ class TestEngineXray:
             assert r.flops > 0 and r.peak_hbm_bytes > 0
             assert r.errors() == []
 
+    @pytest.mark.parametrize("kv_cache_dtype", [None, "int8"])
+    def test_an_engine_s_steps_donate_their_pool_no_H108(self,
+                                                         kv_cache_dtype):
+        """The decode and chunk steps return a pool of their input's
+        shapes; they take it donated, so the audit finds nothing to
+        double-buffer (at a threshold the tiny pool's leaves pass)."""
+        from paddle_tpu.models import LlamaConfig, LlamaForCausalLM
+        from paddle_tpu.serving import Engine, ServingConfig
+
+        paddle.seed(0)
+        model = LlamaForCausalLM(LlamaConfig.tiny())
+        model.eval()
+        cfg = ServingConfig(max_batch_size=2, block_size=4, num_blocks=16,
+                            chunk_tokens=16, kv_cache_dtype=kv_cache_dtype)
+        eng = Engine(model, cfg)
+        leaves = sum(len(entry) for entry in eng.pool.layers)
+        args = xray._serving_abstract_args(
+            model, batch=2, num_blocks=16, block_size=4,
+            max_blocks_per_seq=eng.max_blocks_per_seq, chunk_tokens=16,
+            kv_cache_dtype=kv_cache_dtype)
+        for step, step_args in zip((eng._decode_step, eng._prefill_step),
+                                   args):
+            report = xray.analyze(step, step_args, chip="cpu",
+                                  min_donation_bytes=1)
+            assert sum(report.donated) == leaves
+            assert "H108" not in _codes(report.hazards)
+
+            # the same program undonated is what H108 is for
+            def undonated(*a, _step=step):
+                return _step._fn._jitted.__wrapped__(_step._fn._weights(),
+                                                     *a)
+
+            bare = xray.analyze(undonated, step_args, chip="cpu",
+                                min_donation_bytes=1)
+            assert _codes(bare.hazards).count("H108") == leaves
+
     def test_engine_xray_budget_violation_raises(self):
         from paddle_tpu.models import LlamaConfig, LlamaForCausalLM
         from paddle_tpu.serving import Engine, ServingConfig
