@@ -78,7 +78,7 @@ def _row_tile(RT, KVH):
 
 
 def _chunk_kernel(bt_ref, pos_ref, q_ref, *rest, chunk, kv_dtype=None,
-                  mask_block=1):
+                  mask_block=1, window=None):
     hbm_refs, (o_ref, acc_ref, m_ref, l_ref), bufs, sems = \
         _split_walk_refs(rest, kv_dtype)
     b = pl.program_id(0)
@@ -90,7 +90,14 @@ def _chunk_kernel(bt_ref, pos_ref, q_ref, *rest, chunk, kv_dtype=None,
     # The walk never reads a table entry, or fetches a page, past them.
     pos = pos_ref[b]
     live = jnp.minimum((pos + chunk + bs - 1) // bs, bt_ref.shape[1])
-    walk = _PageWalk(bt_ref, b, 0, live, hbm_refs, bufs, sems, kv_dtype)
+    lo = 0
+    if window is not None:
+        # a window layer: query ``q`` sees the keys at ``k_pos > q -
+        # window``, so nothing before the first key of the chunk's FIRST
+        # query is seen by any.  The walk starts at that key's page; no
+        # table entry before it is read
+        lo = jnp.maximum(pos - window + 1, 0) // bs
+    walk = _PageWalk(bt_ref, b, lo, live, hbm_refs, bufs, sems, kv_dtype)
     K = walk.G * bs                                     # keys a block
 
     acc_ref[:] = jnp.zeros_like(acc_ref)
@@ -104,6 +111,9 @@ def _chunk_kernel(bt_ref, pos_ref, q_ref, *rest, chunk, kv_dtype=None,
         jax.lax.broadcasted_iota(jnp.int32, (1, rows, K), 1)
     visible = _visible_upto(pos + row % chunk, mask_block)
     key = jax.lax.broadcasted_iota(jnp.int32, (1, rows, K), 2)
+    if window is not None:
+        key = key + lo * bs
+        hidden = pos + row % chunk - window     # the last key NOT seen
 
     walk.start()
 
@@ -117,14 +127,30 @@ def _chunk_kernel(bt_ref, pos_ref, q_ref, *rest, chunk, kv_dtype=None,
             # position.  Block 0 always holds key position 0, so m stays
             # anchored to a real score and masked lanes underflow to
             # exp(-inf); a dead page's keys lie past every query.
-            scores = jnp.where(j * K + key <= visible, scores, NEG_INF)
+            seen = j * K + key <= visible
+            if window is not None:
+                # (a row whose window starts past this block finds no
+                # key in it: the next block's real scores rescale what
+                # the all-masked update left to nothing)
+                seen = seen & (j * K + key > hidden)
+            scores = jnp.where(seen, scores, NEG_INF)
         m_ref[:], l_ref[:], acc_ref[:] = _online_softmax(
             scores, walk.values(slot), m_ref[:], l_ref[:], acc_ref[:])
 
     # compute blocks that end before ``pos`` are seen whole by every query
     # of the chunk: only the ones that reach it pay for the compare
-    clear = jnp.minimum(pos // K, walk.num_blocks)
-    jax.lax.fori_loop(0, clear,
+    if window is None:
+        clear = jnp.minimum(pos // K, walk.num_blocks)
+    else:
+        # ... and that begin inside the LAST query's window: the blocks
+        # before those pay for the window's compare
+        clear = jnp.clip((pos - lo * bs) // K, 0, walk.num_blocks)
+        head = jnp.clip((pos + chunk - window - lo * bs + K - 1) // K,
+                        0, clear)
+        jax.lax.fori_loop(0, head,
+                          functools.partial(compute_block, masked=True),
+                          None)
+    jax.lax.fori_loop(0 if window is None else head, clear,
                       functools.partial(compute_block, masked=False), None)
     jax.lax.fori_loop(clear, walk.num_blocks,
                       functools.partial(compute_block, masked=True), None)
@@ -133,10 +159,11 @@ def _chunk_kernel(bt_ref, pos_ref, q_ref, *rest, chunk, kv_dtype=None,
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret",
-                                             "kv_dtype", "mask_block"))
+                                             "kv_dtype", "mask_block",
+                                             "window"))
 def _pallas_chunked(q_g, k_pool, v_pool, block_table, positions, chunk,
                     interpret, k_scale=None, v_scale=None, kv_dtype=None,
-                    mask_block=1):
+                    mask_block=1, window=None):
     """q_g: grouped, ROTATED, pre-scaled [B, KVH, RT, D] f32 queries;
     returns the normalized context [B, KVH, RT, D] f32.
 
@@ -175,7 +202,7 @@ def _pallas_chunked(q_g, k_pool, v_pool, block_table, positions, chunk,
         else 0
     return pl.pallas_call(
         functools.partial(_chunk_kernel, chunk=chunk, kv_dtype=kv_dtype,
-                          mask_block=mask_block),
+                          mask_block=mask_block, window=window),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, KVH, RT, D), jnp.float32),
         compiler_params=pltpu.CompilerParams(
@@ -191,7 +218,8 @@ def _pallas_chunked(q_g, k_pool, v_pool, block_table, positions, chunk,
 
 
 def _xla_chunked(q_g, k_pool, v_pool, block_table, positions, chunk,
-                 k_scale=None, v_scale=None, kv_dtype=None, mask_block=1):
+                 k_scale=None, v_scale=None, kv_dtype=None, mask_block=1,
+                 window=None):
     """Same grouped-query chunk attention in plain XLA: q_g is the
     ROTATED and pre-scaled [B, KVH, RT, D] f32 query (scale folded in,
     exactly as the caller hands the kernel)."""
@@ -217,6 +245,9 @@ def _xla_chunked(q_g, k_pool, v_pool, block_table, positions, chunk,
     q_pos = positions[:, None] + jnp.arange(RT) % chunk  # [B, RT]
     valid = k_pos[None, None, None, :] <= \
         _visible_upto(q_pos, mask_block)[:, None, :, None]
+    if window is not None:
+        valid = valid & (k_pos[None, None, None, :] >
+                         (q_pos - window)[:, None, :, None])
     scores = jnp.where(valid, scores, NEG_INF)
     m = jnp.max(scores, axis=-1, keepdims=True)
     pexp = jnp.exp(scores - m)
@@ -229,7 +260,8 @@ def _xla_chunked(q_g, k_pool, v_pool, block_table, positions, chunk,
 def fused_chunked_attention(q, k_pool, v_pool, block_table, positions,
                             *, use_pallas=None, interpret=None,
                             k_scale=None, v_scale=None,
-                            kv_cache_dtype=None, mask_block=1):
+                            kv_cache_dtype=None, mask_block=1,
+                            window=None):
     """Paged attention for one prefill chunk, fused end to end.
 
     q: [B, T, H, D] ROTATED queries for the chunk; k_pool/v_pool:
@@ -252,6 +284,11 @@ def fused_chunked_attention(q, k_pool, v_pool, block_table, positions,
     (a model that denoises a block of positions together), so the chunk
     must end on a block boundary; 1 is the causal mask.
 
+    ``window`` (static) makes the layer a WINDOW layer under the causal
+    mask: query ``q`` sees the keys at ``q - window < k_pos <= q``, the
+    walk starts at the page of the chunk's first query's first key, and
+    no table entry before that page is read.
+
     On TPU the gather + mask + softmax + context is one Pallas kernel
     with an online softmax; elsewhere the numerically-identical XLA
     lowering runs instead.
@@ -265,6 +302,9 @@ def fused_chunked_attention(q, k_pool, v_pool, block_table, positions,
     scale = 1.0 / math.sqrt(D)
 
     use_pallas, interpret = pallas_lowering(use_pallas, interpret)
+    if window is not None and mask_block != 1:
+        raise ValueError("a window layer attends under the causal mask "
+                         "(mask_block 1)")
 
     # GQA grouping: head h = kvh * rep + r, so the grouped row index is
     # r * T + t and every row of group kvh reads KV head kvh
@@ -275,11 +315,12 @@ def fused_chunked_attention(q, k_pool, v_pool, block_table, positions,
                               positions, T, interpret,
                               k_scale=k_scale, v_scale=v_scale,
                               kv_dtype=kv_cache_dtype,
-                              mask_block=mask_block)
+                              mask_block=mask_block, window=window)
     else:
         out = _xla_chunked(q_g, k_pool, v_pool, block_table, positions,
                            T, k_scale=k_scale, v_scale=v_scale,
-                           kv_dtype=kv_cache_dtype, mask_block=mask_block)
+                           kv_dtype=kv_cache_dtype, mask_block=mask_block,
+                           window=window)
     return out.reshape(B, KVH, rep, T, D).transpose(0, 3, 1, 2, 4) \
         .reshape(B, T, H, D).astype(q.dtype)
 

@@ -22,8 +22,9 @@ one row over a tile), tiles past the last real one are skipped: they
 keep the last real tile's weight block, so nothing is fetched for
 them, and write zeros.
 
-``route_topk`` is the router every caller shares: softmax scores in
-float32 over ALL experts, the ``k`` largest, renormalised.
+``route_topk`` is the router every caller shares: softmax (or sigmoid)
+scores in float32 over ALL experts, the ``k`` largest (by score, or by
+score plus a selection-only bias), renormalised and scaled.
 ``grouped_experts`` takes the routing and the held experts' weights
 and returns the combined output and what the step read
 (``RouteStats``), counted on the device.
@@ -56,16 +57,33 @@ class RouteStats(NamedTuple):
                           self.assignments_max]).astype(jnp.int32)
 
 
-def route_topk(x, router_w, k, *, normalize=True):
+def route_topk(x, router_w, k, *, normalize=True, scores="softmax",
+               bias=None, scale=None, norm_eps=None):
     """``x [T, H]``, ``router_w [H, E]`` -> ``(chosen [T, k] int32,
-    gates [T, k] f32)``: softmax over all ``E`` experts in float32,
-    the ``k`` largest, divided by their sum when ``normalize``."""
-    scores = jax.nn.softmax(
-        jnp.dot(x.astype(jnp.float32), router_w.astype(jnp.float32),
-                preferred_element_type=jnp.float32), axis=-1)
-    gates, chosen = jax.lax.top_k(scores, k)
+    gates [T, k] f32)``: scores over all ``E`` experts in float32
+    (``"softmax"`` over them, or a ``"sigmoid"`` each), the ``k``
+    largest, divided by their sum (plus ``norm_eps``) when
+    ``normalize``, times ``scale``.  ``bias [E]`` moves the SELECTION
+    only: the ``k`` largest of ``score + bias`` are chosen, and each
+    keeps its own score as its gate."""
+    logits = jnp.dot(x.astype(jnp.float32), router_w.astype(jnp.float32),
+                     preferred_element_type=jnp.float32)
+    if scores == "softmax":
+        scores = jax.nn.softmax(logits, axis=-1)
+    elif scores == "sigmoid":
+        scores = jax.nn.sigmoid(logits)
+    else:
+        raise ValueError(f"scores must be softmax or sigmoid, got {scores!r}")
+    if bias is None:
+        gates, chosen = jax.lax.top_k(scores, k)
+    else:
+        _, chosen = jax.lax.top_k(scores + bias.astype(jnp.float32), k)
+        gates = jnp.take_along_axis(scores, chosen, axis=-1)
     if normalize:
-        gates = gates / jnp.sum(gates, axis=-1, keepdims=True)
+        total = jnp.sum(gates, axis=-1, keepdims=True)
+        gates = gates / (total if norm_eps is None else total + norm_eps)
+    if scale is not None:
+        gates = gates * scale
     return chosen.astype(jnp.int32), gates
 
 

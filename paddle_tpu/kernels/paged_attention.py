@@ -251,7 +251,7 @@ def _online_softmax(scores, values, m, l, acc):
 
 
 def _decode_kernel(bt_ref, pos_ref, q_ref, cos_ref, sin_ref, *rest,
-                   bs, pages_per_split, scale, kv_dtype=None):
+                   bs, pages_per_split, scale, kv_dtype=None, window=None):
     hbm_refs, (o_ref, m_out_ref, l_out_ref), bufs, sems = \
         _split_walk_refs(rest, kv_dtype)
     b = pl.program_id(0)
@@ -262,11 +262,18 @@ def _decode_kernel(bt_ref, pos_ref, q_ref, cos_ref, sin_ref, *rest,
     # walk never reads a table entry, or fetches a page, past them.
     pos = pos_ref[b]
     live = jnp.minimum(pos // bs + 1, bt_ref.shape[1])
-    lo = s * pages_per_split
-    walk = _PageWalk(bt_ref, b, lo, jnp.minimum(lo + pages_per_split, live),
-                     hbm_refs, bufs, sems, kv_dtype)
+    split_lo = lo = s * pages_per_split
+    hi = jnp.minimum(lo + pages_per_split, live)
+    if window is not None:
+        # a window layer sees the last ``window`` keys (k_pos > pos -
+        # window): the walk starts at the page of the first of them, and
+        # that page's earlier keys are masked.  No page before it is
+        # read: its table entry may name a page given back long ago
+        first_key = jnp.maximum(pos - window + 1, 0)
+        lo = jnp.maximum(lo, first_key // bs)
+    walk = _PageWalk(bt_ref, b, lo, hi, hbm_refs, bufs, sems, kv_dtype)
     G, num_blocks = walk.G, walk.num_blocks
-    key_limit = jnp.minimum(pos + 1, (lo + pages_per_split) * bs)
+    key_limit = jnp.minimum(pos + 1, (split_lo + pages_per_split) * bs)
 
     # rotate + pre-scale q once per (batch, split) cell: RoPE lives
     # inside the kernel, and folding 1/sqrt(D) into q here keeps the
@@ -284,7 +291,10 @@ def _decode_kernel(bt_ref, pos_ref, q_ref, cos_ref, sin_ref, *rest,
             preferred_element_type=jnp.float32)         # [KVH,rep,G*bs]
         k_pos = (lo + j * G) * bs + jax.lax.broadcasted_iota(
             jnp.int32, scores.shape, 2)
-        scores = jnp.where(k_pos < key_limit, scores, NEG_INF)
+        seen = k_pos < key_limit
+        if window is not None:
+            seen = seen & (k_pos >= first_key)
+        scores = jnp.where(seen, scores, NEG_INF)
         return _online_softmax(scores, walk.values(slot), *carry)
 
     KVH, rep, D = q_rot.shape
@@ -303,10 +313,12 @@ def _decode_kernel(bt_ref, pos_ref, q_ref, cos_ref, sin_ref, *rest,
 
 
 @functools.partial(jax.jit, static_argnames=("num_splits", "scale",
-                                             "interpret", "kv_dtype"))
+                                             "interpret", "kv_dtype",
+                                             "window"))
 def _pallas_partials(q, cos_b, sin_b, k_pool, v_pool, block_table,
                      positions, num_splits, scale, interpret,
-                     k_scale=None, v_scale=None, kv_dtype=None):
+                     k_scale=None, v_scale=None, kv_dtype=None,
+                     window=None):
     """q: UNROTATED [B, KVH, rep, D]; returns (acc [B,S,KVH,rep,D] f32,
     m [B,S,KVH,rep] f32, l [B,S,KVH,rep] f32).
 
@@ -354,7 +366,7 @@ def _pallas_partials(q, cos_b, sin_b, k_pool, v_pool, block_table,
     scale_bytes = 2 * B * L * 4 if kv_dtype is not None else 0
     acc, m_b, l_b = pl.pallas_call(
         functools.partial(_decode_kernel, bs=bs, pages_per_split=P,
-                          scale=scale, kv_dtype=kv_dtype),
+                          scale=scale, kv_dtype=kv_dtype, window=window),
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct((B, num_splits, KVH, rep, D),
@@ -382,7 +394,8 @@ def _pallas_partials(q, cos_b, sin_b, k_pool, v_pool, block_table,
 # ---------------------------------------------------------------------------
 
 def _xla_partials(q_rot, k_pool, v_pool, block_table, positions,
-                  num_splits, k_scale=None, v_scale=None, kv_dtype=None):
+                  num_splits, k_scale=None, v_scale=None, kv_dtype=None,
+                  window=None):
     """Same split-K partials in plain XLA: q_rot is the ROTATED and
     pre-scaled [B, KVH, rep, D] f32 query (scale folded in, exactly as
     the kernel does once a grid cell).  Quantized pools dequant at the gather
@@ -407,6 +420,9 @@ def _xla_partials(q_rot, k_pool, v_pool, block_table, positions,
     k_pos = jnp.arange(nbs * bs).reshape(num_splits, Lp)
     valid = k_pos[None, :, None, None, :] <= \
         positions[:, None, None, None, None]
+    if window is not None:
+        valid = valid & (k_pos[None, :, None, None, :] >
+                         positions[:, None, None, None, None] - window)
     scores = jnp.where(valid, scores, NEG_INF)
     m = jnp.max(scores, axis=-1)                        # [B,S,KVH,rep]
     pexp = jnp.exp(scores - m[..., None])
@@ -451,7 +467,8 @@ def _default_splits(nbs):
 def fused_paged_decode(q, k_new, v_new, k_pool, v_pool, block_table,
                        positions, cos, sin, *, num_splits=None,
                        use_pallas=None, interpret=None,
-                       k_scale=None, v_scale=None, kv_cache_dtype=None):
+                       k_scale=None, v_scale=None, kv_cache_dtype=None,
+                       window=None):
     """One fused decode step of paged attention.
 
     q: [B, 1, H, D] UNROTATED queries; k_new/v_new: [B, 1, KVH, D]
@@ -474,6 +491,12 @@ def fused_paged_decode(q, k_new, v_new, k_pool, v_pool, block_table,
     The new token quantizes at write and dequant fuses into the block
     DMA; the return grows to (attn_out, new_k_pool, new_v_pool,
     new_k_scale, new_v_scale).
+
+    ``window`` (static) makes the layer a WINDOW layer: sequence ``b``
+    sees the keys at ``positions[b] - window < k_pos <= positions[b]``,
+    the walk starts at the page of the first of them, and no table
+    entry before that page is read.  ``cos=None`` (with ``sin``) is the
+    identity rotation: a layer with no position encoding.
     """
     from .fusion import pallas_lowering
 
@@ -495,10 +518,16 @@ def fused_paged_decode(q, k_new, v_new, k_pool, v_pool, block_table,
     # B rows — XLA prologue shared verbatim by both lowerings).  A
     # quantized pool quantizes the token's row here, at write time,
     # inside the traced step.
-    c = cos[positions]                                  # [B, half] f32
-    s = sin[positions]
-    k_rot = _rotate_half(k_new[:, 0].astype(jnp.float32),
-                         c[:, None, :], s[:, None, :]).astype(k_new.dtype)
+    if cos is None:
+        c = jnp.ones((B, D // 2), jnp.float32)
+        s = jnp.zeros((B, D // 2), jnp.float32)
+        k_rot = k_new[:, 0]
+    else:
+        c = cos[positions]                              # [B, half] f32
+        s = sin[positions]
+        k_rot = _rotate_half(
+            k_new[:, 0].astype(jnp.float32),
+            c[:, None, :], s[:, None, :]).astype(k_new.dtype)
     with jax.named_scope("kv_write"):
         if kv_cache_dtype is not None:
             new_k_pool, new_k_scale = _scatter_token_quant(
@@ -520,7 +549,7 @@ def fused_paged_decode(q, k_new, v_new, k_pool, v_pool, block_table,
             q_g, c, s, new_k_pool, new_v_pool, block_table,
             positions, num_splits, scale, interpret,
             k_scale=new_k_scale, v_scale=new_v_scale,
-            kv_dtype=kv_cache_dtype)
+            kv_dtype=kv_cache_dtype, window=window)
     else:
         q_rot = _rotate_half(q_g.astype(jnp.float32),
                              c[:, None, None, :],
@@ -529,7 +558,7 @@ def fused_paged_decode(q, k_new, v_new, k_pool, v_pool, block_table,
                                   block_table, positions, num_splits,
                                   k_scale=new_k_scale,
                                   v_scale=new_v_scale,
-                                  kv_dtype=kv_cache_dtype)
+                                  kv_dtype=kv_cache_dtype, window=window)
     out = _combine_splits(acc, m, l)                    # [B,KVH,rep,D]
     out = out.reshape(B, 1, H, D).astype(q.dtype)
     if kv_cache_dtype is not None:

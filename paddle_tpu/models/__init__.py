@@ -9,3 +9,4 @@ from . import generation  # noqa: F401
 from .generation import generate  # noqa: F401
 from .sdar_moe import (DroplessMoE, SDARMoEConfig,  # noqa: F401
                        SDARMoEForCausalLM)
+from .afmoe import AfmoeConfig, AfmoeForCausalLM  # noqa: F401

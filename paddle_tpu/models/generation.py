@@ -363,7 +363,8 @@ def _wrap_paged(pools, block_tables, kv_dtype, model=None):
     """Pool entries -> PagedKVCache views: (k, v) tuples for full-
     precision pools, (k, v, k_scale, v_scale) for quantized ones
     (serving/cache.py BlockKVPool.layers); a model whose entries hold
-    more than K and V (``pool_sidecars``) builds its own views.  Called
+    more than K and V (its ``cache_layers()`` name sidecars) builds its
+    own views.  Called
     at TRACE time only — the branch is on the build-time kv_dtype
     constant, never a traced value, and lives outside the H106-audited
     step source."""
@@ -422,6 +423,11 @@ def make_paged_decode_step(model, fused=None, kv_cache_dtype=None):
 
     from ..core.dispatch import no_grad_ctx
 
+    if hasattr(model, "decode_token"):
+        return cached_step(model, ("grouped_paged_decode", fused, kv_dtype),
+                           _grouped_decode_step(model, fused),
+                           donate="pools")
+
     def paged_decode_step(tok, pools, block_tables, lengths):
         with no_grad_ctx(), serving_fusion(fused):
             wrapped = _wrap_paged(pools, block_tables, kv_dtype)
@@ -477,6 +483,10 @@ def make_chunked_prefill_step(model, fused=None, kv_cache_dtype=None):
     if getattr(model, "block_diffusion", None) is not None:
         return cached_step(model, ("block_chunked_prefill", fused, kv_dtype),
                            _block_chunk_step(model, fused), donate="pools")
+    if hasattr(model, "decode_token"):
+        return cached_step(
+            model, ("grouped_chunked_prefill", fused, kv_dtype),
+            _grouped_chunk_step(model, fused), donate="pools")
 
     def chunked_prefill_step(ids, pools, block_table, start, last_index):
         with no_grad_ctx(), serving_fusion(fused):
@@ -493,6 +503,47 @@ def make_chunked_prefill_step(model, fused=None, kv_cache_dtype=None):
 
     return cached_step(model, ("chunked_prefill", fused, kv_dtype),
                        chunked_prefill_step, donate="pools")
+
+
+def _grouped_decode_step(model, fused):
+    """The decode program of a model whose cache lies in two GROUPS of
+    pages, a block table each (window layers beside full ones:
+    ``model.decode_token``, models/afmoe.py): the same name, lane and
+    call signature as :func:`make_paged_decode_step`'s, so the engine's
+    decode iteration, the trace and the metrics read it as they read any
+    decode step.  ``block_tables`` is the pair ``(full group's [S,
+    max_blocks], window group's [S, max_blocks])``, and the first result
+    the pair ``(logits [S, V] f32, stats [3] int32)``: what the routed
+    layers read (experts read, assignments, the busiest expert's
+    assignments, summed over layers)."""
+    from ..core.dispatch import no_grad_ctx
+    from ..kernels.fusion import serving_fusion
+
+    def paged_decode_step(tok, pools, block_tables, lengths):
+        with no_grad_ctx(), serving_fusion(fused):
+            logits, stats, new_pools = model.decode_token(
+                tok, pools, block_tables, lengths)
+            return (logits, stats), new_pools
+
+    return paged_decode_step
+
+
+def _grouped_chunk_step(model, fused):
+    """The chunk program of such a model (``model.prefill_chunk``), as
+    :func:`make_chunked_prefill_step`'s is called: ``block_table`` is
+    the pair of ``[1, max_blocks]`` tables, the first result ``(logits
+    [1, V] f32 of the chunk's last real token, stats [3] int32)``."""
+    from ..core.dispatch import no_grad_ctx
+    from ..kernels.fusion import serving_fusion
+
+    def chunked_prefill_step(ids, pools, block_table, start, last_index):
+        with no_grad_ctx(), serving_fusion(fused):
+            valid = (jnp.arange(ids.shape[1]) <= last_index)[None, :]
+            last, stats, new_pools = model.prefill_chunk(
+                ids, valid, pools, block_table, start, last_index)
+            return (last, stats), new_pools
+
+    return chunked_prefill_step
 
 
 def unmask_schedule(block_length: int, denoising_steps: int):

@@ -936,6 +936,16 @@ class LlamaForCausalLM(nn.Layer):
         return logits
 
     # --------------------------------------------------------- generation
+    def cache_layers(self):
+        """The model's description of its cache, a record a layer
+        (``serving/cache.py::LayerCache``): full attention, one K/V
+        geometry, nothing beside K and V."""
+        from ..serving.cache import LayerCache
+        from .generation import _cache_dims
+
+        return [LayerCache(*_cache_dims(self))
+                for _ in range(self.config.num_hidden_layers)]
+
     def generate(self, input_ids, max_new_tokens=32, temperature=1.0,
                  top_k: Optional[int] = None, top_p: float = 1.0,
                  do_sample: Optional[bool] = None, num_beams: int = 1,

@@ -205,21 +205,36 @@ class _ChunkedNormal:
 
 
 class DroplessMoE(nn.Layer):
-    """Softmax top-k routed experts with no capacity and no dropped
-    token.  The router scores ALL ``num_experts``; this layer holds the
-    matrices of ``held`` (expert ids; ``None``: all) and computes the
-    part of the output that its experts give.  Summed over holders that
-    together hold every expert once, the parts are the whole layer's
-    output.  Weights are ``[E_held, M, H]`` for gate, up and (transposed)
-    down: ``kernels/moe_experts``."""
+    """Top-k routed experts with no capacity and no dropped token.  The
+    router scores ALL ``num_experts``; this layer holds the matrices of
+    ``held`` (expert ids; ``None``: all) and computes the part of the
+    output that its experts give.  Summed over holders that together
+    hold every expert once, the parts are the whole layer's output.
+    Weights are ``[E_held, M, H]`` for gate, up and (transposed) down:
+    ``kernels/moe_experts``.
+
+    The router is a softmax over the experts, or (``scores="sigmoid"``)
+    a sigmoid each; ``selection_bias`` adds the buffer ``expert_bias
+    [E]`` to the scores for the SELECTION only (a chosen expert's gate
+    is its own score); ``normalize`` divides the chosen gates by their
+    sum (plus ``norm_eps``) and ``route_scale`` multiplies them."""
 
     def __init__(self, hidden_size, intermediate_size, num_experts, top_k,
-                 *, normalize=True, held=None, dtype="float32"):
+                 *, normalize=True, held=None, dtype="float32",
+                 scores="softmax", selection_bias=False, route_scale=None,
+                 norm_eps=None):
         super().__init__()
         from ..nn import initializer as I
 
         self.num_experts, self.top_k = num_experts, top_k
         self.normalize = normalize
+        self.scores, self.route_scale = scores, route_scale
+        self.norm_eps = norm_eps
+        if selection_bias:
+            # float32 whatever the layer is narrowed to later: whoever
+            # narrows the model registers it again (models/afmoe.py)
+            self.register_buffer("expert_bias", Tensor(
+                jnp.zeros((num_experts,), jnp.float32)))
         self.held = None if held is None else tuple(held)
         n_held = num_experts if held is None else len(self.held)
         h, m = hidden_size, intermediate_size
@@ -239,9 +254,13 @@ class DroplessMoE(nn.Layer):
     def route(self, x2d):
         from ..kernels.moe_experts import route_topk
 
+        bias = self._buffers.get("expert_bias")
         with jax.named_scope("moe_router"):
-            return route_topk(x2d, self.router._value, self.top_k,
-                              normalize=self.normalize)
+            return route_topk(
+                x2d, self.router._value, self.top_k,
+                normalize=self.normalize, scores=self.scores,
+                bias=None if bias is None else bias._value,
+                scale=self.route_scale, norm_eps=self.norm_eps)
 
     def experts(self, x2d, chosen, gates, token_valid=None):
         from ..kernels.moe_experts import grouped_experts
@@ -356,10 +375,19 @@ class SDARMoEForCausalLM(nn.Layer):
         that has them is served by the block iteration."""
         return self.config
 
-    def pool_sidecars(self):
-        """Per-position arrays a pool entry holds beside K and V: the
-        routing witness, ``k`` expert ids."""
-        return [((self.config.num_experts_per_tok,), jnp.int32)]
+    def cache_layers(self):
+        """The model's description of its cache, a record a layer
+        (``serving/cache.py::LayerCache``): full attention everywhere,
+        and beside K and V the routing witness, ``k`` expert ids a
+        position."""
+        from ..serving.cache import LayerCache
+
+        cfg = self.config
+        return [LayerCache(
+            cfg.num_key_value_heads, cfg.head_dim,
+            jnp.bfloat16 if cfg.dtype == "bfloat16" else jnp.float32,
+            sidecars=(((cfg.num_experts_per_tok,), jnp.int32),))
+            for _ in range(cfg.num_hidden_layers)]
 
     def paged_cache_views(self, pools, block_tables):
         return [RoutedKVCache(k, v, block_tables, chosen)

@@ -40,13 +40,28 @@ Block 0 is a reserved garbage sink: idle engine slots decode with
 block-table entries pointing at it, so the compiled step never needs a
 host-side branch on "is this slot live" (the write lands in garbage,
 attention masks it, and the hot loop stays device-resident — H106).
+
+The pool is laid out BY LAYER KIND, from the model's own description of
+its cache (:class:`LayerCache`, one record a layer; :func:`describe_cache`).
+A FULL layer keeps every page of a sequence for the sequence's life: its
+pages are the blocks above.  A WINDOW layer sees the last ``window`` keys
+only, so its pages are a second GROUP with an allocator of its own
+(``pool.window``), far smaller than the full group: a sequence holds at
+most ``window_pages_per_seq`` of them and gives pages back, while it
+runs, as its window moves past them (:meth:`BlockKVPool.advance_window`).
+Each group has its own block table: a released page's entry names the
+group's garbage block 0, and the kernels of a window layer start their
+walk at the window's first page, so such an entry is never read as a
+live page.  One manager owns both groups: ``free_request``,
+``check_leaks``, ``reset`` and ``lost`` cover both.
 """
 from __future__ import annotations
 
 import functools
 import hashlib
+import dataclasses
 from collections import OrderedDict
-from typing import Dict, List, Optional, Sequence, Set, Tuple
+from typing import Any, Dict, List, Optional, Sequence, Set, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -68,20 +83,206 @@ class PoolExhausted(Exception):
     """No free or evictable blocks: the caller must preempt or wait."""
 
 
-class BlockKVPool:
+@dataclasses.dataclass(frozen=True)
+class LayerCache:
+    """What ONE layer of a served model keeps in the paged pool: the
+    model's own description of its cache, from which the engine builds
+    the pool (``model.cache_layers()``, :func:`describe_cache`).
+
+    ``window`` ``None`` is a FULL layer, which keeps every page of a
+    sequence for its life; a number is a WINDOW layer that sees the last
+    ``window`` keys and whose pages come from the window group.
+    ``sidecars`` are the ``(shape, dtype)`` a position of what the layer
+    keeps beside K and V (the experts its router chose, say); they are
+    addressed through the FULL group's table whatever the layer's kind,
+    so they outlive a window layer's pages."""
+
+    kv_heads: int
+    head_dim: int
+    dtype: Any
+    window: Optional[int] = None
+    sidecars: Tuple = ()
+
+    @property
+    def kind(self) -> str:
+        return "full" if self.window is None else "window"
+
+
+def describe_cache(model) -> List[LayerCache]:
+    """The model's records, one a layer.  A model that does not describe
+    its cache (the tests' small stand-ins) is given full layers of the
+    geometry its config states."""
+    if hasattr(model, "cache_layers"):
+        return list(model.cache_layers())
+    from ..models.generation import _cache_dims
+
+    kv_heads, head_dim, dtype = _cache_dims(model)
+    return [LayerCache(kv_heads, head_dim, dtype)
+            for _ in range(model.config.num_hidden_layers)]
+
+
+class BlockAllocator:
+    """A group's blocks: a LIFO free list over blocks ``1..n-1`` (block 0
+    is the group's garbage sink) and, for every block in use, the set of
+    request ids that hold it."""
+
+    def __init__(self, num_blocks: int):
+        if num_blocks < 2:
+            raise ValueError("need >= 2 blocks (block 0 is the reserved "
+                             "garbage sink)")
+        self.num_blocks = num_blocks
+        self._free: List[int] = list(range(num_blocks - 1, 0, -1))
+        # block id -> set of owning request ids (refcount = len), for the
+        # blocks in use.  A block's set is made once and kept for the
+        # group's life (empty while the block is free): a fresh set a
+        # block a request is some two thousand containers a long
+        # request, each living long enough to reach the collector's
+        # oldest generation and to bring its full passes on sooner
+        self._owner_sets: List[Set] = [set() for _ in range(num_blocks)]
+        self._owners: Dict[int, Set] = {}
+
+    def _own(self, block: int, request_id):
+        """``request_id`` the first owner of a block that had none."""
+        owners = self._owners[block] = self._owner_sets[block]
+        owners.add(request_id)
+
+    @property
+    def capacity_blocks(self) -> int:
+        """Allocatable blocks (excludes the reserved garbage block)."""
+        return self.num_blocks - 1
+
+    @property
+    def num_free(self) -> int:
+        return len(self._free)
+
+    @property
+    def num_used(self) -> int:
+        """Blocks referenced by at least one live request."""
+        return self.capacity_blocks - self.num_free
+
+    def can_allocate(self, n: int) -> bool:
+        return self.num_free >= n
+
+    def owned_by(self, request_id) -> List[int]:
+        return [b for b, o in self._owners.items() if request_id in o]
+
+    def refcount(self, block: int) -> int:
+        return len(self._owners.get(block, ()))
+
+    def _take(self) -> int:
+        return self._free.pop()
+
+    def _release_block(self, b: int):
+        self._owners.pop(b, None)
+        self._free.append(b)
+
+    def _exhausted(self, n: int) -> str:
+        return (f"need {n} block(s), {self.num_free} free "
+                f"(capacity {self.capacity_blocks})")
+
+    def allocate(self, request_id, n: int = 1) -> List[int]:
+        """Hand ``n`` private blocks to ``request_id``.  Raises
+        :class:`PoolExhausted` (allocating nothing) when the group has
+        fewer."""
+        if self.num_free < n:
+            raise PoolExhausted(self._exhausted(n))
+        blocks = []
+        for _ in range(n):
+            b = self._take()
+            self._own(b, request_id)
+            blocks.append(b)
+        return blocks
+
+    def free(self, blocks: Sequence[int], request_id=None):
+        """Drop ``request_id``'s reference on each block (refcount
+        decrement); a block with no owners left is recycled.  Without a
+        ``request_id`` the block must be singly-owned (the pre-refcount
+        call shape); freeing a block the id does not own — or freeing an
+        unowned block — is the classic double free, reported with the
+        CURRENT owner set to ease debugging."""
+        for b in blocks:
+            owners = self._owners.get(b)
+            if owners is None:
+                raise ValueError(
+                    f"double free of block {b} (no current owner)")
+            if request_id is None:
+                if len(owners) > 1:
+                    raise ValueError(
+                        f"block {b} is shared (owned by "
+                        f"{sorted(map(str, owners))}); "
+                        f"free(..., request_id=...) required")
+                owners.clear()
+            else:
+                if request_id not in owners:
+                    raise ValueError(
+                        f"double free of block {b} by {request_id!r} "
+                        f"(owned by {sorted(map(str, owners))})")
+                owners.discard(request_id)
+            if not owners:
+                self._release_block(b)
+
+    def free_request(self, request_id):
+        """Release every block ``request_id`` references, in REVERSE
+        acquisition order.  A request owning nothing is a safe no-op."""
+        blocks = self.owned_by(request_id)
+        if blocks:
+            self.free(list(reversed(blocks)), request_id)
+
+    def check_leaks(self, group: str = ""):
+        """Raise if any block is still owned by a request."""
+        if self._owners:
+            raise AssertionError(
+                f"leaked {group}blocks: "
+                f"{sorted((b, sorted(map(str, o))) for b, o in self._owners.items())}")
+
+    def reset(self):
+        """Every block free again, once no request holds one."""
+        self.check_leaks()
+        self._free = list(range(self.num_blocks - 1, 0, -1))
+
+
+class BlockKVPool(BlockAllocator):
+    """The manager of the paged pool: the full group's blocks (this
+    allocator, with the prefix cache on top), the window group's
+    (``window``, where the model has window layers) and the device
+    buffers of every layer (``layers``, in the model's layer order)."""
+
     def __init__(self, num_layers: int, num_blocks: int, block_size: int,
                  kv_heads: int, head_dim: int, dtype=jnp.float32,
                  enable_prefix_cache: bool = True,
                  kv_cache_dtype: Optional[str] = None,
-                 sidecars: Sequence[tuple] = ()):
-        if num_blocks < 2:
-            raise ValueError("need >= 2 blocks (block 0 is the reserved "
-                             "garbage sink)")
-        self.num_layers = num_layers
-        self.num_blocks = num_blocks
+                 sidecars: Sequence[tuple] = (),
+                 layer_caches: Optional[Sequence[LayerCache]] = None,
+                 window_blocks: int = 0, window_pages_per_seq: int = 0):
+        super().__init__(num_blocks)
+        if layer_caches is None:
+            layer_caches = [LayerCache(kv_heads, head_dim, dtype,
+                                       sidecars=tuple(sidecars))
+                            for _ in range(num_layers)]
+        #: the model's description, a record a layer
+        self.layer_caches = list(layer_caches)
+        windows = {c.window for c in self.layer_caches} - {None}
+        if len(windows) > 1:
+            raise ValueError(f"window layers of one size only, got "
+                             f"{sorted(windows)}")
+        #: the window layers' span in keys, None where every layer is full
+        self.window_size: Optional[int] = windows.pop() if windows else None
+        #: the window group's allocator (None: every layer is full)
+        self.window: Optional[BlockAllocator] = None
+        #: the most window pages one sequence ever holds
+        self.window_pages_per_seq = window_pages_per_seq
+        if self.window_size is not None:
+            if enable_prefix_cache or kv_cache_dtype is not None:
+                raise ValueError(
+                    "a pool with a window group has no prefix cache and "
+                    "no quantized entries yet: which layers can reuse a "
+                    "block, and a quantized window page, are later work")
+            self.window = BlockAllocator(window_blocks)
+        self.num_layers = len(self.layer_caches)
         self.block_size = block_size
-        self.kv_heads = kv_heads
-        self.head_dim = head_dim
+        self.kv_heads = self.layer_caches[0].kv_heads
+        self.head_dim = self.layer_caches[0].head_dim
+        dtype = self.layer_caches[0].dtype
         #: quant scheme: None (full precision) / "int8" / "fp8"
         self.kv_cache_dtype = resolve_kv_cache_dtype(kv_cache_dtype)
         #: the MODEL's kv dtype (what dequant produces / fp32 pools hold)
@@ -102,13 +303,7 @@ class BlockKVPool:
         # row write wants and relaid around every write), written by
         # the model's step programs at the positions they write K/V and
         # moved with its block by copy-on-write
-        self._sidecars = [(int(np.prod(shape, dtype=np.int64)), dt)
-                          for shape, dt in sidecars]
         self.layers: List[Tuple[jax.Array, ...]] = self._fresh_layers()
-        # LIFO free list over blocks 1..n-1 (block 0 reserved)
-        self._free: List[int] = list(range(num_blocks - 1, 0, -1))
-        # block id -> set of owning request ids (refcount = len)
-        self._owners: Dict[int, Set] = {}
         # content index: chain hash -> block id, and its reverse.
         # Invariant: b in _block_hash  <=>  _hash_index[_block_hash[b]] == b
         self._hash_index: Dict[bytes, int] = {}
@@ -130,19 +325,25 @@ class BlockKVPool:
         quantized ones: int8 code pools plus one f32 absmax scale per
         (block, token) row (kernels/kv_quant.py); then the sidecars.
         Every leaf is a buffer of its own: the step programs DONATE the
-        pool, and the runtime refuses to donate one buffer twice."""
-        rows = (self.num_blocks, self.block_size)
+        pool, and the runtime refuses to donate one buffer twice.  A
+        window layer's K and V have the window group's blocks; its
+        sidecars, like every layer's, the full group's."""
 
-        def entry():
-            kv = tuple(jnp.zeros(rows + (self.kv_heads, self.head_dim),
-                                 self.dtype) for _ in range(2))
+        def entry(c: LayerCache):
+            blocks = self.num_blocks if c.window is None \
+                else self.window.num_blocks
+            rows = (blocks, self.block_size)
+            store = kv_storage_dtype(self.kv_cache_dtype) or c.dtype
+            kv = tuple(jnp.zeros(rows + (c.kv_heads, c.head_dim), store)
+                       for _ in range(2))
             if self.kv_cache_dtype is not None:
                 kv += tuple(jnp.ones(rows, jnp.float32) for _ in range(2))
             return kv + tuple(
-                jnp.zeros((self.num_blocks, self.block_size * size), dt)
-                for size, dt in self._sidecars)
+                jnp.zeros((self.num_blocks, self.block_size
+                           * int(np.prod(shape, dtype=np.int64))), dt)
+                for shape, dt in c.sidecars)
 
-        return [entry() for _ in range(self.num_layers)]
+        return [entry(c) for c in self.layer_caches]
 
     def lost(self) -> bool:
         """A step program consumed the pool and gave none back (it
@@ -155,9 +356,10 @@ class BlockKVPool:
         ``Engine.revive()`` does about a lost pool, once no request
         references a block (the cached K/V went with the buffers, so an
         indexed block would serve zeros)."""
-        self.check_leaks()
+        super().reset()
+        if self.window is not None:
+            self.window.reset()
         self.layers = self._fresh_layers()
-        self._free = list(range(self.num_blocks - 1, 0, -1))
         self._hash_index.clear()
         self._block_hash.clear()
         self._cached_free.clear()
@@ -165,20 +367,10 @@ class BlockKVPool:
 
     # ------------------------------------------------------- accounting
     @property
-    def capacity_blocks(self) -> int:
-        """Allocatable blocks (excludes the reserved garbage block)."""
-        return self.num_blocks - 1
-
-    @property
     def num_free(self) -> int:
         """Blocks allocatable RIGHT NOW: truly free plus cached-but-
         unreferenced (the latter evict on demand)."""
         return len(self._free) + len(self._cached_free)
-
-    @property
-    def num_used(self) -> int:
-        """Blocks referenced by at least one live request."""
-        return self.capacity_blocks - self.num_free
 
     @property
     def num_cached(self) -> int:
@@ -213,9 +405,10 @@ class BlockKVPool:
         return int(num_layers * 2 * per_side)
 
     def block_bytes(self) -> int:
-        """HBM bytes one block costs in THIS pool (all layers, k + v,
-        including quantized scale rows)."""
-        return self.block_bytes_for(self.num_layers, self.block_size,
+        """HBM bytes one block of the full group costs in THIS pool (its
+        layers, k + v, including quantized scale rows)."""
+        full = sum(1 for c in self.layer_caches if c.window is None)
+        return self.block_bytes_for(full, self.block_size,
                                     self.kv_heads, self.head_dim,
                                     self.model_dtype, self.kv_cache_dtype)
 
@@ -236,20 +429,15 @@ class BlockKVPool:
         pressure signal: two pools sized from the same ``kv_pool_bytes``
         budget at different dtypes report comparable pressure per byte,
         not per block."""
-        return self.used_bytes() / self.capacity_bytes()
+        capacity = self.capacity_bytes()
+        # (a model whose every layer is a window layer keeps no K/V in
+        # the full group: its blocks still count)
+        return self.used_bytes() / capacity if capacity \
+            else self.utilization()
 
     def blocks_for(self, num_tokens: int) -> int:
         """Blocks needed to hold ``num_tokens`` cache positions."""
         return -(-int(num_tokens) // self.block_size)
-
-    def can_allocate(self, n: int) -> bool:
-        return self.num_free >= n
-
-    def owned_by(self, request_id) -> List[int]:
-        return [b for b, o in self._owners.items() if request_id in o]
-
-    def refcount(self, block: int) -> int:
-        return len(self._owners.get(block, ()))
 
     def is_shared(self, block: int) -> bool:
         """True when a write into ``block`` would be observable outside
@@ -259,21 +447,16 @@ class BlockKVPool:
             or block in self._block_hash
 
     # ------------------------------------------------------- allocation
-    def allocate(self, request_id, n: int = 1) -> List[int]:
-        """Hand ``n`` private blocks to ``request_id``, evicting LRU
-        cached blocks if the free list alone cannot cover the request.
-        Raises :class:`PoolExhausted` (allocating nothing) otherwise."""
-        if self.num_free < n:
-            raise PoolExhausted(
-                f"need {n} block(s), {len(self._free)} free + "
+    # (``allocate`` is the group allocator's: a block comes off the free
+    # list, or, that dry, the least-recently-parked cached block is
+    # evicted and recycled)
+    def _take(self) -> int:
+        return self._free.pop() if self._free else self._evict_lru()
+
+    def _exhausted(self, n: int) -> str:
+        return (f"need {n} block(s), {len(self._free)} free + "
                 f"{len(self._cached_free)} evictable "
                 f"(capacity {self.capacity_blocks})")
-        blocks = []
-        for _ in range(n):
-            b = self._free.pop() if self._free else self._evict_lru()
-            self._owners[b] = {request_id}
-            blocks.append(b)
-        return blocks
 
     def _evict_lru(self) -> int:
         """Drop the least-recently-parked cached block from the prefix
@@ -305,44 +488,17 @@ class BlockKVPool:
     def _release_block(self, b: int):
         """Last owner gone: park indexed content in the LRU, recycle the
         rest."""
-        self._owners.pop(b, None)
         if self.enable_prefix_cache and b in self._block_hash:
+            self._owners.pop(b, None)
             self._cached_free[b] = None     # LRU tail = most recent
         else:
-            self._free.append(b)
-
-    def free(self, blocks: Sequence[int], request_id=None):
-        """Drop ``request_id``'s reference on each block (refcount
-        decrement); a block with no owners left is recycled.  Without a
-        ``request_id`` the block must be singly-owned (the pre-refcount
-        call shape); freeing a block the id does not own — or freeing an
-        unowned block — is the classic double free, reported with the
-        CURRENT owner set to ease debugging."""
-        for b in blocks:
-            owners = self._owners.get(b)
-            if owners is None:
-                raise ValueError(
-                    f"double free of block {b} (no current owner)")
-            if request_id is None:
-                if len(owners) > 1:
-                    raise ValueError(
-                        f"block {b} is shared (owned by "
-                        f"{sorted(map(str, owners))}); "
-                        f"free(..., request_id=...) required")
-                owners.clear()
-            else:
-                if request_id not in owners:
-                    raise ValueError(
-                        f"double free of block {b} by {request_id!r} "
-                        f"(owned by {sorted(map(str, owners))})")
-                owners.discard(request_id)
-            if not owners:
-                self._release_block(b)
+            super()._release_block(b)
 
     def free_request(self, request_id):
-        """Release every block ``request_id`` references.  A request
-        owning nothing (never prefilled, or already released) is a safe
-        no-op — retire paths call this unconditionally.
+        """Release every block ``request_id`` references, in BOTH
+        groups.  A request owning nothing (never prefilled, or already
+        released) is a safe no-op — retire paths call this
+        unconditionally.
 
         Blocks release in REVERSE acquisition order, so a prompt
         chain's tail blocks park in the LRU before its head: under
@@ -350,20 +506,53 @@ class BlockKVPool:
         which ANY extension of the prefix can reuse, where a tail only
         serves exact matches — survives longest (the radix-tree
         leaf-first eviction order of the prefix-caching literature)."""
-        blocks = self.owned_by(request_id)
-        if not blocks:
-            return
-        self.free(list(reversed(blocks)), request_id)
+        super().free_request(request_id)
+        if self.window is not None:
+            self.window.free_request(request_id)
 
-    def check_leaks(self):
-        """Raise if any block is still owned by a request — used by
-        tests and engine shutdown to prove references round-trip.
-        Cached-but-unreferenced blocks are NOT leaks (they are
-        reclaimable on demand)."""
-        if self._owners:
-            raise AssertionError(
-                "leaked blocks: "
-                f"{sorted((b, sorted(map(str, o))) for b, o in self._owners.items())}")
+    def check_leaks(self, group: str = ""):
+        """Raise if any block of either group is still owned by a
+        request — used by tests and engine shutdown to prove references
+        round-trip.  Cached-but-unreferenced blocks are NOT leaks (they
+        are reclaimable on demand)."""
+        super().check_leaks(group)
+        if self.window is not None:
+            self.window.check_leaks("window-group ")
+
+    # ----------------------------------------------------- window group
+    def window_first_page(self, pos: int) -> int:
+        """The first page a window layer still needs once the earliest
+        query to come sits at position ``pos``: the page of the key at
+        ``pos - window + 1``.  Every page before it lies wholly behind
+        every future query's window."""
+        return max(0, int(pos) - self.window_size + 1) // self.block_size
+
+    def advance_window(self, request_id, pages: Dict[int, int], row,
+                       first_query: int, end: int) -> int:
+        """Move one sequence's window pages to where its next queries
+        need them: pages wholly behind the window of the query at
+        ``first_query`` go back to the window group's free list (their
+        entries of ``row``, the sequence's row of the window group's
+        table, then name the garbage block), and a page is taken for
+        every one up to the position before ``end`` that is not held
+        yet.  ``pages`` maps page index -> block.  Returns how many
+        pages were released; raises :class:`PoolExhausted`, having
+        released but taken nothing, when the group has too few."""
+        # (pages are taken in rising order and given back from the low
+        # end, so the dict's first key is its lowest and its last its
+        # highest: each call costs what it moves, not what is held)
+        first = self.window_first_page(first_query)
+        gone = []
+        while pages and next(iter(pages)) < first:
+            gone.append(next(iter(pages)))
+            self.window.free([pages.pop(gone[-1])], request_id)
+        if gone:
+            row[gone] = 0
+        start = max(first, next(reversed(pages)) + 1) if pages else first
+        want = range(start, self.blocks_for(end))
+        for p, b in zip(want, self.window.allocate(request_id, len(want))):
+            pages[p] = row[p] = b
+        return len(gone)
 
     # ---------------------------------------------------- prefix cache
     @staticmethod
@@ -409,7 +598,7 @@ class BlockKVPool:
                 owners.add(request_id)
             elif b in self._cached_free:
                 del self._cached_free[b]
-                self._owners[b] = {request_id}
+                self._own(b, request_id)
             else:
                 raise ValueError(
                     f"cannot acquire block {b}: neither owned nor cached")
@@ -463,6 +652,9 @@ class BlockKVPool:
         return new
 
     def _copy_block(self, src: int, dst: int):
+        if self.window is not None:
+            raise RuntimeError("copy-on-write in a pool with a window "
+                               "group: nothing shares a block there")
         # ``layers`` is donated, as to a step program: one live pool
         new = _copy_block_impl(tuple(self.layers), np.int32(src),
                                np.int32(dst))
@@ -478,7 +670,14 @@ class BlockKVPool:
         need = self.blocks_for(len(tokens) + extra_tokens) - len(matched)
         need = max(need, 0)
         from_lru = sum(1 for b in matched if b in self._cached_free)
-        return matched, need, need <= self.num_free - from_lru
+        feasible = need <= self.num_free - from_lru
+        if self.window is not None:
+            # by group: the window group must hold what one sequence holds
+            # of it at most
+            feasible = feasible and self.window.can_allocate(min(
+                self.blocks_for(len(tokens) + extra_tokens),
+                self.window_pages_per_seq))
+        return matched, need, feasible
 
     def stats(self) -> dict:
         return {
@@ -495,6 +694,10 @@ class BlockKVPool:
             "used_bytes": self.used_bytes(),
             "capacity_bytes": self.capacity_bytes(),
             "byte_utilization": round(self.byte_utilization(), 4),
+            **({} if self.window is None else {
+                "window_capacity_blocks": self.window.capacity_blocks,
+                "window_used_blocks": self.window.num_used,
+                "window_pages_per_seq": self.window_pages_per_seq}),
         }
 
     def prefix_summary(self, max_roots: int = 8) -> dict:

@@ -36,12 +36,12 @@ from typing import Any, Dict, List, Optional
 
 import numpy as np
 
-from ..models.generation import (_cache_dims, make_chunked_prefill_step,
+from ..models.generation import (make_chunked_prefill_step,
                                  make_paged_block_step,
                                  make_paged_decode_step,
                                  normalize_stop_sequences, unmask_schedule)
 from ..observability import warn_on_retrace
-from .cache import BlockKVPool, PoolExhausted
+from .cache import BlockKVPool, PoolExhausted, describe_cache
 from .metrics import ServingMetrics
 from .overload import EngineQuarantined, OverloadController
 from .sampling import make_sampled_decode_step, resolve_sampling, sample_at
@@ -178,8 +178,17 @@ _BLOCK_IDLE, _BLOCK_DENOISE, _BLOCK_COMMIT = 0, 1, 2
 class Engine:
     """Continuous-batching engine for any causal LM following the
     cache contract of models/llama.py (StaticKVCache + PagedKVCache),
-    and for a model that generates by DIFFUSION OVER BLOCKS (one that
-    has ``block_diffusion`` generation settings: models/sdar_moe.py).
+    for a model that generates by DIFFUSION OVER BLOCKS (one that
+    has ``block_diffusion`` generation settings: models/sdar_moe.py),
+    and for a model with WINDOW layers beside full ones (one whose
+    ``cache_layers()`` names a window: models/afmoe.py).  The pool is
+    built from the model's own description of its cache, a record a
+    layer (serving/cache.py).  A window model's pages live in two
+    groups with a block table each; the same loop serves it, and the
+    window group's pages go back to its free list in the very
+    ``step()`` that moved a sequence's window past them
+    (``_advance_window``: inside ``prefill_dispatch`` for a chunk,
+    inside ``decode_prepare`` for a decode iteration).
 
     A block model is served by the same loop: admission, the pool, the
     chunked prefill lane (the prompt's WHOLE blocks of ``L =
@@ -207,10 +216,18 @@ class Engine:
             from ..quantization.serving import quantize_model_weights
 
             quantize_model_weights(model, cfg.weight_dtype)
-        kv_heads, head_dim, dtype = _cache_dims(model)
+        layer_caches = describe_cache(model)
+        kv_heads, head_dim, dtype = (layer_caches[0].kv_heads,
+                                     layer_caches[0].head_dim,
+                                     layer_caches[0].dtype)
         self.block = getattr(model, "block_diffusion", None)
         if self.block is not None:
             self._check_block_model()
+        #: the window layers' span in keys (None: every layer is full)
+        self.window = next((c.window for c in layer_caches
+                            if c.window is not None), None)
+        if self.window is not None:
+            self._check_window_model()
         model_max = getattr(model.config, "max_position_embeddings", None)
         self.max_model_len = min(
             cfg.max_model_len or model_max or 1 << 30,
@@ -225,7 +242,6 @@ class Engine:
             spec = SpeculativeConfig(draft_model=spec)
         self.spec = spec
         self._n_target_layers = model.config.num_hidden_layers
-        num_layers = self._n_target_layers
         if spec is not None:
             spec.validate_against(model)
             if cfg.mesh is not None:
@@ -239,7 +255,7 @@ class Engine:
                 raise ValueError(
                     f"draft max_position_embeddings ({draft_max}) < "
                     f"max_model_len ({self.max_model_len})")
-            num_layers += spec.draft_model.config.num_hidden_layers
+            layer_caches = layer_caches + describe_cache(spec.draft_model)
         if spec is not None and self.kv_cache_dtype is not None:
             raise ValueError(
                 "speculative decoding with a quantized KV cache is not "
@@ -251,8 +267,11 @@ class Engine:
         # blocks in the same budget — the occupancy headline)
         self.num_blocks = cfg.num_blocks
         if cfg.kv_pool_bytes is not None:
+            # (the budget is the full group's: a window group's size
+            # follows from the window, below)
             per_block = BlockKVPool.block_bytes_for(
-                num_layers, cfg.block_size, kv_heads, head_dim, dtype,
+                sum(1 for c in layer_caches if c.window is None),
+                cfg.block_size, kv_heads, head_dim, dtype,
                 self.kv_cache_dtype)
             self.num_blocks = int(cfg.kv_pool_bytes) // per_block
             if self.num_blocks < 2:
@@ -260,13 +279,22 @@ class Engine:
                     f"kv_pool_bytes={cfg.kv_pool_bytes} fits only "
                     f"{self.num_blocks} block(s) of {per_block} bytes; "
                     "need >= 2 (block 0 is the reserved garbage sink)")
+        # the window group's size follows from the window, the chunk,
+        # the block and the bucket: a sequence holds at most the pages
+        # that (window + chunk) keys can span, whatever its length
+        window_pages = 0 if self.window is None else \
+            -(-(self.window + self.chunk_tokens) // cfg.block_size) + 1
         self.pool = BlockKVPool(
-            num_layers, self.num_blocks, cfg.block_size,
+            len(layer_caches), self.num_blocks, cfg.block_size,
             kv_heads, head_dim, dtype,
-            enable_prefix_cache=cfg.enable_prefix_cache,
+            # which layers could reuse a block of a window model is a
+            # later PR's: no registration, no match
+            enable_prefix_cache=cfg.enable_prefix_cache
+            and self.window is None,
             kv_cache_dtype=self.kv_cache_dtype,
-            sidecars=model.pool_sidecars()
-            if hasattr(model, "pool_sidecars") else ())
+            layer_caches=layer_caches,
+            window_blocks=window_pages * cfg.max_batch_size + 1,
+            window_pages_per_seq=window_pages)
         self.scheduler = Scheduler(self.pool,
                                    max_queue_len=cfg.max_queue_len)
         self.metrics = ServingMetrics()
@@ -281,6 +309,11 @@ class Engine:
         self._slots: List[Optional[Request]] = [None] * S
         self._block_tables = np.zeros((S, self.max_blocks_per_seq),
                                       np.int32)
+        # a window model's second table: the window group's pages, by
+        # the same page index (a released page's entry names the garbage
+        # block)
+        self._window_tables = None if self.window is None else \
+            np.zeros_like(self._block_tables)
         self._lengths = np.zeros((S,), np.int32)
         self._pending = np.zeros((S,), np.int32)  # next token to decode
         # per-slot sampling state, all fixed-shape device-step inputs:
@@ -301,8 +334,9 @@ class Engine:
             self._blk_masked = np.zeros((S, L), bool)
             self._blk_mode = np.zeros((S,), np.int32)
             self._blk_step = np.zeros((S,), np.int32)
-            # routing stats of chunk programs, still on the device
-            self._route_stats = []
+        # routing stats of chunk programs, still on the device (a model
+        # whose programs count what their routed layers read)
+        self._route_stats = []
         # runtime SPMD: shard weights + KV pool BEFORE the steps first
         # run — they take the weights as arguments, so the rebind here
         # is what makes the compiled programs multi-device
@@ -332,7 +366,8 @@ class Engine:
                                       kv_cache_dtype=self.kv_cache_dtype),
             after=1, label="serving::prefill_step",
             on_retrace="raise" if cfg.strict_no_retrace else "count")
-        self._sampled_decode_step = None if self.block is not None \
+        self._sampled_decode_step = None \
+            if self.block is not None or self.window is not None \
             else warn_on_retrace(
                 make_sampled_decode_step(model, fused=cfg.fused_kernels,
                                          kv_cache_dtype=self.kv_cache_dtype),
@@ -398,6 +433,24 @@ class Engine:
                 f"({cfg.chunk_tokens}) must be multiples of the model's "
                 f"block_length ({L}): K/V blocks, chunks and generated "
                 "blocks are aligned")
+
+    def _check_window_model(self):
+        """What a model with a window group is not served with yet is
+        refused here, with an error that says so."""
+        cfg = self.config
+        for what, given in (
+                ("speculative decoding", cfg.speculative),
+                ("a quantized KV cache", cfg.kv_cache_dtype),
+                ("a runtime mesh", cfg.mesh),
+                ("the start-up X-ray", cfg.xray_on_start or None),
+                ("the static shard plan", cfg.shardplan)):
+            if given is not None:
+                raise ValueError(
+                    f"{what} is not supported for a model with window "
+                    "layers: its pages live in two groups with a table "
+                    "each, and a draft's rollback, a quantized window "
+                    "page and the analysers' one-table programs are "
+                    "later work")
 
     def _shardplan_startup(self):
         """Statically plan the decode and chunked-prefill programs on
@@ -573,6 +626,10 @@ class Engine:
                 "sampling is not supported for a model that generates by "
                 "diffusion over blocks: its denoise step picks the argmax "
                 "at every masked position (greedy only)")
+        if params is not None and self.window is not None:
+            raise ValueError(
+                "sampling is not supported yet for a model with window "
+                "layers: its decode program is the greedy one only")
         prompt = np.asarray(
             prompt.numpy() if hasattr(prompt, "numpy") else prompt,
             np.int32).reshape(-1)
@@ -749,6 +806,10 @@ class Engine:
         self._slots[slot] = req
         self._block_tables[slot] = 0
         self._block_tables[slot, :len(blocks)] = blocks
+        if self.window is not None:
+            # window pages are taken chunk by chunk, as the window moves
+            req.window_pages = {}
+            self._window_tables[slot] = 0
         # frontier/pending stay 0 until the prompt completes: the decode
         # view masks this slot's block table to the garbage block
         self._lengths[slot] = 0
@@ -846,6 +907,12 @@ class Engine:
             # written again (no first-token fetch): the program gets a
             # copy, not a view the host may change under it
             bt = bt.copy()
+        if self.window is not None:
+            # the chunk's first query sits at ``start``: what lies behind
+            # its window goes back, the chunk's own pages are taken
+            self._advance_window(req, start, start + n_tok)
+            bt = (bt.copy(),
+                  self._window_tables[req.slot:req.slot + 1].copy())
         # watchdog-wrapped dispatch (serving/overload.py): monotonic
         # budget; the program consumes the pool it is handed (donated),
         # so what comes back is bound at once, and only a failure from
@@ -854,6 +921,10 @@ class Engine:
             self._prefill_step, ids, self._target_pools(), bt,
             np.asarray([start], np.int32), np.int32(n_tok - 1))
         self._rebind_target(new_pools)
+        if isinstance(last, tuple):
+            # a routed model's chunk also says what its experts read
+            last, stats = last
+            self._route_stats.append(stats)
         if self.spec is not None:
             # the draft prefills the same chunk into its own layer slice
             # of the SAME blocks (already CoW-protected above), so the
@@ -944,6 +1015,19 @@ class Engine:
                 req.blocks.extend(new)
             if preempted:
                 continue
+            if self.window is not None:
+                # the next query sits at ``pos``: what lies behind its
+                # window goes back, the page it writes is taken.  (The
+                # group holds ``window_pages_per_seq`` a slot, so it
+                # cannot run dry; were it to, the youngest goes as above)
+                while req.slot is not None:
+                    try:
+                        self._advance_window(req, pos, pos + horizon)
+                        break
+                    except PoolExhausted:
+                        self._preempt(self.scheduler.pick_victim())
+                if req.slot is None:
+                    continue
             # a written block may be shared (prefix-cache hit on the
             # whole prompt, or a registered prompt tail): break the
             # share before decode writes into it.  Freshly allocated
@@ -972,6 +1056,14 @@ class Engine:
             if preempted:
                 continue
 
+    def _advance_window(self, req: Request, first_query: int, end: int):
+        """The window group's pages of ``req`` for queries from
+        ``first_query`` that write up to ``end``: released behind the
+        window, taken ahead (``BlockKVPool.advance_window``)."""
+        self.metrics.window_pages_released += self.pool.advance_window(
+            req.request_id, req.window_pages,
+            self._window_tables[req.slot], first_query, end)
+
     def _preempt(self, victim: Request):
         """Evict-and-requeue (recompute mode): free everything, head of
         the queue, original FCFS ordinal."""
@@ -981,12 +1073,17 @@ class Engine:
         victim.preemptions += 1
         self.metrics.on_preempt(victim.request_id, victim.num_generated)
         self._slots[slot] = None
-        self._block_tables[slot] = 0
+        self._clear_tables(slot)
         self._lengths[slot] = 0
         self._pending[slot] = 0
         self._clear_sampling_slot(slot)
         self._clear_block_slot(slot)
         self.scheduler.requeue_preempted(victim)
+
+    def _clear_tables(self, slot: int):
+        self._block_tables[slot] = 0
+        if self.window is not None:
+            self._window_tables[slot] = 0
 
     def _clear_sampling_slot(self, slot: int):
         self._temps[slot] = 0.0
@@ -998,15 +1095,21 @@ class Engine:
     def _decode_block_view(self):
         """Decode view of the block tables: slots still mid-prefill are
         masked to the garbage block so a bucket-wide step can never
-        write into (possibly shared) blocks of an unfinished prompt."""
-        bt = self._block_tables
-        if any(r is not None and r.state == PREFILLING
-               for r in self._slots):
-            bt = bt.copy()
-            for i, r in enumerate(self._slots):
-                if r is not None and r.state == PREFILLING:
-                    bt[i] = 0
-        return bt
+        write into (possibly shared) blocks of an unfinished prompt.  A
+        window model's view is the pair ``(full group's, window
+        group's)``."""
+        prefilling = [i for i, r in enumerate(self._slots)
+                      if r is not None and r.state == PREFILLING]
+
+        def view(bt):
+            if prefilling:
+                bt = bt.copy()
+                bt[prefilling] = 0
+            return bt
+
+        if self.window is None:
+            return view(self._block_tables)
+        return view(self._block_tables), view(self._window_tables)
 
     def _emit_token(self, req: Request, tok: int) -> bool:
         """Per-accepted-token hooks: reset the rolling inter-token
@@ -1046,6 +1149,13 @@ class Engine:
                 out, pools = self._decode_step(tokens, layers, tables,
                                                lengths)
             with phase("decode_fetch"):
+                if isinstance(out, tuple):
+                    # a routed model's step also says what its experts
+                    # read; so did the chunks since the last fetch
+                    out, stats = out
+                    chunk_stats = [np.asarray(s) for s in self._route_stats]
+                    return (np.asarray(out), chunk_stats,
+                            np.asarray(stats)), pools
                 return np.asarray(out), pools
 
         logits, new_pools = self.overload.decode_watchdog.call(
@@ -1053,6 +1163,13 @@ class Engine:
             self._target_pools(), bt, self._lengths)
         with phase("sample_emit"):
             self._rebind_target(new_pools)
+            if isinstance(logits, tuple):
+                logits, chunk_stats, stats = logits
+                self._route_stats = []
+                for chunk in chunk_stats:
+                    self.metrics.on_route_stats(*(int(v) for v in chunk))
+                self.metrics.on_route_stats(*(int(v) for v in stats),
+                                            decode=True)
             self._on_decode_iteration(active)
             for req in active:
                 slot = req.slot
@@ -1080,6 +1197,10 @@ class Engine:
     def _on_decode_iteration(self, active):
         # every slot that is not running has length 0
         self.metrics.decode_context_tokens += int(self._lengths.sum())
+        if self.window is not None:
+            self.metrics.on_window_iteration(
+                int(np.minimum(self._lengths, self.window).sum()),
+                sum(len(r.window_pages) for r in active), len(active))
         self.metrics.on_decode_iteration(
             len(active), self.config.max_batch_size,
             self.pool.utilization())
@@ -1370,7 +1491,7 @@ class Engine:
         req.slot = None
         if slot is not None:
             self._slots[slot] = None
-            self._block_tables[slot] = 0
+            self._clear_tables(slot)
             self._lengths[slot] = 0
             self._pending[slot] = 0
             self._clear_sampling_slot(slot)
@@ -1445,8 +1566,7 @@ class Engine:
             if self.mesh_executor is not None:
                 self.pool.layers = self.mesh_executor.shard_kv_layers(
                     self.pool.layers)
-            if self.block is not None:
-                self._route_stats = []
+            self._route_stats = []
         self.overload.health.revive()
 
     def pending_prefill_tokens(self) -> int:

@@ -138,6 +138,21 @@ class ServingMetrics:
         self.experts_read = 0
         self.expert_assignments = 0
         self.expert_assignments_max = 0
+        # ... and of them what the one-token decode runs read (a chunk
+        # reads nearly every expert, a decode run of a few rows far
+        # fewer: one mean over both says nothing)
+        self.experts_read_decode = 0
+        self.expert_assignments_decode = 0
+        # a model with window layers (serving/cache.py's window group;
+        # all stay 0 where every layer is full): pages given back while
+        # their request ran, and over the decode iterations the K/V
+        # tokens a window layer's walk covers (min(context, window) a
+        # running slot), the window-group pages live (a layer) and the
+        # running slots
+        self.window_pages_released = 0
+        self.decode_window_tokens = 0
+        self.window_pages_live = 0
+        self.window_seq_steps = 0
         # prefix cache / chunked prefill
         self.prefix_cache_hits = 0      # admissions reusing >= 1 block
         self.prefix_cache_misses = 0    # admissions reusing none
@@ -492,11 +507,22 @@ class ServingMetrics:
         self.block_context_tokens += context_tokens
 
     def on_route_stats(self, experts_read: int, assignments: int,
-                       assignments_max: int):
-        """What one step program's routed layers read, from the device."""
+                       assignments_max: int, decode: bool = False):
+        """What one step program's routed layers read, from the device
+        (``decode``: the program was a one-token decode run)."""
         self.experts_read += experts_read
         self.expert_assignments += assignments
         self.expert_assignments_max += assignments_max
+        if decode:
+            self.experts_read_decode += experts_read
+            self.expert_assignments_decode += assignments
+
+    def on_window_iteration(self, window_tokens: int, pages_live: int,
+                            running: int):
+        """One decode iteration of a model with window layers."""
+        self.decode_window_tokens += window_tokens
+        self.window_pages_live += pages_live
+        self.window_seq_steps += running
 
     # --------------------------------------------------------- export
     def as_dict(self) -> dict:
@@ -542,6 +568,12 @@ class ServingMetrics:
                 "experts_read": self.experts_read,
                 "expert_assignments": self.expert_assignments,
                 "expert_assignments_max": self.expert_assignments_max,
+                "experts_read_decode": self.experts_read_decode,
+                "expert_assignments_decode": self.expert_assignments_decode,
+                "window_pages_released": self.window_pages_released,
+                "decode_window_tokens": self.decode_window_tokens,
+                "window_pages_live": self.window_pages_live,
+                "window_seq_steps": self.window_seq_steps,
                 **{f"step_ns.{p}": ns for p, ns in self.step_ns.items()},
             },
             "gauges": {

@@ -95,6 +95,9 @@ class Request:
     state: str = QUEUED
     slot: Optional[int] = None
     blocks: List[int] = field(default_factory=list)
+    # a model with window layers: the window group's pages this request
+    # holds now, page index -> block (serving/cache.py advance_window)
+    window_pages: dict = field(default_factory=dict)
     generated: List[int] = field(default_factory=list)
     # "eos" | "stop" | "length" | "timeout" | "error"
     finish_reason: Optional[str] = None
@@ -201,6 +204,7 @@ class Scheduler:
         req.state = PREEMPTED
         req.slot = None
         req.blocks = []
+        req.window_pages = {}
         req.generated = []
         req.prefill_pos = 0
         req.cached_tokens = 0

@@ -406,6 +406,9 @@ class TestServingMirror:
         "block_steps", "block_slot_steps", "commit_slot_steps",
         "tokens_unmasked", "blocks_committed", "block_context_tokens",
         "experts_read", "expert_assignments", "expert_assignments_max",
+        "experts_read_decode", "expert_assignments_decode",
+        "window_pages_released", "decode_window_tokens",
+        "window_pages_live", "window_seq_steps",
     } | {f"step_ns.{phase}" for phase in (
         "admit", "prefill_dispatch", "first_token", "decode_prepare",
         "decode_dispatch", "decode_fetch", "sample_emit", "pool_sync")}

@@ -254,3 +254,84 @@ def test_context_partials_of_a_block_of_positions(compile_for_chip):
         ((32, SDAR_KVH, rows, D), bf16), SDAR_POOL, SDAR_POOL,
         ((32, 256), i32), ((32,), i32))
     assert "tpu_custom_call" in text and "fused_paged_decode" in text
+
+
+# ---- a window layer's kernels at the widths of the model with window and
+# full layers that the benchmark serves: 32 heads, 4 KV heads, head 128,
+# a window of 2,048 keys, 16 slots over a 1,024-page table (16 k tokens:
+# 16 x 1,024 table entries ride in as ONE scalar-prefetch operand), the
+# window group's 2,321 blocks and the full group's 16,384
+WINDOW, WINDOW_SLOTS, WINDOW_PAGES = 2048, 16, 1024
+WINDOW_POOLS = {"window": (2321, WINDOW), "full": (16384, None)}
+
+
+@pytest.mark.parametrize("group", sorted(WINDOW_POOLS))
+def test_fused_paged_decode_of_a_window_layer(compile_for_chip, group):
+    pa = _kernel("paged_attention")
+    blocks, window = WINDOW_POOLS[group]
+    pool = ((blocks, BLOCK, SDAR_KVH, D), bf16)
+    rope_table = ((WINDOW_PAGES * BLOCK, D // 2), f32)
+    text = compile_for_chip(
+        lambda q, k_new, v_new, kp, vp, table, pos, cos, sin:
+        pa.fused_paged_decode(
+            q, k_new, v_new, kp, vp, table, pos,
+            # a full layer of that model has no position encoding
+            cos if window else None, sin if window else None,
+            use_pallas=True, interpret=False, window=window),
+        ((WINDOW_SLOTS, 1, H, D), bf16), ((WINDOW_SLOTS, 1, SDAR_KVH, D), bf16),
+        ((WINDOW_SLOTS, 1, SDAR_KVH, D), bf16), pool, pool,
+        ((WINDOW_SLOTS, WINDOW_PAGES), i32), ((WINDOW_SLOTS,), i32),
+        rope_table, rope_table)
+    assert "tpu_custom_call" in text and "fused_paged_decode" in text
+
+
+@pytest.mark.parametrize("group", sorted(WINDOW_POOLS))
+def test_fused_chunked_attention_of_a_window_layer(compile_for_chip, group):
+    cp = _kernel("chunked_prefill")
+    blocks, window = WINDOW_POOLS[group]
+    pool = ((blocks, BLOCK, SDAR_KVH, D), bf16)
+    text = compile_for_chip(
+        lambda q, kp, vp, table, pos: cp.fused_chunked_attention(
+            q, kp, vp, table, pos, use_pallas=True, interpret=False,
+            window=window),
+        ((1, CHUNK, H, D), bf16), pool, pool, ((1, WINDOW_PAGES), i32),
+        ((1,), i32))
+    assert "tpu_custom_call" in text and "fused_chunked_prefill" in text
+
+
+# that model's other shapes: hidden 2048 into q and the gate (4096), the
+# dense MLP (6144) and the shared expert (1024) behind a folded norm; 128
+# experts of width 1024 over the 16 rows of a decode run and a chunk's 256
+@pytest.mark.parametrize("rows", [WINDOW_SLOTS, CHUNK])
+@pytest.mark.parametrize("width,act", [(4096, "none"), (6144, "silu"),
+                                       (1024, "silu")])
+def test_fused_norm_linear_at_hidden_2048(compile_for_chip, rows, width, act):
+    fnl = _kernel("fused_norm_linear")
+    text = compile_for_chip(
+        lambda x, nw, w: fnl.fused_rmsnorm_linear(
+            x, nw, w, 1e-5, activation=act, use_pallas=True,
+            interpret=False),
+        ((rows, SDAR_HIDDEN), bf16), ((SDAR_HIDDEN,), bf16),
+        ((SDAR_HIDDEN, width), bf16))
+    assert "tpu_custom_call" in text and "fused_norm_linear" in text
+
+
+@pytest.mark.parametrize("tokens", [WINDOW_SLOTS, CHUNK],
+                         ids=["decode", "chunk"])
+def test_grouped_experts_behind_a_sigmoid_router(compile_for_chip, tokens):
+    me = _kernel("moe_experts")
+
+    def experts(x, router, bias, wg, wu, wd):
+        chosen, gates = me.route_topk(x, router, TOP_K, scores="sigmoid",
+                                      bias=bias, scale=2.826,
+                                      norm_eps=1e-20)
+        out, stats = me.grouped_experts(x, chosen, gates, wg, wu, wd,
+                                        use_pallas=True, interpret=False)
+        return out, stats.as_vector()
+
+    w = ((EXPERTS, 1024, SDAR_HIDDEN), bf16)
+    text = compile_for_chip(experts, ((tokens, SDAR_HIDDEN), bf16),
+                            ((SDAR_HIDDEN, EXPERTS), bf16),
+                            ((EXPERTS,), f32), w, w, w)
+    assert "tpu_custom_call" in text and "moe_grouped_experts" in text
+    assert " while(" not in text
