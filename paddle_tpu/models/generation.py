@@ -208,9 +208,17 @@ class jit_with_weights:
     argument, so its output pool aliases its input and the step's
     scatter writes in place (undonated, the compiler copies the whole
     pool first, every step).  After a call the arrays handed in are
-    deleted: the caller binds the returned pool and keeps no other."""
+    deleted: the caller binds the returned pool and keeps no other.
 
-    def __init__(self, model, fn, donate=None):
+    ``chooses``: ``fn`` gives ``((logits, ids, *more), pools)``, the ids
+    being the tokens it chose from those logits.  The ONE program then
+    has two readers.  Calling the step hands out ``(logits, pools)``
+    (``((logits, *more), pools)``), as if no id were there; :meth:`ids`
+    hands out ``(ids, pools)`` (``((ids, *more), pools)``) and drops the
+    logits' handle unread, so they stay on the device.  Both run the
+    same jitted function: one executable, one entry in its cache."""
+
+    def __init__(self, model, fn, donate=None, chooses=False):
         # rebinding a tensor's ``_value`` (optimizer step, load, mesh
         # placement) is seen by the next call; a NEW parameter or buffer
         # is not, which ``holds`` tells ``cached_step``
@@ -231,6 +239,7 @@ class jit_with_weights:
         # HLO then read ``jit_paged_decode_step``, not ``jit_with_weights``
         # for every step of every model
         with_weights.__name__ = with_weights.__qualname__ = fn.__name__
+        self._chooses = chooses
         # the weights shift the pool by one inside ``with_weights``
         self._jitted = jax.jit(
             with_weights,
@@ -248,7 +257,15 @@ class jit_with_weights:
         return [t._value for t in self._tensors]
 
     def __call__(self, *args):
-        return self._jitted(self._weights(), *args)
+        out = self._jitted(self._weights(), *args)
+        return _without(out, 1) if self._chooses else out
+
+    def ids(self, *args):
+        """The same call into the same program, read for the ids it
+        chose in place of the logits it chose them from."""
+        if not self._chooses:
+            raise TypeError(f"{self.__name__} chooses no token")
+        return _without(self._jitted(self._weights(), *args), 0)
 
     def lower(self, *args):
         return self._jitted.lower(self._weights(), *args)
@@ -257,13 +274,29 @@ class jit_with_weights:
         return self._jitted._cache_size()
 
 
-def cached_step(model, key, fn, donate=None):
+def _without(out, i):
+    """``((logits, ids, *more), pools)`` less the first result's
+    ``i``-th part; one part left stands alone."""
+    first, pools = out
+    kept = first[:i] + first[i + 1:]
+    return (kept[0] if len(kept) == 1 else kept), pools
+
+
+def _chosen(logits):
+    """The greedy token of each row of float32 ``logits``, int32: the
+    lowest index among equal maxima, as ``np.argmax`` has it."""
+    return jnp.argmax(logits, axis=-1).astype(jnp.int32)
+
+
+def cached_step(model, key, fn, donate=None, chooses=False):
     """The one table of compiled steps, kept on the model: ``key`` is
     ``(kind, fused, kv_dtype, *extras)`` and ``fn`` the raw step, which
     is registered under its kind and compiled with
     :class:`jit_with_weights` the first time its key is asked for
     (``donate``: the name of ``fn``'s argument that is the paged pool
-    it consumes and returns; every step over a paged pool names it).  The
+    it consumes and returns; every step over a paged pool names it;
+    ``chooses``: the step also returns the ids it chose, read through
+    its ``ids`` accessor).  The
     same key then returns the same object, with its executables, to
     every engine and every ``generate()`` call: a fresh wrapper per call
     would retrace and recompile the whole transformer per request.
@@ -277,7 +310,7 @@ def cached_step(model, key, fn, donate=None):
     step = table.get(key)
     if step is None or not step.holds(model):
         step = table[key] = jit_with_weights(
-            model, register_decode_step(fn, kind=key[0]), donate)
+            model, register_decode_step(fn, kind=key[0]), donate, chooses)
     return step
 
 
@@ -402,6 +435,13 @@ def make_paged_decode_step(model, fused=None, kv_cache_dtype=None):
     returned pool is the same buffers written in place, and the arrays
     handed in are deleted by the call, so the caller binds the result.
 
+    The program also chooses each row's greedy token where its logits
+    are (``argmax`` over the same float32 logits, int32 ``[B]``, the
+    lowest index on ties): ``step.ids(...)``, the same call into the
+    same executable, gives ``(ids, new_pools)`` and leaves the logits on
+    the device.  The serving engine's greedy lane reads that; whoever
+    wants logits calls the step.
+
     ``fused`` pins the serving-fusion mode (kernels/fusion) for the
     whole traced program: True forces the fused paged-attention decode
     kernel + RMSNorm epilogues (XLA fallback off-TPU), False forces the
@@ -426,18 +466,19 @@ def make_paged_decode_step(model, fused=None, kv_cache_dtype=None):
     if hasattr(model, "decode_token"):
         return cached_step(model, ("grouped_paged_decode", fused, kv_dtype),
                            _grouped_decode_step(model, fused),
-                           donate="pools")
+                           donate="pools", chooses=True)
 
     def paged_decode_step(tok, pools, block_tables, lengths):
         with no_grad_ctx(), serving_fusion(fused):
             wrapped = _wrap_paged(pools, block_tables, kv_dtype)
             logits, new_caches = model(Tensor(tok), caches=wrapped,
                                        position_offset=lengths)
-            return (logits._value[:, -1].astype(jnp.float32),
+            last = logits._value[:, -1].astype(jnp.float32)
+            return ((last, _chosen(last)),
                     _unwrap_paged(new_caches, kv_dtype))
 
     return cached_step(model, ("paged_decode", fused, kv_dtype),
-                       paged_decode_step, donate="pools")
+                       paged_decode_step, donate="pools", chooses=True)
 
 
 def make_chunked_prefill_step(model, fused=None, kv_cache_dtype=None):
@@ -459,7 +500,9 @@ def make_chunked_prefill_step(model, fused=None, kv_cache_dtype=None):
     prompt yields the first generated token.  Both ``start`` and
     ``last_index`` are traced, so every chunk of every prompt hits the
     SAME executable (the serving engine asserts this via
-    ``warn_on_retrace``).
+    ``warn_on_retrace``).  As the decode step does, the program chooses
+    that row's greedy token too: ``step.ids(...)`` gives ``(ids [1]
+    int32, new_pools)``.
 
     ``fused`` (see make_paged_decode_step) pins the serving-fusion mode:
     fused prefill folds each RMSNorm into the following projections
@@ -486,7 +529,8 @@ def make_chunked_prefill_step(model, fused=None, kv_cache_dtype=None):
     if hasattr(model, "decode_token"):
         return cached_step(
             model, ("grouped_chunked_prefill", fused, kv_dtype),
-            _grouped_chunk_step(model, fused), donate="pools")
+            _grouped_chunk_step(model, fused), donate="pools",
+            chooses=True)
 
     def chunked_prefill_step(ids, pools, block_table, start, last_index):
         with no_grad_ctx(), serving_fusion(fused):
@@ -497,12 +541,13 @@ def make_chunked_prefill_step(model, fused=None, kv_cache_dtype=None):
                                        caches=wrapped,
                                        position_offset=start)
             last = jax.lax.dynamic_index_in_dim(
-                logits._value, last_index, axis=1, keepdims=False)
-            return (last.astype(jnp.float32),
+                logits._value, last_index, axis=1,
+                keepdims=False).astype(jnp.float32)
+            return ((last, _chosen(last)),
                     _unwrap_paged(new_caches, kv_dtype))
 
     return cached_step(model, ("chunked_prefill", fused, kv_dtype),
-                       chunked_prefill_step, donate="pools")
+                       chunked_prefill_step, donate="pools", chooses=True)
 
 
 def _grouped_decode_step(model, fused):
@@ -515,7 +560,8 @@ def _grouped_decode_step(model, fused):
     max_blocks], window group's [S, max_blocks])``, and the first result
     the pair ``(logits [S, V] f32, stats [3] int32)``: what the routed
     layers read (experts read, assignments, the busiest expert's
-    assignments, summed over layers)."""
+    assignments, summed over layers); through ``step.ids`` the pair
+    ``(ids [S] int32, stats)``."""
     from ..core.dispatch import no_grad_ctx
     from ..kernels.fusion import serving_fusion
 
@@ -523,7 +569,7 @@ def _grouped_decode_step(model, fused):
         with no_grad_ctx(), serving_fusion(fused):
             logits, stats, new_pools = model.decode_token(
                 tok, pools, block_tables, lengths)
-            return (logits, stats), new_pools
+            return (logits, _chosen(logits), stats), new_pools
 
     return paged_decode_step
 
@@ -532,7 +578,8 @@ def _grouped_chunk_step(model, fused):
     """The chunk program of such a model (``model.prefill_chunk``), as
     :func:`make_chunked_prefill_step`'s is called: ``block_table`` is
     the pair of ``[1, max_blocks]`` tables, the first result ``(logits
-    [1, V] f32 of the chunk's last real token, stats [3] int32)``."""
+    [1, V] f32 of the chunk's last real token, stats [3] int32)``
+    (``(ids [1] int32, stats)`` through ``step.ids``)."""
     from ..core.dispatch import no_grad_ctx
     from ..kernels.fusion import serving_fusion
 
@@ -541,7 +588,7 @@ def _grouped_chunk_step(model, fused):
             valid = (jnp.arange(ids.shape[1]) <= last_index)[None, :]
             last, stats, new_pools = model.prefill_chunk(
                 ids, valid, pools, block_table, start, last_index)
-            return (last, stats), new_pools
+            return (last, _chosen(last), stats), new_pools
 
     return chunked_prefill_step
 

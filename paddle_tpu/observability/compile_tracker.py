@@ -101,9 +101,19 @@ class TrackedFunction:
     _cache_size = cache_size
 
     def __call__(self, *args, **kwargs):
+        return self._call(self._fn, *args, **kwargs)
+
+    def sibling(self, name: str) -> Callable:
+        """The wrapped function's method ``name``, another way into the
+        SAME jit cache (a second reader of a step's one program), called
+        under this function's accounting: its calls and compiles count
+        here."""
+        return functools.partial(self._call, getattr(self._fn, name))
+
+    def _call(self, fn, *args, **kwargs):
         before = jit_cache_size(self._fn)
         t0 = time.perf_counter()
-        out = self._fn(*args, **kwargs)
+        out = fn(*args, **kwargs)
         self.calls += 1
         after = jit_cache_size(self._fn)
         if after > before:

@@ -34,6 +34,7 @@ import time
 from dataclasses import dataclass
 from typing import Any, Dict, List, Optional
 
+import jax
 import numpy as np
 
 from ..models.generation import (make_chunked_prefill_step,
@@ -366,6 +367,13 @@ class Engine:
                                       kv_cache_dtype=self.kv_cache_dtype),
             after=1, label="serving::prefill_step",
             on_retrace="raise" if cfg.strict_no_retrace else "count")
+        # the greedy lane's reader of the same two programs: the ids
+        # they chose where their logits are (a block model's programs
+        # choose inside the block step)
+        self._decode_ids = self._prefill_ids = None
+        if self.block is None:
+            self._decode_ids = self._decode_step.sibling("ids")
+            self._prefill_ids = self._prefill_step.sibling("ids")
         self._sampled_decode_step = None \
             if self.block is not None or self.window is not None \
             else warn_on_retrace(
@@ -563,6 +571,16 @@ class Engine:
     def _rebind_draft(self, new_pools):
         self.pool.layers = self.pool.layers[:self._n_target_layers] \
             + [tuple(entry) for entry in new_pools]
+
+    def _fetch(self, result):
+        """A step program's ``result`` (an array, or a list of them) on
+        the host, its bytes counted.  Waits for the device; a list's
+        copies are all started before one is waited for, so a copy's
+        latency is paid once."""
+        out = jax.device_get(result)
+        self.metrics.on_fetch(sum(
+            a.nbytes for a in (out if isinstance(out, list) else [out])))
+        return out
 
     # ----------------------------------------------------------- submit
     def submit(self, prompt, max_new_tokens: int = 32,
@@ -884,8 +902,10 @@ class Engine:
     def _dispatch_chunk(self, req: Request, start: int, n_tok: int):
         """Phase ``prefill_dispatch``: copy-on-write checks, the chunk's
         ids, and the call into the chunk program until it returns
-        handles.  Returns the (device) logits row of the chunk's last
-        real token."""
+        handles.  Returns, still on the device, what the chunk's last
+        real token gives: the id the program chose (a greedy request),
+        the logits row (a sampled one), a block model's routing
+        stats."""
         bs = self.config.block_size
         C = self.chunk_tokens
         self.metrics.on_prefill_dispatch(req.request_id, start, n_tok)
@@ -917,8 +937,10 @@ class Engine:
         # budget; the program consumes the pool it is handed (donated),
         # so what comes back is bound at once, and only a failure from
         # before the program took it is retried
+        greedy = self.block is None and req.sampling is None
         last, new_pools = self.overload.prefill_watchdog.call(
-            self._prefill_step, ids, self._target_pools(), bt,
+            self._prefill_ids if greedy else self._prefill_step,
+            ids, self._target_pools(), bt,
             np.asarray([start], np.int32), np.int32(n_tok - 1))
         self._rebind_target(new_pools)
         if isinstance(last, tuple):
@@ -939,21 +961,22 @@ class Engine:
 
     def _first_token(self, req: Request, last):
         """Phase ``first_token``: the prompt is complete, and the last
-        chunk's logits row IS the first token (token index 0 — sampled
+        chunk gives the first token: the id its program chose (greedy:
+        4 bytes to the host), or its logits row (token index 0 — sampled
         lanes fold the base key with 0, the same program generate()
         runs, so the streams agree from the very first token).  Reading
         ``last`` waits for the device."""
         params = req.sampling
         if params is not None:
             first_tok = int(np.asarray(sample_at(
-                np.asarray(last).astype(np.float32),
+                self._fetch(last).astype(np.float32),
                 np.asarray([params.temperature], np.float32),
                 np.asarray([params.top_k], np.int32),
                 np.asarray([params.top_p], np.float32),
                 req.sampling_key[None, :],
                 np.asarray([0], np.int32)))[0])
         else:
-            first_tok = int(np.argmax(np.asarray(last)[0]))
+            first_tok = int(self._fetch(last)[0])
         req.state = RUNNING
         req.generated = [first_tok]
         self.metrics.tokens_generated += 1
@@ -1138,33 +1161,34 @@ class Engine:
         if any(r.sampling is not None for r in active):
             self._sampled_iteration(active, bt)
             return
-        phase = self.metrics.phase
+        phase, fetch = self.metrics.phase, self._fetch
 
-        # the np.asarray device→host sync happens INSIDE the timed
+        # the device→host sync (the ids the program chose: 4 bytes a
+        # slot; the logits stay on the device) happens INSIDE the timed
         # closure so the watchdog budget covers device execution, not
         # just dispatch; a fault that surfaces in it finds the donated
         # pool consumed (the watchdog quarantines, ``revive`` rebuilds)
         def _timed_decode(tokens, layers, tables, lengths):
             with phase("decode_dispatch", slots=len(active)):
-                out, pools = self._decode_step(tokens, layers, tables,
-                                               lengths)
+                out, pools = self._decode_ids(tokens, layers, tables,
+                                              lengths)
             with phase("decode_fetch"):
                 if isinstance(out, tuple):
                     # a routed model's step also says what its experts
-                    # read; so did the chunks since the last fetch
-                    out, stats = out
-                    chunk_stats = [np.asarray(s) for s in self._route_stats]
-                    return (np.asarray(out), chunk_stats,
-                            np.asarray(stats)), pools
-                return np.asarray(out), pools
+                    # read; so did the chunks since the last fetch (one
+                    # batch of copies: a copy's latency is paid once)
+                    out, stats, *chunk_stats = fetch(
+                        [*out, *self._route_stats])
+                    return (out, chunk_stats, stats), pools
+                return fetch(out), pools
 
-        logits, new_pools = self.overload.decode_watchdog.call(
+        ids, new_pools = self.overload.decode_watchdog.call(
             _timed_decode, self._pending[:, None],
             self._target_pools(), bt, self._lengths)
         with phase("sample_emit"):
             self._rebind_target(new_pools)
-            if isinstance(logits, tuple):
-                logits, chunk_stats, stats = logits
+            if isinstance(ids, tuple):
+                ids, chunk_stats, stats = ids
                 self._route_stats = []
                 for chunk in chunk_stats:
                     self.metrics.on_route_stats(*(int(v) for v in chunk))
@@ -1175,7 +1199,7 @@ class Engine:
                 slot = req.slot
                 # the pending token was written at position lengths[slot]
                 self._lengths[slot] += 1
-                next_tok = int(np.argmax(logits[slot]))
+                next_tok = int(ids[slot])
                 self._append_token(req, next_tok)
                 self._pending[slot] = next_tok
                 self._counters[slot] = len(req.generated)
@@ -1215,7 +1239,7 @@ class Engine:
         categorical — runs whenever ANY active slot samples (greedy
         slots ride along on the temperature-0 argmax lane, so the
         bucket stays ONE compiled program with zero retraces)."""
-        phase = self.metrics.phase
+        phase, fetch = self.metrics.phase, self._fetch
 
         def _timed_decode(tokens, layers, tables, lengths, temps,
                           tks, tps, keys, counters):
@@ -1224,7 +1248,7 @@ class Engine:
                     tokens, layers, tables, lengths, temps, tks, tps,
                     keys, counters)
             with phase("decode_fetch"):
-                return np.asarray(out), pools
+                return fetch(out), pools
 
         toks, new_pools = self._sampled_wd.call(
             _timed_decode, self._pending[:, None],
@@ -1251,13 +1275,12 @@ class Engine:
         ([S, K+1] chunked-shaped program with on-device acceptance) →
         host commit of each slot's accepted tokens → block-granular KV
         rollback of the rejected tail.  Only the committed token ids
-        and accepted lengths sync to host — less per-iteration traffic
-        than the greedy step's [S, V] logits."""
+        and accepted lengths sync to host."""
         k_draft = self.spec.num_draft_tokens
         active, bt = self._decode_prepare(horizon=k_draft + 1)
         if not active:
             return
-        phase = self.metrics.phase
+        phase, fetch = self.metrics.phase, self._fetch
 
         # draft proposals + distributions stay ON DEVICE between the
         # two steps; the verify closure's np.asarray is the only host
@@ -1282,7 +1305,7 @@ class Engine:
                     pending, proposals, probs, layers, tables, lengths,
                     temps, tks, tps, keys, counters)
             with phase("decode_fetch"):
-                return np.asarray(committed), np.asarray(accepted), pools
+                return fetch(committed), fetch(accepted), pools
 
         committed, accepted, new_target = self._spec_verify_wd.call(
             _timed_verify, self._pending, props, dprobs,
@@ -1375,8 +1398,8 @@ class Engine:
             with phase("decode_dispatch", slots=len(active)):
                 small, _probe, pools = self._decode_step(*args)
             with phase("decode_fetch"):
-                stats = [np.asarray(s) for s in self._route_stats]
-                return np.asarray(small), stats, pools
+                stats = [self._fetch(s) for s in self._route_stats]
+                return self._fetch(small), stats, pools
 
         small, chunk_stats, new_pools = self.overload.decode_watchdog.call(
             _timed_block, self._blk_ids, self._blk_masked, self._lengths,

@@ -120,6 +120,7 @@ class ServingMetrics:
         self.prefill_chunks_run = 0     # raised per chunk dispatched
         self.prefill_context_tokens = 0  # sum of start + tokens per chunk
         self.decode_context_tokens = 0  # sum of active lengths per step
+        self.fetched_bytes = 0          # device results read on the host
         self.admissions = 0             # requests admitted a first time
         self.queue_wait_ns = 0          # first admission - submit
         self.lane_wait_ns = 0           # first chunk - first admission
@@ -202,6 +203,13 @@ class ServingMetrics:
         ``metadata`` as its stats (a request id goes there, never into
         the name), and its host time into ``step_ns[name]``."""
         return _Phase(self, name, metadata)
+
+    def on_fetch(self, nbytes: int):
+        """A step program's result of ``nbytes`` was read on the host:
+        ``fetched_bytes`` over ``decode_iterations`` is what a decode
+        step sends to the host, 4 bytes a slot where the program chose
+        the token."""
+        self.fetched_bytes += nbytes
 
     def on_prefill_dispatch(self, request_id: str, start: int, tokens: int):
         """One chunk of ``tokens`` prompt tokens at positions ``start ..``
@@ -554,6 +562,7 @@ class ServingMetrics:
                 "prefill_chunks_run": self.prefill_chunks_run,
                 "prefill_context_tokens": self.prefill_context_tokens,
                 "decode_context_tokens": self.decode_context_tokens,
+                "fetched_bytes": self.fetched_bytes,
                 "prompt_tokens": self._prompt_tokens_sum,
                 "cached_prompt_tokens": self._cached_tokens_sum,
                 "admissions": self.admissions,

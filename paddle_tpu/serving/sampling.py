@@ -198,8 +198,8 @@ def make_sampled_decode_step(model, fused=None, kv_cache_dtype=None):
 
     Identical forward pass to ``make_paged_decode_step``; the only
     addition is the on-device fold + filter + categorical on the last
-    logits, so only the chosen token ids sync back (a [S] int32 instead
-    of the greedy step's [S, V] logits).  All per-slot sampling state
+    logits, so only the chosen token ids sync back (a [S] int32, as
+    from the greedy step's ``ids`` reader).  All per-slot sampling state
     rides in fixed-shape device arrays — zero retraces, zero host
     round-trips in the token loop (H106).  Kept in the model's table of
     steps (``cached_step``), like every other step builder's."""

@@ -31,8 +31,7 @@ Two compiled steps, both in the decode-step registry:
     at position K.  Every key derives from the request's base key and
     TOKEN INDEX, so preemption + recompute replays identically.
 
-  Only ``(committed [S, K+1], accepted_len [S])`` sync to host — less
-  traffic than the greedy step's [S, V] logits sync.
+  Only ``(committed [S, K+1], accepted_len [S])`` sync to host.
 
 KV bookkeeping is the engine's job: the verify step writes target KV
 for all K+1 positions; the engine truncates each slot back to its

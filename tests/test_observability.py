@@ -409,6 +409,8 @@ class TestServingMirror:
         "experts_read_decode", "expert_assignments_decode",
         "window_pages_released", "decode_window_tokens",
         "window_pages_live", "window_seq_steps",
+        # what the fetches bring to the host (ISSUE 35)
+        "fetched_bytes",
     } | {f"step_ns.{phase}" for phase in (
         "admit", "prefill_dispatch", "first_token", "decode_prepare",
         "decode_dispatch", "decode_fetch", "sample_emit", "pool_sync")}

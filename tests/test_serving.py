@@ -629,3 +629,86 @@ class TestChunkedPrefill:
         np.testing.assert_array_equal(r_long.output_ids(), refs[1])
         assert r_long.prefill_chunks == 5    # ceil(40 / 8)
         eng.pool.check_leaks()
+
+
+# ---------------------------------------------------------------------------
+# the greedy lane: the token is chosen where its logits are (ISSUE 35)
+# ---------------------------------------------------------------------------
+
+# what the engine served for these prompts while it fetched the logits
+# and took ``np.argmax`` on the host (the commit before ISSUE 35, this
+# suite's conftest, tiny Llama of seed 0)
+_GREEDY_LENGTHS = (3, 21, 9, 34, 5, 17)
+_GREEDY_SERVED_BEFORE = (
+    (69, 243, 4, 85, 66, 217, 109, 56),
+    (174, 104, 87, 201, 64, 62, 135, 20),
+    (21, 230, 174, 195, 206, 17, 135, 76),
+    (194, 40, 45, 21, 230, 174, 44, 157),
+    (200, 217, 109, 209, 4, 85, 176, 44),
+    (39, 50, 17, 137, 243, 199, 231, 73),
+)
+
+
+class TestGreedyLane:
+    def _serve(self, model, **kw):
+        eng = Engine(model, _config(chunk_tokens=16, **kw))
+        prompts = _prompts(_GREEDY_LENGTHS, seed=35)
+        outs = eng.generate(prompts, max_new_tokens=8)
+        return eng, prompts, [o[len(p):] for p, o in zip(prompts, outs)]
+
+    def test_serves_the_tokens_it_served_before(self, model):
+        _, _, served = self._serve(model)
+        assert [tuple(int(t) for t in row) for row in served] == \
+            list(_GREEDY_SERVED_BEFORE)
+
+    @pytest.mark.parametrize("kv_cache_dtype", [None, "int8"])
+    def test_a_served_token_is_the_argmax_of_the_public_steps_logits(
+            self, model, kv_cache_dtype):
+        """The rule of before, replayed: every sequence teacher-forced
+        through the public step programs, whose logits a caller still
+        gets, and ``np.argmax`` on the host; wherever the best logit
+        leads by more than rounding the served token is that one."""
+        from logit_check import causal_engine_logits, decided
+
+        eng, prompts, served = self._serve(model,
+                                           kv_cache_dtype=kv_cache_dtype)
+        rows = judged = 0
+        for prompt, tokens in zip(prompts, served):
+            logits = causal_engine_logits(eng, prompt, tokens[:-1])
+            sure = decided(logits, 1e-5)
+            np.testing.assert_array_equal(
+                np.argmax(logits, axis=-1)[sure], tokens[sure])
+            rows, judged = rows + len(tokens), judged + int(sure.sum())
+        assert judged >= 0.9 * rows, (judged, rows)
+
+    def test_a_greedy_bucket_fetches_four_bytes_a_slot(self, model):
+        eng, prompts, _ = self._serve(model)
+        S, c = eng.config.max_batch_size, eng.stats()["counters"]
+        assert c["decode_iterations"] > 0
+        # a first token is the chunk's one id, a decode step the bucket's
+        assert c["fetched_bytes"] == \
+            4 * len(prompts) + c["decode_iterations"] * S * 4
+
+    def test_a_sampled_first_token_still_reads_its_logits_row(self, model):
+        from paddle_tpu.serving import SamplingParams
+
+        eng = Engine(model, _config(chunk_tokens=16))
+        prompt = _prompts((9,), seed=36)[0]
+        req = eng.submit(prompt, max_new_tokens=4,
+                         sampling=SamplingParams(temperature=0.8, seed=3))
+        eng.run_until_complete()
+        assert req.num_generated == 4
+        S, c = eng.config.max_batch_size, eng.stats()["counters"]
+        V = model.config.vocab_size
+        # the sampled decode program has always returned ids
+        assert c["fetched_bytes"] == \
+            4 * V + c["decode_iterations"] * S * 4
+        # ... through the one chunk program the greedy lane reads
+        greedy = eng.submit(prompt, max_new_tokens=1)
+        eng.run_until_complete()
+        assert greedy.num_generated == 1
+        assert eng.stats()["counters"]["fetched_bytes"] == \
+            c["fetched_bytes"] + 4
+        # (the module's model is shared: count what THIS engine compiled)
+        assert eng._prefill_step.compiles <= 1
+        assert eng._prefill_step.retraces == 0

@@ -307,3 +307,147 @@ def test_the_block_engine_runs_the_objects_the_factories_return():
         model, fused=fused, kv_cache_dtype=eng.config.kv_cache_dtype)
     assert eng._decode_step.__name__ == "paged_block_step"
     assert eng._prefill_step.__name__ == "chunked_prefill_step"
+
+
+# ------------------------------ the token is chosen where its logits are
+def _afmoe(seed=0):
+    from paddle_tpu.models import AfmoeConfig, AfmoeForCausalLM
+
+    paddle.seed(seed)
+    model = AfmoeForCausalLM(AfmoeConfig.tiny())
+    model.eval()
+    return model
+
+
+# a model a case: (its maker, the engine's options)
+CHOOSERS = {
+    "llama": (_llama, {}),
+    "llama_int8": (_llama, dict(kv_cache_dtype="int8")),
+    "afmoe": (_afmoe, dict(max_model_len=64)),   # its pair of tables
+}
+PROGRAMS = {"decode": "paged_decode_step", "chunk": "chunked_prefill_step"}
+
+
+class _Chooser:
+    """One sequence of five prompt tokens on blocks 1 and 2 of an idle
+    pool, driven by hand: its chunk, then one decode step."""
+
+    def __init__(self, case):
+        model_of, engine_kw = CHOOSERS[case]
+        self.model = model_of()
+        self.eng = eng = Engine(self.model, ServingConfig(
+            max_batch_size=2, block_size=8, num_blocks=16, chunk_tokens=16,
+            **engine_kw))
+        kw = dict(fused=eng.config.fused_kernels,
+                  kv_cache_dtype=eng.config.kv_cache_dtype)
+        self.steps = {"chunk": gen.make_chunked_prefill_step(self.model, **kw),
+                      "decode": gen.make_paged_decode_step(self.model, **kw)}
+        self.grouped = eng.window is not None
+
+    def _tables(self, rows):
+        _, table = _paged_inputs(self.eng)
+        table = table[:rows]
+        return (table, table.copy()) if self.grouped else table
+
+    def args(self, program):
+        """The program's arguments over the pool as it stands (the
+        chunk's, or a decode step's behind that chunk)."""
+        S = self.eng.config.max_batch_size
+        if program == "chunk":
+            ids = np.zeros((1, self.eng.chunk_tokens), np.int32)
+            ids[0, :5] = (3, 1, 4, 1, 5)
+            return (ids, self.eng.pool.layers, self._tables(1),
+                    np.zeros((1,), np.int32), np.int32(4))
+        lengths = np.zeros((S,), np.int32)
+        lengths[0] = 5
+        return (np.full((S, 1), 9, np.int32), self.eng.pool.layers,
+                self._tables(S), lengths)
+
+    def bind(self, pools):
+        self.eng.pool.layers = [tuple(entry) for entry in pools]
+
+    def both(self, program):
+        """``(logits, ids)`` of ONE call of the program (neither reader
+        gives both), the pool bound again."""
+        step = self.steps[program]
+        first, pools = step._jitted(step._weights(), *self.args(program))
+        self.bind(pools)
+        return np.asarray(first[0]), np.asarray(first[1])
+
+    def upto(self, program):
+        """The pool as ``program`` finds it in service."""
+        if program == "decode":
+            self.bind(self.steps["chunk"](*self.args("chunk"))[1])
+        return self
+
+
+CHOOSER_CASES = [(c, p) for c in sorted(CHOOSERS) for p in sorted(PROGRAMS)]
+
+
+@pytest.mark.parametrize("case,program", CHOOSER_CASES)
+def test_a_step_chooses_the_argmax_of_the_logits_it_returns(case, program):
+    """The ids are ``np.argmax`` of the logits of the same call, int32,
+    one a row; the two readers hand out one call's results, logits or
+    ids, in the same place; and both run ONE executable."""
+    hand = _Chooser(case).upto(program)
+    step = hand.steps[program]
+    logits, ids = hand.both(program)
+    rows = 1 if program == "chunk" else hand.eng.config.max_batch_size
+    assert logits.shape == (rows, hand.model.config.vocab_size)
+    assert logits.dtype == np.float32
+    assert ids.shape == (rows,) and ids.dtype == np.int32
+    np.testing.assert_array_equal(ids, np.argmax(logits, axis=-1))
+    # the same inputs write the same K/V again: the readers agree
+    read, pools = step(*hand.args(program))
+    hand.bind(pools)
+    chosen, pools = step.ids(*hand.args(program))
+    hand.bind(pools)
+    if hand.grouped:
+        (read, stats), (chosen, stats_again) = read, chosen
+        np.testing.assert_array_equal(np.asarray(stats),
+                                      np.asarray(stats_again))
+        assert np.asarray(stats).shape == (3,)
+    np.testing.assert_array_equal(np.asarray(read), logits)
+    np.testing.assert_array_equal(np.asarray(chosen), ids)
+    assert step._cache_size() == 1
+
+
+@pytest.mark.parametrize("case,program", CHOOSER_CASES)
+def test_of_two_equal_maxima_the_lower_index_wins(case, program):
+    """A head of zeros but for two identical columns: two logits of a
+    row are equal and, given the sign that makes them so, the largest;
+    the program takes the first, as ``np.argmax`` does."""
+    hand = _Chooser(case).upto(program)
+    head = hand.model.lm_head.weight
+    low, high = 11, 200
+    column = np.random.default_rng(0).standard_normal(head.shape[0])
+    for sign in (1.0, -1.0):
+        planted = np.zeros(head.shape, np.float32)
+        planted[:, low] = planted[:, high] = sign * column
+        head._value = jax.numpy.asarray(planted).astype(head._value.dtype)
+        logits, ids = hand.both(program)
+        if logits[0, low] > 0:
+            break
+    assert logits[0, low] == logits[0, high] == logits[0].max() > 0
+    assert ids[0] == low == np.argmax(logits[0])
+    assert hand.steps[program]._cache_size() == 1
+
+
+@pytest.mark.parametrize("case,program", CHOOSER_CASES)
+def test_a_choosing_step_is_one_program_under_its_name(case, program):
+    """The lowered text: one module, named after the step (the trace's
+    ``jit_paged_decode_step`` / ``jit_chunked_prefill_step``), every
+    pool leaf still an input that an output aliases."""
+    hand = _Chooser(case).upto(program)
+    args = hand.args(program)
+    leaves = sum(len(entry) for entry in hand.eng.pool.layers)
+    text = hand.steps[program].lower(*args).as_text()
+    assert text.count("module @") == 1
+    assert f"module @jit_{PROGRAMS[program]} " in text
+    assert text.count("tf.aliasing_output") == leaves
+
+
+def test_a_step_that_chooses_nothing_has_no_ids():
+    step = gen.make_paged_block_step(_sdar())
+    with pytest.raises(TypeError, match="chooses no token"):
+        step.ids()
