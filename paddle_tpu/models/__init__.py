@@ -10,3 +10,5 @@ from .generation import generate  # noqa: F401
 from .sdar_moe import (DroplessMoE, SDARMoEConfig,  # noqa: F401
                        SDARMoEForCausalLM)
 from .afmoe import AfmoeConfig, AfmoeForCausalLM  # noqa: F401
+from .glm4_moe_lite import (Glm4MoeLiteConfig,  # noqa: F401
+                            Glm4MoeLiteForCausalLM)

@@ -551,13 +551,18 @@ def make_chunked_prefill_step(model, fused=None, kv_cache_dtype=None):
 
 
 def _grouped_decode_step(model, fused):
-    """The decode program of a model whose cache lies in two GROUPS of
-    pages, a block table each (window layers beside full ones:
-    ``model.decode_token``, models/afmoe.py): the same name, lane and
-    call signature as :func:`make_paged_decode_step`'s, so the engine's
-    decode iteration, the trace and the metrics read it as they read any
-    decode step.  ``block_tables`` is the pair ``(full group's [S,
-    max_blocks], window group's [S, max_blocks])``, and the first result
+    """The decode program of a model that runs its own served passes
+    over the pool's entries (``model.decode_token``): one whose cache
+    lies in two GROUPS of pages, a block table each (window layers
+    beside full ones, models/afmoe.py), or whose entries are not ``(k,
+    v)`` at all (one latent array a position, models/glm4_moe_lite.py).
+    The same name, lane and call signature as
+    :func:`make_paged_decode_step`'s, so the engine's decode iteration,
+    the trace and the metrics read it as they read any decode step.
+    ``block_tables`` is what the engine lays out for the model and the
+    program hands on unread: the pair ``(full group's [S, max_blocks],
+    window group's [S, max_blocks])`` of a window model, the one ``[S,
+    max_blocks]`` table of a one-group model.  The first result is
     the pair ``(logits [S, V] f32, stats [3] int32)``: what the routed
     layers read (experts read, assignments, the busiest expert's
     assignments, summed over layers); through ``step.ids`` the pair
@@ -577,7 +582,8 @@ def _grouped_decode_step(model, fused):
 def _grouped_chunk_step(model, fused):
     """The chunk program of such a model (``model.prefill_chunk``), as
     :func:`make_chunked_prefill_step`'s is called: ``block_table`` is
-    the pair of ``[1, max_blocks]`` tables, the first result ``(logits
+    the pair of ``[1, max_blocks]`` tables or the one table, the first
+    result ``(logits
     [1, V] f32 of the chunk's last real token, stats [3] int32)``
     (``(ids [1] int32, stats)`` through ``step.ids``)."""
     from ..core.dispatch import no_grad_ctx

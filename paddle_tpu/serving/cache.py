@@ -6,7 +6,8 @@
 prefix reuse, layered on models/llama.py PagedKVCache semantics).
 
 The pool owns per-layer (k, v) device buffers of shape
-``[num_blocks, block_size, kv_heads, head_dim]``.  Sequences own
+``[num_blocks, block_size, kv_heads, head_dim]`` (a LATENT layer, one
+array a position with no heads: ``[num_blocks, block_size, lanes]``).  Sequences own
 BLOCKS, not contiguous buffer ranges: a free-list allocator hands out
 ``block_size``-token blocks one at a time as a sequence's frontier
 grows, so cache capacity is packed at block granularity instead of
@@ -95,17 +96,66 @@ class LayerCache:
     ``sidecars`` are the ``(shape, dtype)`` a position of what the layer
     keeps beside K and V (the experts its router chose, say); they are
     addressed through the FULL group's table whatever the layer's kind,
-    so they outlive a window layer's pages."""
+    so they outlive a window layer's pages.
+
+    ``value_dim`` makes the record a LATENT one (compressed, MLA): the
+    layer keeps ONE array a position and no heads, a key of ``head_dim``
+    numbers (``kv_heads`` is 1) whose first ``value_dim`` are the value
+    too, and no V beside it.  A latent layer is a full layer (its pages
+    are the full group's; prefix reuse, copy-on-write and eviction treat
+    them as any page).  Its pool entry is ``[num_blocks, block_size,
+    latent_lanes]``: the entry's lanes padded with zeros to whole
+    128-lane registers, the only layout the chip's kernels can slice
+    (``kernels/latent_attention.py``)."""
 
     kv_heads: int
     head_dim: int
     dtype: Any
     window: Optional[int] = None
     sidecars: Tuple = ()
+    value_dim: Optional[int] = None
+
+    def __post_init__(self):
+        if self.value_dim is not None and (
+                self.window is not None or self.kv_heads != 1
+                or not 0 < self.value_dim <= self.head_dim):
+            raise ValueError(
+                "a latent record is a full layer of one head-less key "
+                f"whose first value_dim lanes are the value, got {self}")
 
     @property
     def kind(self) -> str:
+        if self.value_dim is not None:
+            return "latent"
         return "full" if self.window is None else "window"
+
+    @property
+    def latent_lanes(self) -> int:
+        """Lanes a position of a latent layer takes in the pool."""
+        from ..kernels.latent_attention import latent_pool_lanes
+
+        return latent_pool_lanes(self.head_dim)
+
+    def block_bytes(self, block_size: int,
+                    kv_cache_dtype: Optional[str] = None) -> int:
+        """Bytes this layer adds to ONE block of the full group: its
+        entries (K and V with a quantized pool's scale rows; a latent
+        record's one array as padded; a window layer's none, its pages
+        being the window group's) and its sidecars' rows."""
+        side = sum(int(np.prod(shape, dtype=np.int64))
+                   * jnp.dtype(dt).itemsize for shape, dt in self.sidecars)
+        if self.value_dim is not None:
+            entries = block_size * self.latent_lanes \
+                * jnp.dtype(self.dtype).itemsize
+        elif self.window is not None:
+            entries = 0
+        else:
+            scheme = resolve_kv_cache_dtype(kv_cache_dtype)
+            entries = 2 * (
+                block_size * self.kv_heads * self.head_dim
+                * kv_bytes_per_element(scheme, self.dtype)
+                + kv_scale_bytes_per_block(block_size, scheme))
+        return int(entries + block_size * side)
 
 
 def describe_cache(model) -> List[LayerCache]:
@@ -271,6 +321,14 @@ class BlockKVPool(BlockAllocator):
         self.window: Optional[BlockAllocator] = None
         #: the most window pages one sequence ever holds
         self.window_pages_per_seq = window_pages_per_seq
+        latent = [c.value_dim is not None for c in self.layer_caches]
+        if any(latent) and (kv_cache_dtype is not None
+                            or self.window_size is not None
+                            or not all(latent)):
+            raise ValueError(
+                "a pool of latent records has no quantized entries yet, "
+                "and latent layers beside K/V or window layers in one "
+                "model are later work")
         if self.window_size is not None:
             if enable_prefix_cache or kv_cache_dtype is not None:
                 raise ValueError(
@@ -289,6 +347,10 @@ class BlockKVPool(BlockAllocator):
         self.model_dtype = dtype
         #: the STORAGE dtype the pool arrays actually carry
         self.dtype = kv_storage_dtype(self.kv_cache_dtype) or dtype
+        # (asked every engine step by the pressure ladder: summed once)
+        self._block_bytes = sum(
+            c.block_bytes(block_size, self.kv_cache_dtype)
+            for c in self.layer_caches)
         self.enable_prefix_cache = enable_prefix_cache
         # content-hash chains are seeded with the dtype tag, so an int8
         # pool can never match blocks registered under an fp32 config
@@ -334,8 +396,13 @@ class BlockKVPool(BlockAllocator):
                 else self.window.num_blocks
             rows = (blocks, self.block_size)
             store = kv_storage_dtype(self.kv_cache_dtype) or c.dtype
-            kv = tuple(jnp.zeros(rows + (c.kv_heads, c.head_dim), store)
-                       for _ in range(2))
+            if c.value_dim is not None:
+                # ONE array a position: the key, whose first lanes are
+                # the value
+                kv = (jnp.zeros(rows + (c.latent_lanes,), store),)
+            else:
+                kv = tuple(jnp.zeros(rows + (c.kv_heads, c.head_dim), store)
+                           for _ in range(2))
             if self.kv_cache_dtype is not None:
                 kv += tuple(jnp.ones(rows, jnp.float32) for _ in range(2))
             return kv + tuple(
@@ -398,19 +465,14 @@ class BlockKVPool(BlockAllocator):
         pools plus quantized scale sidecars) — computable before the
         pool exists, so the engine can size ``num_blocks`` from a fixed
         ``kv_pool_bytes`` budget per dtype."""
-        scheme = resolve_kv_cache_dtype(kv_cache_dtype)
-        esize = kv_bytes_per_element(scheme, dtype)
-        per_side = block_size * kv_heads * head_dim * esize \
-            + kv_scale_bytes_per_block(block_size, scheme)
-        return int(num_layers * 2 * per_side)
+        return num_layers * LayerCache(kv_heads, head_dim, dtype).block_bytes(
+            block_size, kv_cache_dtype)
 
     def block_bytes(self) -> int:
-        """HBM bytes one block of the full group costs in THIS pool (its
-        layers, k + v, including quantized scale rows)."""
-        full = sum(1 for c in self.layer_caches if c.window is None)
-        return self.block_bytes_for(full, self.block_size,
-                                    self.kv_heads, self.head_dim,
-                                    self.model_dtype, self.kv_cache_dtype)
+        """HBM bytes one block of the full group costs in THIS pool:
+        what its leaves take, every layer's record asked
+        (:meth:`LayerCache.block_bytes`)."""
+        return self._block_bytes
 
     def capacity_bytes(self) -> int:
         return self.capacity_blocks * self.block_bytes()
@@ -725,7 +787,8 @@ class BlockKVPool(BlockAllocator):
 @functools.partial(jax.jit, donate_argnums=(0,))
 def _copy_block_impl(layers, src, dst):
     # one executable per pool geometry: src/dst ride in as traced
-    # scalars.  Entries are (k, v) or (k, v, k_scale, v_scale) — a CoW
-    # copy of a quantized block must move the scale rows with the codes
+    # scalars.  Entries are (k, v), (k, v, k_scale, v_scale) or a latent
+    # layer's one array, then the sidecars — a CoW copy of a block must
+    # move the scale rows and the sidecars' rows with it
     return [tuple(a.at[dst].set(a[src]) for a in entry)
             for entry in layers]

@@ -181,8 +181,11 @@ class Engine:
     cache contract of models/llama.py (StaticKVCache + PagedKVCache),
     for a model that generates by DIFFUSION OVER BLOCKS (one that
     has ``block_diffusion`` generation settings: models/sdar_moe.py),
-    and for a model with WINDOW layers beside full ones (one whose
-    ``cache_layers()`` names a window: models/afmoe.py).  The pool is
+    for a model with WINDOW layers beside full ones (one whose
+    ``cache_layers()`` names a window: models/afmoe.py) and for a model
+    that keeps LATENT records (one array a position, no heads: one whose
+    ``cache_layers()`` names a ``value_dim``: models/glm4_moe_lite.py;
+    one group, one table, the prefix cache on).  The pool is
     built from the model's own description of its cache, a record a
     layer (serving/cache.py).  A window model's pages live in two
     groups with a block table each; the same loop serves it, and the
@@ -228,7 +231,20 @@ class Engine:
         self.window = next((c.window for c in layer_caches
                             if c.window is not None), None)
         if self.window is not None:
-            self._check_window_model()
+            self._refuse_unsupported(
+                "a model with window layers",
+                "its pages live in two groups with a table each, and a "
+                "draft's rollback, a quantized window page and the "
+                "analysers' one-table programs are later work")
+        #: the model keeps latent records (one array a position, no
+        #: heads: ``LayerCache.value_dim``)
+        self.latent = any(c.value_dim is not None for c in layer_caches)
+        if self.latent:
+            self._refuse_unsupported(
+                "a model that keeps latent (compressed) cache records",
+                "a draft's rollback over a latent pool, a quantized "
+                "latent page, a sharded latent pool and the analysers' "
+                "(k, v) programs are later work")
         model_max = getattr(model.config, "max_position_embeddings", None)
         self.max_model_len = min(
             cfg.max_model_len or model_max or 1 << 30,
@@ -270,10 +286,9 @@ class Engine:
         if cfg.kv_pool_bytes is not None:
             # (the budget is the full group's: a window group's size
             # follows from the window, below)
-            per_block = BlockKVPool.block_bytes_for(
-                sum(1 for c in layer_caches if c.window is None),
-                cfg.block_size, kv_heads, head_dim, dtype,
-                self.kv_cache_dtype)
+            per_block = sum(c.block_bytes(cfg.block_size,
+                                          self.kv_cache_dtype)
+                            for c in layer_caches)
             self.num_blocks = int(cfg.kv_pool_bytes) // per_block
             if self.num_blocks < 2:
                 raise ValueError(
@@ -376,7 +391,7 @@ class Engine:
             self._prefill_ids = self._prefill_step.sibling("ids")
         self._sampled_decode_step = None \
             if self.block is not None or self.window is not None \
-            else warn_on_retrace(
+            or self.latent else warn_on_retrace(
                 make_sampled_decode_step(model, fused=cfg.fused_kernels,
                                          kv_cache_dtype=self.kv_cache_dtype),
                 after=1, label="serving::sampled_decode_step",
@@ -442,9 +457,10 @@ class Engine:
                 f"block_length ({L}): K/V blocks, chunks and generated "
                 "blocks are aligned")
 
-    def _check_window_model(self):
-        """What a model with a window group is not served with yet is
-        refused here, with an error that says so."""
+    def _refuse_unsupported(self, model_words: str, why: str):
+        """What a model whose cache is not one table of (k, v) pages is
+        not served with yet is refused here, with an error that says
+        so."""
         cfg = self.config
         for what, given in (
                 ("speculative decoding", cfg.speculative),
@@ -454,11 +470,7 @@ class Engine:
                 ("the static shard plan", cfg.shardplan)):
             if given is not None:
                 raise ValueError(
-                    f"{what} is not supported for a model with window "
-                    "layers: its pages live in two groups with a table "
-                    "each, and a draft's rollback, a quantized window "
-                    "page and the analysers' one-table programs are "
-                    "later work")
+                    f"{what} is not supported for {model_words}: {why}")
 
     def _shardplan_startup(self):
         """Statically plan the decode and chunked-prefill programs on
@@ -648,6 +660,11 @@ class Engine:
             raise ValueError(
                 "sampling is not supported yet for a model with window "
                 "layers: its decode program is the greedy one only")
+        if params is not None and self.latent:
+            raise ValueError(
+                "sampling is not supported yet for a model that keeps "
+                "latent cache records: its decode program is the greedy "
+                "one only")
         prompt = np.asarray(
             prompt.numpy() if hasattr(prompt, "numpy") else prompt,
             np.int32).reshape(-1)

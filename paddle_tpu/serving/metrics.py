@@ -119,6 +119,7 @@ class ServingMetrics:
         self.prefill_steps = 0          # steps that ran >= 1 chunk
         self.prefill_chunks_run = 0     # raised per chunk dispatched
         self.prefill_context_tokens = 0  # sum of start + tokens per chunk
+        self.prefill_attended_pairs = 0  # (query, key) pairs chunks attend
         self.decode_context_tokens = 0  # sum of active lengths per step
         self.fetched_bytes = 0          # device results read on the host
         self.admissions = 0             # requests admitted a first time
@@ -217,6 +218,11 @@ class ServingMetrics:
         self.prefill_chunks_run += 1
         # the key positions the chunk kernel's walk covers
         self.prefill_context_tokens += start + tokens
+        # the keys each REAL query token of the chunk sees, summed: the
+        # whole context before the chunk, then itself and its
+        # predecessors inside it
+        self.prefill_attended_pairs += tokens * start \
+            + tokens * (tokens + 1) // 2
         t = self.requests[request_id]
         if t.first_chunk_ns == 0:
             t.first_chunk_ns = _now_ns()
@@ -561,6 +567,7 @@ class ServingMetrics:
                 "prefill_steps": self.prefill_steps,
                 "prefill_chunks_run": self.prefill_chunks_run,
                 "prefill_context_tokens": self.prefill_context_tokens,
+                "prefill_attended_pairs": self.prefill_attended_pairs,
                 "decode_context_tokens": self.decode_context_tokens,
                 "fetched_bytes": self.fetched_bytes,
                 "prompt_tokens": self._prompt_tokens_sum,

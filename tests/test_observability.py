@@ -402,6 +402,8 @@ class TestServingMirror:
         "admissions", "queue_wait_ns", "lane_wait_ns",
         # what the chunk kernel's walk covers (ISSUE 31)
         "prefill_context_tokens",
+        # the (query, key) pairs the chunks attend (ISSUE 36)
+        "prefill_attended_pairs",
         # the block iteration and the routed experts (ISSUE 30)
         "block_steps", "block_slot_steps", "commit_slot_steps",
         "tokens_unmasked", "blocks_committed", "block_context_tokens",

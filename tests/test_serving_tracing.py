@@ -75,6 +75,9 @@ def test_counters_of_a_workload_worked_out_by_hand(model):
     assert c["prefill_steps"] == 3 and c["prefill_chunks_run"] == 3
     # each chunk's start + tokens: the keys its kernel's walk covers
     assert c["prefill_context_tokens"] == (0 + 4) + (4 + 2) + (0 + 3)
+    # every real query token sees the context before its chunk, itself
+    # and its predecessors inside the chunk
+    assert c["prefill_attended_pairs"] == 10 + (2 * 4 + 3) + 6
     assert c["prompt_tokens"] == 9
     assert c["cached_prompt_tokens"] == 0
     assert c["decode_iterations"] == 3

@@ -14,6 +14,7 @@ only one process may load the TPU library, and every xdist worker imports
 this file.  All such tests stay in this one file for the same reason.
 """
 import importlib
+import math
 import re
 
 import jax
@@ -333,5 +334,100 @@ def test_grouped_experts_behind_a_sigmoid_router(compile_for_chip, tokens):
     text = compile_for_chip(experts, ((tokens, SDAR_HIDDEN), bf16),
                             ((SDAR_HIDDEN, EXPERTS), bf16),
                             ((EXPERTS,), f32), w, w, w)
+    assert "tpu_custom_call" in text and "moe_grouped_experts" in text
+    assert " while(" not in text
+
+
+# ---- the two latent-attention kernels at the widths of the model with
+# latent (MLA) pages that the benchmark serves: 20 heads, an entry of 576
+# numbers (512 of them the value) in 640 lanes, block 16, the pool's
+# 24,576 blocks, 8 slots over a 2,048-page table (32 k tokens: 8 x 2,048
+# table entries ride in as ONE scalar-prefetch operand), a chunk of 256
+LATENT_HEADS, LATENT_ENTRY, LATENT_VALUE = 20, 576, 512
+LATENT_SLOTS, LATENT_PAGES, LATENT_BLOCKS = 8, 2048, 24576
+
+
+def _latent_pool(la):
+    return ((LATENT_BLOCKS, BLOCK, la.latent_pool_lanes(LATENT_ENTRY)), bf16)
+
+
+def test_fused_latent_decode(compile_for_chip):
+    la = _kernel("latent_attention")
+    pool = _latent_pool(la)
+    lanes = pool[0][-1]
+    assert lanes == 640
+    text = compile_for_chip(
+        lambda q, entry, pages, table, pos: la.fused_latent_decode(
+            q, entry, pages, table, pos, value_lanes=LATENT_VALUE,
+            use_pallas=True, interpret=False),
+        ((LATENT_SLOTS, LATENT_HEADS, lanes), bf16),
+        ((LATENT_SLOTS, lanes), bf16), pool,
+        ((LATENT_SLOTS, LATENT_PAGES), i32), ((LATENT_SLOTS,), i32))
+    assert "tpu_custom_call" in text and "fused_latent_decode" in text
+
+
+def test_fused_latent_chunk(compile_for_chip):
+    la = _kernel("latent_attention")
+    pool = _latent_pool(la)
+    text = compile_for_chip(
+        lambda q, pages, table, pos: la.fused_latent_chunk(
+            q, pages, table, pos, value_lanes=LATENT_VALUE,
+            use_pallas=True, interpret=False),
+        ((1, CHUNK, LATENT_HEADS, pool[0][-1]), bf16), pool,
+        ((1, LATENT_PAGES), i32), ((1,), i32))
+    assert "tpu_custom_call" in text and "fused_latent_chunk" in text
+
+
+def test_a_page_of_576_lanes_is_refused_by_the_chip_s_compiler(
+        compile_for_chip):
+    """Why the pool pads a latent entry to 640 lanes: Mosaic slices a
+    page only at whole 128-lane tiles."""
+    la = _kernel("latent_attention")
+    with pytest.raises(Exception, match="aligned to tiling"):
+        compile_for_chip(
+            lambda q, pages, table, pos: la.fused_latent_chunk(
+                q, pages, table, pos, value_lanes=LATENT_VALUE,
+                use_pallas=True, interpret=False),
+            ((1, CHUNK, LATENT_HEADS, LATENT_ENTRY), bf16),
+            ((LATENT_BLOCKS, BLOCK, LATENT_ENTRY), bf16),
+            ((1, LATENT_PAGES), i32), ((1,), i32))
+
+
+# that model's other shapes: hidden 2048 into the low-rank query (768) and
+# from it into 20 heads of 256 (5120), the dense MLP (10240) and the
+# shared expert (1536) behind a folded norm; 64 experts of width 1536
+# over the 8 rows of a decode run and a chunk's 256
+@pytest.mark.parametrize("rows", [LATENT_SLOTS, CHUNK])
+@pytest.mark.parametrize("k,width,act", [
+    (SDAR_HIDDEN, 768, "none"), (768, 5120, "none"),
+    (SDAR_HIDDEN, 10240, "silu"), (SDAR_HIDDEN, 1536, "silu")])
+def test_fused_norm_linear_at_the_latent_model_s_widths(
+        compile_for_chip, rows, k, width, act):
+    # (the model asks for the deepest tile that divides the rank of 768)
+    fnl = _kernel("fused_norm_linear")
+    text = compile_for_chip(
+        lambda x, nw, w: fnl.fused_rmsnorm_linear(
+            x, nw, w, 1e-5, activation=act, use_pallas=True,
+            interpret=False, bk=math.gcd(k, 512)),
+        ((rows, k), bf16), ((k,), bf16), ((k, width), bf16))
+    assert "tpu_custom_call" in text and "fused_norm_linear" in text
+
+
+@pytest.mark.parametrize("tokens", [LATENT_SLOTS, CHUNK],
+                         ids=["decode", "chunk"])
+def test_grouped_experts_at_64_experts_of_width_1536(compile_for_chip,
+                                                     tokens):
+    me = _kernel("moe_experts")
+
+    def experts(x, router, bias, wg, wu, wd):
+        chosen, gates = me.route_topk(x, router, 4, scores="sigmoid",
+                                      bias=bias, scale=1.8, norm_eps=1e-20)
+        out, stats = me.grouped_experts(x, chosen, gates, wg, wu, wd,
+                                        use_pallas=True, interpret=False)
+        return out, stats.as_vector()
+
+    w = ((64, 1536, SDAR_HIDDEN), bf16)
+    text = compile_for_chip(experts, ((tokens, SDAR_HIDDEN), bf16),
+                            ((SDAR_HIDDEN, 64), bf16), ((64,), f32), w, w, w)
     assert "tpu_custom_call" in text and "moe_grouped_experts" in text
     assert " while(" not in text
