@@ -58,8 +58,7 @@ SEED = 0
 LOGIT_TOL = 2.0 ** -5
 # sharded-vs-one-device loss, relative: reductions reorder across devices
 LOSS_TOL = 1e-2
-SERVING_KERNELS = ("fused_paged_decode", "fused_chunked_prefill",
-                   "fused_norm_linear")
+SERVING_KERNELS = ("fused_paged_decode", "fused_chunked_prefill")
 TRAINING_KERNELS = ("flash_attention_fwd", "flash_attention_bwd_dq",
                     "flash_attention_bwd_dkv", "rms_norm", "fused_rope")
 

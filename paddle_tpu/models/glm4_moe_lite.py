@@ -309,10 +309,7 @@ class Glm4MoeLiteForCausalLM(nn.Layer):
             rs = rms_scale(x, eps)
             with jax.named_scope("attn_q_latent"):
                 c_q = fused_norm_linear(x, rs, nw, w_qa)
-                # (the rank is no multiple of the kernel's own 512-deep
-                # tile: the deepest that divides it)
-                q = fused_norm_linear(c_q, rms_scale(c_q, eps), qn, w_qb,
-                                      bk=math.gcd(c_q.shape[-1], 512))
+                q = fused_norm_linear(c_q, rms_scale(c_q, eps), qn, w_qb)
             with jax.named_scope("attn_kv_latent"):
                 kv = fused_norm_linear(x, rs, nw, w_kva)
         else:

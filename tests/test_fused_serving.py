@@ -398,15 +398,17 @@ def _norm_linear_oracle(x, nw, w, eps, act):
 
 class TestFusedNormLinear:
     @pytest.mark.parametrize("act", ["none", "silu"])
-    @pytest.mark.parametrize("use_pallas", [False, True])
-    def test_parity_vs_oracle(self, act, use_pallas):
+    @pytest.mark.parametrize("jitted", [False, True])
+    def test_parity_vs_oracle(self, act, jitted):
+        # one form on every backend (PR 37 took the Pallas kernel out):
+        # op by op and as XLA fuses it under jit
         rng = np.random.RandomState(0)
         x = jnp.asarray(rng.randn(8, 16).astype(np.float32))
         nw = jnp.asarray(rng.randn(16).astype(np.float32))
         w = jnp.asarray(rng.randn(16, 32).astype(np.float32))
         eps = 1e-5
-        got = fused_rmsnorm_linear(x, nw, w, eps, activation=act,
-                                   use_pallas=use_pallas, interpret=True)
+        fn = functools.partial(fused_rmsnorm_linear, eps=eps, activation=act)
+        got = (jax.jit(fn) if jitted else fn)(x, nw, w)
         want = _norm_linear_oracle(x, nw, w, eps, act)
         np.testing.assert_allclose(np.asarray(got), want,
                                    rtol=2e-5, atol=2e-5)
@@ -431,6 +433,86 @@ class TestFusedNormLinear:
         with pytest.raises(ValueError, match="activation"):
             fused_rmsnorm_linear(x, jnp.ones((8,)), jnp.zeros((8, 8)),
                                  1e-5, activation="tanhh")
+
+    @pytest.mark.parametrize("act", ["none", "silu"])
+    @pytest.mark.parametrize("K", [512, 768])
+    @pytest.mark.parametrize("rows", [8, 16, 32, 256])
+    def test_bf16_against_float64_oracle(self, rows, K, act):
+        # bf16 operands into the product, float32 accumulation: against
+        # the contract in float64 with the contract's own roundings,
+        # within one bf16 rounding of the output; compiled and op by op
+        rng = np.random.RandomState(rows + K)
+        N = 384
+        x = jnp.asarray(rng.randn(rows, K), jnp.bfloat16)
+        nw = jnp.asarray(1.0 + 0.1 * rng.randn(K), jnp.bfloat16)
+        w = jnp.asarray(rng.randn(K, N) * K ** -0.5, jnp.bfloat16)
+        rs = rms_scale(x, 1e-5)
+
+        def bf16(a):
+            return np.asarray(jnp.asarray(a, jnp.bfloat16), np.float64)
+
+        f64 = functools.partial(np.asarray, dtype=np.float64)
+        normed = bf16(bf16(f64(x) * f64(rs)) * f64(nw))
+        z = normed @ f64(w)
+        if act == "silu":
+            z = z / (1.0 + np.exp(-z))
+        fn = functools.partial(fused_norm_linear, activation=act)
+        for form in (fn, jax.jit(fn)):
+            got = form(x, rs, nw, w)
+            assert got.dtype == jnp.bfloat16 and got.shape == (rows, N)
+            # a rounding to bf16 is at most 2^-9 of the value; the
+            # float32 sum's own error is what the absolute term allows
+            np.testing.assert_allclose(np.asarray(got, np.float64), z,
+                                       rtol=2.0 ** -8, atol=1e-4)
+
+    @staticmethod
+    def _the_product(x, nw, w):
+        jaxpr = jax.make_jaxpr(fused_norm_linear)(
+            x, jax.ShapeDtypeStruct(x.shape[:-1] + (1,), jnp.float32), nw, w)
+        dots = [e for e in jaxpr.jaxpr.eqns
+                if e.primitive.name == "dot_general"]
+        assert len(dots) == 1
+        assert not any(e.primitive.name == "pallas_call"
+                       for e in jaxpr.jaxpr.eqns)
+        return ([v.aval.dtype for v in dots[0].invars],
+                dots[0].outvars[0].aval.dtype, jaxpr.out_avals[0])
+
+    @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+    def test_product_takes_its_operands_in_their_own_dtype(self, dtype):
+        # what the MXU is handed: bf16 x bf16 -> float32 in every cell,
+        # float32 operands for a float32 caller (tier-1, tiny configs)
+        spec = functools.partial(jax.ShapeDtypeStruct, dtype=dtype)
+        operands, result, _ = self._the_product(
+            spec((32, 256)), spec((256,)), spec((256, 128)))
+        assert operands == [dtype, dtype] and result == jnp.float32
+
+    def test_the_narrower_operand_is_widened_to_the_other(self):
+        operands, result, out = self._the_product(
+            jax.ShapeDtypeStruct((4, 256), jnp.float32),
+            jax.ShapeDtypeStruct((256,), jnp.float32),
+            jax.ShapeDtypeStruct((256, 128), jnp.bfloat16))
+        assert operands == [jnp.float32] * 2 and result == jnp.float32
+        assert out.dtype == jnp.float32
+
+    def test_every_served_shape_feeds_bf16_to_one_product(self):
+        # every (rows, K, N) a served configuration calls it with: a
+        # decode run's rows and a chunk's 256; traced only, nothing this
+        # size runs here
+        spec = functools.partial(jax.ShapeDtypeStruct, dtype=jnp.bfloat16)
+        for rows, K, widths in (
+                ((32, 256), 4096, (4096, 1024, 14336)),            # Mistral
+                ((128, 256), 2048, (4096, 512)),                   # SDAR
+                ((16, 256), 2048, (4096, 512, 6144, 1024)),        # Trinity
+                ((8, 256), 2048, (768, 576, 10240, 1536)),         # GLM
+                ((8, 256), 768, (5120,))):
+            for M in rows:
+                for N in widths:
+                    operands, result, out = self._the_product(
+                        spec((1, M, K)), spec((K,)), spec((K, N)))
+                    assert operands == [jnp.bfloat16] * 2
+                    assert result == jnp.float32
+                    assert out.shape == (1, M, N)
+                    assert out.dtype == jnp.bfloat16
 
 
 # ---------------------------------------------------------------------------
@@ -544,7 +626,7 @@ class TestKernelCostValidation:
 
     def test_serving_kernels_registered(self):
         assert "fused_paged_decode" in registered_kernels()
-        assert "fused_norm_linear" in registered_kernels()
+        assert "fused_chunked_prefill" in registered_kernels()
 
 
 # ---------------------------------------------------------------------------

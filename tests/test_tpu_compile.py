@@ -14,7 +14,6 @@ only one process may load the TPU library, and every xdist worker imports
 this file.  All such tests stay in this one file for the same reason.
 """
 import importlib
-import math
 import re
 
 import jax
@@ -126,16 +125,30 @@ def test_fused_rope_forward_and_backward(compile_for_chip, heads):
     assert used and max(used) <= 8 << 20
 
 
-@pytest.mark.parametrize("rows", [BATCH, CHUNK])
-def test_fused_norm_linear(compile_for_chip, rows):
+def _one_bf16_product(text, rows, k, width):
+    """The folded norm's projection as XLA lowers it since PR 37 took the
+    Pallas kernel out (PERF.md section 6): no kernel call, one matmul
+    accumulated in float32, and the weight never widened on its way in."""
+    assert "tpu_custom_call" not in text
+    assert f"f32[{rows},{width}]" in text and "convolution(" in text
+    assert f"f32[{k},{width}]" not in text
+
+
+# the MLP's gate behind a folded norm, then that model's projections
+# into q (4096) and into k and v (1024), at a decode bucket's 32 rows
+# (8 in the first case) and a chunk's 256
+@pytest.mark.parametrize("rows,width,act", [
+    (BATCH, MLP, "silu"), (CHUNK, MLP, "silu"),
+    (32, HIDDEN, "none"), (CHUNK, HIDDEN, "none"),
+    (32, KVH * D, "none"), (CHUNK, KVH * D, "none")])
+def test_fused_norm_linear(compile_for_chip, rows, width, act):
     fnl = _kernel("fused_norm_linear")
     text = compile_for_chip(
         lambda x, rs, nw, w: fnl.fused_norm_linear(
-            x, rs, nw, w, activation="silu", use_pallas=True,
-            interpret=False),
+            x, rs, nw, w, activation=act),
         ((rows, HIDDEN), bf16), ((rows, 1), f32), ((HIDDEN,), bf16),
-        ((HIDDEN, MLP), bf16))
-    assert "tpu_custom_call" in text and "fused_norm_linear" in text
+        ((HIDDEN, width), bf16))
+    _one_bf16_product(text, rows, HIDDEN, width)
 
 
 _POOLS = [(None, bf16), ("int8", i8), ("fp8", i8)]
@@ -310,11 +323,10 @@ def test_fused_norm_linear_at_hidden_2048(compile_for_chip, rows, width, act):
     fnl = _kernel("fused_norm_linear")
     text = compile_for_chip(
         lambda x, nw, w: fnl.fused_rmsnorm_linear(
-            x, nw, w, 1e-5, activation=act, use_pallas=True,
-            interpret=False),
+            x, nw, w, 1e-5, activation=act),
         ((rows, SDAR_HIDDEN), bf16), ((SDAR_HIDDEN,), bf16),
         ((SDAR_HIDDEN, width), bf16))
-    assert "tpu_custom_call" in text and "fused_norm_linear" in text
+    _one_bf16_product(text, rows, SDAR_HIDDEN, width)
 
 
 @pytest.mark.parametrize("tokens", [WINDOW_SLOTS, CHUNK],
@@ -394,23 +406,22 @@ def test_a_page_of_576_lanes_is_refused_by_the_chip_s_compiler(
 
 
 # that model's other shapes: hidden 2048 into the low-rank query (768) and
-# from it into 20 heads of 256 (5120), the dense MLP (10240) and the
-# shared expert (1536) behind a folded norm; 64 experts of width 1536
-# over the 8 rows of a decode run and a chunk's 256
+# the latent entry (576), the rank into 20 heads of 256 (5120), the dense
+# MLP (10240) and the shared expert (1536) behind a folded norm; 64 experts
+# of width 1536 over the 8 rows of a decode run and a chunk's 256
 @pytest.mark.parametrize("rows", [LATENT_SLOTS, CHUNK])
 @pytest.mark.parametrize("k,width,act", [
     (SDAR_HIDDEN, 768, "none"), (768, 5120, "none"),
+    (SDAR_HIDDEN, LATENT_ENTRY, "none"),
     (SDAR_HIDDEN, 10240, "silu"), (SDAR_HIDDEN, 1536, "silu")])
 def test_fused_norm_linear_at_the_latent_model_s_widths(
         compile_for_chip, rows, k, width, act):
-    # (the model asks for the deepest tile that divides the rank of 768)
     fnl = _kernel("fused_norm_linear")
     text = compile_for_chip(
         lambda x, nw, w: fnl.fused_rmsnorm_linear(
-            x, nw, w, 1e-5, activation=act, use_pallas=True,
-            interpret=False, bk=math.gcd(k, 512)),
+            x, nw, w, 1e-5, activation=act),
         ((rows, k), bf16), ((k,), bf16), ((k, width), bf16))
-    assert "tpu_custom_call" in text and "fused_norm_linear" in text
+    _one_bf16_product(text, rows, k, width)
 
 
 @pytest.mark.parametrize("tokens", [LATENT_SLOTS, CHUNK],
