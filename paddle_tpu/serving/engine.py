@@ -425,6 +425,14 @@ class Engine:
                 "draft_propose_step")
             self._spec_verify_wd = self.overload.extra_watchdog(
                 "spec_verify_step")
+        # the step's account reads their calls and compiles
+        self.metrics.programs = tuple(
+            step for step in (
+                self._decode_step, self._prefill_step,
+                self._sampled_decode_step,
+                *((self._draft_prefill_step, self._draft_propose_step,
+                   self._spec_verify_step) if spec is not None else ()))
+            if step is not None)
         if self.block is not None:
             self._unmask_schedule = unmask_schedule(
                 self.block.block_length, self.block.denoising_steps)
@@ -737,30 +745,31 @@ class Engine:
             raise EngineQuarantined(
                 f"engine quarantined FAILED "
                 f"({self.overload.health.last_error}); revive() first")
-        # The phases below (``metrics.phase``: serving::admit,
-        # prefill_dispatch, first_token, decode_prepare, decode_dispatch,
-        # decode_fetch, sample_emit, pool_sync) are flat, never nested,
-        # and together cover the step, so that in a profiler trace every
-        # idle gap of the device has one owner.
+        # One span, ``serving::step``, and one account a step
+        # (``metrics.step``); inside it the phases (``metrics.phase``:
+        # serving::admit, prefill_dispatch, first_token, decode_prepare,
+        # decode_dispatch, decode_fetch, sample_emit, pool_sync), flat
+        # among themselves, so that in a profiler trace every idle gap
+        # of the device has one owner: a phase, or between two of them
+        # the step.
         metrics = self.metrics
-        with metrics.phase("admit"):
-            # one hysteresis step of the memory-pressure ladder BEFORE
-            # admission, so pause_admissions takes effect this iteration
-            self.overload.ladder.tick(self)
-            self._admit()
-        chunks_before = metrics.prefill_chunks_run
-        self._prefill_tick()
-        if any(r is not None and r.state == RUNNING for r in self._slots):
-            if self.block is not None:
-                self._block_iteration()
-            else:
-                self._decode_iteration()
-        with metrics.phase("pool_sync"):
-            self._sync_pool_metrics()
-        metrics.engine_steps += 1
-        if metrics.prefill_chunks_run > chunks_before:
-            metrics.prefill_steps += 1
-        return self.has_work()
+        with metrics.step():
+            with metrics.phase("admit"):
+                # one hysteresis step of the memory-pressure ladder
+                # BEFORE admission, so pause_admissions takes effect this
+                # iteration
+                self.overload.ladder.tick(self)
+                self._admit()
+            self._prefill_tick()
+            if any(r is not None and r.state == RUNNING
+                   for r in self._slots):
+                if self.block is not None:
+                    self._block_iteration()
+                else:
+                    self._decode_iteration()
+            with metrics.phase("pool_sync"):
+                self._sync_pool_metrics()
+            return self.has_work()
 
     def has_work(self) -> bool:
         return bool(self.scheduler.waiting) or \
@@ -1440,7 +1449,6 @@ class Engine:
                     # the block's K/V are in the pool: the next begins
                     self._lengths[slot] += L
                     self._block_open(slot)
-                    metrics.blocks_committed += 1
                     continue
                 metrics.tokens_unmasked += int(
                     self._blk_masked[slot].sum() - new_masked[slot].sum())

@@ -6,13 +6,19 @@ preemptions), exportable three ways:
 
 - ``as_dict()`` — everything, JSON-ready (the metrics schema in
   README "Serving");
-- ``phase(name)`` — the spans inside ``Engine.step()``
-  (``serving::admit`` … ``serving::pool_sync``, flat and never nested):
-  each is a ``profiler.RecordEvent``, so it lands in any
-  ``jax.profiler`` trace on the device trace's clock (and in an active
-  ``paddle_tpu.profiler`` session's chrome export), and its host time
-  accumulates into the ``step_ns.<phase>`` counters whether or not
-  anything records;
+- ``step()`` and ``phase(name)`` — the span ``serving::step`` around
+  one ``Engine.step()`` and the eight inside it (``serving::admit`` …
+  ``serving::pool_sync``, flat among themselves): each is a
+  ``profiler.RecordEvent``, so it lands in any ``jax.profiler`` trace
+  on the device trace's clock (and in an active ``paddle_tpu.profiler``
+  session's chrome export), and its host time accumulates into the
+  ``step_ns.<phase>`` / ``step_wall_ns`` counters whether or not
+  anything records.  Every few steps, and after a slow one, the
+  calling thread's CPU time is read too (``step_cpu_ns``), so that a
+  step that stood still is told from one that ran; a step far over the
+  usual one (``SLOW_STEP_FACTOR``)
+  leaves one record of what the host was doing in it
+  (``as_dict()["slow_steps"]``, the last ``SLOW_STEP_LOG`` of them);
 - the shared ``paddle_tpu.observability`` registry — every lifecycle
   event is mirrored (``serving_*`` counters/gauges, TTFT/TPOT/queue/e2e
   latency histograms) whenever telemetry is enabled, so serving shows
@@ -23,20 +29,73 @@ unchanged by the registry mirror.
 """
 from __future__ import annotations
 
+import functools
+import gc
 import time
+from collections import deque
 from dataclasses import dataclass
+from types import SimpleNamespace
 from typing import Dict, Optional
 
 from ..observability import registry as _obsreg
 from ..profiler import RecordEvent
 
 _now_ns = time.perf_counter_ns
+_cpu_ns = time.thread_time_ns     # of the thread that calls Engine.step()
+
+# the calling thread's context switches (``ru_nvcsw`` voluntary,
+# ``ru_nivcsw`` involuntary) and page faults (``ru_minflt``,
+# ``ru_majflt``) so far; zeros where the platform keeps none by thread
+# (no ``RUSAGE_THREAD``; or, as the sandboxed kernel of the benchmark's
+# hosts, every count 0 in a thread that has imported all of this, at the
+# price of a system call of 6 us each time it is asked)
+_NO_USAGE = SimpleNamespace(ru_nvcsw=0, ru_nivcsw=0, ru_minflt=0,
+                            ru_majflt=0)
+try:
+    import resource
+    _thread_usage = functools.partial(resource.getrusage,
+                                      resource.RUSAGE_THREAD)
+    if not _thread_usage().ru_minflt:
+        raise OSError("RUSAGE_THREAD counts nothing")
+except (ImportError, AttributeError, OSError):
+    def _thread_usage():
+        return _NO_USAGE
 
 # the phases of one ``Engine.step()``, in the order they run; a span is
 # named ``serving::<phase>``
 PHASES = ("admit", "prefill_dispatch", "first_token", "decode_prepare",
           "decode_dispatch", "decode_fetch", "sample_emit", "pool_sync")
 _SPAN_NAMES = {phase: "serving::" + phase for phase in PHASES}
+
+# A step is slow when its wall time is over this many times the usual
+# step's (``ServingMetrics._usual_ns``: a mean in which a step weighs
+# 1/64 at most).  The largest ordinary iteration of a cell is 2.3 times
+# its usual one and the smallest stall on record 5.4 times (PERF.md
+# sections 3 and 6, PR 38).
+SLOW_STEP_FACTOR = 3
+_USUAL_STEPS = 64       # steps the usual step is the mean of
+SLOW_STEP_LOG = 64      # records of slow steps kept
+# The calling thread's CPU clock and its usage are system calls (6 us in
+# a tight loop and several times that between other work on the
+# sandboxed kernel of the benchmark's hosts, where two of them at each
+# end of every step were 0.1 ms a step): they are read at the end of a
+# slow step and of every so-many-th other step, and a reading is
+# compared with the one before it.
+THREAD_READ_EVERY = 8
+FINISHED_REQUESTS = 1024    # timelines of retired requests kept
+
+
+_collections = [0]      # passes of Python's collector in this process
+
+
+def _on_collection(phase, info):
+    if phase == "stop":
+        _collections[0] += 1
+
+
+# counted as they happen: ``gc.get_stats()`` at both ends of every step
+# would build six dicts a step to learn the same
+gc.callbacks.append(_on_collection)
 
 
 class _Phase:
@@ -58,6 +117,80 @@ class _Phase:
         spent = _now_ns() - self._t0
         self._event.end()
         self._metrics.step_ns[self._phase] += spent
+        return False
+
+
+class _Step:
+    """The open span and account of one ``Engine.step()``
+    (``ServingMetrics.step``)."""
+
+    __slots__ = ("_metrics", "_event", "_t0", "_collections", "_chunks",
+                 "_phase_ns")
+
+    def __init__(self, metrics):
+        self._metrics = metrics
+        self._event = RecordEvent("serving::step",
+                                  step=metrics.engine_steps)
+
+    def __enter__(self):
+        m = self._metrics
+        self._t0 = _now_ns()
+        self._event.begin()
+        self._phase_ns = tuple(m.step_ns.values())
+        self._chunks = m.prefill_chunks_run
+        self._collections = _collections[0]
+        m._step_slots = m._step_behind = 0
+        return self
+
+    def __exit__(self, exc_type, exc, tb):
+        m = self._metrics
+        self._event.end()
+        end = _now_ns()
+        wall = end - self._t0
+        chunks = m.prefill_chunks_run - self._chunks
+        slow = compiled = 0
+        # a step that dispatched nothing had no one waiting for it: it
+        # is neither slow nor part of the usual step
+        if chunks or m._step_slots:
+            usual = m._usual_ns
+            # (the step programs run inside steps only)
+            compiled = m.programs_compiled() - m._compiled_seen
+            m._compiled_seen += compiled
+            slow = m._usual_seen >= _USUAL_STEPS \
+                and wall > SLOW_STEP_FACTOR * usual
+            if not compiled:
+                # a slow step enters the mean as SLOW_STEP_FACTOR usual
+                # ones at most: a stall of seconds moves it by 3 %, and
+                # a load that has changed for good is followed within a
+                # dozen steps (left out of it, the mean would stay where
+                # it was and every step after the change read as slow)
+                m._usual_seen = seen = min(m._usual_seen + 1, _USUAL_STEPS)
+                m._usual_ns = usual + (
+                    (min(wall, SLOW_STEP_FACTOR * usual) if seen > 1
+                     else wall) - usual) / seen
+        m._steps_unread += 1
+        if slow or m._steps_unread >= THREAD_READ_EVERY:
+            thread = m._read_thread(end)
+            if slow:
+                m.slow_steps += 1
+                m.slow_step_excess_ns += wall - int(usual)
+                phase_ns = [b - a for a, b in zip(self._phase_ns,
+                                                  m.step_ns.values())]
+                m.slow_step_log.append({
+                    "step": m.engine_steps, "start_ns": self._t0,
+                    "wall_ns": wall, "usual_ns": int(usual),
+                    "phase_ns": dict(zip(PHASES, phase_ns)),
+                    "unowned_ns": wall - sum(phase_ns), "chunks": chunks,
+                    "slots": m._step_slots,
+                    "programs_behind": m._step_behind, **thread,
+                    "gc_collections": _collections[0] - self._collections,
+                    "programs_compiled": compiled})
+        if exc_type is None:
+            m.engine_steps += 1
+            if chunks:
+                m.prefill_steps += 1
+        # (read again: the account's own time is the step's too)
+        m.step_wall_ns += _now_ns() - self._t0
         return False
 
 
@@ -116,6 +249,36 @@ class ServingMetrics:
         # and what the steps did
         self.step_ns = dict.fromkeys(PHASES, 0)
         self.engine_steps = 0
+        # the step's account (``step()``): wall time, summed over steps;
+        # the calling thread's CPU time, involuntary context switches and
+        # minor page faults while it steps, summed over the readings of
+        # ``_read_thread`` (``THREAD_READ_EVERY``: between two of them
+        # lies the caller's turn between the steps too)
+        self.step_wall_ns = 0
+        self.step_cpu_ns = 0
+        self._thread_read = None    # (wall clock, CPU clock, usage) then
+        self._steps_unread = 0      # steps ended since
+        self.step_nivcsw = 0
+        self.step_minflt = 0
+        # the engine's step programs (``TrackedFunction``s, which count
+        # their calls and compiles), set by the engine that owns them
+        self.programs = ()
+        # host reads that wait for a program's result, and over them the
+        # programs dispatched since the read before returned: the device
+        # runs its programs in order, so that many the read may wait for
+        self.blocking_reads = 0
+        self.programs_behind_reads = 0
+        self._dispatched_at_read = 0
+        # slow steps (``SLOW_STEP_FACTOR``), their time beyond a usual
+        # step's, and the last of their records
+        self.slow_steps = 0
+        self.slow_step_excess_ns = 0
+        self.slow_step_log = deque(maxlen=SLOW_STEP_LOG)
+        self._usual_ns = 0.0        # the usual step: mean of the last 64
+        self._usual_seen = 0        # steps in it so far, 64 at most
+        self._compiled_seen = 0     # programs_compiled() at the last step
+        self._step_slots = 0        # decoding slots of the open step
+        self._step_behind = 0       # largest programs_behind of its reads
         self.prefill_steps = 0          # steps that ran >= 1 chunk
         self.prefill_chunks_run = 0     # raised per chunk dispatched
         self.prefill_context_tokens = 0  # sum of start + tokens per chunk
@@ -131,7 +294,6 @@ class ServingMetrics:
         self.block_slot_steps = 0       # live slots over those runs
         self.commit_slot_steps = 0      # of them, slots committing a block
         self.tokens_unmasked = 0        # positions a denoise step finalised
-        self.blocks_committed = 0       # blocks whose K/V were written
         self.block_context_tokens = 0   # sum of cached lengths per run
         # routed experts, counted on the device by every step program
         # that runs them (block steps and prefill chunks), summed over
@@ -188,8 +350,10 @@ class ServingMetrics:
         self._gauge_samples = 0
         self.last_batch_occupancy = 0.0
         self.last_cache_utilization = 0.0
-        # per-request
+        # per-request: live while the request is, then among the last
+        # ``FINISHED_REQUESTS`` retired ones
         self.requests: Dict[str, RequestTimeline] = {}
+        self.finished = deque(maxlen=FINISHED_REQUESTS)  # (id, to_dict())
 
     # handles are looked up per event (not cached) so a test calling
     # ``registry.clear()`` never leaves a mirror pointing at dead metrics
@@ -205,12 +369,58 @@ class ServingMetrics:
         the name), and its host time into ``step_ns[name]``."""
         return _Phase(self, name, metadata)
 
+    def step(self) -> _Step:
+        """Context manager around one ``Engine.step()``, for the engine
+        alone: the span ``serving::step`` (stat ``step``: the value of
+        ``engine_steps`` as it began), the step's account into the
+        ``step_*`` counters, ``engine_steps`` and ``prefill_steps``, and
+        a record in ``slow_step_log`` where the step was slow."""
+        return _Step(self)
+
+    def _read_thread(self, now: int) -> dict:
+        """Read the calling thread's CPU clock and usage at wall time
+        ``now``, the end of a step, and add what they moved by since the
+        last reading to the counters.  Returns that as a slow step's
+        record has it: ``cpu_ns`` of the ``cpu_over_ns`` of wall time
+        and ``cpu_over_steps`` steps, this one the last, since that
+        reading (a thread that slept through a stall shows no more CPU
+        time than its few usual steps take, one that was busy shows the
+        stall's), and the switches and faults of the same stretch."""
+        cpu, usage = _cpu_ns(), _thread_usage()
+        then, cpu0, usage0 = self._thread_read or (now, cpu, usage)
+        self._thread_read = (now, cpu, usage)
+        steps, self._steps_unread = self._steps_unread, 0
+        self.step_cpu_ns += cpu - cpu0
+        self.step_nivcsw += usage.ru_nivcsw - usage0.ru_nivcsw
+        self.step_minflt += usage.ru_minflt - usage0.ru_minflt
+        return {"cpu_ns": cpu - cpu0, "cpu_over_ns": now - then,
+                "cpu_over_steps": steps,
+                "nvcsw": usage.ru_nvcsw - usage0.ru_nvcsw,
+                "nivcsw": usage.ru_nivcsw - usage0.ru_nivcsw,
+                "minflt": usage.ru_minflt - usage0.ru_minflt,
+                "majflt": usage.ru_majflt - usage0.ru_majflt}
+
+    def programs_dispatched(self) -> int:
+        """Calls into the engine's step programs so far, every lane."""
+        return sum(p.calls for p in self.programs)
+
+    def programs_compiled(self) -> int:
+        """Programs they compiled (or loaded from the compile cache)."""
+        return sum(p.compiles for p in self.programs)
+
     def on_fetch(self, nbytes: int):
-        """A step program's result of ``nbytes`` was read on the host:
-        ``fetched_bytes`` over ``decode_iterations`` is what a decode
-        step sends to the host, 4 bytes a slot where the program chose
-        the token."""
+        """A step program's result of ``nbytes`` was read on the host,
+        which waited for it: ``fetched_bytes`` over ``decode_iterations``
+        is what a decode step sends to the host, 4 bytes a slot where
+        the program chose the token."""
         self.fetched_bytes += nbytes
+        dispatched = self.programs_dispatched()
+        behind = dispatched - self._dispatched_at_read
+        self._dispatched_at_read = dispatched
+        self.blocking_reads += 1
+        self.programs_behind_reads += behind
+        if behind > self._step_behind:
+            self._step_behind = behind
 
     def on_prefill_dispatch(self, request_id: str, start: int, tokens: int):
         """One chunk of ``tokens`` prompt tokens at positions ``start ..``
@@ -339,10 +549,12 @@ class ServingMetrics:
         # finished inside its SLO (timeouts/sheds/errors contribute 0)
         if reason in ("eos", "stop", "length"):
             self.goodput_tokens += tokens
-        t = self.requests[request_id]
+        t = self.requests.pop(request_id)
         t.finished_ns = _now_ns()
         t.tokens_generated = tokens
         t.finish_reason = reason
+        d = t.to_dict()
+        self.finished.append((request_id, d))
         reg = self._obs()
         if reg is not None:
             reg.counter("serving_requests_completed_total",
@@ -364,7 +576,6 @@ class ServingMetrics:
                 reg.counter("serving_goodput_tokens_total",
                             "tokens from requests finished within "
                             "deadline").inc(tokens)
-            d = t.to_dict()
             if d["tpot_s"] is not None:
                 reg.histogram("serving_tpot_seconds",
                               "time per output token (decode phase)"
@@ -495,6 +706,7 @@ class ServingMetrics:
     def on_decode_iteration(self, active: int, batch_size: int,
                             cache_utilization: float):
         self.decode_iterations += 1
+        self._step_slots = active
         occ = active / batch_size if batch_size else 0.0
         self.last_batch_occupancy = occ
         self.last_cache_utilization = cache_utilization
@@ -579,7 +791,6 @@ class ServingMetrics:
                 "block_slot_steps": self.block_slot_steps,
                 "commit_slot_steps": self.commit_slot_steps,
                 "tokens_unmasked": self.tokens_unmasked,
-                "blocks_committed": self.blocks_committed,
                 "block_context_tokens": self.block_context_tokens,
                 "experts_read": self.experts_read,
                 "expert_assignments": self.expert_assignments,
@@ -591,6 +802,15 @@ class ServingMetrics:
                 "window_pages_live": self.window_pages_live,
                 "window_seq_steps": self.window_seq_steps,
                 **{f"step_ns.{p}": ns for p, ns in self.step_ns.items()},
+                "step_wall_ns": self.step_wall_ns,
+                "step_cpu_ns": self.step_cpu_ns,
+                "step_nivcsw": self.step_nivcsw,
+                "step_minflt": self.step_minflt,
+                "programs_dispatched": self.programs_dispatched(),
+                "blocking_reads": self.blocking_reads,
+                "programs_behind_reads": self.programs_behind_reads,
+                "slow_steps": self.slow_steps,
+                "slow_step_excess_ns": self.slow_step_excess_ns,
             },
             "gauges": {
                 "degradation_level": self.degradation_level,
@@ -608,6 +828,8 @@ class ServingMetrics:
                 "serving_kv_cache_dtype": self.kv_cache_dtype_code,
                 "kv_quant_scale_bytes": self.kv_quant_scale_bytes,
             },
-            "requests": {rid: t.to_dict()
-                         for rid, t in self.requests.items()},
+            "requests": {**dict(self.finished),
+                         **{rid: t.to_dict()
+                            for rid, t in self.requests.items()}},
+            "slow_steps": [dict(r) for r in self.slow_step_log],
         }

@@ -168,6 +168,8 @@ def replay_trace(router: Router, trace: Sequence[Arrival]) -> dict:
     i = 0
     step = 0
     results: Dict[str, object] = {}
+    timelines: Dict[str, dict] = {}
+    retired: Dict[str, int] = {}    # replica -> metrics.completed read
     while i < len(pending) or router.has_work():
         while i < len(pending) and pending[i].step <= step:
             a = pending[i]
@@ -192,15 +194,16 @@ def replay_trace(router: Router, trace: Sequence[Arrival]) -> dict:
                     tally.finished.get("rejected", 0) + 1
         router.step()
         step += 1
+        # a replica keeps the timelines of its last
+        # ``metrics.FINISHED_REQUESTS`` retired requests, a replay may
+        # hold more: read what each step retired as it goes (from
+        # whichever replica finished the request)
+        for rep in router.replicas:
+            metrics = rep.engine.metrics
+            new = metrics.completed - retired.get(rep.name, 0)
+            retired[rep.name] = metrics.completed
+            timelines.update(list(metrics.finished)[-new:] if new else ())
     results.update(router.run_until_complete())
-    # one timeline lookup per finished request, from whichever replica
-    # finished it (resubmitted requests have a timeline on each replica
-    # they visited; the finishing one has finished_ns set)
-    timelines: Dict[str, dict] = {}
-    for rep in router.replicas:
-        for rid, t in rep.engine.metrics.requests.items():
-            if t.finished_ns:
-                timelines[rid] = t.to_dict()
     for rid, req in results.items():
         tenant = by_rid.get(rid)
         if tenant is None:

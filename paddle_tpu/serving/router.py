@@ -576,6 +576,8 @@ class Router:
         eng._pending[:] = 0
         for req in stranded:
             eng.pool.free_request(req.request_id)
+            # (its timeline goes on where it is resubmitted)
+            eng.metrics.requests.pop(req.request_id, None)
         log.warning("router: drained %d stranded request(s) from %s",
                     len(stranded), rep.name)
         for req in sorted(stranded, key=lambda r: r.ordinal):

@@ -265,7 +265,6 @@ def test_engine_generates_what_the_plain_loop_does(L, T, rule):
     c = eng.stats()["counters"]
     assert c["block_slot_steps"] >= c["commit_slot_steps"] > 0
     assert c["tokens_unmasked"] >= sum(n for _, _, _, n in sent)
-    assert c["blocks_committed"] == c["commit_slot_steps"]
     layers = model.config.num_hidden_layers
     runs = c["block_steps"] + c["prefill_chunks_run"]
     assert 0 < c["experts_read"] <= runs * layers * model.config.num_experts
@@ -285,7 +284,7 @@ def test_static_rule_yields_l_over_t_plus_one_tokens_a_slot_step():
     # 8 blocks: 2 denoise steps each, a commit after all but the last
     assert c["block_slot_steps"] == 8 * T + 7
     assert c["tokens_unmasked"] == 8 * L
-    assert c["commit_slot_steps"] == c["blocks_committed"] == 7
+    assert c["commit_slot_steps"] == 7
 
 
 def test_eos_ends_a_request_inside_a_block():

@@ -406,13 +406,17 @@ class TestServingMirror:
         "prefill_attended_pairs",
         # the block iteration and the routed experts (ISSUE 30)
         "block_steps", "block_slot_steps", "commit_slot_steps",
-        "tokens_unmasked", "blocks_committed", "block_context_tokens",
+        "tokens_unmasked", "block_context_tokens",
         "experts_read", "expert_assignments", "expert_assignments_max",
         "experts_read_decode", "expert_assignments_decode",
         "window_pages_released", "decode_window_tokens",
         "window_pages_live", "window_seq_steps",
         # what the fetches bring to the host (ISSUE 35)
         "fetched_bytes",
+        # the step's account (ISSUE 38)
+        "step_wall_ns", "step_cpu_ns", "step_nivcsw",
+        "step_minflt", "programs_dispatched", "blocking_reads",
+        "programs_behind_reads", "slow_steps", "slow_step_excess_ns",
     } | {f"step_ns.{phase}" for phase in (
         "admit", "prefill_dispatch", "first_token", "decode_prepare",
         "decode_dispatch", "decode_fetch", "sample_emit", "pool_sync")}
