@@ -34,6 +34,12 @@ place of the online rescale).  On CPU the fused path lowers through
 it, so tier-1 and the jaxpr audits cover the exact fused-step math
 with no pallas_call in the program.  models/llama.py's ``_paged_attn``
 gather path stays the unfused parity oracle.
+
+Heads of other shapes, as ``paged_attention`` has them (each a static
+choice that changes nothing at its default): a value narrower than the
+key, key lanes past the key's width (zeros), and a SINK a head, with
+which a row's online softmax starts: running max the sink's logit,
+exp-sum 1, no value.
 """
 from __future__ import annotations
 
@@ -48,8 +54,8 @@ from jax.experimental.pallas import tpu as pltpu
 
 from .costs import KernelCost, register_kernel_cost
 from .kv_quant import decode_codes
-from .paged_attention import (_PageWalk, _online_softmax, _pool_streams,
-                              _split_walk_refs)
+from .paged_attention import (_LANES, _PageWalk, _online_softmax,
+                              _pool_streams, _split_walk_refs, pad_lanes)
 
 KERNEL_NAME = "fused_chunked_prefill"
 NEG_INF = -1e30
@@ -65,12 +71,13 @@ def _visible_upto(q_pos, mask_block):
     return (q_pos // mask_block + 1) * mask_block - 1
 
 
-def _row_tile(RT, KVH):
+def _row_tile(RT, KVH, lanes=_LANES):
     """Query rows of one grid cell: all ``RT`` where a ``[KVH, rows,
     128]`` f32 tile (the scores of one compute block; q, the accumulator
-    and the output are as large) stays within 1 MiB, else the largest
-    divisor of ``RT`` that does and is a whole number of sublanes."""
-    cap = max(8, 2048 // KVH)
+    and the output are as large; q ``lanes / 128`` times as large where
+    the key is wider) stays within 1 MiB, else the largest divisor of
+    ``RT`` that does and is a whole number of sublanes."""
+    cap = max(8, 2048 // KVH * _LANES // max(lanes, _LANES))
     if RT <= cap:
         return RT
     fits = [r for r in range(8, cap + 1, 8) if RT % r == 0]
@@ -78,7 +85,9 @@ def _row_tile(RT, KVH):
 
 
 def _chunk_kernel(bt_ref, pos_ref, q_ref, *rest, chunk, kv_dtype=None,
-                  mask_block=1, window=None):
+                  mask_block=1, window=None, sink=False):
+    if sink:
+        sink_ref, rest = rest[0], rest[1:]
     hbm_refs, (o_ref, acc_ref, m_ref, l_ref), bufs, sems = \
         _split_walk_refs(rest, kv_dtype)
     b = pl.program_id(0)
@@ -101,8 +110,14 @@ def _chunk_kernel(bt_ref, pos_ref, q_ref, *rest, chunk, kv_dtype=None,
     K = walk.G * bs                                     # keys a block
 
     acc_ref[:] = jnp.zeros_like(acc_ref)
-    m_ref[:] = jnp.full_like(m_ref, NEG_INF)
-    l_ref[:] = jnp.zeros_like(l_ref)
+    if sink:
+        # the sink is one more key of the row's softmax, seen first:
+        # its logit, an exp-sum of 1 and no value
+        m_ref[:] = sink_ref[:]
+        l_ref[:] = jnp.ones_like(l_ref)
+    else:
+        m_ref[:] = jnp.full_like(m_ref, NEG_INF)
+        l_ref[:] = jnp.zeros_like(l_ref)
 
     # q rows are [rep * chunk, D] with row r * chunk + t, tiled over the
     # grid's second axis; scale is already folded into q by the caller,
@@ -163,29 +178,38 @@ def _chunk_kernel(bt_ref, pos_ref, q_ref, *rest, chunk, kv_dtype=None,
                                              "window"))
 def _pallas_chunked(q_g, k_pool, v_pool, block_table, positions, chunk,
                     interpret, k_scale=None, v_scale=None, kv_dtype=None,
-                    mask_block=1, window=None):
-    """q_g: grouped, ROTATED, pre-scaled [B, KVH, RT, D] f32 queries;
-    returns the normalized context [B, KVH, RT, D] f32.
+                    mask_block=1, window=None, sink_g=None):
+    """q_g: grouped, ROTATED, pre-scaled [B, KVH, RT, D] f32 queries
+    (``D`` the pools' key lanes), ``sink_g`` None or the sink of each
+    grouped row, ``[KVH, RT, 1]`` f32; returns the normalized context
+    [B, KVH, RT, Dv] f32, ``Dv`` the value pool's width.
 
     Jitted so that a model's layers share ONE trace and ONE lowering of
     the kernel (see ``paged_attention._pallas_partials``)."""
     B, KVH, RT, D = q_g.shape
     bs = k_pool.shape[1]
+    Dv = v_pool.shape[-1]
     nbs = block_table.shape[1]
-    rows = _row_tile(RT, KVH)
+    rows = _row_tile(RT, KVH, D)
     pool_specs, pool_operands, pool_scratch = _pool_streams(
         k_pool, v_pool, k_scale, v_scale, kv_dtype)
 
     def q_tile(b, t, bt, pos):
         return (b, 0, t, 0)
 
+    sink_specs, sink_operands = [], []
+    if sink_g is not None:
+        sink_specs = [pl.BlockSpec((KVH, rows, 1),
+                                   lambda b, t, bt, pos: (0, t, 0))]
+        sink_operands = [sink_g]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(B, RT // rows),
-        in_specs=[pl.BlockSpec((1, KVH, rows, D), q_tile), *pool_specs],
-        out_specs=pl.BlockSpec((1, KVH, rows, D), q_tile),
+        in_specs=[pl.BlockSpec((1, KVH, rows, D), q_tile), *sink_specs,
+                  *pool_specs],
+        out_specs=pl.BlockSpec((1, KVH, rows, Dv), q_tile),
         scratch_shapes=[
-            pltpu.VMEM((KVH, rows, D), jnp.float32),
+            pltpu.VMEM((KVH, rows, Dv), jnp.float32),
             pltpu.VMEM((KVH, rows, 1), jnp.float32),
             pltpu.VMEM((KVH, rows, 1), jnp.float32),
             *pool_scratch,
@@ -197,32 +221,33 @@ def _pallas_chunked(q_g, k_pool, v_pool, block_table, positions, chunk,
     esize = jnp.dtype(k_pool.dtype).itemsize
     # every row tile walks the pages again; a quantized pool also
     # streams one f32 scale per (pool, token) row
-    kv_bytes = 2 * B * L * KVH * D * esize * (RT // rows)
+    kv_bytes = B * L * KVH * (D + Dv) * esize * (RT // rows)
     scale_bytes = 2 * B * L * 4 * (RT // rows) if kv_dtype is not None \
         else 0
     return pl.pallas_call(
         functools.partial(_chunk_kernel, chunk=chunk, kv_dtype=kv_dtype,
-                          mask_block=mask_block, window=window),
+                          mask_block=mask_block, window=window,
+                          sink=sink_g is not None),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, KVH, RT, D), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((B, KVH, RT, Dv), jnp.float32),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel"))
         if not interpret else None,
         cost_estimate=pl.CostEstimate(
-            flops=4 * B * KVH * RT * D * L,
+            flops=2 * B * KVH * RT * (D + Dv) * L,
             bytes_accessed=kv_bytes + scale_bytes,
             transcendentals=B * KVH * RT * L),
         interpret=interpret,
         name=KERNEL_NAME,
-    )(block_table, positions, q_g, *pool_operands)
+    )(block_table, positions, q_g, *sink_operands, *pool_operands)
 
 
 def _xla_chunked(q_g, k_pool, v_pool, block_table, positions, chunk,
                  k_scale=None, v_scale=None, kv_dtype=None, mask_block=1,
-                 window=None):
+                 window=None, sink_g=None):
     """Same grouped-query chunk attention in plain XLA: q_g is the
     ROTATED and pre-scaled [B, KVH, RT, D] f32 query (scale folded in,
-    exactly as the caller hands the kernel)."""
+    exactly as the caller hands the kernel), ``sink_g`` the kernel's."""
     B, KVH, RT, D = q_g.shape
     bs = k_pool.shape[1]
     nbs = block_table.shape[1]
@@ -238,7 +263,7 @@ def _xla_chunked(q_g, k_pool, v_pool, block_table, positions, chunk,
         kb = k_pool[block_table].astype(jnp.float32)    # [B,nbs,bs,KVH,D]
         vb = v_pool[block_table].astype(jnp.float32)
     kb = kb.reshape(B, L, KVH, D)
-    vb = vb.reshape(B, L, KVH, D)
+    vb = vb.reshape(B, L, KVH, vb.shape[-1])
     scores = jnp.einsum("bkrd,blkd->bkrl", q_g, kb,
                         preferred_element_type=jnp.float32)
     k_pos = jnp.arange(L)
@@ -250,8 +275,12 @@ def _xla_chunked(q_g, k_pool, v_pool, block_table, positions, chunk,
                          (q_pos - window)[:, None, :, None])
     scores = jnp.where(valid, scores, NEG_INF)
     m = jnp.max(scores, axis=-1, keepdims=True)
+    if sink_g is not None:
+        m = jnp.maximum(m, sink_g[None])
     pexp = jnp.exp(scores - m)
     l = jnp.sum(pexp, axis=-1, keepdims=True)
+    if sink_g is not None:
+        l = l + jnp.exp(sink_g[None] - m)
     acc = jnp.einsum("bkrl,blkd->bkrd", pexp, vb,
                      preferred_element_type=jnp.float32)
     return acc / jnp.maximum(l, 1e-30)
@@ -261,7 +290,7 @@ def fused_chunked_attention(q, k_pool, v_pool, block_table, positions,
                             *, use_pallas=None, interpret=None,
                             k_scale=None, v_scale=None,
                             kv_cache_dtype=None, mask_block=1,
-                            window=None):
+                            window=None, sink=None):
     """Paged attention for one prefill chunk, fused end to end.
 
     q: [B, T, H, D] ROTATED queries for the chunk; k_pool/v_pool:
@@ -289,6 +318,12 @@ def fused_chunked_attention(q, k_pool, v_pool, block_table, positions,
     walk starts at the page of the chunk's first query's first key, and
     no table entry before that page is read.
 
+    Heads of other shapes: ``v_pool`` may be narrower than the key
+    (``Dv``; the context is ``[B, T, H, Dv]``), ``k_pool`` may hold more
+    lanes than q's ``D`` (zeros past it; the scale stays ``1/sqrt(D)``),
+    and ``sink [H]`` f32 is a logit a head in the softmax's denominator
+    that weighs no value.
+
     On TPU the gather + mask + softmax + context is one Pallas kernel
     with an online softmax; elsewhere the numerically-identical XLA
     lowering runs instead.
@@ -298,6 +333,7 @@ def fused_chunked_attention(q, k_pool, v_pool, block_table, positions,
     B, T, H, D = q.shape
     KVH = k_pool.shape[2]
     rep = H // KVH
+    Dv = v_pool.shape[-1]
     positions = jnp.asarray(positions, jnp.int32)
     scale = 1.0 / math.sqrt(D)
 
@@ -310,19 +346,25 @@ def fused_chunked_attention(q, k_pool, v_pool, block_table, positions,
     # r * T + t and every row of group kvh reads KV head kvh
     q_g = q.reshape(B, T, KVH, rep, D).transpose(0, 2, 3, 1, 4) \
         .reshape(B, KVH, rep * T, D).astype(jnp.float32) * scale
+    q_g = pad_lanes(q_g, k_pool.shape[-1])
+    # a head's sink on each of its grouped rows (row r * T + t of group
+    # kvh is head kvh * rep + r)
+    sink_g = None if sink is None else jnp.repeat(
+        sink.astype(jnp.float32).reshape(KVH, rep), T, axis=1)[..., None]
     if use_pallas:
         out = _pallas_chunked(q_g, k_pool, v_pool, block_table,
                               positions, T, interpret,
                               k_scale=k_scale, v_scale=v_scale,
                               kv_dtype=kv_cache_dtype,
-                              mask_block=mask_block, window=window)
+                              mask_block=mask_block, window=window,
+                              sink_g=sink_g)
     else:
         out = _xla_chunked(q_g, k_pool, v_pool, block_table, positions,
                            T, k_scale=k_scale, v_scale=v_scale,
                            kv_dtype=kv_cache_dtype, mask_block=mask_block,
-                           window=window)
-    return out.reshape(B, KVH, rep, T, D).transpose(0, 3, 1, 2, 4) \
-        .reshape(B, T, H, D).astype(q.dtype)
+                           window=window, sink_g=sink_g)
+    return out.reshape(B, KVH, rep, T, Dv).transpose(0, 3, 1, 2, 4) \
+        .reshape(B, T, H, Dv).astype(q.dtype)
 
 
 # ---------------------------------------------------------------------------
@@ -331,15 +373,19 @@ def fused_chunked_attention(q, k_pool, v_pool, block_table, positions,
 
 def _chunked_prefill_cost(in_avals, out_avals):
     # operand order fixed by _pallas_chunked:
-    # (block_table, positions, q_g, k_pool, v_pool[, k_scale, v_scale])
+    # (block_table, positions, q_g[, sink_g], k_pool, v_pool[, k_scale,
+    # v_scale]); the sink's rows ([KVH, RT, 1]) are priced with q's
+    if len(in_avals[3][0]) == 3:
+        in_avals = [*in_avals[:3], *in_avals[4:]]
     bt_shape = in_avals[0][0]
     q_shape, q_dtype = in_avals[2][0], in_avals[2][1]
     pool_shape, pool_dtype = in_avals[3][0], in_avals[3][1]
     B, nbs = int(bt_shape[0]), int(bt_shape[1])
     KVH, RT, D = int(q_shape[1]), int(q_shape[2]), int(q_shape[3])
+    Dv = int(in_avals[4][0][-1])                        # the value's width
     bs = int(pool_shape[1])
     L = nbs * bs
-    flops = 4.0 * B * KVH * RT * D * L                  # qk^T + pv MACs
+    flops = 2.0 * B * KVH * RT * (D + Dv) * L           # qk^T + pv MACs
     trans = float(B * KVH * RT * L)                     # exp per score
     esize = np.dtype(pool_dtype).itemsize
     in_bytes = sum(
@@ -351,8 +397,8 @@ def _chunked_prefill_cost(in_avals, out_avals):
     # only the chunk's live pages, but shapes cannot see where a chunk
     # starts: this is the worst case, the table's last chunk.  Per-row
     # f32 scale sidecars ride along when present
-    tiles = RT // _row_tile(RT, KVH)
-    kv_bytes = 2.0 * B * L * KVH * D * esize * tiles
+    tiles = RT // _row_tile(RT, KVH, D)
+    kv_bytes = 1.0 * B * L * KVH * (D + Dv) * esize * tiles
     if len(in_avals) > 5:
         kv_bytes += 2.0 * B * L * tiles * \
             np.dtype(in_avals[5][1]).itemsize

@@ -71,14 +71,6 @@ def latent_pool_lanes(entry_lanes: int) -> int:
     return -(-int(entry_lanes) // _LANES) * _LANES
 
 
-def pad_lanes(x, lanes: int):
-    """``x [.., n]`` with zeros up to ``lanes``."""
-    extra = lanes - x.shape[-1]
-    if extra == 0:
-        return x
-    return jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, extra)])
-
-
 class _LatentWalk(_PageWalk):
     """``_PageWalk`` over ONE stream of pages ``[bs, W]``: a compute
     block's rows are its keys and, their first lanes, its values."""

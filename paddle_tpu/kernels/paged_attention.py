@@ -37,6 +37,14 @@ reference (``paged_decode_reference``) reproduces models/llama.py's
 scatter/gather path for parity tests.
 
 ``num_splits`` defaults to ``_default_splits`` of the table's depth.
+
+Heads of other shapes, each a static choice that changes nothing at its
+default: a value narrower than the key (the value width is V's own), a
+key wider than one 128-lane register stored in whole registers (the
+pool's lanes past the key width are zeros; the query is padded to match,
+the scale is the key's own), a rotation of the first ``rotary`` lanes
+only, and a SINK: a learned logit a head that joins the softmax's
+denominator and weighs no value, counted once, in the split-K combine.
 """
 from __future__ import annotations
 
@@ -63,6 +71,22 @@ def _rotate_half(x, c, s):
     half = x.shape[-1] // 2
     x1, x2 = x[..., :half], x[..., half:]
     return jnp.concatenate([x1 * c - x2 * s, x2 * c + x1 * s], axis=-1)
+
+
+def _rotate_lanes(x, c, s, rotary):
+    """Rotate-half RoPE on the first ``rotary`` lanes of ``x`` (pairs
+    ``i, i + rotary / 2``; ``c``/``s`` are ``rotary / 2`` wide), the
+    other lanes as they are."""
+    return jnp.concatenate([_rotate_half(x[..., :rotary], c, s),
+                            x[..., rotary:]], axis=-1)
+
+
+def pad_lanes(x, lanes):
+    """``x [.., D]`` with zeros up to ``lanes`` (``x`` itself where
+    ``D`` is already that wide)."""
+    if x.shape[-1] == lanes:
+        return x
+    return jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, lanes - x.shape[-1])])
 
 
 def _scatter_token(pool, new, block_table, positions):
@@ -222,7 +246,7 @@ def _pool_streams(k_pool, v_pool, k_scale, v_scale, kv_dtype):
     in_specs = [pl.BlockSpec(memory_space=pl.ANY)] * 2
     operands = [k_pool, v_pool]
     scratch = [pltpu.VMEM((2, G, bs, KVH, D), k_pool.dtype),
-               pltpu.VMEM((2, G, bs, KVH, D), v_pool.dtype)]
+               pltpu.VMEM((2, G) + v_pool.shape[1:], v_pool.dtype)]
     if kv_dtype is not None:
         # a page's [bs] f32 scale row rides with the page, one more copy;
         # Mosaic slices an HBM array only in whole 128-lane rows
@@ -297,14 +321,14 @@ def _decode_kernel(bt_ref, pos_ref, q_ref, cos_ref, sin_ref, *rest,
         scores = jnp.where(seen, scores, NEG_INF)
         return _online_softmax(scores, walk.values(slot), *carry)
 
-    KVH, rep, D = q_rot.shape
+    KVH, rep, _ = q_rot.shape
     # a split with no live page emits (NEG_INF, 0, 0): the combine
     # weighs it to zero
     m, l, acc = jax.lax.fori_loop(
         0, num_blocks, compute_block,
         (jnp.full((KVH, rep, 1), NEG_INF, jnp.float32),
          jnp.zeros((KVH, rep, 1), jnp.float32),
-         jnp.zeros((KVH, rep, D), jnp.float32)))
+         jnp.zeros((KVH, rep, o_ref.shape[-1]), jnp.float32)))
     o_ref[0, 0] = acc
     # per-row scalars broadcast over the lane dim (flash kernel lse
     # idiom: a 1-wide trailing dim is not a legal TPU output tile)
@@ -319,14 +343,16 @@ def _pallas_partials(q, cos_b, sin_b, k_pool, v_pool, block_table,
                      positions, num_splits, scale, interpret,
                      k_scale=None, v_scale=None, kv_dtype=None,
                      window=None):
-    """q: UNROTATED [B, KVH, rep, D]; returns (acc [B,S,KVH,rep,D] f32,
-    m [B,S,KVH,rep] f32, l [B,S,KVH,rep] f32).
+    """q: UNROTATED [B, KVH, rep, D] (``D`` the pools' key lanes);
+    returns (acc [B,S,KVH,rep,Dv] f32, m [B,S,KVH,rep] f32, l
+    [B,S,KVH,rep] f32), ``Dv`` the value pool's width.
 
     Jitted so that a model's layers share ONE trace and ONE lowering of
     the kernel: ``pallas_call`` traces its kernel at every call, and a
     step program's first call is part of a server's start."""
     B, KVH, rep, D = q.shape
     bs = k_pool.shape[1]
+    Dv = v_pool.shape[-1]
     nbs = block_table.shape[1]
     P = nbs // num_splits
     half = D // 2
@@ -348,7 +374,7 @@ def _pallas_partials(q, cos_b, sin_b, k_pool, v_pool, block_table,
         grid=(B, num_splits),
         in_specs=in_specs,
         out_specs=[
-            pl.BlockSpec((1, 1, KVH, rep, D),
+            pl.BlockSpec((1, 1, KVH, rep, Dv),
                          lambda b, s, bt, pos: (b, s, 0, 0, 0)),
             pl.BlockSpec((1, 1, KVH, rep, _LANES),
                          lambda b, s, bt, pos: (b, s, 0, 0, 0)),
@@ -369,7 +395,7 @@ def _pallas_partials(q, cos_b, sin_b, k_pool, v_pool, block_table,
                           scale=scale, kv_dtype=kv_dtype, window=window),
         grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct((B, num_splits, KVH, rep, D),
+            jax.ShapeDtypeStruct((B, num_splits, KVH, rep, Dv),
                                  jnp.float32),
             jax.ShapeDtypeStruct((B, num_splits, KVH, rep, _LANES),
                                  jnp.float32),
@@ -380,8 +406,8 @@ def _pallas_partials(q, cos_b, sin_b, k_pool, v_pool, block_table,
             dimension_semantics=("parallel", "parallel"))
         if not interpret else None,
         cost_estimate=pl.CostEstimate(
-            flops=4 * B * H * D * L,
-            bytes_accessed=2 * B * L * KVH * D * esize + scale_bytes,
+            flops=2 * B * H * (D + Dv) * L,
+            bytes_accessed=B * L * KVH * (D + Dv) * esize + scale_bytes,
             transcendentals=B * H * L),
         interpret=interpret,
         name=KERNEL_NAME,
@@ -432,14 +458,20 @@ def _xla_partials(q_rot, k_pool, v_pool, block_table, positions,
     return acc, m, l
 
 
-def _combine_splits(acc, m, l):
+def _combine_splits(acc, m, l, sink=None):
     """Log-sum-exp merge of the per-split partials — shared verbatim by
-    both lowerings, so the combine rounding is identical."""
+    both lowerings, so the combine rounding is identical.  ``sink
+    [KVH, rep]`` (a logit a head, or None) joins the merge once, as one
+    more partial of exp-sum 1 and no value."""
     m_g = jnp.max(m, axis=1)                            # [B,KVH,rep]
+    if sink is not None:
+        m_g = jnp.maximum(m_g, sink)
     w = jnp.exp(m - m_g[:, None])                       # [B,S,KVH,rep]
     l_g = jnp.sum(w * l, axis=1)
+    if sink is not None:
+        l_g = l_g + jnp.exp(sink - m_g)
     out = jnp.sum(w[..., None] * acc, axis=1)
-    return out / jnp.maximum(l_g, 1e-30)[..., None]     # [B,KVH,rep,D]
+    return out / jnp.maximum(l_g, 1e-30)[..., None]     # [B,KVH,rep,Dv]
 
 
 # ---------------------------------------------------------------------------
@@ -468,7 +500,7 @@ def fused_paged_decode(q, k_new, v_new, k_pool, v_pool, block_table,
                        positions, cos, sin, *, num_splits=None,
                        use_pallas=None, interpret=None,
                        k_scale=None, v_scale=None, kv_cache_dtype=None,
-                       window=None):
+                       window=None, sink=None, rotary=None):
     """One fused decode step of paged attention.
 
     q: [B, 1, H, D] UNROTATED queries; k_new/v_new: [B, 1, KVH, D]
@@ -497,6 +529,15 @@ def fused_paged_decode(q, k_new, v_new, k_pool, v_pool, block_table,
     the walk starts at the page of the first of them, and no table
     entry before that page is read.  ``cos=None`` (with ``sin``) is the
     identity rotation: a layer with no position encoding.
+
+    Heads of other shapes: ``v_new``/``v_pool`` may be narrower than the
+    key (``Dv``; the output is ``[B, 1, H, Dv]``); ``k_pool`` may hold
+    more lanes than the key's ``D`` (zeros past it: the new key and the
+    query are padded to them, the scale stays ``1/sqrt(D)``); ``rotary``
+    (static) turns the first ``rotary`` lanes of q and the new key only,
+    under ``cos``/``sin`` tables ``rotary / 2`` wide; ``sink [H]`` f32 is
+    a logit a head in the softmax's denominator (``exp(sink) + sum_j
+    exp(s_j)``) that weighs no value.
     """
     from .fusion import pallas_lowering
 
@@ -506,6 +547,9 @@ def fused_paged_decode(q, k_new, v_new, k_pool, v_pool, block_table,
                          f"got T == {T}")
     KVH = k_new.shape[2]
     rep = H // KVH
+    out_dtype = q.dtype
+    Dv = v_new.shape[-1]
+    lanes = k_pool.shape[-1]
     nbs = block_table.shape[1]
     positions = jnp.asarray(positions, jnp.int32)
     scale = 1.0 / math.sqrt(D)
@@ -518,9 +562,20 @@ def fused_paged_decode(q, k_new, v_new, k_pool, v_pool, block_table,
     # B rows — XLA prologue shared verbatim by both lowerings).  A
     # quantized pool quantizes the token's row here, at write time,
     # inside the traced step.
-    if cos is None:
-        c = jnp.ones((B, D // 2), jnp.float32)
-        s = jnp.zeros((B, D // 2), jnp.float32)
+    if rotary is not None:
+        # the first ``rotary`` lanes turn here, q's too; the kernel is
+        # handed the identity rotation over the pool's lanes
+        c, s = cos[positions], sin[positions]           # [B, rotary/2]
+        k_rot = _rotate_lanes(k_new[:, 0].astype(jnp.float32),
+                              c[:, None, :], s[:, None, :],
+                              rotary).astype(k_new.dtype)
+        q = _rotate_lanes(q.astype(jnp.float32), c[:, None, None, :],
+                          s[:, None, None, :], rotary)
+        c = jnp.ones((B, lanes // 2), jnp.float32)
+        s = jnp.zeros((B, lanes // 2), jnp.float32)
+    elif cos is None:
+        c = jnp.ones((B, lanes // 2), jnp.float32)
+        s = jnp.zeros((B, lanes // 2), jnp.float32)
         k_rot = k_new[:, 0]
     else:
         c = cos[positions]                              # [B, half] f32
@@ -537,13 +592,14 @@ def fused_paged_decode(q, k_new, v_new, k_pool, v_pool, block_table,
                 v_pool, v_scale, v_new[:, 0], block_table, positions,
                 kv_cache_dtype)
         else:
-            new_k_pool = _scatter_token(k_pool, k_rot, block_table,
-                                        positions)
+            new_k_pool = _scatter_token(k_pool, pad_lanes(k_rot, lanes),
+                                        block_table, positions)
             new_v_pool = _scatter_token(v_pool, v_new[:, 0], block_table,
                                         positions)
             new_k_scale = new_v_scale = None
 
-    q_g = q[:, 0].reshape(B, KVH, rep, D)               # GQA grouping
+    q_g = pad_lanes(q[:, 0].reshape(B, KVH, rep, D),    # GQA grouping
+                    lanes)
     if use_pallas:
         acc, m, l = _pallas_partials(
             q_g, c, s, new_k_pool, new_v_pool, block_table,
@@ -559,8 +615,9 @@ def fused_paged_decode(q, k_new, v_new, k_pool, v_pool, block_table,
                                   k_scale=new_k_scale,
                                   v_scale=new_v_scale,
                                   kv_dtype=kv_cache_dtype, window=window)
-    out = _combine_splits(acc, m, l)                    # [B,KVH,rep,D]
-    out = out.reshape(B, 1, H, D).astype(q.dtype)
+    out = _combine_splits(acc, m, l, None if sink is None else
+                          sink.astype(jnp.float32).reshape(KVH, rep))
+    out = out.reshape(B, 1, H, Dv).astype(out_dtype)
     if kv_cache_dtype is not None:
         return out, new_k_pool, new_v_pool, new_k_scale, new_v_scale
     return out, new_k_pool, new_v_pool
@@ -660,9 +717,10 @@ def _paged_decode_cost(in_avals, out_avals):
     pool_shape, pool_dtype = in_avals[5][0], in_avals[5][1]
     B, nbs = int(bt_shape[0]), int(bt_shape[1])
     KVH, rep, D = int(q_shape[1]), int(q_shape[2]), int(q_shape[3])
+    Dv = int(in_avals[6][0][-1])                        # the value's width
     bs = int(pool_shape[1])
     H, L = KVH * rep, nbs * bs
-    flops = 4.0 * B * H * D * L                         # qk^T + pv MACs
+    flops = 2.0 * B * H * (D + Dv) * L                  # qk^T + pv MACs
     trans = float(B * H * L)                            # exp per score
     esize = np.dtype(pool_dtype).itemsize
     in_bytes = sum(
@@ -672,7 +730,7 @@ def _paged_decode_cost(in_avals, out_avals):
     # not the whole pool allocation.  The kernel walks only the live
     # pages, but shapes cannot see lengths: this is the worst case, every
     # sequence at the table's end
-    kv_bytes = 2.0 * B * L * KVH * D * esize
+    kv_bytes = 1.0 * B * L * KVH * (D + Dv) * esize
     if len(in_avals) > 7:                               # quantized pool
         # one f32 absmax per (pool, token) row streams with its block
         kv_bytes += 2.0 * B * L * np.dtype(in_avals[7][1]).itemsize

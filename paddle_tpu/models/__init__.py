@@ -12,3 +12,4 @@ from .sdar_moe import (DroplessMoE, SDARMoEConfig,  # noqa: F401
 from .afmoe import AfmoeConfig, AfmoeForCausalLM  # noqa: F401
 from .glm4_moe_lite import (Glm4MoeLiteConfig,  # noqa: F401
                             Glm4MoeLiteForCausalLM)
+from .mimo_v2 import MimoV2Config, MimoV2ForCausalLM  # noqa: F401

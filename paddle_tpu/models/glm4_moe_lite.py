@@ -347,14 +347,14 @@ class Glm4MoeLiteForCausalLM(nn.Layer):
     def _entry(self, c_kv, k_rope, lanes):
         """What a position caches, ``[c_kv | k_rope]`` and zeros up to
         the pool's ``lanes``."""
-        from ..kernels.latent_attention import pad_lanes
+        from ..kernels.paged_attention import pad_lanes
 
         return pad_lanes(jnp.concatenate([c_kv, k_rope], axis=-1), lanes)
 
     def _absorbed_query(self, layer, q_nope, q_rope, lanes):
         """``q~ = [q_nope.W_UK^T | q_rope] / sqrt(qk_head_dim)`` a head,
         zeros up to ``lanes``: ``[B, T, H, lanes]``."""
-        from ..kernels.latent_attention import pad_lanes
+        from ..kernels.paged_attention import pad_lanes
 
         w_uk, _ = self._up_projections(layer)
         with jax.named_scope("attn_absorb"):

@@ -6,8 +6,10 @@
 prefix reuse, layered on models/llama.py PagedKVCache semantics).
 
 The pool owns per-layer (k, v) device buffers of shape
-``[num_blocks, block_size, kv_heads, head_dim]`` (a LATENT layer, one
-array a position with no heads: ``[num_blocks, block_size, lanes]``).  Sequences own
+``[num_blocks, block_size, kv_heads, head_dim]`` (each layer its own:
+a value may be narrower than its key, and a key wider than one 128-lane
+register is stored in whole registers; a LATENT layer, one array a
+position with no heads: ``[num_blocks, block_size, lanes]``).  Sequences own
 BLOCKS, not contiguous buffer ranges: a free-list allocator hands out
 ``block_size``-token blocks one at a time as a sequence's frontier
 grows, so cache capacity is packed at block granularity instead of
@@ -98,6 +100,13 @@ class LayerCache:
     addressed through the FULL group's table whatever the layer's kind,
     so they outlive a window layer's pages.
 
+    ``v_head_dim`` is a K/V record's value width where it is not the
+    key's (``None``: ``head_dim``).  A key wider than one 128-lane
+    register that is not a whole number of them is stored in whole
+    registers, zeros past ``head_dim`` (``key_lanes``): the chip's
+    kernels copy a page only in whole registers, and the device keeps a
+    row in them whatever its width.
+
     ``value_dim`` makes the record a LATENT one (compressed, MLA): the
     layer keeps ONE array a position and no heads, a key of ``head_dim``
     numbers (``kv_heads`` is 1) whose first ``value_dim`` are the value
@@ -114,14 +123,27 @@ class LayerCache:
     window: Optional[int] = None
     sidecars: Tuple = ()
     value_dim: Optional[int] = None
+    v_head_dim: Optional[int] = None
 
     def __post_init__(self):
         if self.value_dim is not None and (
                 self.window is not None or self.kv_heads != 1
-                or not 0 < self.value_dim <= self.head_dim):
+                or not 0 < self.value_dim <= self.head_dim
+                or self.v_head_dim is not None):
             raise ValueError(
                 "a latent record is a full layer of one head-less key "
                 f"whose first value_dim lanes are the value, got {self}")
+
+    @property
+    def value_width(self) -> int:
+        """Numbers a head's value takes (a K/V record)."""
+        return self.head_dim if self.v_head_dim is None else self.v_head_dim
+
+    @property
+    def key_lanes(self) -> int:
+        """Lanes a head's key takes in the pool (a K/V record)."""
+        d, lanes = self.head_dim, 128
+        return d if d <= lanes or d % lanes == 0 else -(-d // lanes) * lanes
 
     @property
     def kind(self) -> str:
@@ -136,25 +158,29 @@ class LayerCache:
 
         return latent_pool_lanes(self.head_dim)
 
+    def page_bytes(self, block_size: int,
+                   kv_cache_dtype: Optional[str] = None) -> int:
+        """Bytes of ONE page of this layer's entries in its own group: K
+        and V as stored (with a quantized pool's scale rows), or a latent
+        record's one array as padded."""
+        if self.value_dim is not None:
+            return block_size * self.latent_lanes \
+                * jnp.dtype(self.dtype).itemsize
+        scheme = resolve_kv_cache_dtype(kv_cache_dtype)
+        return int(block_size * self.kv_heads
+                   * (self.key_lanes + self.value_width)
+                   * kv_bytes_per_element(scheme, self.dtype)
+                   + 2 * kv_scale_bytes_per_block(block_size, scheme))
+
     def block_bytes(self, block_size: int,
                     kv_cache_dtype: Optional[str] = None) -> int:
         """Bytes this layer adds to ONE block of the full group: its
-        entries (K and V with a quantized pool's scale rows; a latent
-        record's one array as padded; a window layer's none, its pages
-        being the window group's) and its sidecars' rows."""
+        entries (a window layer's none, its pages being the window
+        group's: :meth:`page_bytes`) and its sidecars' rows."""
         side = sum(int(np.prod(shape, dtype=np.int64))
                    * jnp.dtype(dt).itemsize for shape, dt in self.sidecars)
-        if self.value_dim is not None:
-            entries = block_size * self.latent_lanes \
-                * jnp.dtype(self.dtype).itemsize
-        elif self.window is not None:
-            entries = 0
-        else:
-            scheme = resolve_kv_cache_dtype(kv_cache_dtype)
-            entries = 2 * (
-                block_size * self.kv_heads * self.head_dim
-                * kv_bytes_per_element(scheme, self.dtype)
-                + kv_scale_bytes_per_block(block_size, scheme))
+        entries = 0 if self.window is not None \
+            else self.page_bytes(block_size, kv_cache_dtype)
         return int(entries + block_size * side)
 
 
@@ -338,8 +364,8 @@ class BlockKVPool(BlockAllocator):
             self.window = BlockAllocator(window_blocks)
         self.num_layers = len(self.layer_caches)
         self.block_size = block_size
-        self.kv_heads = self.layer_caches[0].kv_heads
-        self.head_dim = self.layer_caches[0].head_dim
+        # (each layer's geometry is its own record's; the type is the
+        # model's, one for every layer)
         dtype = self.layer_caches[0].dtype
         #: quant scheme: None (full precision) / "int8" / "fp8"
         self.kv_cache_dtype = resolve_kv_cache_dtype(kv_cache_dtype)
@@ -401,8 +427,8 @@ class BlockKVPool(BlockAllocator):
                 # the value
                 kv = (jnp.zeros(rows + (c.latent_lanes,), store),)
             else:
-                kv = tuple(jnp.zeros(rows + (c.kv_heads, c.head_dim), store)
-                           for _ in range(2))
+                kv = tuple(jnp.zeros(rows + (c.kv_heads, width), store)
+                           for width in (c.key_lanes, c.value_width))
             if self.kv_cache_dtype is not None:
                 kv += tuple(jnp.ones(rows, jnp.float32) for _ in range(2))
             return kv + tuple(
@@ -476,6 +502,15 @@ class BlockKVPool(BlockAllocator):
 
     def capacity_bytes(self) -> int:
         return self.capacity_blocks * self.block_bytes()
+
+    def window_bytes(self) -> int:
+        """HBM bytes of the window group's pages, every window layer's
+        record asked (0 where every layer is full)."""
+        if self.window is None:
+            return 0
+        return self.window.num_blocks * sum(
+            c.page_bytes(self.block_size, self.kv_cache_dtype)
+            for c in self.layer_caches if c.window is not None)
 
     def used_bytes(self) -> int:
         """Bytes referenced by live requests — the quantity degradation
@@ -758,6 +793,7 @@ class BlockKVPool(BlockAllocator):
             "byte_utilization": round(self.byte_utilization(), 4),
             **({} if self.window is None else {
                 "window_capacity_blocks": self.window.capacity_blocks,
+                "window_bytes": self.window_bytes(),
                 "window_used_blocks": self.window.num_used,
                 "window_pages_per_seq": self.window_pages_per_seq}),
         }

@@ -287,9 +287,6 @@ class Engine:
 
             quantize_model_weights(model, cfg.weight_dtype)
         layer_caches = describe_cache(model)
-        kv_heads, head_dim, dtype = (layer_caches[0].kv_heads,
-                                     layer_caches[0].head_dim,
-                                     layer_caches[0].dtype)
         self.block = getattr(model, "block_diffusion", None)
         if self.block is not None:
             self._check_block_model()
@@ -368,7 +365,8 @@ class Engine:
             -(-(self.window + self.chunk_tokens) // cfg.block_size) + 1
         self.pool = BlockKVPool(
             len(layer_caches), self.num_blocks, cfg.block_size,
-            kv_heads, head_dim, dtype,
+            # (every layer's geometry is its own record's)
+            None, None, layer_caches[0].dtype,
             # which layers could reuse a block of a window model is a
             # later PR's: no registration, no match
             enable_prefix_cache=cfg.enable_prefix_cache
@@ -1022,7 +1020,8 @@ class Engine:
         stats."""
         bs = self.config.block_size
         C = self.chunk_tokens
-        self.metrics.on_prefill_dispatch(req.request_id, start, n_tok)
+        self.metrics.on_prefill_dispatch(req.request_id, start, n_tok,
+                                         window=self.window)
         # blocks this chunk writes: CoW any that are shared/registered
         # (a cache hit whose last block the final recompute token lands
         # in, or blocks registered by a previous admission)

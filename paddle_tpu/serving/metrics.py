@@ -290,6 +290,9 @@ class ServingMetrics:
         self.prefill_chunks_run = 0     # raised per chunk dispatched
         self.prefill_context_tokens = 0  # sum of start + tokens per chunk
         self.prefill_attended_pairs = 0  # (query, key) pairs chunks attend
+        # ... of them, those a WINDOW layer's walk attends (each query
+        # sees min(position + 1, window) keys; 0 where every layer is full)
+        self.prefill_window_pairs = 0
         self.decode_context_tokens = 0  # sum of active lengths per step
         self.fetched_bytes = 0          # device results read on the host
         self.admissions = 0             # requests admitted a first time
@@ -314,6 +317,14 @@ class ServingMetrics:
         # fewer: one mean over both says nothing)
         self.experts_read_decode = 0
         self.expert_assignments_decode = 0
+        # the router's (token, expert) choices, and of them those that
+        # land on experts this replica HOLDS (``DroplessMoE(held=)``; the
+        # two are equal where it holds every expert), all runs and the
+        # decode runs apart
+        self.route_picks = 0
+        self.held_picks = 0
+        self.route_picks_decode = 0
+        self.held_picks_decode = 0
         # a model with window layers (serving/cache.py's window group;
         # all stay 0 where every layer is full): pages given back while
         # their request ran, and over the decode iterations the K/V
@@ -429,9 +440,11 @@ class ServingMetrics:
         if behind > self._step_behind:
             self._step_behind = behind
 
-    def on_prefill_dispatch(self, request_id: str, start: int, tokens: int):
+    def on_prefill_dispatch(self, request_id: str, start: int, tokens: int,
+                            window: Optional[int] = None):
         """One chunk of ``tokens`` prompt tokens at positions ``start ..``
-        is about to be dispatched for ``request_id``."""
+        is about to be dispatched for ``request_id`` (``window``: the
+        model's window layers' span in keys)."""
         self.prefill_chunks_run += 1
         # the key positions the chunk kernel's walk covers
         self.prefill_context_tokens += start + tokens
@@ -440,6 +453,15 @@ class ServingMetrics:
         # predecessors inside it
         self.prefill_attended_pairs += tokens * start \
             + tokens * (tokens + 1) // 2
+        if window is not None:
+            # the query at p sees min(p + 1, window) keys: those of the
+            # positions before the chunk's end less those before its start
+            def seen(n):
+                if n <= window:
+                    return n * (n + 1) // 2
+                return window * (window + 1) // 2 + (n - window) * window
+
+            self.prefill_window_pairs += seen(start + tokens) - seen(start)
         t = self.requests[request_id]
         if t.first_chunk_ns == 0:
             t.first_chunk_ns = _now_ns()
@@ -740,15 +762,25 @@ class ServingMetrics:
         self.block_context_tokens += context_tokens
 
     def on_route_stats(self, experts_read: int, assignments: int,
-                       assignments_max: int, decode: bool = False):
+                       assignments_max: int, route_picks: Optional[int] = None,
+                       decode: bool = False):
         """What one step program's routed layers read, from the device
-        (``decode``: the program was a one-token decode run)."""
+        (``decode``: the program was a one-token decode run).
+        ``assignments`` are the choices the replica's held experts
+        computed; ``route_picks`` all the router's choices, where a
+        program counts them apart (``None``: the replica holds every
+        expert, and the two are one count)."""
+        picks = assignments if route_picks is None else route_picks
         self.experts_read += experts_read
         self.expert_assignments += assignments
         self.expert_assignments_max += assignments_max
+        self.route_picks += picks
+        self.held_picks += assignments
         if decode:
             self.experts_read_decode += experts_read
             self.expert_assignments_decode += assignments
+            self.route_picks_decode += picks
+            self.held_picks_decode += assignments
 
     def on_window_iteration(self, window_tokens: int, pages_live: int,
                             running: int):
@@ -789,6 +821,7 @@ class ServingMetrics:
                 "prefill_chunks_run": self.prefill_chunks_run,
                 "prefill_context_tokens": self.prefill_context_tokens,
                 "prefill_attended_pairs": self.prefill_attended_pairs,
+                "prefill_window_pairs": self.prefill_window_pairs,
                 "decode_context_tokens": self.decode_context_tokens,
                 "fetched_bytes": self.fetched_bytes,
                 "prompt_tokens": self._prompt_tokens_sum,
@@ -806,6 +839,10 @@ class ServingMetrics:
                 "expert_assignments_max": self.expert_assignments_max,
                 "experts_read_decode": self.experts_read_decode,
                 "expert_assignments_decode": self.expert_assignments_decode,
+                "route_picks": self.route_picks,
+                "held_picks": self.held_picks,
+                "route_picks_decode": self.route_picks_decode,
+                "held_picks_decode": self.held_picks_decode,
                 "window_pages_released": self.window_pages_released,
                 "decode_window_tokens": self.decode_window_tokens,
                 "window_pages_live": self.window_pages_live,

@@ -404,6 +404,10 @@ class TestServingMirror:
         "prefill_context_tokens",
         # the (query, key) pairs the chunks attend (ISSUE 36)
         "prefill_attended_pairs",
+        # ... those of the window layers, and the router's choices beside
+        # those a replica's held experts computed (ISSUE 41)
+        "prefill_window_pairs", "route_picks", "held_picks",
+        "route_picks_decode", "held_picks_decode",
         # the block iteration and the routed experts (ISSUE 30)
         "block_steps", "block_slot_steps", "commit_slot_steps",
         "tokens_unmasked", "block_context_tokens",

@@ -442,3 +442,101 @@ def test_grouped_experts_at_64_experts_of_width_1536(compile_for_chip,
                             ((SDAR_HIDDEN, 64), bf16), ((64,), f32), w, w, w)
     assert "tpu_custom_call" in text and "moe_grouped_experts" in text
     assert " while(" not in text
+
+
+# ---- the K/V kernels at the widths of the model with window and full
+# layers of different head counts that the benchmark serves: 64 heads,
+# keys of 192 stored in 256 lanes (zeros past 192), values of 128, 64 of
+# 192 lanes turned; full layers of 4 KV heads over the full group's
+# 24,576 blocks, window layers of 8 over the window group's 801 with a
+# window of 128 and a sink a head; 32 slots over a 1,792-page table
+MIMO_HEADS, MIMO_KEY, MIMO_LANES, MIMO_VALUE, MIMO_ROTARY = 64, 192, 256, \
+    128, 64
+MIMO_SLOTS, MIMO_PAGES = 32, 1792
+MIMO_GROUPS = {"full": (4, 24576, None), "window": (8, 801, 128)}
+
+
+def _mimo_pools(group):
+    kvh, blocks, window = MIMO_GROUPS[group]
+    return kvh, window, ((blocks, BLOCK, kvh, MIMO_LANES), bf16), \
+        ((blocks, BLOCK, kvh, MIMO_VALUE), bf16)
+
+
+@pytest.mark.parametrize("group", sorted(MIMO_GROUPS))
+def test_fused_paged_decode_of_keys_wider_than_values(compile_for_chip,
+                                                      group):
+    pa = _kernel("paged_attention")
+    kvh, window, k_pool, v_pool = _mimo_pools(group)
+    rope_table = ((MIMO_PAGES * BLOCK, MIMO_ROTARY // 2), f32)
+    text = compile_for_chip(
+        lambda q, k_new, v_new, kp, vp, table, pos, cos, sin, sink:
+        pa.fused_paged_decode(
+            q, k_new, v_new, kp, vp, table, pos, cos, sin,
+            use_pallas=True, interpret=False, window=window,
+            sink=sink if window else None, rotary=MIMO_ROTARY),
+        ((MIMO_SLOTS, 1, MIMO_HEADS, MIMO_KEY), bf16),
+        ((MIMO_SLOTS, 1, kvh, MIMO_KEY), bf16),
+        ((MIMO_SLOTS, 1, kvh, MIMO_VALUE), bf16), k_pool, v_pool,
+        ((MIMO_SLOTS, MIMO_PAGES), i32), ((MIMO_SLOTS,), i32), rope_table,
+        rope_table, ((MIMO_HEADS,), f32))
+    assert "tpu_custom_call" in text and "fused_paged_decode" in text
+
+
+@pytest.mark.parametrize("group", sorted(MIMO_GROUPS))
+def test_fused_chunked_attention_of_keys_wider_than_values(
+        compile_for_chip, group):
+    cp = _kernel("chunked_prefill")
+    _, window, k_pool, v_pool = _mimo_pools(group)
+    text = compile_for_chip(
+        lambda q, kp, vp, table, pos, sink: cp.fused_chunked_attention(
+            q, kp, vp, table, pos, use_pallas=True, interpret=False,
+            window=window, sink=sink if window else None),
+        ((1, CHUNK, MIMO_HEADS, MIMO_KEY), bf16), k_pool, v_pool,
+        ((1, MIMO_PAGES), i32), ((1,), i32), ((MIMO_HEADS,), f32))
+    assert "tpu_custom_call" in text and "fused_chunked_prefill" in text
+    used = [int(n) for line in text.splitlines()
+            if "tpu_custom_call" in line and "fused_chunked_prefill" in line
+            for n in re.findall(r'"used_scoped_memory_configs":\[\{'
+                                r'"memory_space":"1","offset":"0",'
+                                r'"size":"(\d+)"', line)]
+    assert used and max(used) <= 14 << 20
+
+
+def test_a_key_of_192_lanes_is_refused_by_the_chip_s_compiler(
+        compile_for_chip):
+    """Why the pool stores a key of 192 in 256 lanes: Mosaic copies a
+    page only in whole 128-lane tiles."""
+    pa = _kernel("paged_attention")
+    pool = ((24576, BLOCK, 4, MIMO_KEY), bf16)
+    with pytest.raises(Exception, match="aligned to tiling"):
+        compile_for_chip(
+            lambda q, k_new, v_new, kp, vp, table, pos:
+            pa.fused_paged_decode(
+                q, k_new, v_new, kp, vp, table, pos, None, None,
+                use_pallas=True, interpret=False),
+            ((MIMO_SLOTS, 1, MIMO_HEADS, MIMO_KEY), bf16),
+            ((MIMO_SLOTS, 1, 4, MIMO_KEY), bf16),
+            ((MIMO_SLOTS, 1, 4, MIMO_KEY), bf16), pool, pool,
+            ((MIMO_SLOTS, MIMO_PAGES), i32), ((MIMO_SLOTS,), i32))
+
+
+@pytest.mark.parametrize("tokens", [MIMO_SLOTS, CHUNK],
+                         ids=["decode", "chunk"])
+def test_grouped_experts_holding_16_of_256_experts(compile_for_chip, tokens):
+    """The router over all 256 experts, the matrices of the 16 held here
+    (width 2048 over hidden 4096)."""
+    me = _kernel("moe_experts")
+
+    def experts(x, router, bias, wg, wu, wd):
+        chosen, gates = me.route_topk(x, router, TOP_K, scores="sigmoid",
+                                      bias=bias, scale=1.0, norm_eps=1e-20)
+        out, stats = me.grouped_experts(
+            x, chosen, gates, wg, wu, wd, held=tuple(range(16)),
+            num_experts=256, use_pallas=True, interpret=False)
+        return out, stats.as_vector()
+
+    w = ((16, 2048, HIDDEN), bf16)
+    text = compile_for_chip(experts, ((tokens, HIDDEN), bf16),
+                            ((HIDDEN, 256), bf16), ((256,), f32), w, w, w)
+    assert "tpu_custom_call" in text and "moe_grouped_experts" in text
+    assert " while(" not in text
