@@ -14,29 +14,29 @@ ride in as scalar-prefetch operands.  Sequence ``b`` has
 ``positions[b] // bs + 1`` live pages (keys at ``k_pos <=
 positions[b]``, the new token included; an idle slot has one), and
 nothing past them is read: not the table entry, not the page.  Pages
-are fetched with ``pltpu.make_async_copy``, a COMPUTE BLOCK of ``G =
-max(1, 128 // bs)`` pages at a time, into a double-buffered VMEM
-scratch: one online-softmax update a compute block, the next block's
-copies in flight under it, ``ceil(live_pages / G)`` turns of a
-``fori_loop`` whose bound is read from ``positions``.
+are fetched with ``pltpu.make_async_copy``, a COMPUTE BLOCK at a time
+(``_decode_block_pages``: whole 128-key units of pages, about 1 MiB of
+K and V) into a double-buffered VMEM scratch, and reach the MXU as they
+are stored: a block's ``[G * bs, KVH, D]`` rows are ONE ``[G * bs *
+KVH, D]`` operand in the pool's dtype, every query row against every
+head's keys, its own head's kept by the mask; one online-softmax update
+a block, the next block's copies in flight under it.
 
-Flash-decoding split-K: the table's pages are divided into
-``num_splits`` independent chunks.  Each (batch, split) grid cell
-walks the live pages of its chunk and produces an UNNORMALIZED partial
-— running max ``m``, exp-sum ``l`` and accumulator ``acc`` — and the
-chunks are combined afterwards with the standard log-sum-exp merge.  A
-chunk with no live page emits ``(NEG_INF, 0, 0)``, which the merge
-weighs to zero, so every ``num_splits`` gives the same answer.
+Split-K (``_decode_splits``): on a chip of one TensorCore each sequence
+is ONE grid cell, the cells run in order, and a cell's last block has
+the NEXT sequence's first block in flight under it.  On a chip of
+several cores the table's pages are divided into ``_default_splits``
+independent chunks whose (batch, split) cells the cores share.  A cell
+emits an UNNORMALIZED partial (running max ``m``, exp-sum ``l``,
+accumulator ``acc``), merged after by the log-sum-exp combine; a chunk
+with no live page emits ``(NEG_INF, 0, 0)``, which it weighs to zero.
 
-Numerics contract: ``_xla_partials`` + ``_combine_splits`` is the
-SAME split-K math in plain XLA ops (identical masking semantics, f32
-accumulation, identical combine code object).  On CPU the fused path
-lowers through it, so tier-1 and the jaxpr audits cover the exact
-fused-step math with no pallas_call in the program.  The unfused
-reference (``paged_decode_reference``) reproduces models/llama.py's
-scatter/gather path for parity tests.
-
-``num_splits`` defaults to ``_default_splits`` of the table's depth.
+Numerics contract: ``_xla_partials`` + ``_combine_splits`` is the SAME
+split-K math in plain XLA (identical masking, products in an unquantized
+pool's dtype, f32 accumulation, the same combine); on CPU the fused path
+lowers through it.  ``paged_decode_reference`` reproduces models/llama.py's
+scatter/gather path for parity tests.  A quantized pool's block is
+dequantized to f32 at the DMA boundary and read head by head.
 
 Heads of other shapes, each a static choice that changes nothing at its
 default: a value narrower than the key (the value width is V's own), a
@@ -234,15 +234,15 @@ def _split_walk_refs(rest, kv_dtype):
     return rest[:n], rest[n:-n - 1], rest[-n - 1:-1], rest[-1]
 
 
-def _pool_streams(k_pool, v_pool, k_scale, v_scale, kv_dtype):
+def _pool_streams(k_pool, v_pool, k_scale, v_scale, kv_dtype, G=None):
     """What a walking kernel's ``pallas_call`` needs for its pools:
     ``(in_specs, operands, scratch_shapes)``.  The pools (and a
     quantized pool's scale rows) stay in HBM; each stream has a
     ``[2, G, page]`` VMEM buffer and a DMA semaphore a slot."""
     bs, KVH, D = k_pool.shape[1:]
-    # a compute block is about 128 keys, whatever the pool's page size:
-    # 8 pages at the served ``block_size`` 16
-    G = max(1, _LANES // bs)
+    # ``G`` pages a compute block; by default about 128 keys, whatever the
+    # pool's page size: 8 pages at the served ``block_size`` 16
+    G = G or max(1, _LANES // bs)
     in_specs = [pl.BlockSpec(memory_space=pl.ANY)] * 2
     operands = [k_pool, v_pool]
     scratch = [pltpu.VMEM((2, G, bs, KVH, D), k_pool.dtype),
@@ -274,66 +274,232 @@ def _online_softmax(scores, values, m, l, acc):
     return m_new, l, acc
 
 
+# ---------------------------------------------------------------------------
+# the decode walk: its own block read, block size and split-K width
+# ---------------------------------------------------------------------------
+
+# a compute block's keys and values together, about: the decode walk takes
+# as many whole 128-key units of pages as come nearest to it
+_DECODE_BLOCK_BYTES = 1 << 20
+
+
+def _decode_block_pages(bs, page_bytes, pages):
+    """Pages of one compute block of the decode walk: whole units of
+    ``max(1, 128 // bs)`` pages (128 keys: a lane row of scores), as many
+    as come nearest to ``_DECODE_BLOCK_BYTES`` of K and V at
+    ``page_bytes`` a page of both pools, and no more units than cover
+    ``pages`` (a split's share of the table)."""
+    unit = max(1, _LANES // bs)
+    units = max(1, round(_DECODE_BLOCK_BYTES / (unit * page_bytes)))
+    return unit * min(units, -(-pages // unit))
+
+
+def _tensor_cores():
+    """TensorCores of the device the program runs on: 1 off a TPU."""
+    return getattr(jax.devices()[0], "num_cores", 1)
+
+
+def _decode_splits(nbs, cores):
+    """Split-K width of the decode walk: ONE cell a sequence where the
+    chip has one core (its cells run in order, and a split would only add
+    cells with nothing to walk); the table's split where it has more, its
+    cells shared out among the cores."""
+    return 1 if cores == 1 else _default_splits(nbs)
+
+
+class _DecodeWalk(_PageWalk):
+    """``_PageWalk`` as the decode kernel walks it.  Compute block ``j``
+    sits in slot ``(first_slot + j) % 2``, so a grid cell can start where
+    the cell before it left the double buffer; ``arrive(j, after_last)``
+    calls ``after_last(free_slot)`` at the cell's last block, the other
+    slot free, which is where the next cell's first block is fetched.  An
+    unquantized pool's block reaches the products as it is stored: its
+    pages' ``[bs, KVH, D]`` rows, every head's, as ONE ``[G * bs * KVH,
+    D]`` operand in the pool's dtype (row ``(t, h)`` is key ``t`` of head
+    ``h``).  A quantized pool's is dequantized as ``_PageWalk`` does."""
+
+    def __init__(self, *args, first_slot=0):
+        super().__init__(*args)
+        self.first_slot = first_slot
+
+    def start(self, fetched=False):
+        """The first block's copies, unless the cell before started them."""
+        @pl.when((self.num_blocks > 0) & jnp.logical_not(fetched))
+        def _prologue():
+            self._fetch(0, self.first_slot)
+
+    def fetch_first(self, slot):
+        """Start the first block's copies into ``slot``: the cell before
+        this one's, under its last block."""
+        self._fetch(0, slot)
+
+    def arrive(self, j, after_last=None):
+        slot = jax.lax.rem(self.first_slot + j, 2)
+
+        # the next block's copies fly under this block's arithmetic
+        @pl.when(j + 1 < self.num_blocks)
+        def _prefetch():
+            self._fetch(j + 1, 1 - slot)
+
+        if after_last is not None:
+            @pl.when(j + 1 == self.num_blocks)
+            def _next_cell():
+                after_last(1 - slot)
+
+        self._wait(j, slot)
+        return slot
+
+    def _block(self, buf, scale_buf, slot):
+        if self.kv_dtype is not None:
+            return self._keys_major(buf, scale_buf, slot)
+        return buf[slot].reshape(-1, buf.shape[-1])
+
+    def keys(self, slot):
+        return self._block(self.k_buf, self.ks_buf, slot)
+
+    def values(self, slot):
+        return self._block(self.v_buf, self.vs_buf, slot)
+
+
+def _decode_update(scores, values, carry, dims, precision):
+    """``_online_softmax`` for either product of the decode kernel
+    (``dims`` its dimension numbers), the exponentials meeting the values
+    in the values' dtype."""
+    m, l, acc = carry
+    m_new = jnp.maximum(m, jnp.max(scores, axis=-1, keepdims=True))
+    alpha = jnp.exp(m - m_new)
+    pexp = jnp.exp(scores - m_new)
+    acc = acc * alpha + jax.lax.dot_general(
+        pexp.astype(values.dtype), values, dims, precision=precision,
+        preferred_element_type=jnp.float32)
+    l = l * alpha + jnp.sum(pexp, axis=-1, keepdims=True)
+    return m_new, l, acc
+
+
 def _decode_kernel(bt_ref, pos_ref, q_ref, cos_ref, sin_ref, *rest,
-                   bs, pages_per_split, scale, kv_dtype=None, window=None):
-    hbm_refs, (o_ref, m_out_ref, l_out_ref), bufs, sems = \
-        _split_walk_refs(rest, kv_dtype)
+                   bs, pages_per_split, scale, kv_dtype=None, window=None,
+                   chained=False):
+    hbm_refs, own, bufs, sems = _split_walk_refs(rest, kv_dtype)
+    o_ref, m_out_ref, l_out_ref = own[:3]
     b = pl.program_id(0)
     s = pl.program_id(1)
+    nbs = bt_ref.shape[1]
 
-    # this cell's pages: its split of the table, cut at the sequence's
-    # live pages (keys at k_pos <= pos, the new token included).  The
-    # walk never reads a table entry, or fetches a page, past them.
-    pos = pos_ref[b]
-    live = jnp.minimum(pos // bs + 1, bt_ref.shape[1])
-    split_lo = lo = s * pages_per_split
-    hi = jnp.minimum(lo + pages_per_split, live)
-    if window is not None:
-        # a window layer sees the last ``window`` keys (k_pos > pos -
-        # window): the walk starts at the page of the first of them, and
-        # that page's earlier keys are masked.  No page before it is
-        # read: its table entry may name a page given back long ago
-        first_key = jnp.maximum(pos - window + 1, 0)
-        lo = jnp.maximum(lo, first_key // bs)
-    walk = _PageWalk(bt_ref, b, lo, hi, hbm_refs, bufs, sems, kv_dtype)
+    def cell_pages(b):
+        # sequence b's pages in this split, cut at its live pages (keys at
+        # k_pos <= pos, the new token included): the walk never reads a
+        # table entry, or fetches a page, past them.  A window layer sees
+        # the last ``window`` keys (k_pos > pos - window): the walk starts
+        # at the page of the first of them, and that page's earlier keys
+        # are masked.  No page before it is read: its table entry may name
+        # a page given back long ago
+        pos = pos_ref[b]
+        lo = s * pages_per_split
+        hi = jnp.minimum(lo + pages_per_split,
+                         jnp.minimum(pos // bs + 1, nbs))
+        first_key = None
+        if window is not None:
+            first_key = jnp.maximum(pos - window + 1, 0)
+            lo = jnp.maximum(lo, first_key // bs)
+        return pos, lo, hi, first_key
+
+    def walk_of(b, lo, hi, first_slot=0):
+        return _DecodeWalk(bt_ref, b, lo, hi, hbm_refs, bufs, sems,
+                           kv_dtype, first_slot=first_slot)
+
+    pos, lo, hi, first_key = cell_pages(b)
+    key_limit = jnp.minimum(pos + 1, (s + 1) * pages_per_split * bs)
+    after_last, fetched = None, False
+    if chained:
+        # one cell a sequence, run in order: ``state`` hands the double
+        # buffer on from cell to cell (the slot of the cell's first block;
+        # whether the cell before started its copies)
+        state = own[3]
+
+        @pl.when(b == 0)
+        def _first_cell():
+            state[0] = 0
+            state[1] = 0
+
+        first_slot, fetched = state[0], state[1] == 1
+        state[1] = 0
+        walk = walk_of(b, lo, hi, first_slot)
+
+        def after_last(free_slot):
+            # the next sequence's first block flies under this one's last
+            @pl.when(b + 1 < pl.num_programs(0))
+            def _next_sequence():
+                _, n_lo, n_hi, _ = cell_pages(b + 1)
+                nxt = walk_of(b + 1, n_lo, n_hi)
+
+                @pl.when(nxt.num_blocks > 0)
+                def _fetch_first_block():
+                    nxt.fetch_first(free_slot)
+                    state[1] = 1
+    else:
+        walk = walk_of(b, lo, hi)
     G, num_blocks = walk.G, walk.num_blocks
-    key_limit = jnp.minimum(pos + 1, (split_lo + pages_per_split) * bs)
 
-    # rotate + pre-scale q once per (batch, split) cell: RoPE lives
-    # inside the kernel, and folding 1/sqrt(D) into q here keeps the
-    # score math a bare dot
+    # rotate + pre-scale q once per cell: RoPE lives inside the kernel,
+    # and folding 1/sqrt(D) into q here keeps the score math a bare dot
     q_rot = _rotate_half(q_ref[0].astype(jnp.float32),      # [KVH,rep,D]
                          cos_ref[0].astype(jnp.float32),    # [1, half]
                          sin_ref[0].astype(jnp.float32)) * scale
+    KVH, rep, D = q_rot.shape
+    precision = None
+    if kv_dtype is None:
+        # every head's rows against every head's keys, in the pool's
+        # dtype (a float32 product is one bf16 pass on the chip anyway):
+        # [H, D] x [G*bs*KVH, D], each row's own head kept by the mask.
+        # Operands narrower than float32 are exact in one pass, whatever
+        # precision a caller asks of float32 products
+        q_rot = q_rot.reshape(KVH * rep, D).astype(bufs[0].dtype)
+        if bufs[0].dtype != jnp.float32:
+            precision = jax.lax.Precision.DEFAULT
+        col = jax.lax.broadcasted_iota(jnp.int32,
+                                       (KVH * rep, G * bs * KVH), 1)
+        own_head = col % KVH == jax.lax.broadcasted_iota(
+            jnp.int32, col.shape, 0) // rep
+        col_pos = col // KVH
+        dims = ((((1,), (1,)), ((), ())), (((1,), (0,)), ((), ())))
+    else:
+        col_pos = jax.lax.broadcasted_iota(jnp.int32, (KVH, rep, G * bs), 2)
+        dims = ((((2,), (2,)), ((0,), (0,))), (((2,), (1,)), ((0,), (0,))))
 
-    walk.start()
+    walk.start(fetched)
 
     def compute_block(j, carry):
-        slot = walk.arrive(j)
+        slot = walk.arrive(j, after_last)
         scores = jax.lax.dot_general(
-            q_rot, walk.keys(slot), (((2,), (2,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32)         # [KVH,rep,G*bs]
-        k_pos = (lo + j * G) * bs + jax.lax.broadcasted_iota(
-            jnp.int32, scores.shape, 2)
+            q_rot, walk.keys(slot), dims[0], precision=precision,
+            preferred_element_type=jnp.float32)
+        k_pos = (lo + j * G) * bs + col_pos
         seen = k_pos < key_limit
+        if kv_dtype is None:
+            seen = seen & own_head
         if window is not None:
             seen = seen & (k_pos >= first_key)
         scores = jnp.where(seen, scores, NEG_INF)
-        return _online_softmax(scores, walk.values(slot), *carry)
+        return _decode_update(scores, walk.values(slot), carry, dims[1],
+                              precision)
 
-    KVH, rep, _ = q_rot.shape
+    rows = q_rot.shape[:-1]
     # a split with no live page emits (NEG_INF, 0, 0): the combine
     # weighs it to zero
     m, l, acc = jax.lax.fori_loop(
         0, num_blocks, compute_block,
-        (jnp.full((KVH, rep, 1), NEG_INF, jnp.float32),
-         jnp.zeros((KVH, rep, 1), jnp.float32),
-         jnp.zeros((KVH, rep, o_ref.shape[-1]), jnp.float32)))
-    o_ref[0, 0] = acc
+        (jnp.full(rows + (1,), NEG_INF, jnp.float32),
+         jnp.zeros(rows + (1,), jnp.float32),
+         jnp.zeros(rows + (o_ref.shape[-1],), jnp.float32)))
+    if chained:
+        state[0] = jax.lax.rem(first_slot + num_blocks, 2)
+    o_ref[0, 0] = acc.reshape(o_ref.shape[2:])
     # per-row scalars broadcast over the lane dim (flash kernel lse
     # idiom: a 1-wide trailing dim is not a legal TPU output tile)
-    m_out_ref[0, 0] = jnp.broadcast_to(m, m_out_ref.shape[2:])
-    l_out_ref[0, 0] = jnp.broadcast_to(l, l_out_ref.shape[2:])
+    m_out_ref[0, 0] = jnp.broadcast_to(m.reshape(-1, 1),
+                                       m_out_ref.shape[2:])
+    l_out_ref[0, 0] = jnp.broadcast_to(l.reshape(-1, 1),
+                                       l_out_ref.shape[2:])
 
 
 @functools.partial(jax.jit, static_argnames=("num_splits", "scale",
@@ -351,13 +517,19 @@ def _pallas_partials(q, cos_b, sin_b, k_pool, v_pool, block_table,
     the kernel: ``pallas_call`` traces its kernel at every call, and a
     step program's first call is part of a server's start."""
     B, KVH, rep, D = q.shape
+    H = KVH * rep
     bs = k_pool.shape[1]
     Dv = v_pool.shape[-1]
     nbs = block_table.shape[1]
     P = nbs // num_splits
     half = D // 2
-    pool_specs, pool_operands, scratch = _pool_streams(
-        k_pool, v_pool, k_scale, v_scale, kv_dtype)
+    page_bytes = bs * KVH * (D * k_pool.dtype.itemsize
+                             + Dv * v_pool.dtype.itemsize)
+    pool_specs, pool_operands, pool_scratch = _pool_streams(
+        k_pool, v_pool, k_scale, v_scale, kv_dtype,
+        _decode_block_pages(bs, page_bytes, P))
+    # one split: the cells run in order and hand the double buffer on
+    chained = num_splits == 1
 
     in_specs = [
         pl.BlockSpec((1, KVH, rep, D), lambda b, s, bt, pos: (b, 0, 0, 0)),
@@ -369,41 +541,37 @@ def _pallas_partials(q, cos_b, sin_b, k_pool, v_pool, block_table,
     ]
     operands = [q, cos_b[:, None, :], sin_b[:, None, :], *pool_operands]
 
+    def out(width):
+        return pl.BlockSpec((1, 1, H, width),
+                            lambda b, s, bt, pos: (b, s, 0, 0))
+
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(B, num_splits),
         in_specs=in_specs,
-        out_specs=[
-            pl.BlockSpec((1, 1, KVH, rep, Dv),
-                         lambda b, s, bt, pos: (b, s, 0, 0, 0)),
-            pl.BlockSpec((1, 1, KVH, rep, _LANES),
-                         lambda b, s, bt, pos: (b, s, 0, 0, 0)),
-            pl.BlockSpec((1, 1, KVH, rep, _LANES),
-                         lambda b, s, bt, pos: (b, s, 0, 0, 0)),
-        ],
-        scratch_shapes=scratch,
+        out_specs=[out(Dv), out(_LANES), out(_LANES)],
+        scratch_shapes=([pltpu.SMEM((2,), jnp.int32)] if chained else [])
+        + pool_scratch,
     )
     # priced for the whole table, the worst case: shapes cannot see the
     # lengths that bound the walk
     L = nbs * bs
-    H = KVH * rep
     esize = jnp.dtype(k_pool.dtype).itemsize
     # quantized pools also stream one f32 scale per (pool, token) row
     scale_bytes = 2 * B * L * 4 if kv_dtype is not None else 0
     acc, m_b, l_b = pl.pallas_call(
         functools.partial(_decode_kernel, bs=bs, pages_per_split=P,
-                          scale=scale, kv_dtype=kv_dtype, window=window),
+                          scale=scale, kv_dtype=kv_dtype, window=window,
+                          chained=chained),
         grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct((B, num_splits, KVH, rep, Dv),
-                                 jnp.float32),
-            jax.ShapeDtypeStruct((B, num_splits, KVH, rep, _LANES),
-                                 jnp.float32),
-            jax.ShapeDtypeStruct((B, num_splits, KVH, rep, _LANES),
-                                 jnp.float32),
-        ],
+            jax.ShapeDtypeStruct((B, num_splits, H, width), jnp.float32)
+            for width in (Dv, _LANES, _LANES)],
+        # chained cells must run in order; split cells are independent and
+        # shared out among a chip's cores
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel"))
+            dimension_semantics=("arbitrary",) * 2 if chained
+            else ("parallel",) * 2)
         if not interpret else None,
         cost_estimate=pl.CostEstimate(
             flops=2 * B * H * (D + Dv) * L,
@@ -412,7 +580,9 @@ def _pallas_partials(q, cos_b, sin_b, k_pool, v_pool, block_table,
         interpret=interpret,
         name=KERNEL_NAME,
     )(block_table, positions, *operands)
-    return acc, m_b[..., 0], l_b[..., 0]
+    lead = (B, num_splits, KVH, rep)
+    return (acc.reshape(lead + (Dv,)), m_b[..., 0].reshape(lead),
+            l_b[..., 0].reshape(lead))
 
 
 # ---------------------------------------------------------------------------
@@ -437,8 +607,11 @@ def _xla_partials(q_rot, k_pool, v_pool, block_table, positions,
         vb = decode_codes(v_pool[block_table], kv_dtype) \
             * v_scale[block_table][..., None, None]
     else:
-        kb = k_pool[block_table].astype(jnp.float32)    # [B,nbs,bs,KVH,D]
-        vb = v_pool[block_table].astype(jnp.float32)
+        # the kernel's products take an unquantized pool's dtype: q and
+        # the exponentials are rounded to it, here as there
+        kb = k_pool[block_table]                        # [B,nbs,bs,KVH,D]
+        vb = v_pool[block_table]
+        q_rot = q_rot.astype(kb.dtype)
     kb = kb.reshape(B, num_splits, Lp, kb.shape[3], kb.shape[4])
     vb = vb.reshape(B, num_splits, Lp, vb.shape[3], vb.shape[4])
     scores = jnp.einsum("bkrd,bslkd->bskrl", q_rot, kb,
@@ -453,7 +626,7 @@ def _xla_partials(q_rot, k_pool, v_pool, block_table, positions,
     m = jnp.max(scores, axis=-1)                        # [B,S,KVH,rep]
     pexp = jnp.exp(scores - m[..., None])
     l = jnp.sum(pexp, axis=-1)
-    acc = jnp.einsum("bskrl,bslkd->bskrd", pexp, vb,
+    acc = jnp.einsum("bskrl,bslkd->bskrd", pexp.astype(vb.dtype), vb,
                      preferred_element_type=jnp.float32)
     return acc, m, l
 
@@ -556,7 +729,7 @@ def fused_paged_decode(q, k_new, v_new, k_pool, v_pool, block_table,
 
     use_pallas, interpret = pallas_lowering(use_pallas, interpret)
     if num_splits is None or nbs % num_splits:
-        num_splits = _default_splits(nbs)
+        num_splits = _decode_splits(nbs, _tensor_cores())
 
     # per-sequence RoPE rows + scatter of the rotated new token (tiny:
     # B rows — XLA prologue shared verbatim by both lowerings).  A
@@ -647,7 +820,7 @@ def paged_context_partials(q_rot, k_pool, v_pool, block_table, last_pos,
     scale = 1.0 / math.sqrt(D)
     use_pallas, interpret = pallas_lowering(use_pallas, interpret)
     if num_splits is None or nbs % num_splits:
-        num_splits = _default_splits(nbs)
+        num_splits = _decode_splits(nbs, _tensor_cores())
     if use_pallas:
         return _pallas_partials(
             q_rot, jnp.ones((B, D // 2), jnp.float32),

@@ -634,17 +634,19 @@ def test_chunk_kernel_with_a_window(paged, window, use_pallas):
 # traced before.  A change that is MEANT to alter those programs renews
 # the hashes (the assertion prints them).  ``llama.chunk`` and
 # ``llama.decode`` were renewed when the two programs gained the argmax
-# that chooses the greedy token beside their logits (ISSUE 35); the block
-# model's pair is to the byte what it was: its programs were not touched.
+# that chooses the greedy token beside their logits;
+# ``llama.decode`` and ``sdar.block`` again when the decode walk took one
+# split-K cell a sequence on a device of one core (the CPU among them).
+# The two chunk programs are to the byte what they were.
 STEP_PROGRAM_SHA256 = {
     "llama.chunk":
         "8ad79cca6afccdcd36ecf6ef8de7a2381fce4446b9b795030ad3fa55549ce525",
     "llama.decode":
-        "b1db3eeb575c0aea4b9292232d7b8723f1b04801aceca3688f4bd14972644021",
+        "181f7eaa25e0f70bf9fdf09172e375f4e78e8f407fe5c9fbee40438b9a43db9e",
     "sdar.chunk":
         "3c83e27bbb15e94dda73a11feede617d7e5831daef1a62c3251ae3258327822f",
     "sdar.block":
-        "b40815f815eadb14a8e99fd6df58d442028c8cfec5f45317b778088e3adad82a",
+        "de46134c77ebc3fdcadede4a5fd39270e98302e156645eb4c835712838a495ab",
 }
 
 

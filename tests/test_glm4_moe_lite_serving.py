@@ -492,12 +492,14 @@ def test_latent_kernels_against_plain_attention(dtype, tol, use_pallas):
 # programs, taken on the commit before this model came (the Mistral-shaped
 # and the block model's are ``test_afmoe_serving.STEP_PROGRAM_SHA256``).
 # A change that is MEANT to alter those programs renews the hashes (the
-# assertion prints them).
+# assertion prints them).  ``afmoe.decode`` was renewed when the decode
+# walk took one split-K cell a sequence on a device of one core (the CPU
+# among them).
 AFMOE_PROGRAM_SHA256 = {
     "afmoe.chunk":
         "da4c1754481fdd03e98e251d542587894f7b84e395d64be054cd296bf42629c9",
     "afmoe.decode":
-        "78e0d3a03bfc5e041cf9690b2df1851af30094ca8ccc77e764a2ce8ad7c69b31",
+        "6e39e42f8755a44e45f40a0109632b1a2eddb2614907740a9ad5011ad6998874",
 }
 
 

@@ -540,3 +540,50 @@ def test_grouped_experts_holding_16_of_256_experts(compile_for_chip, tokens):
                             ((HIDDEN, 256), bf16), ((256,), f32), w, w, w)
     assert "tpu_custom_call" in text and "moe_grouped_experts" in text
     assert " while(" not in text
+
+
+# ---- the decode walk as the served cells run it on a chip of one core: ONE
+# cell a sequence (the kernel's partials carry a single split), compute
+# blocks of about 1 MiB of K and V read as they are stored, within the
+# scoped VMEM a kernel may use; Mistral's 8 KV heads over a 256-page table
+# (its quantized pools too) and MiMo's two layer kinds over 1,792 pages
+_WALKS = {
+    "mistral": (KVH, D, D, 2048, 256, None, None, bf16),
+    "mistral-int8": (KVH, D, D, 2048, 256, None, "int8", i8),
+    "mistral-fp8": (KVH, D, D, 2048, 256, None, "fp8", i8),
+    "mimo-full": (4, MIMO_LANES, MIMO_VALUE, 24576, MIMO_PAGES, None, None,
+                  bf16),
+    "mimo-window": (8, MIMO_LANES, MIMO_VALUE, 801, MIMO_PAGES, 128, None,
+                    bf16),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_WALKS))
+def test_the_decode_walk_at_the_served_shapes(compile_for_chip, case):
+    pa = _kernel("paged_attention")
+    kvh, lanes, dv, blocks, pages, window, kv_dtype, pool_dtype = \
+        _WALKS[case]
+    heads = MIMO_HEADS if lanes == MIMO_LANES else H
+    scales = [((blocks, BLOCK), f32)] * 2 if kv_dtype else []
+
+    def partials(q, kp, vp, table, pos, *sc):
+        ks, vs = sc if sc else (None, None)
+        c = jnp.ones((32, lanes // 2), f32)
+        s = jnp.zeros((32, lanes // 2), f32)
+        return pa._pallas_partials(
+            q, c, s, kp, vp, table, pos, 1, 0.1, False, k_scale=ks,
+            v_scale=vs, kv_dtype=kv_dtype, window=window)
+
+    text = compile_for_chip(
+        partials, ((32, kvh, heads // kvh, lanes), bf16),
+        ((blocks, BLOCK, kvh, lanes), pool_dtype),
+        ((blocks, BLOCK, kvh, dv), pool_dtype), ((32, pages), i32),
+        ((32,), i32), *scales)
+    assert "fused_paged_decode" in text
+    assert f"f32[32,1,{heads},{dv}]" in text
+    used = [int(n) for line in text.splitlines()
+            if "tpu_custom_call" in line and "fused_paged_decode" in line
+            for n in re.findall(r'"used_scoped_memory_configs":\[\{'
+                                r'"memory_space":"1","offset":"0",'
+                                r'"size":"(\d+)"', line)]
+    assert used and max(used) <= 8 << 20, used
