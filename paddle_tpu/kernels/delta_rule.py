@@ -27,11 +27,15 @@ G_j)`` for ``i >= j`` ever formed (never a positive exponent),
     O       = (Gamma Q) S + ((Q K^T) * exp(G_i - G_j))_{i >= j} U
     S'      = Gamma_c S + (K * exp(G_c - G))^T U
 
-The triangular system is solved by forward substitution in float32, row
-by row on the vector unit (a lane-masked reduce gives the row's
-coefficients, a sublane reduce the update): with ``beta`` up to 2 the
+The triangular system is solved in float32 blockwise: its diagonal
+blocks by forward substitution on the vector unit, a row of every block
+of every head of a grid step at once, then the blocks doubled by
+products on the MXU (``_solve_unit_lower``).  It is forward
+substitution's arithmetic grouped differently: with ``beta`` up to 2 the
 rows of ``T`` are what the recurrence makes them, and no power of ``L``
-is formed.  Products are in the operands' type with float32
+is formed.  The Pallas kernel takes several heads a grid step
+(``_heads_per_step``, from the shapes and a VMEM budget), so that their
+independent chains overlap.  Products are in the operands' type with float32
 accumulation (float32 operands at full precision, the MXU's multi-pass
 product); the state is float32 throughout.  A position with ``g = 0``
 and ``beta = 0`` leaves the state as it was (the padding of a prompt's
@@ -57,6 +61,7 @@ three inputs lie in one row of the conv window and of its taps.
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -70,6 +75,10 @@ SUB_CHUNK = 64
 L2_EPS = 1e-6
 _NEG = -1e30
 _VMEM_LIMIT = 64 * 1024 * 1024
+# the chunk kernel: VMEM a grid step's heads may fill, and the diagonal
+# blocks its triangular solve substitutes before doubling them by products
+_GROUP_VMEM = 32 * 1024 * 1024
+_SOLVE_BLOCK = 16
 
 
 def l2_normalize(x):
@@ -121,71 +130,114 @@ def delta_rule_reference(q, k, v, g, beta, state0):
 # chunk: Pallas
 # ---------------------------------------------------------------------------
 
-def _solve_unit_lower(lt):
-    """``T = (I + L)^-1`` of each ``[c, c]`` block, given ``lt = L^T``
-    (``lt[.., j, i] = L[i, j]``, zero where ``i <= j``): forward
-    substitution ``T[i] = e_i - sum_{j < i} L[i, j] T[j]``, a row a
-    step, float32."""
-    c = lt.shape[-1]
-    row = jax.lax.broadcasted_iota(jnp.int32, lt.shape, lt.ndim - 2)
-    col = jax.lax.broadcasted_iota(jnp.int32, lt.shape, lt.ndim - 1)
-    eye = jnp.where(row == col, 1.0, 0.0).astype(jnp.float32)
+def _heads_per_step(H, n, c, dk, dv):
+    """Heads a grid step of the chunk kernel: as many as fit
+    ``_GROUP_VMEM`` bytes (their blocks, in lane-padded float32 and
+    double-buffered, and the step's working set), spread evenly over the
+    steps that takes; the last step is padded up to a whole group."""
+    def lanes(x):
+        return -(-x // 128) * 128
 
-    def body(i, t):
-        # the row's coefficients L[i, j] as a column over j
-        coef = jnp.sum(jnp.where(col == i, lt, 0.0), axis=-1,
+    blocks = n * c * (2 * lanes(dk) + 2 * lanes(dv) + 2 * 128) \
+        + n * (dk + 16) * lanes(c) + 2 * dk * lanes(dv)
+    work = n * c * (6 * lanes(c) + lanes(dk) + 2 * lanes(dv))
+    most = max(1, min(H, _GROUP_VMEM // (4 * (2 * blocks + work))))
+    return -(-H // -(-H // most))
+
+
+def _dot(a, b):
+    """``[B, m, k] x [B, k, n]`` with float32 accumulation: float32
+    operands at full precision (the MXU's multi-pass product), narrower
+    ones exact in one pass, whatever precision a caller asks of float32
+    products."""
+    precision = jax.lax.Precision.HIGHEST if a.dtype == jnp.float32 \
+        else jax.lax.Precision.DEFAULT
+    return jax.lax.dot_general(
+        a, b, (((2,), (1,)), ((0,), (0,))),
+        preferred_element_type=jnp.float32, precision=precision)
+
+
+def _solve_unit_lower(l, lt):
+    """``T = (I + L)^-1`` of each ``[c, c]`` matrix of a batch, ``L``
+    strictly lower and ``lt = L^T``, float32.  The diagonal blocks of
+    ``b`` (``_SOLVE_BLOCK``, or what of it divides ``c``) by forward
+    substitution, a row of every block of every matrix a step (``b - 1``
+    steps; the coefficients ``L[r, j]`` of the block's rows ``j`` are a
+    lane-masked reduce of ``lt``, their update a reduce over the block's
+    sublanes); then the blocks doubled by products until they span the
+    matrix: with ``T_m`` the inverse of ``I + L``'s diagonal blocks of
+    ``m`` and ``F`` the lower-left ``m``-block of each ``2m``-block of
+    ``L``, ``T_2m = T_m - T_m (F T_m)``.  The same sums as forward
+    substitution, grouped; no power of ``L`` is formed."""
+    nb, c, _ = l.shape
+    b = math.gcd(c, _SOLVE_BLOCK)
+    row = jax.lax.broadcasted_iota(jnp.int32, l.shape, 1)
+    col = jax.lax.broadcasted_iota(jnp.int32, l.shape, 2)
+    first = row // b * b                    # the first row of row's block
+    t = jnp.where(row == col, 1.0, 0.0).astype(jnp.float32)
+    for s in range(1, b):
+        # each row j's coefficient in the row ``first(j) + s`` of its block
+        coef = jnp.sum(jnp.where(col == first + s, lt, 0.0), axis=-1,
                        keepdims=True)
-        upd = jnp.sum(coef * t, axis=-2, keepdims=True)
-        return jnp.where(row == i, t - upd, t)
-
-    return jax.lax.fori_loop(1, c, body, eye)
+        upd = jnp.sum((coef * t).reshape(nb, c // b, b, c), axis=2,
+                      keepdims=True)
+        upd = jnp.broadcast_to(upd, (nb, c // b, b, c)).reshape(nb, c, c)
+        t = jnp.where(row == first + s, t - upd, t)
+    m = b
+    while m < c:
+        below = (row // m == col // m + 1) & (row // m % 2 == 1)
+        t = t - _dot(t, _dot(jnp.where(below, l, 0.0), t))
+        m *= 2
+    return t
 
 
 def _chunk_kernel(q_ref, k_ref, kt_ref, v_ref, gc_ref, gr_ref, bc_ref,
-                  br_ref, s0_ref, o_ref, s1_ref, *, n, c):
-    """One head: ``n`` sub-chunks of ``c`` positions, in order; products
-    in the operands' type (``mm``), float32 accumulation."""
+                  br_ref, s0_ref, o_ref, s1_ref):
+    """A group of heads: every sub-chunk's triangular system and
+    state-independent products at once, then the ``n`` sub-chunks in
+    order on the group's states.  Products that share an operand are one
+    product of the others stacked by rows (``[K; Q] K^T``, ``[W; Gamma Q]
+    S``, ``[within; K decay^T] U``); in the operands' type (``mm``),
+    float32 accumulation.  Blocks are sub-chunk major, ``[n, G, ...]``."""
     mm, f32 = q_ref.dtype, jnp.float32
-    # float32 operands are multiplied in float32 (the MXU's multi-pass
-    # product); narrower ones are exact in one pass, whatever precision a
-    # caller asks of float32 products
-    precision = jax.lax.Precision.HIGHEST if mm == f32 \
-        else jax.lax.Precision.DEFAULT
+    n, g, c, _ = q_ref.shape
+    nb = n * g
 
-    def dot(a, b):
-        return jnp.dot(a, b, preferred_element_type=f32, precision=precision)
+    def whole(ref):
+        return ref[...].reshape((nb,) + ref.shape[2:])
 
-    row = jax.lax.broadcasted_iota(jnp.int32, (c, c), 0)
-    col = jax.lax.broadcasted_iota(jnp.int32, (c, c), 1)
-    lts = []
+    q, k, kt = whole(q_ref), whole(k_ref), whole(kt_ref)
+    gc, gr, bc, br = whole(gc_ref), whole(gr_ref), whole(bc_ref), \
+        whole(br_ref)
+    row = jax.lax.broadcasted_iota(jnp.int32, (nb, c, c), 1)
+    col = jax.lax.broadcasted_iota(jnp.int32, (nb, c, c), 2)
+    kq = _dot(jnp.concatenate([k, q], axis=1), kt)
+    kk, qk = kq[:, :c], kq[:, c:]           # K K^T is symmetric: L and L^T
+    # L[i, j] = beta_i (k_i . k_j) exp(G_i - G_j), i > j
+    lower = bc * kk * jnp.exp(jnp.where(row > col, gc - gr, _NEG))
+    upper = br * kk * jnp.exp(jnp.where(col > row, gr - gc, _NEG))
+    t = _solve_unit_lower(lower, upper).astype(mm)
+    gamma = jnp.exp(gc)                                  # [nb, c, 1]
+    w = _dot(t, (k.astype(f32) * (bc * gamma)).astype(mm))
+    ut = _dot(t, (whole(v_ref).astype(f32) * bc).astype(mm))
+    within = qk * jnp.exp(jnp.where(row >= col, gc - gr, _NEG))
+    # the sub-chunk's last (and least: G falls) cumulative decay
+    g_end = jnp.min(gc, axis=1, keepdims=True)           # [nb, 1, 1]
+    kd = kt.astype(f32) * jnp.exp(g_end - gr)            # [nb, dk, c]
+    on_state = jnp.concatenate([w, q.astype(f32) * gamma], axis=1)
+    on_delta = jnp.concatenate([within, kd], axis=1)
+
+    def at(x, s):
+        return x.reshape((n, g) + x.shape[1:])[s]
+
+    state = s0_ref[...]                                  # [G, dk, dv]
     for s in range(n):
-        kk = dot(k_ref[0, s], kt_ref[0, s])
-        # L^T[j, i] = beta_i (k_i . k_j) exp(G_i - G_j), i > j
-        back = jnp.where(col > row, gr_ref[0, s] - gc_ref[0, s], _NEG)
-        lts.append(br_ref[0, s] * kk * jnp.exp(back))
-    t_all = _solve_unit_lower(jnp.stack(lts))
-    state = s0_ref[0]
-    for s in range(n):
-        t = t_all[s].astype(mm)
-        gc, gr = gc_ref[0, s], gr_ref[0, s]
-        gamma = jnp.exp(gc)                                  # [c, 1]
-        beta = bc_ref[0, s]                                  # [c, 1]
-        q, k, kt = q_ref[0, s], k_ref[0, s], kt_ref[0, s]
-        bv = (v_ref[0, s].astype(f32) * beta).astype(mm)
-        bk = (k.astype(f32) * (beta * gamma)).astype(mm)
-        sb = state.astype(mm)
-        w = dot(t, bk)
-        u = dot(t, bv) - dot(w.astype(mm), sb)
-        ub = u.astype(mm)
-        qk = dot(q, kt)
-        within = qk * jnp.exp(jnp.where(row >= col, gc - gr, _NEG))
-        o_ref[0, s] = dot((q.astype(f32) * gamma).astype(mm), sb) \
-            + dot(within.astype(mm), ub)
-        # the sub-chunk's last (and least: G falls) cumulative decay
-        g_end = jnp.min(gc)
-        kd = (kt.astype(f32) * jnp.exp(g_end - gr)).astype(mm)
-        state = jnp.exp(g_end) * state + dot(kd, ub)
-    s1_ref[0] = state
+        x = _dot(at(on_state, s).astype(mm), state.astype(mm))
+        u = at(ut, s) - x[:, :c]
+        y = _dot(at(on_delta, s).astype(mm), u.astype(mm))
+        o_ref[s] = x[:, c:] + y[:, :c]
+        state = jnp.exp(at(g_end, s)) * state + y[:, c:]
+    s1_ref[...] = state
 
 
 def _layouts(q, k, v, g, beta, c):
@@ -211,20 +263,28 @@ def _pallas_chunked(q, k, v, g, beta, state0, c, interpret):
     T, H, dk = q.shape
     dv = v.shape[-1]
     n = T // c
-    ops = _layouts(q, k, v, g, beta, c)
+    group = _heads_per_step(H, n, c, dk, dv)
+    pad = -H % group
+    # sub-chunk major, the heads padded to whole groups with heads that
+    # compute nothing (no input, no decay, a zero state)
+    ops = [jnp.pad(x.swapaxes(0, 1), [(0, 0), (0, pad), (0, 0), (0, 0)])
+           for x in _layouts(q, k, v, g, beta, c)]
+    s0 = jnp.pad(state0.astype(jnp.float32), [(0, pad), (0, 0), (0, 0)])
 
     def spec(*tail):
-        return pl.BlockSpec((1,) + tail, lambda h: (h,) + (0,) * len(tail))
+        return pl.BlockSpec((n, group) + tail,
+                            lambda h: (0, h) + (0,) * len(tail))
 
+    state_spec = pl.BlockSpec((group, dk, dv), lambda h: (h, 0, 0))
     o, state1 = pl.pallas_call(
-        functools.partial(_chunk_kernel, n=n, c=c),
-        grid=(H,),
-        in_specs=[spec(n, c, dk), spec(n, c, dk), spec(n, dk, c),
-                  spec(n, c, dv), spec(n, c, 1), spec(n, 1, c),
-                  spec(n, c, 1), spec(n, 1, c), spec(dk, dv)],
-        out_specs=[spec(n, c, dv), spec(dk, dv)],
-        out_shape=[jax.ShapeDtypeStruct((H, n, c, dv), jnp.float32),
-                   jax.ShapeDtypeStruct((H, dk, dv), jnp.float32)],
+        _chunk_kernel,
+        grid=((H + pad) // group,),
+        in_specs=[spec(c, dk), spec(c, dk), spec(dk, c), spec(c, dv),
+                  spec(c, 1), spec(1, c), spec(c, 1), spec(1, c),
+                  state_spec],
+        out_specs=[spec(c, dv), state_spec],
+        out_shape=[jax.ShapeDtypeStruct((n, H + pad, c, dv), jnp.float32),
+                   jax.ShapeDtypeStruct((H + pad, dk, dv), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",),
             vmem_limit_bytes=_VMEM_LIMIT) if not interpret else None,
@@ -235,8 +295,9 @@ def _pallas_chunked(q, k, v, g, beta, state0, c, interpret):
             transcendentals=H * n * c * (2 * c + 2)),
         interpret=interpret,
         name=CHUNK_KERNEL_NAME,
-    )(*ops, state0.astype(jnp.float32))
-    return o.reshape(H, T, dv).transpose(1, 0, 2), state1
+    )(*ops, s0)
+    o = o[:, :H].transpose(0, 2, 1, 3).reshape(T, H, dv)
+    return o, state1[:H]
 
 
 # ---------------------------------------------------------------------------
@@ -297,7 +358,8 @@ def chunked_delta_rule(q, k, v, g, beta, state0, *, sub_chunk=SUB_CHUNK,
     from .fusion import pallas_lowering
 
     T = q.shape[0]
-    c = min(sub_chunk, T)
+    # a short chunk is one sub-chunk of whole sublane tiles
+    c = min(sub_chunk, -(-T // 8) * 8)
     pad = -T % c
     if pad:
         def padded(x):

@@ -1,7 +1,9 @@
 """The gated delta-rule kernels (kernels/delta_rule.py) against the
 per-position recurrence: the chunk kernel (Pallas in interpret mode, and
 its XLA lowering) over chunks that end inside a sub-chunk, betas near 0
-and near 2, decays near 0 and near 1; the one-token decode kernel against
+and near 2, decays near 0 and near 1, and at the served widths over a
+group of heads the grid step does not fill; its blocked triangular solve
+against a float64 inverse; the one-token decode kernel against
 one step of the recurrence, with the rows that are not decoding left
 bit-identical in both pools."""
 import jax.numpy as jnp
@@ -21,15 +23,15 @@ CASES = {
 }
 
 
-def _inputs(seed, T, beta, g, dtype=jnp.float32):
+def _inputs(seed, T, beta, g, dtype=jnp.float32, heads=H, dk=DK, dv=DV):
     rng = np.random.default_rng(seed)
-    q = dr.l2_normalize(jnp.asarray(rng.normal(size=(T, H, DK)))) \
-        * DK ** -0.5
-    k = dr.l2_normalize(jnp.asarray(rng.normal(size=(T, H, DK))))
-    v = jnp.asarray(rng.normal(size=(T, H, DV)), jnp.float32)
-    gs = jnp.asarray(rng.uniform(*g, size=(T, H)), jnp.float32)
-    bs = jnp.asarray(rng.uniform(*beta, size=(T, H)), jnp.float32)
-    s0 = jnp.asarray(rng.normal(size=(H, DK, DV)), jnp.float32)
+    q = dr.l2_normalize(jnp.asarray(rng.normal(size=(T, heads, dk)))) \
+        * dk ** -0.5
+    k = dr.l2_normalize(jnp.asarray(rng.normal(size=(T, heads, dk))))
+    v = jnp.asarray(rng.normal(size=(T, heads, dv)), jnp.float32)
+    gs = jnp.asarray(rng.uniform(*g, size=(T, heads)), jnp.float32)
+    bs = jnp.asarray(rng.uniform(*beta, size=(T, heads)), jnp.float32)
+    s0 = jnp.asarray(rng.normal(size=(heads, dk, dv)), jnp.float32)
     return (q.astype(dtype), k.astype(dtype), v.astype(dtype), gs, bs, s0)
 
 
@@ -44,6 +46,66 @@ def test_the_chunk_kernel_is_the_recurrence(case, pallas):
     o_ref, s_ref = dr.delta_rule_reference(*args)
     o, s = dr.chunked_delta_rule(*args, sub_chunk=32, use_pallas=pallas,
                                  interpret=True)
+    assert o.shape == o_ref.shape and s.shape == s_ref.shape
+    assert _rel(o, o_ref) < 1e-4 and _rel(s, s_ref) < 1e-4
+
+
+def _forward_substitution(lt):
+    """``T = (I + L)^-1`` from ``lt = L^T`` one row a step, float32:
+    the row-by-row solve the blocked one regroups, whose rounding is the
+    yardstick."""
+    c = lt.shape[-1]
+    row = jnp.arange(c)[:, None]
+    col = jnp.arange(c)[None, :]
+    t = jnp.broadcast_to(jnp.eye(c, dtype=jnp.float32), lt.shape)
+    for i in range(1, c):
+        coef = jnp.sum(jnp.where(col == i, lt, 0.0), axis=-1, keepdims=True)
+        upd = jnp.sum(coef * t, axis=-2, keepdims=True)
+        t = jnp.where(row == i, t - upd, t)
+    return t
+
+
+@pytest.mark.parametrize("c", [dr.SUB_CHUNK, 40])
+@pytest.mark.parametrize("case", sorted(CASES))
+def test_the_blocked_solve_rounds_like_forward_substitution(case, c):
+    """Each head's first sub-chunk of ``c`` (a whole number of the
+    solve's blocks, or 40: blocks of 8 whose last doubling is partial):
+    the blocked solve against a float64 inverse, within 4 times the
+    rounding of the row-by-row solve (or of float32's epsilon)."""
+    _, k, _, g, beta, _ = (np.asarray(x) for x in _inputs(1, **CASES[case]))
+    k = k[:c].transpose(1, 0, 2)                         # [H, c, dk]
+    G = np.cumsum(g[:c].T, axis=-1)
+    i = np.arange(c)
+    lower = np.where(
+        i[:, None] > i[None, :],
+        beta[:c].T[:, :, None] * np.einsum("hid,hjd->hij", k, k)
+        * np.exp(np.minimum(G[:, :, None] - G[:, None, :], 0.0)),
+        0.0).astype(np.float32)
+    want = np.linalg.inv(np.eye(c) + lower.astype(np.float64))
+    upper = lower.transpose(0, 2, 1)
+    got = np.asarray(dr._solve_unit_lower(jnp.asarray(lower),
+                                          jnp.asarray(upper)))
+    by_rows = np.asarray(_forward_substitution(jnp.asarray(upper)))
+
+    def err(t):
+        return np.abs(t - want).max() / np.abs(want).max()
+
+    assert err(got) <= 4 * max(err(by_rows), np.finfo(np.float32).eps)
+
+
+@pytest.mark.parametrize("T", [150, 256])
+def test_the_pallas_chunk_kernel_at_served_widths_over_a_partial_group(T):
+    """Keys of 96 and values of 192, and a head count that the kernel's
+    heads a grid step do not divide: the last group is padded with heads
+    that compute nothing."""
+    dk, dv, c = 96, 192, dr.SUB_CHUNK
+    n = -(-T // c)
+    heads = next(h for h in range(2, 64)
+                 if h % dr._heads_per_step(h, n, c, dk, dv))
+    args = _inputs(5, T, beta=(0.0, 2.0), g=(-0.5, 0.0), heads=heads,
+                   dk=dk, dv=dv)
+    o_ref, s_ref = dr.delta_rule_reference(*args)
+    o, s = dr.chunked_delta_rule(*args, use_pallas=True, interpret=True)
     assert o.shape == o_ref.shape and s.shape == s_ref.shape
     assert _rel(o, o_ref) < 1e-4 and _rel(s, s_ref) < 1e-4
 

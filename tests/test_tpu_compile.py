@@ -611,6 +611,26 @@ def test_the_chunked_delta_rule(compile_for_chip):
     assert "tpu_custom_call" in text and "chunked_delta_rule" in text
 
 
+def test_the_chunked_delta_rule_at_another_remainder(compile_for_chip):
+    """32 heads: whichever of the cell's 30 and these the kernel's heads
+    a grid step divide, the other pads its last group, so both paths of
+    the rule compile for the chip."""
+    dr = _kernel("delta_rule")
+    heads, n = 32, CHUNK // dr.SUB_CHUNK
+
+    def rest(h):
+        return h % dr._heads_per_step(h, n, dr.SUB_CHUNK, HYB_DK, HYB_DV)
+
+    assert (rest(heads) == 0) != (rest(HYB_HEADS) == 0)
+    text = compile_for_chip(
+        lambda q, k, v, g, b, s: dr.chunked_delta_rule(
+            q, k, v, g, b, s, use_pallas=True, interpret=False),
+        ((CHUNK, heads, HYB_DK), f32), ((CHUNK, heads, HYB_DK), f32),
+        ((CHUNK, heads, HYB_DV), f32), ((CHUNK, heads), f32),
+        ((CHUNK, heads), f32), ((heads, HYB_DK, HYB_DV), f32))
+    assert "tpu_custom_call" in text and "chunked_delta_rule" in text
+
+
 def test_the_delta_rule_decode_step_in_place(compile_for_chip):
     dr = _kernel("delta_rule")
     width = 2 * HYB_DK + HYB_DV
