@@ -49,6 +49,83 @@ class TestFlashAttention:
         assert out.shape == (1, 1, 37, 16)
 
 
+    # (Tq, Tk, block_q, block_k, causal, q heads, kv heads): blocks that
+    # leave wholly masked pairs both ways round, Tk > Tq with Tk - Tq not a
+    # multiple of block_k, no mask, and GQA through the [B, T, H, D] form
+    PAIR_CASES = {
+        "causal-bq32-bk64": (256, 256, 32, 64, True, 2, 2),
+        "causal-bq64-bk32": (256, 256, 64, 32, True, 2, 2),
+        "causal-tk-over-tq": (96, 128, 32, 64, True, 2, 2),
+        "not-causal": (128, 128, 32, 64, False, 2, 2),
+        "gqa-bthd": (128, 128, 32, 64, True, 4, 2),
+    }
+
+    @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+    @pytest.mark.parametrize("case", sorted(PAIR_CASES))
+    def test_forward_and_grads_match_reference(self, case, dtype):
+        from paddle_tpu.kernels.flash_attention import (
+            _attn_reference, flash_attention_bhtd, flash_attention_bthd)
+
+        Tq, Tk, bq, bk, causal, hq, hk = self.PAIR_CASES[case]
+        D = 32
+        rng = np.random.RandomState(Tq + Tk + bq + bk + hq)
+        q, g = (rng.randn(1, Tq, hq, D) for _ in range(2))
+        k, v = (rng.randn(1, Tk, hk, D) for _ in range(2))
+        dt = jnp.dtype(dtype)
+        q, k, v, g = (jnp.asarray(a, dt) for a in (q, k, v, g))
+
+        def flash(q, k, v):
+            if hq != hk:
+                return flash_attention_bthd(q, k, v, causal=causal,
+                                            block_q=bq, block_k=bk)
+            t = lambda a: jnp.swapaxes(a, 1, 2)  # noqa: E731
+            return t(flash_attention_bhtd(t(q), t(k), t(v), causal=causal,
+                                          block_q=bq, block_k=bk))
+
+        def reference(q, k, v):
+            # float32 whatever the operands' dtype, GQA by repeating heads
+            k, v = (jnp.repeat(a, hq // hk, axis=2) for a in (k, v))
+            t = lambda a: jnp.swapaxes(a.astype(jnp.float32), 1, 2)  # noqa
+            return t(_attn_reference(t(q), t(k), t(v), causal,
+                                     1 / np.sqrt(D)))
+
+        def loss(fn):
+            return lambda *a: (fn(*a).astype(jnp.float32)
+                               * g.astype(jnp.float32)).sum()
+
+        got = [flash(q, k, v)] + list(
+            jax.grad(loss(flash), (0, 1, 2))(q, k, v))
+        want = [reference(q, k, v)] + list(
+            jax.grad(loss(reference), (0, 1, 2))(q, k, v))
+        atol = 2e-5 if dtype == "float32" else 6e-2
+        for a, b, name in zip(got, want, ("out", "dq", "dk", "dv")):
+            assert a.dtype == dt, name
+            np.testing.assert_allclose(
+                np.asarray(a, np.float32), np.asarray(b, np.float32),
+                atol=atol, rtol=atol, err_msg=f"{case} {name}")
+
+    def test_causal_pairs(self):
+        from paddle_tpu.kernels.flash_attention import causal_pairs
+
+        # per q block of 256 the pairs needed are 1, 1, 2, 2, 3, 3, 4, 4
+        assert causal_pairs(2048, 2048, 256, 512, True) == (20, 32)
+        assert causal_pairs(2048, 2048, 256, 512, False) == (32, 32)
+        assert causal_pairs(256, 256, 32, 64, True) == (20, 32)
+        # rows that see no key (Tk < Tq) keep every pair
+        assert causal_pairs(128, 64, 32, 32, True) == (8, 8)
+
+    def test_rows_that_see_no_key_keep_the_reference_output(self):
+        from paddle_tpu.kernels.flash_attention import (
+            _attn_reference, flash_attention_bhtd)
+
+        q, k, v = r(1, 2, 128, 16), r(1, 2, 64, 16), r(1, 2, 64, 16)
+        out = flash_attention_bhtd(q, k, v, causal=True, block_q=32,
+                                   block_k=32)
+        ref = _attn_reference(q, k, v, True, 0.25)
+        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                                   atol=2e-5)
+
+
 class TestRMSNorm:
     def test_matches_reference(self):
         from paddle_tpu.kernels.rms_norm import _rms_ref, rms_norm

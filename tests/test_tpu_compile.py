@@ -82,7 +82,10 @@ def test_flash_attention_forward(compile_for_chip):
 
 
 def test_flash_attention_backward_with_gqa(compile_for_chip):
+    # the training cell's attention (T 2048, 32 heads over 8, head 128) at
+    # the default blocks, which a block too large for scoped VMEM fails here
     fa = _kernel("flash_attention")
+    assert fa.default_blocks(SEQ, SEQ, D) == (1024, 1024)
     kv = ((1, SEQ, KVH, D), bf16)
     text = compile_for_chip(
         _sum_grads(lambda q, k, v: fa.flash_attention_bthd(
@@ -92,6 +95,19 @@ def test_flash_attention_backward_with_gqa(compile_for_chip):
                  "flash_attention_bwd_dkv"):
         assert name in text
     assert "tpu_custom_call" in text
+
+
+def test_flash_attention_backward_wide_head(compile_for_chip):
+    # a head of 256 halves the default blocks: that step must fit too
+    fa = _kernel("flash_attention")
+    assert fa.default_blocks(2 * SEQ, 2 * SEQ, 2 * D) == (512, 512)
+    q = ((1, 2 * SEQ, 4, 2 * D), bf16)
+    text = compile_for_chip(
+        _sum_grads(lambda q, k, v: fa.flash_attention_bthd(
+            q, k, v, causal=True, interpret=False), 3), q, q, q)
+    for name in ("flash_attention_fwd", "flash_attention_bwd_dq",
+                 "flash_attention_bwd_dkv"):
+        assert name in text
 
 
 def test_rms_norm_at_hidden_4096(compile_for_chip):
